@@ -1,35 +1,37 @@
-"""Candidate-recall measurement: approx_max_k vs exact top_k (VERDICT r4
-next #6).
+"""Candidate-recall measurement: approx_max_k vs exact top_k.
 
 ``method="auto"`` serves ``approx`` (``jax.lax.approx_max_k``,
 recall_target=0.95) on TPU, but the CPU lowering of approx_max_k is exact —
-so the 100%-assignment guarantee behind the TPU default has only ever been
-validated on a backend where the reduction is NOT approximate.  This script
-produces the data that validates (or flips) the default on the backend where
-it matters:
+so the 100%-assignment guarantee behind the TPU default can only be
+validated on the TPU.  This script produces the data that validates (or
+flips) the default on the backend where it matters:
 
 - per-pod candidate recall of ``method="approx"`` against ``method="exact"``
   at 2,048 pods x 10,240 nodes (same k, same stratified spread_bits);
 - solve quality (assigned fraction + mean chosen node score) for both
   methods at that shape;
-- assigned fraction at the 50k x 10,240 north-star shape for approx and
-  chunked (exact too when the backend has the memory for the (P, N)
-  materialization — guarded, skipped on OOM).
+- assigned fraction at the 50k x 10,240 north-star shape for every
+  candidate method.
 
 Decision rule recorded alongside the data: if at-shape
-``assigned_frac_approx`` < 0.99 on TPU, flip ``batch_assign``'s
-``method="auto"`` TPU arm to "chunked"-with-exact-reduction or "exact"
-(ops/batch_assign.py:284) and re-measure.
+``assigned_frac_approx`` < 0.99 on TPU, flip the TPU arm of
+``ops/batch_assign.resolve_candidate_method`` to "chunked_exact" and
+re-measure.
 
-Prints ONE JSON line.  Env knobs KOORD_RECALL_NODES / KOORD_RECALL_PODS /
-KOORD_RECALL_SHAPE_PODS shrink the shapes for CI smoke (the at-shape leg is
-skipped when KOORD_RECALL_SHAPE_PODS=0).
+Prints ONE JSON line stamped with the platform.  The platform must be a TPU
+and any failed leg fails the run; ``--smoke`` is the explicit ask to run on
+whatever backend JAX has (CI: the CPU, where the recall figure is that of
+an exact lowering and says nothing about the default), and prefixes every
+wall-clock field ``smoke_``.  Env knobs KOORD_RECALL_NODES /
+KOORD_RECALL_PODS / KOORD_RECALL_SHAPE_PODS shrink the shapes for it (the
+at-shape leg is skipped when KOORD_RECALL_SHAPE_PODS=0).
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 
 import jax
@@ -90,10 +92,8 @@ def _recall_leg(n_nodes: int, n_pods: int, out: dict) -> None:
 
 def _quality_leg(n_nodes: int, n_pods: int, out: dict) -> None:
     """quality_lp vs greedy (ISSUE 13): assigned fraction, per-dim
-    capacity slack after the solve, and wall time for both engines at
-    one shape — the comparison that used to live in the root-level
-    scratch_quality.py / scratch_score_quality.py experiments, promoted
-    here with slack and provenance attached.  Plus the topo-gang leg:
+    capacity slack after the solve, and compile-plus-first-run wall for
+    both engines at one shape.  Plus the topo-gang leg:
     realized plan diameter of the baseline vs the quality planner on a
     seeded 2x2x2 topology."""
     import numpy as np
@@ -177,29 +177,30 @@ def _at_shape_leg(n_nodes: int, n_pods: int, out: dict) -> None:
     state, pods, cfg = _build_problem(n_nodes, n_pods, seed=42)
     valid = float(np.asarray(pods.valid).sum())
     solve = jax.jit(batch_assign, static_argnames=("k", "method"))
-    # exact last: it is the one that can OOM (full (P, N) materialization)
     for method in ("approx", "chunked", "chunked_exact", "exact"):
-        try:
-            t0 = time.perf_counter()
-            asn, _, _ = solve(state, pods, cfg, k=K, method=method)
-            frac = float((np.asarray(asn) >= 0).sum()) / valid
-            out[f"shape_assigned_frac_{method}_{n_pods}p_{n_nodes}n"] = (
-                round(frac, 4))
-            out[f"shape_wall_s_{method}_{n_pods}p_{n_nodes}n"] = round(
-                time.perf_counter() - t0, 1)
-        except Exception as e:
-            out[f"shape_{method}_error"] = repr(e)[:200]
+        t0 = time.perf_counter()
+        asn, _, _ = solve(state, pods, cfg, k=K, method=method)
+        frac = float((np.asarray(asn) >= 0).sum()) / valid
+        out[f"shape_assigned_frac_{method}_{n_pods}p_{n_nodes}n"] = (
+            round(frac, 4))
+        # compile + first run: a set-up read-out, not a solve time
+        out[f"shape_wall_s_{method}_{n_pods}p_{n_nodes}n"] = round(
+            time.perf_counter() - t0, 1)
 
 
 def main() -> None:
-    from bench import _git_head
+    from bench import _git_head, require_tpu
+    from koordinator_tpu.compile_cache import enable_compile_cache
 
+    smoke = "--smoke" in sys.argv
+    enable_compile_cache()
+    device = require_tpu(allow_cpu=smoke)
     n_nodes = int(os.environ.get("KOORD_RECALL_NODES", "10240"))
     n_pods = int(os.environ.get("KOORD_RECALL_PODS", "2048"))
     shape_pods = int(os.environ.get("KOORD_RECALL_SHAPE_PODS", "50000"))
 
     out: dict = {
-        "backend": jax.default_backend(),
+        **device,
         "provenance": _git_head(),
         "k": K,
         "note": "approx_max_k recall vs exact top_k; CPU lowering of "
@@ -217,10 +218,11 @@ def main() -> None:
         _quality_leg(n_nodes, n_pods, out)
     if shape_pods:
         _at_shape_leg(n_nodes, shape_pods, out)
+    if smoke:
+        out = {(f"smoke_{k}" if "wall_s" in k else k): v
+               for k, v in out.items()}
     print(json.dumps(out))
 
 
 if __name__ == "__main__":
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     main()

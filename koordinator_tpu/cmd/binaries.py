@@ -721,6 +721,11 @@ def main_koord_scheduler(argv: list[str],
 
     args = build_scheduler_parser().parse_args(argv)
     apply_feature_gates(args.feature_gates, SCHEDULER_GATES)
+    # before the first jit: a restarted scheduler finds the programs its
+    # predecessor compiled instead of paying every cold solve again
+    from koordinator_tpu.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.no_timeline:
         from koordinator_tpu import timeline
 

@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
@@ -132,7 +131,7 @@ def sharded_predicted_peaks(
             cpu_buckets=cpu_buckets, mem_buckets=mem_buckets,
             safety_margin_pct=safety_margin_pct)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(NODES_AXIS), P(NODES_AXIS), P(NODES_AXIS),
                   P(NODES_AXIS), P(), P()),

@@ -8,10 +8,11 @@ info JSON files from a directory.
 
 TPU-native redesign: the accelerator collector is provider-based — the
 default :class:`SysfsAcceleratorProvider` reads an ``accel`` class directory
-of the (relocatable) sysfs root, and :class:`JaxDeviceProvider` enumerates
-the JAX runtime's devices (the TPU path: device kind, core count, HBM from
-``memory_stats`` when the backend exposes them).  Collectors stay pure-host
-I/O; tests run them against the fake filesystem like every other collector.
+of the (relocatable) sysfs root.  Collectors stay pure-host I/O — in
+particular the koordlet never asks the JAX runtime for its devices: a chip
+belongs to one process, and on the scheduler's host that process is the
+scheduler sidecar.  Tests run the collectors against the fake filesystem
+like every other collector.
 """
 
 from __future__ import annotations
@@ -76,37 +77,6 @@ class SysfsAcceleratorProvider:
                 numa_node=int(self._read(dev, "numa_node", "-1")),
                 busid=self._read(dev, "busid", ""),
                 health=self._read(dev, "health", "1") == "1",
-            ))
-        return out
-
-
-class JaxDeviceProvider:
-    """Enumerates the JAX runtime's accelerators (the TPU-native path)."""
-
-    def available(self) -> bool:
-        try:
-            import jax
-
-            return len(jax.devices()) > 0
-        except Exception:
-            return False
-
-    def sample(self) -> list[AccelSample]:
-        import jax
-
-        out = []
-        for d in jax.devices():
-            stats = {}
-            try:
-                stats = d.memory_stats() or {}
-            except Exception:
-                pass
-            out.append(AccelSample(
-                uuid=f"{d.platform}-{d.id}",
-                minor=d.id,
-                type=d.platform,  # "tpu" / "gpu"
-                mem_used_bytes=int(stats.get("bytes_in_use", 0)),
-                mem_total_bytes=int(stats.get("bytes_limit", 0)),
             ))
         return out
 

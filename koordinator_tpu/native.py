@@ -2,14 +2,18 @@
 fast path for batched cgroup reads and perf-counter CPI, mirroring the
 reference's cgo touchpoints (libpfm perf groups, NVML).
 
-Loading order: prebuilt ``native/build/libkoordsys.so`` -> on-demand g++
-build into that location -> pure-Python fallback (``available() == False``;
-every caller has one). The build happens at most once per process.
+Loading order: the library this process's ``native/koordsys.cpp`` builds
+(``native/build/libkoordsys-<source hash>.so``) -> on-demand g++ build into
+that location -> pure-Python fallback (``available() == False``; every
+caller has one). The build happens at most once per process.  The source
+hash in the name means a stale or foreign ``.so`` lying in the (git-ignored)
+build directory is never loaded: it is simply not the file looked for.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,9 +22,21 @@ from typing import Optional, Sequence
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO_ROOT, "native", "koordsys.cpp")
 _LIB_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_LIB_DIR, "libkoordsys.so")
 
-#: expected ks_version(); a stale prebuilt .so triggers one rebuild
+
+def _lib_path() -> str:
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        digest = "nosource"     # _build() reports the missing source
+    return os.path.join(_LIB_DIR, f"libkoordsys-{digest}.so")
+
+
+_LIB = _lib_path()
+
+#: expected ks_version(); a source whose version disagrees is a bug, and
+#: its library is not used
 KS_VERSION = 2
 
 _lock = threading.Lock()
@@ -36,11 +52,15 @@ def _build() -> bool:
     if not os.path.exists(_SRC):
         return False
     os.makedirs(_LIB_DIR, exist_ok=True)
+    # build beside the target and rename into place: another process
+    # (tests spawn several) must never dlopen a half-written library
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-Wall", "-shared", "-fPIC", "-o", _LIB, _SRC],
+            ["g++", "-O2", "-Wall", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, _LIB)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -94,24 +114,7 @@ def _load_blocking() -> Optional[ctypes.CDLL]:
             return None
         lib.ks_version.restype = ctypes.c_int
         if lib.ks_version() != KS_VERSION:
-            # stale prebuilt .so from an older source: UNLINK before
-            # rebuilding — g++ would otherwise truncate the still-mmapped
-            # file under the live handle (UB), and dlopen dedupes by
-            # (dev, inode) so only a fresh inode yields a fresh handle
-            # (the stale handle itself is leaked, which is harmless)
-            try:
-                os.unlink(_LIB)
-            except OSError:
-                return None
-            if not _build():
-                return None
-            try:
-                lib = ctypes.CDLL(_LIB)
-            except OSError:
-                return None
-            lib.ks_version.restype = ctypes.c_int
-            if lib.ks_version() != KS_VERSION:
-                return None
+            return None
         lib.ks_batch_read.restype = ctypes.c_int
         lib.ks_batch_read.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_char_p,

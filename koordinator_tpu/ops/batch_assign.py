@@ -356,13 +356,22 @@ class _RoundCarry:
 #:             recall strands pods (bench_recall.py's decision rule):
 #:             the only other recall-exact option materializes (P, N)
 #: - "auto":   "approx" on TPU, "exact" elsewhere
-#:
-#: (a Pallas streaming kernel ("fused") lived here through round 5 —
-#: deleted per the round-4 verdict after four rounds with no TPU time to
-#: compile it; the chunked paths already avoid the (P, N) HBM
-#: materialization with zero compile risk.  git history has the kernel.)
+#:             (``resolve_candidate_method``, the rule's one home)
 CANDIDATE_METHODS = ("auto", "exact", "approx", "chunked",
                      "chunked_exact")
+
+
+def resolve_candidate_method(method: str) -> str:
+    """The one home of the ``"auto"`` rule: the concrete candidate method
+    a requested one runs as on this process's backend.  The scheduler's
+    incremental path and the tenant-axis program key their caches on the
+    resolved name, so they resolve through here too."""
+    if method not in CANDIDATE_METHODS:
+        raise ValueError(f"unknown candidate method {method!r}; "
+                         f"one of {CANDIDATE_METHODS}")
+    if method == "auto":
+        return "approx" if jax.default_backend() == "tpu" else "exact"
+    return method
 
 
 def batch_assign(
@@ -387,8 +396,8 @@ def batch_assign(
     greedy's mean chosen score at 2k nodes x 10k pods) and a pure-rotation
     coverage stratum, because a single sb=5 key strands 14% of a fully
     schedulable 50k-pod queue at 10,240 nodes once the top score band
-    fills (see PERF_NOTES.md round-3 sweeps: sb=5 86.4% assigned,
-    stratified and deep-spread variants 100%).
+    fills (docs/solve_quality.md "Stratified candidates at shape": sb=5
+    86.4% assigned, stratified and deep-spread variants 100%).
 
     ``method`` picks the candidate-selection strategy (CANDIDATE_METHODS);
     every method is force-selectable on every backend so CI can cover the
@@ -432,11 +441,7 @@ def select_candidates(
     clipped composite scores, (P, k) int32 with -1 for invalid slots —
     the persistent form the incremental candidate cache needs to
     recompute any stratum's ranking key without a full rescore."""
-    if method not in CANDIDATE_METHODS:
-        raise ValueError(f"unknown candidate method {method!r}; "
-                         f"one of {CANDIDATE_METHODS}")
-    if method == "auto":
-        method = "approx" if jax.default_backend() == "tpu" else "exact"
+    method = resolve_candidate_method(method)
     strata = (spread_bits if isinstance(spread_bits, (tuple, list))
               else (spread_bits,))
     if method in ("chunked", "chunked_exact"):

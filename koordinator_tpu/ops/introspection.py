@@ -53,31 +53,29 @@ class InstrumentedJit:
     the miss is attributed to the caller-derived shape bucket.
 
     The wrapper is pass-through — donation, static args, and outputs
-    behave exactly as on the wrapped function.  When the runtime does
-    not expose a cache-size probe the wrapper degrades to a plain
-    forward (counting nothing, costing one attribute check).
+    behave exactly as on the wrapped function.  A function without the
+    jit cache-size probe is refused: a wrapper that counted nothing
+    would report "no recompiles" for a solver nobody is watching.
     """
 
     def __init__(self, fn, name: str, shape_of=None):
+        probe = getattr(fn, "_cache_size", None)
+        if probe is None:
+            raise TypeError(
+                f"instrument({name!r}): {fn!r} exposes no _cache_size "
+                f"probe (not a jax.jit function?); its recompiles "
+                f"cannot be counted")
         self.fn = fn
         self.name = name
         self.shape_of = shape_of or default_shape_of
-        self._probe = getattr(fn, "_cache_size", None)
+        self._cache_size = probe
         self.misses = 0
-
-    def _cache_size(self) -> int | None:
-        if self._probe is None:
-            return None
-        try:
-            return int(self._probe())
-        except Exception:  # noqa: BLE001 — probe is best-effort
-            return None
 
     def __call__(self, *args, **kwargs):
         before = self._cache_size()
         out = self.fn(*args, **kwargs)
         after = self._cache_size()
-        if before is not None and after is not None and after > before:
+        if after > before:
             try:
                 shape = self.shape_of(args, kwargs)
             except Exception:  # noqa: BLE001 — labeling must not fail a solve

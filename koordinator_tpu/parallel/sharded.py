@@ -80,7 +80,6 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from koordinator_tpu.ops import batch_assign as ba
@@ -214,10 +213,10 @@ def _select_program(mesh, n_total, k, strata):
     entry (2-D shapes hash by their device GRID, so 2x4 and 1x8 are
     distinct entries), and the kit's outer jit composes (nested jit
     inlines)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_local_select_body, k=k, strata=strata, n_total=n_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP),
-        out_specs=(_PODS, _PODS, _PODS), check_rep=False))
+        out_specs=(_PODS, _PODS, _PODS), check_vma=False))
 
 
 def sharded_select_candidates(mesh, state, pods, cfg, k: int = 32,
@@ -341,10 +340,10 @@ def _rounds_body(st_local, pods, quota, cand_key, cand_node, *,
 @lru_cache(maxsize=None)
 def _rounds_program(mesh, n_total, rounds):
     """Jitted shard_map rounds program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_rounds_body, rounds=rounds, n_total=n_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _PODS, _PODS),
-        out_specs=(_REP, _NODES, _REP), check_rep=False))
+        out_specs=(_REP, _NODES, _REP), check_vma=False))
 
 
 def sharded_assign_rounds(mesh, state, pods, quota, cand_key, cand_node,
@@ -387,10 +386,10 @@ def _round_pass_body(st_local, pods, quota, cand_key, cand_node, cfg, *,
 @lru_cache(maxsize=None)
 def _round_pass_program(mesh, n_total, rounds):
     """Jitted shard_map pass-1 program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_round_pass_body, rounds=rounds, n_total=n_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _PODS, _PODS, _REP),
-        out_specs=(_REP, _NODES, _REP, _NODES), check_rep=False))
+        out_specs=(_REP, _NODES, _REP, _NODES), check_vma=False))
 
 
 def sharded_assign_round_pass(mesh, state, pods, quota, cand_key,
@@ -444,11 +443,11 @@ def _followup_body(st_local, est_local, pods, quota, cfg, *,
 @lru_cache(maxsize=None)
 def _followup_program(mesh, n_total, k, strata, rounds):
     """Jitted shard_map follow-up program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_followup_body, k=k, strata=strata,
                 rounds=rounds, n_total=n_total),
         mesh=mesh, in_specs=(_NODES, _NODES, _PODS, _REP, _REP),
-        out_specs=(_REP, _NODES, _REP, _NODES), check_rep=False))
+        out_specs=(_REP, _NODES, _REP, _NODES), check_vma=False))
 
 
 def sharded_assign_followup_pass(mesh, state, est_accum, pods, quota, cfg,
@@ -538,10 +537,10 @@ def _refresh_body(st_local, pods, cfg, cache, dirty_rows, dirty_valid, *,
 @lru_cache(maxsize=None)
 def _refresh_program(mesh, n_total, k, strata):
     """Jitted shard_map refresh program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_refresh_body, k=k, strata=strata, n_total=n_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _PODS, _REP, _REP),
-        out_specs=(_PODS, _PODS), check_rep=False))
+        out_specs=(_PODS, _PODS), check_vma=False))
 
 
 def sharded_refresh_candidates(mesh, state, pods, cfg, cache, dirty_rows,
@@ -739,12 +738,12 @@ def _gang_body(st_local, pods, cfg, gangs, quota, *, passes, solver,
 def _gang_program(mesh, n_total, p_total, passes, solver, k, strata,
                   rounds):
     """Jitted shard_map gang program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_gang_body, passes=passes, solver=solver, k=k,
                 strata=strata, rounds=rounds, n_total=n_total,
                 p_total=p_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _REP, _REP),
-        out_specs=(_REP, _NODES, _REP), check_rep=False))
+        out_specs=(_REP, _NODES, _REP), check_vma=False))
 
 
 def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
@@ -831,10 +830,10 @@ def _greedy_body(st_local, pods, cfg, quota):
 @lru_cache(maxsize=None)
 def _greedy_program(mesh, n_total):
     """Jitted shard_map greedy program (see :func:`_select_program`)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _greedy_body,
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _REP),
-        out_specs=(_REP, _NODES, _REP), check_rep=False))
+        out_specs=(_REP, _NODES, _REP), check_vma=False))
 
 
 # ---------------------------------------------------------------------------
@@ -881,12 +880,12 @@ def _lp_pack_program(mesh, n_total, ascent_iters, rounding_iters):
     equal meshes built by different ``solver_mesh`` calls share the
     entry; the kit's own jit wrapper composes fine on top (nested jit
     inlines)."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(_lp_pack_body, n_total=n_total,
                 ascent_iters=ascent_iters,
                 rounding_iters=rounding_iters),
         mesh=mesh, in_specs=(_NODES, _REP, _REP, _REP),
-        out_specs=(_REP, _NODES, _REP, _REP), check_rep=False))
+        out_specs=(_REP, _NODES, _REP, _REP), check_vma=False))
 
 
 def sharded_lp_pack_assign(mesh, state, pods, cfg, quota=None,

@@ -2351,9 +2351,7 @@ class Scheduler:
         snap = self.snapshot
         n = snap.capacity
         k = min(self.cand_k, n)
-        method = self.cand_method
-        if method == "auto":
-            method = "approx" if jax.default_backend() == "tpu" else "exact"
+        method = ba.resolve_candidate_method(self.cand_method)
         # sharded-by-default: when the solver mesh is active for this
         # capacity, selection/refresh/passes run the shard_map entries
         # (recall-exact selection; bit-identical acceptance) and the

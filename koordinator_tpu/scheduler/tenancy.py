@@ -697,10 +697,7 @@ class TenantScheduler:
         n = sched0.snapshot.capacity
         k = min(sched0.cand_k, n)
         spread = sched0.cand_spread
-        method = sched0.cand_method
-        if method == "auto":
-            method = ("approx" if jax.default_backend() == "tpu"
-                      else "exact")
+        method = ba.resolve_candidate_method(sched0.cand_method)
         rounds = sched0.solve_rounds
         cfg = sched0.config
         fn = self._batched_fn((k, spread, method, rounds, has_quota))

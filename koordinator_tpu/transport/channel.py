@@ -535,7 +535,15 @@ class RpcServer:
 
 class RpcClient:
     """Blocking request/response client. Unsolicited (request_id 0) frames
-    are delivered to ``on_push`` — the watch stream."""
+    are delivered to ``on_push`` — the watch stream.
+
+    ``timeout`` bounds every call's wait for its response.  The default
+    suits state pushes and warm solves; it does NOT cover a solve that
+    compiles: the first ``SOLVE_REQUEST`` of each shape bucket compiles
+    in-line on the server (tens of seconds at 50,000 pods x 10,240 nodes
+    on a v5e, ``PERF.md``), so a client that drives cold rounds passes a
+    timeout that covers compilation — a caller that gives up early gets
+    ``RpcError("rpc timeout")`` while the server is still solving."""
 
     def __init__(self, path: str, on_push=None, timeout: float = 10.0,
                  faults=None, fault_domain: str = ""):

@@ -177,6 +177,19 @@ class TestExitCodes:
         assert bench_diff.main([b, empty]) == 2
         assert bench_diff.main([str(tmp_path / "absent.jsonl"), b]) == 2
 
+    def test_smoke_capture_never_diffs_against_a_device_capture(
+            self, tmp_path, capsys):
+        """--smoke timings ride their own key; they fold into the same
+        comparison among themselves and are refused across kinds."""
+        smoke = [{("smoke_ms_per_iter" if k == "ms_per_iter" else k): v
+                  for k, v in rec.items()} for rec in BASE]
+        s = _write(tmp_path / "s.jsonl", smoke)
+        b = _write(tmp_path / "b.jsonl", BASE)
+        assert bench_diff.load_stages(s)["score"]["ms_per_iter"] == 2.0
+        assert bench_diff.main([s, s]) == 0
+        assert bench_diff.main([b, s]) == 2
+        assert bench_diff.main([s, b]) == 2
+
     def test_report_rows_are_json_lines(self, tmp_path, capsys):
         b = _write(tmp_path / "b.jsonl", BASE)
         assert bench_diff.main([b, b]) == 0
@@ -209,8 +222,9 @@ class TestCommittedBaseline:
         stages = bench_diff.load_stages(self.BASELINE)
         assert stages, "committed baseline has no timed stages"
         for name, rec in stages.items():
-            if "error" not in rec:
+            if "skipped" not in rec:
                 assert rec["ms_per_iter"] > 0, name
+        assert bench_diff.is_smoke(stages)
 
     def test_baseline_self_diff_passes(self):
         assert bench_diff.main([self.BASELINE, self.BASELINE]) == 0
@@ -233,8 +247,8 @@ class TestCommittedBaseline:
             for line in fh:
                 rec = json.loads(line)
                 if rec.get("stage") == "wire_codec_v1_vs_v2":
-                    rec["ms_per_iter"] = round(
-                        rec["ms_per_iter"] * 10 + 1.0, 2)
+                    rec["smoke_ms_per_iter"] = round(
+                        rec["smoke_ms_per_iter"] * 10 + 1.0, 2)
                 slowed.append(rec)
         c = _write(tmp_path / "cand.jsonl", slowed)
         assert bench_diff.main([self.BASELINE, c]) == 1
@@ -269,8 +283,8 @@ class TestCommittedBaseline:
             for line in fh:
                 rec = json.loads(line)
                 if rec.get("stage") == "journey_ledger_overhead":
-                    rec["ms_per_iter"] = round(
-                        rec["ms_per_iter"] * 10 + 1.0, 2)
+                    rec["smoke_ms_per_iter"] = round(
+                        rec["smoke_ms_per_iter"] * 10 + 1.0, 2)
                 slowed.append(rec)
         c = _write(tmp_path / "cand.jsonl", slowed)
         assert bench_diff.main([self.BASELINE, c]) == 1

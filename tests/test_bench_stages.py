@@ -1,8 +1,7 @@
-"""bench_stages.py (the stage-split profiler) must keep working: its
-predecessor lived in /tmp as scratch_timing.py and rotted away between
-sessions, losing the round-3 stage-split capture recipe.  Run it as a
-subprocess at a tiny shape and assert every stage emits a record —
-exactly how the prober (tools/tpu_probe.sh) invokes it on hardware."""
+"""bench_stages.py (the stage-split profiler) must keep working: run it
+as a subprocess at a tiny shape with the explicit ``--smoke`` flag and
+assert every stage emits a record, keyed so a CPU timing can never pass
+for the device metric."""
 
 import json
 import os
@@ -48,7 +47,11 @@ def test_stage_profiler_smoke():
                  "deltasync_apply_batched", "bind_commit_batched",
                  "tenancy_serial",
                  "tenancy_pipelined", "tenancy_batched"):
-        assert by_stage[name]["ms_per_iter"] > 0, by_stage[name]
+        assert by_stage[name]["smoke_ms_per_iter"] > 0, by_stage[name]
+        # a smoke timing never rides the device metric's key, and every
+        # record names the platform it ran on
+        assert "ms_per_iter" not in by_stage[name]
+        assert by_stage[name]["platform"] == "cpu"
     # the host-plane turbo stages (ISSUE 19) record the legacy path
     # beside the batched one so bench_diff guards both inputs of the
     # speedup ratio
@@ -67,8 +70,10 @@ def test_stage_profiler_smoke():
     assert by_stage["tenancy_serial"]["device_idle_fraction"] is not None
     assert by_stage["tenancy_pipelined"]["speedup_vs_serial"] is not None
     assert by_stage["tenancy_pipelined"]["device_idle_fraction"] is not None
-    # the stage capture stamps code provenance for later promotion
+    # the stage capture stamps code and device provenance
     assert "commit" in by_stage["provenance"]
+    assert by_stage["provenance"]["platform"] == "cpu"
+    assert by_stage["provenance"]["device_kind"]
     # ... and FULL 2-D mesh provenance (ISSUE 14): device count, per-axis
     # split, axis names and the PxN shape string, on the provenance line
     # and on every sharded stage record
@@ -97,186 +102,34 @@ def test_stage_profiler_smoke():
     # wall comparison the perf sentinel gates; the fraction can dip
     # negative on timing noise but must exist and the timed wall must
     # be real
-    assert by_stage["timeline_overhead"]["ms_per_iter"] > 0
+    assert by_stage["timeline_overhead"]["smoke_ms_per_iter"] > 0
     assert by_stage["timeline_overhead"]["overhead_fraction"] is not None
     # the journey-ledger self-overhead stage (ISSUE 20) measures the
     # ledger's hot-path seconds directly (shim accounting), so unlike
     # the wall-differenced delta its fraction is a real upper bound
-    assert by_stage["journey_ledger_overhead"]["ms_per_iter"] > 0
+    assert by_stage["journey_ledger_overhead"]["smoke_ms_per_iter"] > 0
     assert by_stage["journey_ledger_overhead"]["ledger_ms_per_iter"] >= 0
     assert by_stage["journey_ledger_overhead"]["overhead_fraction"] is not None
 
 
-def test_latest_probe_capture_selection(tmp_path):
-    """The zero-record path promotes the prober's newest nonzero capture
-    for the CURRENT metric only — zero records, wrong shapes, garbage
-    files, and captures without verifiable code provenance are skipped."""
-    sys.path.insert(0, REPO)
-    from bench import _git_head, _latest_probe_capture
-
-    head = _git_head()["commit"]
-    assert head, "test must run inside the git repo"
-    stamp = f', "extra": {{"provenance": {{"commit": "{head}"}}}}'
-
-    d = tmp_path / "probe_results"
-    d.mkdir()
-    assert _latest_probe_capture(str(d)) is None
-    (d / "bench_1.json").write_text(
-        '{"metric": "solve_pods_per_sec_50000p_10240n", "value": 0.0'
-        + stamp + '}')
-    (d / "bench_2.json").write_text("not json at all")
-    (d / "bench_3.json").write_text(
-        '{"metric": "solve_pods_per_sec_10p_10n", "value": 99.0'
-        + stamp + '}')
-    assert _latest_probe_capture(str(d)) is None
-    (d / "bench_4.json").write_text(
-        '{"metric": "solve_pods_per_sec_50000p_10240n", "value": 250001.5,'
-        ' "unit": "pods/s", "vs_baseline": 1.0' + stamp + '}')
-    (d / "bench_5.json").write_text(
-        '{"metric": "solve_pods_per_sec_50000p_10240n", "value": 260000.0,'
-        ' "unit": "pods/s", "vs_baseline": 1.04' + stamp + '}')
-    doc, source = _latest_probe_capture(str(d))
-    assert source == "bench_5.json" and doc["value"] == 260000.0
-    # captures older than ~a round (12h by mtime) are from a PREVIOUS
-    # round and must not be re-reported as this round's measurement
-    import time as _time
-
-    old = _time.time() - 13 * 3600
-    os.utime(d / "bench_5.json", (old, old))
-    doc, source = _latest_probe_capture(str(d))
-    assert source == "bench_4.json"
-    os.utime(d / "bench_4.json", (old, old))
-    assert _latest_probe_capture(str(d)) is None
-    # a record that is itself a promotion must never count as a fresh
-    # capture — accepting it would launder one stale measurement into
-    # every future round via its refreshed mtime
-    (d / "bench_6.json").write_text(
-        '{"metric": "solve_pods_per_sec_50000p_10240n", "value": 270000.0,'
-        ' "unit": "pods/s", "vs_baseline": 1.08,'
-        ' "extra": {"probe_capture": {"source": "bench_4.json"},'
-        f' "provenance": {{"commit": "{head}"}}}}')
-    assert _latest_probe_capture(str(d)) is None
-
-
-def test_probe_capture_commit_provenance(tmp_path):
-    """VERDICT r4 weak #2: a capture measured on a DIFFERENT commit with
-    solver changes in between must not become the official number — and
-    an unstamped capture ties to no code at all, so it is refused with a
-    recorded reason."""
-    sys.path.insert(0, REPO)
-    import subprocess
-
-    from bench import _git_head, _latest_probe_capture, _solver_diff
-
-    head = _git_head()["commit"]
-    rec = ('{"metric": "solve_pods_per_sec_50000p_10240n",'
-           ' "value": 250001.5, "unit": "pods/s", "vs_baseline": 1.0%s}')
-
-    d = tmp_path / "probe_results"
-    d.mkdir()
-    # unstamped: refused, with a note
-    (d / "bench_1.json").write_text(rec % "")
-    notes = []
-    assert _latest_probe_capture(str(d), notes=notes) is None
-    assert notes and "unverifiable" in notes[0]
-    # stamped with a commit git does not know: refused
-    (d / "bench_1.json").write_text(
-        rec % ', "extra": {"provenance": {"commit": "f00dfeed"}}')
-    notes = []
-    assert _latest_probe_capture(str(d), notes=notes) is None
-    assert notes and "unverifiable" in notes[0]
-    # stamped with an OLD commit that differs from HEAD by solver files:
-    # refused, naming the files (koordinator_tpu/ churn is guaranteed
-    # between any two round commits; pick one where the diff is nonempty)
-    log = subprocess.run(
-        ["git", "log", "--format=%H", "-n", "200"], capture_output=True,
-        text=True, cwd=REPO).stdout.split()
-    old_commit = next(
-        (c for c in log[1:] if _solver_diff(c, head)), None)
-    if old_commit is not None:
-        (d / "bench_1.json").write_text(
-            rec % f', "extra": {{"provenance": {{"commit": "{old_commit}"}}}}')
-        notes = []
-        assert _latest_probe_capture(str(d), notes=notes) is None
-        assert notes and "solver files changed" in notes[0]
-    # HEAD-stamped but captured on a DIRTY tree: the uncommitted solver
-    # edits the capture measured are invisible to any commit diff, so it
-    # is refused even at the same commit
-    (d / "bench_1.json").write_text(
-        rec % f', "extra": {{"provenance": '
-              f'{{"commit": "{head}", "dirty": true}}}}')
-    notes = []
-    assert _latest_probe_capture(str(d), notes=notes) is None
-    assert notes and "dirty tree" in notes[0]
-    # HEAD-stamped and clean: promoted
-    (d / "bench_1.json").write_text(
-        rec % f', "extra": {{"provenance": {{"commit": "{head}"}}}}')
-    doc, source = _latest_probe_capture(str(d))
-    assert source == "bench_1.json" and doc["value"] == 250001.5
-
-
 def test_bench_recall_smoke():
-    """bench_recall.py (the prober's approx-recall capture) must keep
-    producing a parseable record: tiny shape, at-shape leg off.  On CPU
+    """bench_recall.py must keep producing a parseable record under its
+    explicit ``--smoke`` flag: tiny shape, at-shape leg off.  On CPU
     approx_max_k lowers exactly, so only the float-key quantization can
     cost recall — the mean should stay high."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", KOORD_RECALL_NODES="128",
                KOORD_RECALL_PODS="256", KOORD_RECALL_SHAPE_PODS="0")
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench_recall.py")],
+        [sys.executable, os.path.join(REPO, "bench_recall.py"), "--smoke"],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-2000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["backend"] == "cpu"
-    assert rec["provenance"]["commit"]
+    assert rec["platform"] == "cpu"
+    # an empty stamp outside a git checkout, never a failure
+    assert isinstance(rec["provenance"]["commit"], str)
+    # no wall clock of a CPU run under an unprefixed name
+    assert not [k for k in rec if "wall_s" in k
+                and not k.startswith("smoke_")]
     assert rec["candidate_recall_mean_256p_128n"] >= 0.8
     assert rec["assigned_frac_exact_256p_128n"] >= 0.9
     assert rec["assigned_frac_approx_256p_128n"] >= 0.9
-
-
-def test_latest_probe_stages_promotion(tmp_path):
-    """A recent bench_stages capture promotes into a zero record's extra
-    (staged capture with provenance instead of all-or-nothing); captures
-    whose commit cannot be tied to HEAD promote WITH a caveat — they are
-    marked partial evidence, never refused like the headline."""
-    sys.path.insert(0, REPO)
-    from bench import _git_head, _latest_probe_stages
-
-    head = _git_head()["commit"]
-    d = tmp_path / "probe_results"
-    d.mkdir()
-    assert _latest_probe_stages(str(d)) is None
-    (d / "stages_1.jsonl").write_text("\n".join([
-        json.dumps({"stage": "provenance", "commit": head, "dirty": False,
-                    "n_devices": 8,
-                    "mesh_axes": {"pods": 1, "nodes": 8}}),
-        json.dumps({"stage": "score", "ms_per_iter": 12.5}),
-        json.dumps({"stage": "rounds", "ms_per_iter": 3.2}),
-    ]))
-    rec = _latest_probe_stages(str(d))
-    assert rec["source"] == "stages_1.jsonl"
-    assert rec["stages"]["score"]["ms_per_iter"] == 12.5
-    assert rec["capture_commit"] == head
-    # mesh-shape provenance rides the promotion (ISSUE 10)
-    assert rec["n_devices"] == 8 and rec["mesh_axes"]["nodes"] == 8
-    assert "caveat" not in rec
-    # a NEWER unstamped capture wins but carries a caveat
-    (d / "stages_2.jsonl").write_text(
-        json.dumps({"stage": "score", "ms_per_iter": 1.0}))
-    rec = _latest_probe_stages(str(d))
-    assert rec["source"] == "stages_2.jsonl"
-    assert "caveat" in rec
-
-
-def test_device_alive_kinds():
-    """_device_alive classifies failures into structured error kinds
-    (ROADMAP item 1's diagnosis split); on the CPU test backend the
-    probe must come back clean."""
-    sys.path.insert(0, REPO)
-    from bench import DEVICE_ERROR_KINDS, _device_alive
-
-    assert set(DEVICE_ERROR_KINDS) == {
-        "no_devices_enumerated", "probe_kernel_hung", "transfer_stall",
-        "probe_error"}
-    ok, kind, err = _device_alive(120.0)
-    assert ok and kind == "" and err == ""

@@ -2,8 +2,8 @@
 
 The slow-marked north-star guards (test_north_star_shape.py) pin wave
 convergence for one seed at the full 50k x 10,240 shape; the randomized
-property suites sweep small shapes.  This is the cheap middle ground
-(VERDICT r4 weak #6): three seeds at 15k pods x 3,072 nodes under ~2x
+property suites sweep small shapes.  This is the cheap middle ground:
+three seeds at 15k pods x 3,072 nodes under ~2x
 capacity surplus must each converge to full placement within 3 waves —
 keeping the contention-convergence claim honest without slow-CI cost.
 One jit compile serves all seeds and waves (same shapes throughout).

@@ -1,5 +1,5 @@
 """Device collectors (gpu/rdma/xpu parity) and the resctrl/tc/terwayqos
-runtime hooks — the r1-VERDICT koordlet matrix tail.
+runtime hooks — the tail of the koordlet coverage matrix.
 
 Reference anchors: pkg/koordlet/metricsadvisor/devices/{gpu,rdma,xpu},
 pkg/koordlet/runtimehooks/hooks/{resctrl,tc,terwayqos}.

@@ -27,8 +27,6 @@ LEASE_SECONDS = 20.0
 
 REPLICA = textwrap.dedent("""
     import sys, time
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     sock, ident, status, lease_s = (
         sys.argv[1], sys.argv[2], sys.argv[3], float(sys.argv[4]))
 

@@ -3,7 +3,7 @@
 The fake-fs tests (make_test_config temp trees, the FileTestUtil
 equivalent of util_test_tool.go:93) prove the parsers; they cannot catch
 path-format drift between our path builders and a real /proc //sys —
-that is what this opt-in suite does (VERDICT r4 next #9).  Strictly
+that is what this opt-in suite does.  Strictly
 read-only: no cgroup writes, no resctrl group creation.
 
 Run with:  pytest -m hostfs tests/test_hostfs_smoke.py

@@ -60,10 +60,11 @@ class TestInstrumentedJit:
         assert metrics.solver_recompiles.value(
             {"fn": "twice", "shape": "unknown"}) == 1
 
-    def test_uninstrumentable_fn_degrades_to_passthrough(self):
-        fn = insp.instrument(lambda x: x + 1, "plain")
-        assert fn(41) == 42
-        assert fn.misses == 0
+    def test_uninstrumentable_fn_is_refused(self):
+        """No silent pass-through: a wrapper that cannot see the jit
+        cache would report zero recompiles forever."""
+        with pytest.raises(TypeError, match="_cache_size"):
+            insp.instrument(lambda x: x + 1, "plain")
 
     def test_device_bytes_sums_leaf_nbytes(self):
         from koordinator_tpu.state.cluster_state import ClusterState
@@ -299,13 +300,12 @@ class TestShardedIntrospection:
         mesh = pmesh.solver_mesh()
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x.sum(), ps.NODES_AXIS),
             mesh=mesh, in_specs=(P("nodes"),), out_specs=P(),
-            check_rep=False))
+            check_vma=False))
         got = insp.compiled_collectives(fn, jnp.zeros((64,), jnp.int32))
         assert got.get("all-reduce", 0) >= 1, got
 

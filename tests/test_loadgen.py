@@ -329,9 +329,18 @@ class TestFlightRingSizeFlag:
 
     def test_overwrites_accounted_against_chosen_size(self):
         from koordinator_tpu import metrics
-        from koordinator_tpu.scheduler.flight_recorder import FlightRecorder
+        from koordinator_tpu.scheduler.flight_recorder import (
+            FlightRecorder,
+            RoundRecord,
+        )
 
-        from tests.test_bench_prober import make_record
+        def make_record(n: int) -> RoundRecord:
+            return RoundRecord(
+                round=n, trace_id=f"t{n}", start_time=0.0, duration_s=0.01,
+                solver="batch", solve_path="incremental", pods=1, placed=1,
+                failed=0, suspended=0, degraded=False, staleness_s=0.0,
+                dirty_node_frac=0.0, dirty_pod_frac=0.0, solve_wall_s=0.01,
+                solve_device_s=0.005)
 
         rec = FlightRecorder(capacity=8)
         for n in range(20):

@@ -160,7 +160,7 @@ def test_sharded_reservation_assign_matches_unsharded():
 
 def test_sharded_gang_quota_assign_matches_unsharded():
     """Gang all-or-nothing + elastic-quota admission on the mesh equals the
-    single-device solve (VERDICT r1 item 7: multi-device gang+quota parity)."""
+    single-device solve (multi-device gang+quota parity)."""
     from koordinator_tpu.ops.gang import GangInfo, gang_assign
     from koordinator_tpu.quota.admission import QuotaDeviceState
     from koordinator_tpu.quota.tree import UNBOUNDED, QuotaTree

@@ -22,7 +22,6 @@ WORKER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
     coordinator, pid = sys.argv[1], int(sys.argv[2])
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=2, process_id=pid)

@@ -18,6 +18,19 @@ def files(tmp_path):
     return paths
 
 
+def test_library_name_follows_the_source(tmp_path, monkeypatch):
+    """The loader only ever opens the library its own source builds: a
+    stale or foreign .so in the git-ignored build directory has another
+    name and is rebuilt over, never loaded."""
+    src = tmp_path / "koordsys.cpp"
+    with open(native._SRC, "rb") as f:
+        src.write_bytes(f.read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert native._lib_path() == native._LIB
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert native._lib_path() != native._LIB
+
+
 class TestBatchReader:
     def test_read_and_missing(self, files, tmp_path):
         reader = native.BatchReader(files + [str(tmp_path / "nope")])

@@ -1,11 +1,11 @@
 """Solve quality AT THE NORTH-STAR SHAPE, in CI.
 
-Round 2's spread_bits=5 fix held at a 2k-pod validation shape and
-silently stranded 14% of pods at the real 50k x 10,240 shape; round 3's
-stratified candidate selection fixed it but the at-shape check lived in
-a manual scratch script.  This test pins the real shape in CI (slow-
-marked: `pytest -m slow`) so that class of regression can never ship
-silently again (VERDICT r3 item 9).
+A single spread_bits=5 key held at a 2k-pod validation shape and
+silently stranded 14% of pods at the real 50k x 10,240 shape; stratified
+candidate selection fixed it (docs/solve_quality.md "Stratified candidates
+at shape").  This test pins the real shape in CI (slow-marked:
+`pytest -m slow`) so that class of regression can never ship silently
+again.
 
 The approx float-key candidate path is FORCED — the TPU-serving branch;
 on CPU `approx_max_k`'s lowering is exact, so this isolates the
@@ -25,7 +25,7 @@ NORTH_STAR_PODS = 50_000
 
 @pytest.fixture(scope="module")
 def problem():
-    # seed 42 = the scratch_quality.py shape the round-2 regression hit
+    # seed 42 = the problem the single-key sb=5 regression was found on
     return _build_problem(NORTH_STAR_NODES, NORTH_STAR_PODS, seed=42)
 
 

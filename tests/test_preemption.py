@@ -474,8 +474,8 @@ class TestSchedulerPostFilter:
 
 
 class TestPreemptChain:
-    """preempt_chain == sequential preempt_one + host commit (VERDICT r2
-    item 4: batched PostFilter), plus the scheduler-level round budget."""
+    """preempt_chain == sequential preempt_one + host commit (batched
+    PostFilter), plus the scheduler-level round budget."""
 
     def _chain_problem(self, seed=0, n_nodes=6, n_bound=24, n_fail=8):
         rng = np.random.default_rng(seed)

@@ -2,9 +2,9 @@
 leader election.
 
 - REQUEST_SCHEMAS / validate_doc make peer skew fail loud at the server
-  boundary (VERDICT r2 item 10 — the api.proto versioned-contract role);
+  boundary (the api.proto versioned-contract role);
 - LEASE_GET/LEASE_UPDATE + RemoteLeaseStore let two scheduler PROCESSES
-  contend one lease over the transport (VERDICT r2 item 6); the failover
+  contend one lease over the transport; the failover
   test kill -9s the leading process and the standby must take over.
 """
 

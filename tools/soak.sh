@@ -1,7 +1,6 @@
 #!/bin/bash
-# Reproducible randomized soak over the property suites (VERDICT r4 #8:
-# the ~1,700-run campaign that closed round 4 was run by hand and was
-# unreproducible).  Sweeps FRESH seed windows through every randomized
+# Reproducible randomized soak over the property suites (a campaign run
+# by hand is unreproducible).  Sweeps FRESH seed windows through every randomized
 # invariant suite via the conftest prop_seeds knobs and prints one JSON
 # tally line; CI keeps the cheap default seeds untouched.
 #
