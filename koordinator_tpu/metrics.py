@@ -532,6 +532,11 @@ critical_path_seconds = SCHEDULER.gauge(
     "cause (label: cause); topk(1, ...) names the dominant cause the "
     "ROADMAP item-5 perf attack should aim at.  Every cause is "
     "republished each cycle so cleared ones read 0")
+timeline_segments_dropped = SCHEDULER.counter(
+    "timeline_segments_dropped_total",
+    "Timeline records the recorder's full ring pushed out before any "
+    "window read them; back-to-back spans of one name are one record, "
+    "so a steady scheduler sits at zero")
 
 # -- pod-journey ledger (journey.py, ISSUE 20) --
 pod_journey_latency_seconds = SCHEDULER.gauge(
@@ -567,6 +572,12 @@ solver_recompiles = SCHEDULER.counter(
     "Jit-cache misses (trace+compile) of the solver's jitted entry "
     "points per shape bucket (labels: fn, shape) — a steady-state "
     "scheduler should sit at zero rate; increments mean shape churn")
+solver_load_seconds = SCHEDULER.counter(
+    "solver_load_seconds_total",
+    "Wall seconds of the calls that grew a solver entry point's jit "
+    "cache (label: fn): trace, lower, compile or persistent-cache "
+    "load, and dispatch — what a process pays before its first warm "
+    "round, beside solver_recompiles_total's count of the same calls")
 solver_jit_cache_size = SCHEDULER.gauge(
     "solver_jit_cache_size",
     "Live jit-cache entries per instrumented solver entry point "

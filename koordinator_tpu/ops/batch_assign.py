@@ -447,10 +447,12 @@ def select_candidates(
     if method in ("chunked", "chunked_exact"):
         return _chunked_candidates(state, pods, cfg, k=k, strata=strata,
                                    method=method, with_scores=with_scores)
-    scores, feasible = score_pods(state, pods, cfg)
-    return _reduce_candidates(scores, feasible, strata,
-                              min(k, scores.shape[1]), method,
-                              pods.rot_id, with_scores=with_scores)
+    with jax.named_scope("score"):
+        scores, feasible = score_pods(state, pods, cfg)
+    with jax.named_scope("select"):
+        return _reduce_candidates(scores, feasible, strata,
+                                  min(k, scores.shape[1]), method,
+                                  pods.rot_id, with_scores=with_scores)
 
 
 def _reduce_candidates(scores, feasible, strata, k: int, method: str,
@@ -561,10 +563,12 @@ def _chunked_candidates(state, pods, cfg, k: int, strata,
                 else a.reshape((n_chunks, chunk) + a.shape[1:]))
 
     def body(sub):
-        scores, feasible = score_pods(state, sub, cfg)
-        return _reduce_candidates(scores, feasible, strata, k,
-                                  method, sub.rot_id,
-                                  with_scores=with_scores)
+        with jax.named_scope("score"):
+            scores, feasible = score_pods(state, sub, cfg)
+        with jax.named_scope("select"):
+            return _reduce_candidates(scores, feasible, strata, k,
+                                      method, sub.rot_id,
+                                      with_scores=with_scores)
 
     sub_batches = jax.tree.map(reshape_rows, stacked)
     out = jax.lax.map(body, sub_batches)
@@ -592,8 +596,11 @@ def _choose_candidate(cand_key, cand_tb, fits):
         jnp.where(fits & (masked == best_key), cand_tb, -1), axis=1)
 
 
+@jax.named_scope("assign_rounds")
 def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
-    """The shared propose/accept stage over (P, k) candidates."""
+    """The shared propose/accept stage over (P, k) candidates.  The
+    ``named_scope`` stage names here and below are metadata only: they
+    name the device ops in a profiler trace and change no operand."""
     cand_valid = cand_key >= 0
     cand_tb = (None if _packed_regime(state.capacity)
                else _candidate_tb(cand_node, pods.rot_id, state.capacity))
@@ -609,30 +616,35 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
     )
 
     def round_body(_, c: _RoundCarry) -> _RoundCarry:
-        free = jnp.where(
-            state.node_valid[:, None], state.node_allocatable - c.requested, 0
-        )
-        # each pod's best candidate whose node still fits its request
-        cand_free = free[cand_node]                    # (P, k, R)
-        fits = jnp.all(
-            (pods.requests[:, None, :] <= cand_free)
-            | (pods.requests[:, None, :] == 0),
-            axis=-1,
-        ) & cand_valid
-        best = _choose_candidate(cand_key, cand_tb, fits)
-        has = jnp.take_along_axis(fits, best[:, None], axis=1)[:, 0]
-        choice = jnp.take_along_axis(cand_node, best[:, None], axis=1)[:, 0]
+        with jax.named_scope("propose"):
+            free = jnp.where(
+                state.node_valid[:, None],
+                state.node_allocatable - c.requested, 0
+            )
+            # each pod's best candidate whose node still fits its request
+            cand_free = free[cand_node]                    # (P, k, R)
+            fits = jnp.all(
+                (pods.requests[:, None, :] <= cand_free)
+                | (pods.requests[:, None, :] == 0),
+                axis=-1,
+            ) & cand_valid
+            best = _choose_candidate(cand_key, cand_tb, fits)
+            has = jnp.take_along_axis(fits, best[:, None], axis=1)[:, 0]
+            choice = jnp.take_along_axis(
+                cand_node, best[:, None], axis=1)[:, 0]
 
         act = c.active & has
         if c.quota is not None:
             act = act & quota_admission_mask(
                 c.quota, pods.requests, pods.quota_id, pods.non_preemptible
             )
-        accept = _prefix_accept(choice, pods.requests, free, order, act)
+        with jax.named_scope("prefix_accept"):
+            accept = _prefix_accept(choice, pods.requests, free, order, act)
         if c.quota is not None:
-            accept = accept & _quota_prefix_accept(
-                c.quota, pods.requests, pods, order, act
-            )
+            with jax.named_scope("quota_accept"):
+                accept = accept & _quota_prefix_accept(
+                    c.quota, pods.requests, pods, order, act
+                )
 
         safe = jnp.where(accept, choice, 0)
         add = jnp.where(accept[:, None], pods.requests, 0)
@@ -733,6 +745,7 @@ def align_candidate_cache(
     return CandidateCache(key, node, score), touch
 
 
+@jax.named_scope("refresh")
 def refresh_candidates(
     state: ClusterState,
     pods: PodBatch,
@@ -764,7 +777,8 @@ def refresh_candidates(
     rot = pods.rot_id
 
     sub = state.gather_rows(dirty_rows, dirty_valid)
-    scores, feasible = score_pods(sub, pods, cfg)        # (P, D)
+    with jax.named_scope("score"):
+        scores, feasible = score_pods(sub, pods, cfg)    # (P, D)
     clipped = jnp.clip(scores, 0, _SCORE_CLIP)
     # .max (OR), not .set: padded dirty_rows entries default to row 0
     # with valid=False, and a duplicate-index .set scatter is
@@ -811,6 +825,7 @@ def refresh_candidates(
     return cand_key, CandidateCache(cand_key, cand_node, cand_score)
 
 
+@jax.named_scope("scatter_rows")
 def scatter_candidate_rows(
     cache: CandidateCache,
     rows: jnp.ndarray,        # (S,) int32; out-of-range padding drops

@@ -26,6 +26,7 @@ the <5% overhead claim at the north-star shape).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
@@ -80,6 +81,7 @@ def fit_first_fail(free: jnp.ndarray, requests: jnp.ndarray) -> jnp.ndarray:
     return fails & (prior == 0)
 
 
+@jax.named_scope("explain_reduce")
 def explain_counts(
     state: ClusterState, pods: PodBatch, cfg,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -186,34 +186,35 @@ def gang_assign(
     est_accum = jnp.zeros_like(state.node_usage)
 
     for _ in range(passes):
-        solve_state = cur_state.replace(
-            node_usage=cur_state.node_usage + est_accum,
-            node_agg_usage=cur_state.node_agg_usage + est_accum,
-        )
-        if solver == "batch":
-            a, _, _ = batch_assign(solve_state, active_pods, cfg, cur_quota,
-                                   method=method)
-        else:
-            a, _, _ = greedy_assign(solve_state, active_pods, cfg, cur_quota)
-
-        final, cur_state, keep, failed = rollback_failed_gangs(
-            a, cur_state, active_pods, gangs, prior_kept=kept_so_far
-        )
-        node = jnp.where(keep, final, 0)
-        est_accum = est_accum.at[node].add(
-            jnp.where(keep[:, None], pod_est_all, 0)
-        )
-        if cur_quota is not None:
-            cur_quota = charge_quota_batch(
-                cur_quota, active_pods.requests, active_pods.quota_id,
-                keep, active_pods.non_preemptible,
+        with jax.named_scope("gang_pass"):
+            solve_state = cur_state.replace(
+                node_usage=cur_state.node_usage + est_accum,
+                node_agg_usage=cur_state.node_agg_usage + est_accum,
             )
-        total = jnp.where(keep, final, total)
-        kept_so_far = kept_so_far | keep
-        # next pass: still-unassigned pods stay in play, but rolled-back gangs
-        # back off for the rest of the batch (retry next cycle upstream)
-        active_pods = active_pods.replace(
-            valid=active_pods.valid & ~keep & ~failed
-        )
+            if solver == "batch":
+                a, _, _ = batch_assign(solve_state, active_pods, cfg, cur_quota,
+                                       method=method)
+            else:
+                a, _, _ = greedy_assign(solve_state, active_pods, cfg, cur_quota)
+
+            final, cur_state, keep, failed = rollback_failed_gangs(
+                a, cur_state, active_pods, gangs, prior_kept=kept_so_far
+            )
+            node = jnp.where(keep, final, 0)
+            est_accum = est_accum.at[node].add(
+                jnp.where(keep[:, None], pod_est_all, 0)
+            )
+            if cur_quota is not None:
+                cur_quota = charge_quota_batch(
+                    cur_quota, active_pods.requests, active_pods.quota_id,
+                    keep, active_pods.non_preemptible,
+                )
+            total = jnp.where(keep, final, total)
+            kept_so_far = kept_so_far | keep
+            # next pass: still-unassigned pods stay in play, but rolled-back gangs
+            # back off for the rest of the batch (retry next cycle upstream)
+            active_pods = active_pods.replace(
+                valid=active_pods.valid & ~keep & ~failed
+            )
 
     return total, cur_state, cur_quota

@@ -7,7 +7,9 @@ something else:
 - :func:`instrument` wraps a jitted entry point and counts jit-cache
   misses (a trace+compile happened) per shape bucket into
   ``solver_recompiles_total{fn, shape}`` plus a live
-  ``solver_jit_cache_size{fn}`` gauge.  The power-of-two bucketing in
+  ``solver_jit_cache_size{fn}`` gauge; the wall of each such call is a
+  ``kit.load:<fn>`` timeline span and sums into
+  ``solver_load_seconds_total{fn}``.  The power-of-two bucketing in
   state/cluster_state bounds compiles to O(log N) over cluster life; a
   nonzero steady-state recompile RATE is exactly the regression the
   incremental-solve design must catch, not assume away.
@@ -29,7 +31,7 @@ import tempfile
 import threading
 import time
 
-from koordinator_tpu import metrics
+from koordinator_tpu import metrics, timeline
 
 
 def default_shape_of(args, kwargs) -> str:
@@ -73,9 +75,15 @@ class InstrumentedJit:
 
     def __call__(self, *args, **kwargs):
         before = self._cache_size()
+        t0 = time.perf_counter()
         out = self.fn(*args, **kwargs)
         after = self._cache_size()
         if after > before:
+            t1 = time.perf_counter()
+            timeline.RECORDER.add(t0, t1, "host_other",
+                                  f"kit.load:{self.name}", n=after - before)
+            metrics.solver_load_seconds.inc(t1 - t0,
+                                            labels={"fn": self.name})
             try:
                 shape = self.shape_of(args, kwargs)
             except Exception:  # noqa: BLE001 — labeling must not fail a solve

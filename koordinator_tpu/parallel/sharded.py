@@ -159,44 +159,46 @@ def _local_select_body(st_local, pods, cfg, *, k, strata, n_total):
     for those rows."""
     n_loc = st_local.capacity
     off = _shard_offset(n_loc)
-    scores, feasible = score_pods(st_local, pods, cfg)    # (P_loc, n_loc)
+    with jax.named_scope("score"):
+        scores, feasible = score_pods(st_local, pods, cfg)  # (P_loc, n_loc)
     node_ids = off + jnp.arange(n_loc, dtype=jnp.int32)
     clipped = jnp.clip(scores, 0, ba._SCORE_CLIP)
     rot = pods.rot_id
 
-    splits = ba._stratum_splits(k, len(strata))
-    nodes_out, scores_out = [], []
-    for sb, k_i in zip(strata, splits):
-        if k_i == 0:
-            continue
-        key, tb = ba._rank_parts(scores, feasible, sb, rot,
-                                 node_ids=node_ids, n_total=n_total)
-        m_i = min(k_i, n_loc)
-        val, idx = ba._topk_by_rank(key, tb, m_i, n_total)
-        sel_node = node_ids[idx]
-        sel_score = jnp.where(
-            val >= 0, jnp.take_along_axis(clipped, idx, axis=1), -1)
-        # cross-shard segmented top-k merge: (P_loc, m) tile winners
-        # ride one all_gather over the nodes axis, every tile re-ranks
-        # the union globally; pod rows are independent, so no pod-axis
-        # merge exists
-        g_node = jax.lax.all_gather(sel_node, NODES_AXIS, axis=1,
-                                    tiled=True)
-        g_score = jax.lax.all_gather(sel_score, NODES_AXIS, axis=1,
-                                     tiled=True)
-        g_key = ba._candidate_keys(g_score, g_node, rot, sb, n_total)
-        mval, midx = ba._topk_by_rank(
-            g_key, ba._candidate_tb(g_node, rot, n_total), k_i, n_total)
-        nodes_out.append(jnp.take_along_axis(g_node, midx, axis=1))
-        scores_out.append(jnp.where(
-            mval >= 0, jnp.take_along_axis(g_score, midx, axis=1), -1))
+    with jax.named_scope("select"):
+        splits = ba._stratum_splits(k, len(strata))
+        nodes_out, scores_out = [], []
+        for sb, k_i in zip(strata, splits):
+            if k_i == 0:
+                continue
+            key, tb = ba._rank_parts(scores, feasible, sb, rot,
+                                     node_ids=node_ids, n_total=n_total)
+            m_i = min(k_i, n_loc)
+            val, idx = ba._topk_by_rank(key, tb, m_i, n_total)
+            sel_node = node_ids[idx]
+            sel_score = jnp.where(
+                val >= 0, jnp.take_along_axis(clipped, idx, axis=1), -1)
+            # cross-shard segmented top-k merge: (P_loc, m) tile winners
+            # ride one all_gather over the nodes axis, every tile re-ranks
+            # the union globally; pod rows are independent, so no pod-axis
+            # merge exists
+            g_node = jax.lax.all_gather(sel_node, NODES_AXIS, axis=1,
+                                        tiled=True)
+            g_score = jax.lax.all_gather(sel_score, NODES_AXIS, axis=1,
+                                         tiled=True)
+            g_key = ba._candidate_keys(g_score, g_node, rot, sb, n_total)
+            mval, midx = ba._topk_by_rank(
+                g_key, ba._candidate_tb(g_node, rot, n_total), k_i, n_total)
+            nodes_out.append(jnp.take_along_axis(g_node, midx, axis=1))
+            scores_out.append(jnp.where(
+                mval >= 0, jnp.take_along_axis(g_score, midx, axis=1), -1))
 
-    cand_node = (jnp.concatenate(nodes_out, axis=1)
-                 if len(nodes_out) > 1 else nodes_out[0])
-    cand_score = (jnp.concatenate(scores_out, axis=1)
-                  if len(scores_out) > 1 else scores_out[0])
-    cand_key = ba._candidate_keys(cand_score, cand_node, rot,
-                                  strata[0], n_total)
+        cand_node = (jnp.concatenate(nodes_out, axis=1)
+                     if len(nodes_out) > 1 else nodes_out[0])
+        cand_score = (jnp.concatenate(scores_out, axis=1)
+                      if len(scores_out) > 1 else scores_out[0])
+        cand_key = ba._candidate_keys(cand_score, cand_node, rot,
+                                      strata[0], n_total)
     return cand_key, cand_node, cand_score
 
 
@@ -245,6 +247,7 @@ def sharded_select_candidates(mesh, state, pods, cfg, k: int = 32,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("assign_rounds")
 def _rounds_local(st_local, pods, quota, cand_key, cand_node, *,
                   rounds, n_total):
     """The propose/accept loop over GATHERED (full-P) pod tensors with
@@ -265,21 +268,25 @@ def _rounds_local(st_local, pods, quota, cand_key, cand_node, *,
 
     def round_body(c):
         requested, assignments, active, qstate = c
-        free_loc = jnp.where(
-            st_local.node_valid[:, None],
-            st_local.node_allocatable - requested, 0)
-        # per-candidate free capacity: the owning shard contributes, the
-        # int32 psum reassembles the exact global gather free[cand_node]
-        cand_free = jax.lax.psum(
-            jnp.where(own[:, :, None], free_loc[local_c], 0), NODES_AXIS)
-        fits = jnp.all(
-            (pods.requests[:, None, :] <= cand_free)
-            | (pods.requests[:, None, :] == 0),
-            axis=-1,
-        ) & cand_valid
-        best = ba._choose_candidate(cand_key, cand_tb, fits)
-        has = jnp.take_along_axis(fits, best[:, None], axis=1)[:, 0]
-        choice = jnp.take_along_axis(cand_node, best[:, None], axis=1)[:, 0]
+        with jax.named_scope("propose"):
+            free_loc = jnp.where(
+                st_local.node_valid[:, None],
+                st_local.node_allocatable - requested, 0)
+            # per-candidate free capacity: the owning shard contributes,
+            # the int32 psum reassembles the exact global gather
+            # free[cand_node]
+            cand_free = jax.lax.psum(
+                jnp.where(own[:, :, None], free_loc[local_c], 0),
+                NODES_AXIS)
+            fits = jnp.all(
+                (pods.requests[:, None, :] <= cand_free)
+                | (pods.requests[:, None, :] == 0),
+                axis=-1,
+            ) & cand_valid
+            best = ba._choose_candidate(cand_key, cand_tb, fits)
+            has = jnp.take_along_axis(fits, best[:, None], axis=1)[:, 0]
+            choice = jnp.take_along_axis(
+                cand_node, best[:, None], axis=1)[:, 0]
 
         act = active & has
         if qstate is not None:
@@ -289,14 +296,17 @@ def _rounds_local(st_local, pods, quota, cand_key, cand_node, *,
         loc_choice = choice - off
         own_c = (loc_choice >= 0) & (loc_choice < n_loc)
         loc_choice_c = jnp.clip(loc_choice, 0, n_loc - 1)
-        choice_free = jax.lax.psum(
-            jnp.where((own_c & act)[:, None], free_loc[loc_choice_c], 0),
-            NODES_AXIS)
-        accept = ba._prefix_accept_choice(
-            choice, pods.requests, choice_free, n_total, order, act)
+        with jax.named_scope("prefix_accept"):
+            choice_free = jax.lax.psum(
+                jnp.where((own_c & act)[:, None],
+                          free_loc[loc_choice_c], 0),
+                NODES_AXIS)
+            accept = ba._prefix_accept_choice(
+                choice, pods.requests, choice_free, n_total, order, act)
         if qstate is not None:
-            accept = accept & ba._quota_prefix_accept(
-                qstate, pods.requests, pods, order, act)
+            with jax.named_scope("quota_accept"):
+                accept = accept & ba._quota_prefix_accept(
+                    qstate, pods.requests, pods, order, act)
 
         add = jnp.where((accept & own_c)[:, None], pods.requests, 0)
         requested = requested.at[loc_choice_c].add(add)
@@ -471,6 +481,7 @@ def sharded_assign_followup_pass(mesh, state, est_accum, pods, quota, cfg,
 
 
 # koordlint: shape[st_local: NxR i32 nodes]
+@jax.named_scope("refresh")
 def _refresh_body(st_local, pods, cfg, cache, dirty_rows, dirty_valid, *,
                   k, strata, n_total):
     n_loc = st_local.capacity
@@ -484,7 +495,8 @@ def _refresh_body(st_local, pods, cfg, cache, dirty_rows, dirty_valid, *,
     loc = dirty_rows - off
     own = (loc >= 0) & (loc < n_loc) & dirty_valid
     sub = st_local.gather_rows(jnp.clip(loc, 0, n_loc - 1), own)
-    scores, feasible = score_pods(sub, pods, cfg)           # (P_loc, D)
+    with jax.named_scope("score"):
+        scores, feasible = score_pods(sub, pods, cfg)       # (P_loc, D)
     clipped = jnp.clip(scores, 0, ba._SCORE_CLIP)
 
     # global dirty mask (nodes-replicated): cached slots pointing at ANY
@@ -682,54 +694,55 @@ def _gang_body(st_local, pods, cfg, gangs, quota, *, passes, solver,
     est_local = jnp.zeros_like(st_local.node_usage)
 
     for _ in range(passes):
-        solve_st = st_local.replace(
-            node_requested=requested,
-            node_usage=st_local.node_usage + est_local,
-            node_agg_usage=st_local.node_agg_usage + est_local)
-        act_pods = pods_f.replace(valid=active)
-        if solver == "batch":
-            # selection runs on this tile's LOCAL pod rows against the
-            # est-augmented local node tile; the winners ride the one
-            # nodes-axis merge inside and a pod-axis gather after
-            loc_active = jax.lax.dynamic_slice(active, (poff,), (p_loc,))
-            pods_loc = pods.replace(valid=pods.valid & loc_active)
-            ck_loc, cn_loc, _ = _local_select_body(
-                solve_st, pods_loc, cfg, k=k, strata=strata,
-                n_total=n_total)
-            ck, cn = _gather_pods((ck_loc, cn_loc))
-            a, _, _ = _rounds_local(
-                solve_st, act_pods, cur_quota, ck, cn,
-                rounds=rounds, n_total=n_total)
-        else:
-            a, _, _ = _greedy_local(solve_st, act_pods, cfg, cur_quota)
+        with jax.named_scope("gang_pass"):
+            solve_st = st_local.replace(
+                node_requested=requested,
+                node_usage=st_local.node_usage + est_local,
+                node_agg_usage=st_local.node_agg_usage + est_local)
+            act_pods = pods_f.replace(valid=active)
+            if solver == "batch":
+                # selection runs on this tile's LOCAL pod rows against the
+                # est-augmented local node tile; the winners ride the one
+                # nodes-axis merge inside and a pod-axis gather after
+                loc_active = jax.lax.dynamic_slice(active, (poff,), (p_loc,))
+                pods_loc = pods.replace(valid=pods.valid & loc_active)
+                ck_loc, cn_loc, _ = _local_select_body(
+                    solve_st, pods_loc, cfg, k=k, strata=strata,
+                    n_total=n_total)
+                ck, cn = _gather_pods((ck_loc, cn_loc))
+                a, _, _ = _rounds_local(
+                    solve_st, act_pods, cur_quota, ck, cn,
+                    rounds=rounds, n_total=n_total)
+            else:
+                a, _, _ = _greedy_local(solve_st, act_pods, cfg, cur_quota)
 
-        # rollback_failed_gangs, replicated flags + owner-local rebuild
-        assigned = (a >= 0) & act_pods.valid
-        counted = assigned | kept_so_far
-        counts = _per_gang_counts(counted, pods_f.gang_id, g)
-        gang_ok = (counts >= gangs.min_member) & gangs.valid
-        ok = _group_ok(gang_ok, gangs)
-        pod_gang = jnp.maximum(pods_f.gang_id, 0)
-        keep = assigned & ((pods_f.gang_id < 0) | ok[pod_gang])
-        failed = (pods_f.gang_id >= 0) & ~ok[pod_gang] & act_pods.valid
-        final = jnp.where(keep, a, -1)
+            # rollback_failed_gangs, replicated flags + owner-local rebuild
+            assigned = (a >= 0) & act_pods.valid
+            counted = assigned | kept_so_far
+            counts = _per_gang_counts(counted, pods_f.gang_id, g)
+            gang_ok = (counts >= gangs.min_member) & gangs.valid
+            ok = _group_ok(gang_ok, gangs)
+            pod_gang = jnp.maximum(pods_f.gang_id, 0)
+            keep = assigned & ((pods_f.gang_id < 0) | ok[pod_gang])
+            failed = (pods_f.gang_id >= 0) & ~ok[pod_gang] & act_pods.valid
+            final = jnp.where(keep, a, -1)
 
-        loc = final - off
-        own = keep & (loc >= 0) & (loc < n_loc)
-        loc_c = jnp.clip(loc, 0, n_loc - 1)
-        requested = requested.at[loc_c].add(
-            jnp.where(own[:, None], pods_f.requests, 0))
-        est_local = est_local.at[loc_c].add(
-            jnp.where(own[:, None], pod_est_all, 0))
-        if cur_quota is not None:
-            cur_quota = charge_quota_batch(
-                cur_quota, pods_f.requests, pods_f.quota_id, keep,
-                pods_f.non_preemptible)
-        total = jnp.where(keep, final, total)
-        kept_so_far = kept_so_far | keep
-        # next pass: still-unassigned pods stay in play, but rolled-back
-        # gangs back off for the rest of the batch
-        active = active & ~keep & ~failed
+            loc = final - off
+            own = keep & (loc >= 0) & (loc < n_loc)
+            loc_c = jnp.clip(loc, 0, n_loc - 1)
+            requested = requested.at[loc_c].add(
+                jnp.where(own[:, None], pods_f.requests, 0))
+            est_local = est_local.at[loc_c].add(
+                jnp.where(own[:, None], pod_est_all, 0))
+            if cur_quota is not None:
+                cur_quota = charge_quota_batch(
+                    cur_quota, pods_f.requests, pods_f.quota_id, keep,
+                    pods_f.non_preemptible)
+            total = jnp.where(keep, final, total)
+            kept_so_far = kept_so_far | keep
+            # next pass: still-unassigned pods stay in play, but rolled-back
+            # gangs back off for the rest of the batch
+            active = active & ~keep & ~failed
 
     return total, st_local.replace(node_requested=requested), cur_quota
 

@@ -84,6 +84,7 @@ class QuotaDeviceState:
         return state, index
 
 
+@jax.named_scope("quota_admission")
 def quota_admission_mask(
     quota: QuotaDeviceState,
     pod_requests: jnp.ndarray,     # (P, R) int32

@@ -1,8 +1,8 @@
 """Scheduler monitor: per-round phase timing with slow-round logging.
 
 Equivalent of ``frameworkext/scheduler_monitor.go:44-100`` — records how long
-each scheduling phase takes, keeps a rolling history, and flags rounds that
-exceed the configured timeout (the reference logs pods stuck in a phase).
+each scheduling phase takes and logs phases that exceed the configured
+timeout (the reference logs pods stuck in a phase).
 
 Observability duties (PR 3): each phase is also a trace span (child of
 the round span the scheduler opens, when one is active) and feeds the
@@ -17,7 +17,6 @@ from __future__ import annotations
 import contextlib
 import logging
 import time
-from collections import defaultdict, deque
 
 from koordinator_tpu import metrics, timeline, tracing
 
@@ -25,14 +24,10 @@ logger = logging.getLogger("koordinator_tpu.scheduler")
 
 
 class SchedulerMonitor:
-    def __init__(self, timeout_sec: float = 1.0, history: int = 256,
+    def __init__(self, timeout_sec: float = 1.0,
                  clock=time.perf_counter):
         self.timeout_sec = timeout_sec
         self.clock = clock
-        self.phase_history: dict[str, deque[float]] = defaultdict(
-            lambda: deque(maxlen=history)
-        )
-        self.slow_rounds = 0
         #: per-phase wall times of the round in flight (reset by
         #: start_round; the flight recorder snapshots it at round end)
         self.round_timings: dict[str, float] = {}
@@ -60,23 +55,19 @@ class SchedulerMonitor:
         ctx = tracing.current_context()
         span_cm = (tracing.TRACER.span(f"phase.{name}") if ctx is not None
                    else contextlib.nullcontext())
-        # the timeline segment is timed on perf_counter directly (not
+        # the timeline span is timed on perf_counter directly (not
         # self.clock, which tests may fake): cycle windows clip by real
-        # monotonic time and a synthetic clock would mis-place segments
-        tl_start = (time.perf_counter() if timeline.RECORDER.enabled
-                    else 0.0)
+        # monotonic time and a synthetic clock would mis-place segments.
+        # A section, so the spans recorded inside name it as parent
+        tl = timeline.RECORDER.section(
+            timeline.PHASE_CAUSES.get(name, "host_other"),
+            f"phase.{name}", self.tenant)
         start = self.clock()
         try:
-            with span_cm:
+            with span_cm, tl:
                 yield
         finally:
-            if timeline.RECORDER.enabled:
-                timeline.RECORDER.add(
-                    tl_start, time.perf_counter(),
-                    timeline.PHASE_CAUSES.get(name, "host_other"),
-                    f"phase.{name}", self.tenant)
             elapsed = self.clock() - start + carry_s
-            self.phase_history[name].append(elapsed)
             self.round_timings[name] = (
                 self.round_timings.get(name, 0.0) + elapsed)
             # feed the prometheus surface too (the reference exports
@@ -93,23 +84,7 @@ class SchedulerMonitor:
                 metrics.solver_batch_latency.observe(
                     elapsed, exemplar=exemplar)
             if elapsed > self.timeout_sec:
-                self.slow_rounds += 1
                 logger.warning(
                     "scheduling phase %s took %.3fs (timeout %.3fs)",
                     name, elapsed, self.timeout_sec,
                 )
-
-    def stats(self) -> dict[str, dict[str, float]]:
-        out = {}
-        for name, hist in self.phase_history.items():
-            if not hist:
-                continue
-            s = sorted(hist)
-            out[name] = {
-                "count": float(len(s)),
-                "mean": sum(s) / len(s),
-                "p50": s[len(s) // 2],
-                "p99": s[min(len(s) - 1, int(len(s) * 0.99))],
-                "max": s[-1],
-            }
-        return out

@@ -642,7 +642,17 @@ class Scheduler:
         """Shared freeing for a bound pod leaving the cluster (informer
         delete, eviction, preemption): fine-grained allocations, then the
         reservation-aware node unreserve."""
+        tl_t0 = time.perf_counter()
         self._release_fine_grained(bp.name, bp.node)
+        tl_t1 = time.perf_counter()
+        timeline.RECORDER.add(tl_t0, tl_t1, "host_other",
+                              "release.fine_grained", self.tenant)
+        self._unreserve_bound(bp)
+        timeline.RECORDER.add(tl_t1, time.perf_counter(), "host_other",
+                              "release.unreserve", self.tenant)
+
+    def _unreserve_bound(self, bp: BoundPod) -> None:
+        """The reservation-aware node unreserve of a leaving bound pod."""
         if bp.node not in self.snapshot.node_index:
             return
         if (self.snapshot.node_generation.get(bp.node, 0)
@@ -896,6 +906,7 @@ class Scheduler:
                 self._enqueue_locked(pod)
 
     def _enqueue_locked(self, pod: PodSpec) -> None:  # koordlint: guarded-by(self.lock)
+        tl_t0 = time.perf_counter()
         # arrival-process accounting (ISSUE 9): rate() of this is
         # the admission rate the churn load generator drives.  Only
         # NEW names count — a resync bootstrap replays pod_add for
@@ -925,6 +936,8 @@ class Scheduler:
             sp.end()
             self.pod_traces[pod.name] = sp.context()
             self._register_pod_trace(pod.name, sp.trace_id)
+        timeline.RECORDER.add(tl_t0, time.perf_counter(), "host_other",
+                              "enqueue", self.tenant)
 
     def _register_pod_trace(self, name: str, trace_id: str) -> None:
         """Bounded name -> trace_id map for /debug/trace/<pod>: survives
@@ -1445,7 +1458,12 @@ class Scheduler:
                                           start_wall, duration, path,
                                           half="round")
             if self._round_recordable:
-                self._publish_round_introspection()
+                # after the last monitor phase: a span of its own, or the
+                # round's tail (a device reduction and its wait) has no
+                # name; the pipelined path has round.commit around it
+                with timeline.RECORDER.section(
+                        "host_other", "round.introspection", self.tenant):
+                    self._publish_round_introspection()
             if (self._round_recordable and self.tenant_front is None
                     and timeline.RECORDER.enabled):
                 # an untenanted scheduler's round IS its cycle: the
@@ -1678,9 +1696,14 @@ class Scheduler:
             # one attempt per workload key per round — a gang is one
             # scheduling attempt, not len(members) attempts; synthetic
             # reserve-pods are not workloads
-            for key in {pod.gang or pod.name for pod in pods
-                        if not pod.name.startswith(RSV_POD_PREFIX)}:
-                self.auditor.record_attempt(key)
+            keys = {pod.gang or pod.name for pod in pods
+                    if not pod.name.startswith(RSV_POD_PREFIX)}
+            # between two monitor phases: without a span of its own the
+            # loop is 1.2 s of a 50,000-pod round that nothing names
+            with timeline.RECORDER.section("host_other", "audit.attempts",
+                                           self.tenant, n=len(keys)):
+                for key in keys:
+                    self.auditor.record_attempt(key)
 
         with self.monitor.phase("BatchBuild"):
             self.snapshot.flush()
@@ -1728,6 +1751,7 @@ class Scheduler:
         # produces exactly ONE Solve latency observation — the SLO
         # engine's per-observation bad fractions must not dilute
         dispatch_t0 = time.perf_counter()
+        tl_t0 = timeline.RECORDER.open("round.dispatch")
         try:
             if self.faults is not None:
                 # chaos seam: an injected solve delay lands in the
@@ -1846,15 +1870,12 @@ class Scheduler:
             raise
         finally:
             self._solve_carry_s += time.perf_counter() - dispatch_t0
-            if timeline.RECORDER.enabled:
+            timeline.RECORDER.close(tl_t0, "dispatch", self.tenant)
+            if timeline.RECORDER.enabled and self._tl_device_t0 is None:
                 # the async solve starts executing during this window:
                 # its start doubles as the device-busy leading edge the
                 # idle derivation pairs with the block edge
-                timeline.RECORDER.add(
-                    dispatch_t0, time.perf_counter(), "dispatch",
-                    "round.dispatch", self.tenant)
-                if self._tl_device_t0 is None:
-                    self._tl_device_t0 = dispatch_t0
+                self._tl_device_t0 = dispatch_t0
         # the prepass may have shrunk the batch and charged the quota
         handle.batch, handle.quota, handle.solver = batch, quota, solver
         # stamped here so the pipelined solve-half flight record carries
@@ -2152,18 +2173,11 @@ class Scheduler:
 
         if self.explanations is not None:
             # persist AFTER PostFilter so nominations land on the CR
-            # (successful binds already cleared theirs in _commit_bind)
-            for pod in pods:
-                if pod.name.startswith(RSV_POD_PREFIX):
-                    # an unplaced reservation retries next round; it is not
-                    # a user pod and must not persist ScheduleFailed CRs
-                    continue
-                diag = result.failures.get(pod.name)
-                if diag is not None:
-                    self.explanations.record(pod.name, diag)
-                    if self.auditor is not None:
-                        self.auditor.record(pod.gang or pod.name,
-                                            "ScheduleFailed", diag.message())
+            # (successful binds already cleared theirs in _commit_bind);
+            # after the last phase, so under a span of its own
+            with timeline.RECORDER.section("host_other", "diagnose.persist",
+                                           self.tenant):
+                self._persist_failures(pods, result)
 
         # every round in an ON mode runs the finish hook: "lp" gang
         # rounds must reset _last_quality_iters to 0 or their flight
@@ -2178,6 +2192,25 @@ class Scheduler:
         # own commit edge instead of inheriting this round's
         self._journey_round_t0 = None
         return result
+
+    # koordlint: guarded-by(self.lock)
+    def _persist_failures(self, pods, result: SchedulingResult) -> None:
+        for pod in pods:
+            if pod.name.startswith(RSV_POD_PREFIX):
+                # an unplaced reservation retries next round; it is not
+                # a user pod and must not persist ScheduleFailed CRs
+                continue
+            diag = result.failures.get(pod.name)
+            if diag is not None:
+                # one diagnose.explain run: the store's share
+                tl_t0 = time.perf_counter()
+                self.explanations.record(pod.name, diag)
+                timeline.RECORDER.add(
+                    tl_t0, time.perf_counter(), "host_other",
+                    "diagnose.explain", self.tenant)
+                if self.auditor is not None:
+                    self.auditor.record(pod.gang or pod.name,
+                                        "ScheduleFailed", diag.message())
 
     # -- solve-quality mode (ISSUE 13) --------------------------------------
 
@@ -2250,8 +2283,11 @@ class Scheduler:
         t1 = time.perf_counter()
         self._solve_device_s += t1 - t0
         if timeline.RECORDER.enabled:
+            # merge=False: device_block equals pipeline_host_wait_fraction
+            # by construction, and idle is derived from exact busy edges
             timeline.RECORDER.add(t0, t1, "device_block",
-                                  "block_until_ready", self.tenant)
+                                  "block_until_ready", self.tenant,
+                                  merge=False)
             # device-busy span: the dispatch edge (when this round
             # dispatched async work) to this block edge.  A block with
             # no tracked dispatch (rescue pass) contributes just its
@@ -2259,7 +2295,7 @@ class Scheduler:
             busy_t0 = getattr(self, "_tl_device_t0", None)
             timeline.RECORDER.add(busy_t0 if busy_t0 is not None else t0,
                                   t1, timeline.DEVICE_BUSY,
-                                  "solve", self.tenant)
+                                  "solve", self.tenant, merge=False)
             self._tl_device_t0 = None
         return value
 
@@ -2489,7 +2525,9 @@ class Scheduler:
         state, quota, est_accum = ctx["state"], ctx["quota"], ctx["est_accum"]
         k, method, use_mesh = ctx["k"], ctx["method"], ctx["use_mesh"]
         try:
-            a_np = np.asarray(self._block_timed(ctx["a"]))
+            # a copy: np.asarray of a device array is a read-only view,
+            # and a later pass writes what it placed into it
+            a_np = np.array(self._block_timed(ctx["a"]))
             for _ in range(1, self.gang_passes):
                 leftover = np.asarray(batch.valid) & (a_np < 0)
                 if not leftover.any():
@@ -2671,6 +2709,9 @@ class Scheduler:
         ``charge_quota=False`` converts a nomination whose quota charge is
         already on the tree (``_nomination_assume``)."""
         commit_t0 = time.perf_counter()
+        # the batch commit's bind.* spans, one pod at a time: each stamp
+        # closes the step above it
+        tl, tenant = timeline.RECORDER, self.tenant
         result.assignments[pod.name] = node
         if self.pending.pop(pod.name, None) is not None:
             self._pending_rev += 1
@@ -2685,8 +2726,12 @@ class Scheduler:
             rsv_generation=rsv_generation,
             node_generation=self.snapshot.node_generation.get(node, 0),
         )
+        t_registry = time.perf_counter()
+        tl.add(commit_t0, t_registry, "bind_commit", "bind.registry", tenant)
         if charge_quota:
             self._charge_quota_used(pod, sign=1)
+        t_quota = time.perf_counter()
+        tl.add(t_registry, t_quota, "bind_commit", "bind.quota", tenant)
         self._allocate_fine_grained(pod, node)
         # bind marker in the POD's trace (parented to its enqueue span,
         # linked to the round's trace by attribute), and the trace
@@ -2705,8 +2750,12 @@ class Scheduler:
             sp.end()
             self.resource_status.setdefault(pod.name, {})[
                 tracing.TRACE_ANNOTATION] = sp.context().to_annotation()
+        t_surfaces = time.perf_counter()
+        tl.add(t_quota, t_surfaces, "bind_commit", "bind.surfaces", tenant)
         if self.bind_fn is not None:
             self.bind_fn(pod.name, node)
+        t_emit = time.perf_counter()
+        tl.add(t_surfaces, t_emit, "bind_commit", "bind.emit", tenant)
         # success side of ScheduleExplanation/auditor lifecycle lives here so
         # nominated binds (Nominated phase, before _active_pods) clear their
         # stale failure explanations too
@@ -2714,6 +2763,8 @@ class Scheduler:
             self.explanations.delete(pod.name)
         if self.auditor is not None:
             self.auditor.record(pod.gang or pod.name, "ScheduleSuccess", node)
+        t_explain = time.perf_counter()
+        tl.add(t_emit, t_explain, "bind_commit", "bind.explain", tenant)
         if journey.LEDGER.enabled:
             round_t0 = self._journey_round_t0
             journey.LEDGER.record_bind_batch(
@@ -2721,6 +2772,8 @@ class Scheduler:
                 round_start_perf=(round_t0 if round_t0 is not None
                                   else commit_t0),
                 commit_perf=commit_t0)
+            tl.add(t_explain, time.perf_counter(), "bind_commit",
+                   "bind.journey", tenant)
 
     # koordlint: guarded-by(self.lock)
     def _commit_bind_batch(self, binds: list[tuple[PodSpec, str]],
@@ -2744,76 +2797,89 @@ class Scheduler:
         if not binds:
             return
         commit_t0 = time.perf_counter()
+        tl, tenant = timeline.RECORDER, self.tenant
         # phase 1: registry bookkeeping (assignments / pending /
         # nominations / bound), in order — later same-name entries win
         # exactly as they would sequentially
-        for pod, node in binds:
-            result.assignments[pod.name] = node
-            if self.pending.pop(pod.name, None) is not None:
-                self._pending_rev += 1
-            self.nominations.pop(pod.name, None)
-            self._nomination_gen.pop(pod.name, None)
-            self.bound[pod.name] = BoundPod(
-                name=pod.name, node=node, requests=pod.requests,
-                priority=pod.priority, quota=pod.quota,
-                non_preemptible=pod.non_preemptible,
-                labels=pod.labels, gang=pod.gang,
-                node_generation=self.snapshot.node_generation.get(node, 0),
-            )
+        with tl.section("bind_commit", "bind.registry", tenant):
+            for pod, node in binds:
+                result.assignments[pod.name] = node
+                if self.pending.pop(pod.name, None) is not None:
+                    self._pending_rev += 1
+                self.nominations.pop(pod.name, None)
+                self._nomination_gen.pop(pod.name, None)
+                self.bound[pod.name] = BoundPod(
+                    name=pod.name, node=node, requests=pod.requests,
+                    priority=pod.priority, quota=pod.quota,
+                    non_preemptible=pod.non_preemptible,
+                    labels=pod.labels, gang=pod.gang,
+                    node_generation=self.snapshot.node_generation.get(
+                        node, 0),
+                )
         # phase 2: grouped quota recharge — one used-vector update per
         # touched quota node instead of one per pod
         if self.quota_tree is not None:
-            groups: dict[tuple[str, bool], list[np.ndarray]] = {}
-            for pod, _node in binds:
-                if pod.quota and pod.quota in self.quota_tree.nodes:
-                    groups.setdefault(
-                        (pod.quota, bool(pod.non_preemptible)), []
-                    ).append(pod.requests)
-            for (quota, non_preemptible), reqs in groups.items():
-                q = self.quota_tree.nodes[quota]
-                total = np.sum(np.stack(reqs).astype(np.int64), axis=0)
-                q.used = q.used + total
-                if non_preemptible:
-                    q.non_preemptible_used = (
-                        q.non_preemptible_used + total)
+            with tl.section("bind_commit", "bind.quota", tenant):
+                groups: dict[tuple[str, bool], list[np.ndarray]] = {}
+                for pod, _node in binds:
+                    if pod.quota and pod.quota in self.quota_tree.nodes:
+                        groups.setdefault(
+                            (pod.quota, bool(pod.non_preemptible)), []
+                        ).append(pod.requests)
+                for (quota, non_preemptible), reqs in groups.items():
+                    q = self.quota_tree.nodes[quota]
+                    total = np.sum(np.stack(reqs).astype(np.int64), axis=0)
+                    q.used = q.used + total
+                    if non_preemptible:
+                        q.non_preemptible_used = (
+                            q.non_preemptible_used + total)
         # phase 3: per-pod surfaces, in bind order (fine-grained state
         # mutates per node+pod; trace stamping must follow it because
         # _allocate_fine_grained replaces resource_status wholesale)
-        for pod, node in binds:
-            self._allocate_fine_grained(pod, node)
-            ctx = self.pod_traces.pop(pod.name, None)
-            if ctx is not None:
-                sp = tracing.TRACER.start_span(
-                    "scheduler.bind", service="scheduler", parent=ctx,
-                    attributes={"pod": pod.name, "node": node,
-                                "round": self.round_seq,
-                                "round_trace_id":
-                                    tracing.current_trace_id()})
-                sp.end()
-                self.resource_status.setdefault(pod.name, {})[
-                    tracing.TRACE_ANNOTATION] = (
-                        sp.context().to_annotation())
-            if self.explanations is not None:
-                self.explanations.delete(pod.name)
-            if self.auditor is not None:
-                self.auditor.record(pod.gang or pod.name,
-                                    "ScheduleSuccess", node)
-        if self.bind_batch_fn is not None:
-            self.bind_batch_fn([(pod.name, node) for pod, node in binds])
-        elif self.bind_fn is not None:
+        with tl.section("bind_commit", "bind.surfaces", tenant):
             for pod, node in binds:
-                self.bind_fn(pod.name, node)
+                self._allocate_fine_grained(pod, node)
+                ctx = self.pod_traces.pop(pod.name, None)
+                if ctx is not None:
+                    sp = tracing.TRACER.start_span(
+                        "scheduler.bind", service="scheduler", parent=ctx,
+                        attributes={"pod": pod.name, "node": node,
+                                    "round": self.round_seq,
+                                    "round_trace_id":
+                                        tracing.current_trace_id()})
+                    sp.end()
+                    self.resource_status.setdefault(pod.name, {})[
+                        tracing.TRACE_ANNOTATION] = (
+                            sp.context().to_annotation())
+                if self.explanations is not None:
+                    # one bind.explain run under bind.surfaces: the
+                    # store's share of the commit, n = deletes
+                    tl_t0 = time.perf_counter()
+                    self.explanations.delete(pod.name)
+                    tl.add(tl_t0, time.perf_counter(), "bind_commit",
+                           "bind.explain", tenant)
+                if self.auditor is not None:
+                    self.auditor.record(pod.gang or pod.name,
+                                        "ScheduleSuccess", node)
+        with tl.section("bind_commit", "bind.emit", tenant):
+            if self.bind_batch_fn is not None:
+                self.bind_batch_fn(
+                    [(pod.name, node) for pod, node in binds])
+            elif self.bind_fn is not None:
+                for pod, node in binds:
+                    self.bind_fn(pod.name, node)
         # journey ledger (ISSUE 20): one vectorized pass records the whole
         # round's e2e + stage latencies.  Pure observation — runs after
         # every decision and quota charge above is already committed, so
         # KOORD_JOURNEY=0 is bit-identical on scheduling outcomes.
         if journey.LEDGER.enabled:
-            round_t0 = self._journey_round_t0
-            journey.LEDGER.record_bind_batch(
-                self.tenant, [pod for pod, _node in binds],
-                round_start_perf=(round_t0 if round_t0 is not None
-                                  else commit_t0),
-                commit_perf=commit_t0)
+            with tl.section("bind_commit", "bind.journey", tenant):
+                round_t0 = self._journey_round_t0
+                journey.LEDGER.record_bind_batch(
+                    self.tenant, [pod for pod, _node in binds],
+                    round_start_perf=(round_t0 if round_t0 is not None
+                                      else commit_t0),
+                    commit_perf=commit_t0)
 
     def _allocate_fine_grained(self, pod: PodSpec, node: str) -> None:
         """Reserve-phase fine-grained allocation (nodenumaresource Reserve:

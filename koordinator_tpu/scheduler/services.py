@@ -153,7 +153,10 @@ def debug_timeline_body(scheduler, params: dict | None = None) -> dict:
 
     The recorder is process-wide (``timeline.RECORDER``): a
     multi-tenant front's cycles and an untenanted scheduler's
-    one-round cycles land in the same ring.  400 on a malformed
+    one-round cycles land in the same ring, each after a doc for the
+    wall since the window before it; ``mode`` says which kind a doc is
+    (``round``, a cycle's ``serial``/``pipelined``/``batched``, or
+    ``ingest``).  400 on a malformed
     bound; an empty ``cycles`` list (not an error) means no cycle has
     run with the recorder armed (e.g. ``--no-timeline``)."""
     from koordinator_tpu import timeline

@@ -14,6 +14,7 @@ import dataclasses
 
 import numpy as np
 
+from koordinator_tpu import timeline
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
 from koordinator_tpu.state.cluster_state import ClusterState, _bucket
 
@@ -258,6 +259,12 @@ class ClusterSnapshot:
         if not self._dirty:
             return 0
         rows = sorted(self._dirty)
+        with timeline.RECORDER.section("host_other", "snapshot.flush",
+                                       n=len(rows)):
+            self._flush_rows(rows)
+        return len(rows)
+
+    def _flush_rows(self, rows: list[int]) -> None:
         self._dirty.clear()
         if self._reset_requested:
             reset = jnp.asarray(sorted(self._reset_requested), dtype=jnp.int32)
@@ -297,7 +304,6 @@ class ClusterSnapshot:
             node_valid=jnp.asarray(valid),
             node_class=jnp.asarray(nclass),
         )
-        return k
 
     # -- accounting ---------------------------------------------------------
 

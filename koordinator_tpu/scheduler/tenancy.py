@@ -671,17 +671,15 @@ class TenantScheduler:
         # the per-tenant _round_dispatch window, and the async solve
         # starts executing inside it — its start is the device-busy
         # leading edge each tenant's block pairs with
-        dispatch_t0 = time.perf_counter()
+        dispatch_t0 = timeline.RECORDER.open("tenant_axis.dispatch")
         try:
             if plain:
                 self._dispatch_tenant_axis_inner(plain)
             if quality:
                 self._dispatch_quality_axis_inner(quality)
         finally:
-            if timeline.RECORDER.enabled:
-                timeline.RECORDER.add(
-                    dispatch_t0, time.perf_counter(), "dispatch",
-                    "tenant_axis.dispatch")
+            timeline.RECORDER.close(dispatch_t0, "dispatch")
+            if dispatch_t0:
                 for t, _ in live:
                     if t.scheduler._tl_device_t0 is None:
                         t.scheduler._tl_device_t0 = dispatch_t0

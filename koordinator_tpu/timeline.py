@@ -35,7 +35,35 @@ residual — the phase-accounting invariant test asserts it stays under
 
 ``device_busy`` segments are NOT host work: they mark the device
 executing between a dispatch edge and its block edge, and only feed
-the ``device_idle_fraction`` derivation.
+the ``device_idle_fraction`` derivation.  ``rpc_client`` segments (an
+``RpcClient.call`` and the ``rpc.wait`` inside it) are a CLIENT's view
+of work another thread does; the sweep ignores them too.
+
+**One record per span** (ISSUE 24): start, end, cause, name, tenant,
+plus ``parent`` (the span open on the same thread when it was
+recorded: :meth:`TimelineRecorder.section` and ``open``/``close`` keep
+a thread-local stack, ``add`` takes its top), ``thread``, ``n`` and
+``busy_s``.  Back-to-back spans of one name, parent, tenant and thread
+are ONE stored record with ``n`` members and ``busy_s`` the members'
+exact sum — a 50,000-pod wave is a handful of records.  Back-to-back:
+the thread never stood outside every span for more than
+:data:`COALESCE_S` in between — for a root span, the next start lies
+within 1 ms of the last end; a child's gaps are its parent's other
+work (the ``wire.decode`` of 500 frames of 3.7 ms each is one run while
+the frames are).  The sweep holds a run's extent only at the density
+``busy_s / extent``, so the gaps between members attribute to what
+contains them.  What the ring still drops is counted in
+``timeline_segments_dropped_total``.
+
+**Windows.**  ``finish_cycle(t0, t1)`` reconstructs the round or cycle
+window, and before it the wall since the previous window as a doc of
+its own, ``mode="ingest"``: what the program did between rounds
+(deltasync applies, frames, releases).  An ingest doc's uncovered wall
+is the program standing idle, not a residual — the gauges, the 5%
+invariant and ``tools/soak_report.py`` judge ``round``/``cycle`` docs
+only.  Every doc carries ``by_name``: ``{name: {n, busy_s, self_s,
+wait}}``, self time = busy minus what the span's children on the same
+thread cover; :data:`WAIT_NAMES` are waits, never summed as work.
 
 **Attribution semantics.** ``host_wait_attribution{cause}`` decomposes
 the WHOLE cycle wall into fractions that sum to 1.0 (including
@@ -52,16 +80,22 @@ is pure host-side timing — it never touches solve inputs either way).
 
 Everything here is stdlib-only and thread-safe: segments arrive from
 the cycle thread, RPC reader threads (deltasync applies, wire codec),
-and gateway threads concurrently.
+and gateway threads concurrently.  ``section()`` additionally enters a
+``jax.profiler.TraceAnnotation("koord:<name>")`` when ``jax`` is
+already imported (``sys.modules``; this module never imports it), so a
+``/debug/profile`` capture shows host spans beside the device ops.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import threading
 import time
 from collections import deque
+
+_perf_counter = time.perf_counter
 
 #: attribution priority, most specific first (see the module docstring)
 CAUSES = ("device_block", "lock_wait", "json_codec", "deltasync_apply",
@@ -72,12 +106,54 @@ UNATTRIBUTED = "unattributed"
 ATTRIBUTION_CAUSES = CAUSES + (UNATTRIBUTED,)
 #: device-occupancy marker (feeds device_idle_fraction, not attribution)
 DEVICE_BUSY = "device_busy"
+#: a client's view of a call another thread serves (``rpc.call.<TYPE>``
+#: and its ``rpc.wait``): recorded, never attributed
+RPC_CLIENT = "rpc_client"
+#: doc mode of the wall between two rounds or cycles
+INGEST = "ingest"
+#: span names that are waits on another thread's work: flagged in
+#: ``by_name`` and never summed as work
+WAIT_NAMES = frozenset({"rpc.wait", "round_lock.acquire"})
+#: a thread idle (outside every span) for longer than this ends its runs
+COALESCE_S = 1e-3
 
 _PRIORITY = {cause: i for i, cause in enumerate(CAUSES)}
 
 #: monitor phase name -> attribution cause (anything unlisted is
 #: host_other; the phase name survives on the segment for the gantt)
 PHASE_CAUSES = {"BatchBuild": "build_batch", "Bind": "bind_commit"}
+
+# one stored record (a list, so a run extends in place)
+(_START, _END, _CAUSE, _NAME, _TENANT, _PARENT, _THREAD, _N, _BUSY, _GEN,
+ _EPOCH) = range(11)
+
+
+class _Node:
+    """One position in a thread's span tree (a name under a path of
+    open parents) and the running record of the spans recorded there."""
+
+    __slots__ = ("name", "parent", "kids", "rec")
+
+    def __init__(self, name: str, parent: str):
+        self.name = name
+        self.parent = parent
+        self.kids: dict[str, _Node] = {}
+        self.rec: list | None = None
+
+
+class _ThreadState:
+    """One thread's span tree, its stack of open spans, and its idle
+    epoch: bumped whenever the thread stood outside every span for more
+    than :data:`COALESCE_S`, which ends its runs."""
+
+    __slots__ = ("ident", "root", "stack", "epoch", "root_end")
+
+    def __init__(self):
+        self.ident = threading.get_ident()
+        self.root = _Node("", "")
+        self.stack: list[_Node] = [self.root]
+        self.epoch = 0
+        self.root_end = 0.0
 
 
 def _merge_intervals(intervals: list[tuple[float, float]]
@@ -96,23 +172,27 @@ def _merge_intervals(intervals: list[tuple[float, float]]
 
 def sweep_attribution(segments: list[dict], t0: float, t1: float
                       ) -> tuple[dict, list[dict]]:
-    """Attribute every instant of [t0, t1] to exactly one cause.
+    """Attribute every instant of [t0, t1] to a cause.
 
     An event sweep over the segment boundaries: at each instant the
     highest-priority active segment's cause wins (nesting puts the
     specific segment — a block wait inside the Solve phase, a codec
-    call inside a deltasync apply — above its container).  Returns
-    ``(seconds_by_cause, chain)`` where the chain is the covering
-    sequence of maximal same-cause intervals — the cycle's critical
-    path, since the cycle runs to completion and at every instant the
-    chain names what the wall clock was spent on.  This runs once per
-    cycle on the scheduling thread, so it is O(n log n) in segments,
-    not elementary-intervals x segments.
+    call inside a deltasync apply — above its container).  A segment
+    that is a RUN of back-to-back spans (``busy_s`` below its extent)
+    holds its extent only at the density ``busy_s / extent``: the rest
+    of each instant flows on to the next priority, so the gaps between
+    a run's members attribute to what contains them, not to the run.
+    Returns ``(seconds_by_cause, chain)`` where the chain is the
+    covering sequence of maximal same-cause intervals — the cycle's
+    critical path, since the cycle runs to completion and at every
+    instant the chain names what the wall clock was spent on.  This
+    runs once per cycle on the scheduling thread, so it is O(n log n)
+    in segments, not elementary-intervals x segments.
     """
     totals = {cause: 0.0 for cause in ATTRIBUTION_CAUSES}
     if t1 <= t0:
         return totals, []
-    events: list[tuple[float, int, int, str]] = []
+    events: list[tuple[float, int, int, str, float]] = []
     for s in segments:
         prio = _PRIORITY.get(s["cause"])
         if prio is None:
@@ -120,22 +200,35 @@ def sweep_attribution(segments: list[dict], t0: float, t1: float
         start, end = max(s["start"], t0), min(s["end"], t1)
         if end <= start:
             continue
-        events.append((start, 1, prio, s["name"]))
-        events.append((end, -1, prio, s["name"]))
+        extent = s["end"] - s["start"]
+        density = min(s.get("busy_s", extent) / extent, 1.0)
+        events.append((start, 1, prio, s["name"], density))
+        events.append((end, -1, prio, s["name"], density))
     events.sort(key=lambda e: e[0])
     counts = [0] * len(CAUSES)
+    density_of = [0.0] * len(CAUSES)
     names: list[list[str]] = [[] for _ in CAUSES]
     chain: list[dict] = []
 
     def emit(lo: float, hi: float) -> None:
         if hi <= lo:
             return
-        best = next((p for p, c in enumerate(counts) if c), None)
+        left, best = 1.0, None
+        for p, active in enumerate(counts):
+            if not active:
+                continue
+            if best is None:
+                best = p
+            share = left * min(density_of[p], 1.0)
+            totals[CAUSES[p]] += share * (hi - lo)
+            left -= share
+            if left <= 0.0:
+                break
+        totals[UNATTRIBUTED] += max(left, 0.0) * (hi - lo)
         if best is None:
             cause, name = UNATTRIBUTED, ""
         else:
             cause, name = CAUSES[best], names[best][-1]
-        totals[cause] += hi - lo
         if chain and chain[-1]["cause"] == cause:
             chain[-1]["end"] = hi
         else:
@@ -148,12 +241,16 @@ def sweep_attribution(segments: list[dict], t0: float, t1: float
         now = events[i][0]
         emit(prev, now)
         while i < n and events[i][0] == now:
-            _, delta, prio, name = events[i]
+            _, delta, prio, name, density = events[i]
             if delta > 0:
                 counts[prio] += 1
+                density_of[prio] += density
                 names[prio].append(name)
             else:
                 counts[prio] -= 1
+                # an empty level reads exactly 0, whatever the float sum
+                density_of[prio] = (density_of[prio] - density
+                                    if counts[prio] else 0.0)
                 names[prio].remove(name)
             i += 1
         prev = now
@@ -180,12 +277,37 @@ def device_idle(segments: list[dict], t0: float, t1: float
     return idle, sum(e - s for s, e in busy)
 
 
+def by_name(segments: list[dict]) -> dict:
+    """``{name: {n, busy_s, self_s, wait}}`` over clipped segments:
+    busy is the members' sum, self time is busy minus what the span's
+    children (``parent`` == its name) on the same thread cover."""
+    own: dict[tuple[int, str], list[float]] = {}
+    children: dict[tuple[int, str], float] = {}
+    for s in segments:
+        if s["cause"] == DEVICE_BUSY:
+            continue
+        slot = own.setdefault((s["thread"], s["name"]), [0.0, 0.0])
+        slot[0] += s["n"]
+        slot[1] += s["busy_s"]
+        if s["parent"]:
+            key = (s["thread"], s["parent"])
+            children[key] = children.get(key, 0.0) + s["busy_s"]
+    out: dict[str, dict] = {}
+    for (thread, name), (n, busy) in own.items():
+        doc = out.setdefault(name, {"n": 0.0, "busy_s": 0.0, "self_s": 0.0,
+                                    "wait": name in WAIT_NAMES})
+        doc["n"] += n
+        doc["busy_s"] += busy
+        doc["self_s"] += max(busy - children.get((thread, name), 0.0), 0.0)
+    return out
+
+
 class TimelineRecorder:
-    """Lock-protected segment sink + per-cycle reconstruction ring.
+    """Span sink + per-window reconstruction ring.
 
     One module-level instance (:data:`RECORDER`) serves every
-    scheduler in the process — segments carry a tenant tag, cycle
-    windows clip by time, and the ring backs ``/debug/timeline``.
+    scheduler in the process — segments carry a tenant tag, windows
+    clip by time, and the ring backs ``/debug/timeline``.
     """
 
     def __init__(self, enabled: bool = True, max_segments: int = 16384,
@@ -194,6 +316,14 @@ class TimelineRecorder:
         self._lock = threading.Lock()
         self._segments: deque = deque(maxlen=max_segments)
         self._cycles: deque = deque(maxlen=max_cycles)
+        self._tls = threading.local()
+        #: bumped by every finish_cycle: a record of an older generation
+        #: was handed to a window and is never extended again
+        self._gen = 0
+        #: end of the newest reconstructed window (None until the first)
+        self._last_end: float | None = None
+        #: records the full ring pushed out before any window read them
+        self.dropped = 0
 
     # -- the hot-path surface -------------------------------------------
 
@@ -206,52 +336,142 @@ class TimelineRecorder:
         re-enable can't attribute a stale window."""
         self._enabled = bool(enabled)
         with self._lock:
-            self._segments.clear()
+            self._forget()
+
+    def _forget(self) -> None:  # koordlint: guarded-by(self._lock)
+        """Drop what no window has read; no run goes on across it."""
+        self._segments.clear()
+        self._gen += 1
+        self._last_end = None
+
+    def _thread(self) -> _ThreadState:
+        """This thread's state, made on its first span."""
+        state = self._tls.state = _ThreadState()
+        return state
+
+    def _record(self, st: _ThreadState, node: _Node, start: float,
+                end: float, cause: str, tenant: str, n: int,
+                merge: bool = True) -> None:
+        """One span of ``n`` members at ``node`` of ``st``'s thread:
+        extends the running record there, or starts a new one.  A run
+        goes on while the thread is never idle (outside every span) for
+        more than COALESCE_S: for a root span that is "next start
+        within 1 ms of the last end"; a child's gaps are its parent's
+        other work."""
+        if not node.parent:
+            if start - st.root_end > COALESCE_S:
+                st.epoch += 1
+            st.root_end = end
+        rec = node.rec
+        if (merge and rec is not None and rec[_GEN] == self._gen
+                and rec[_EPOCH] == st.epoch and rec[_TENANT] == tenant):
+            # the run goes on: no lock, this thread owns the record
+            rec[_END] = end
+            rec[_N] += n
+            rec[_BUSY] += end - start
+            return
+        node.rec = rec = [start, end, cause, node.name, tenant, node.parent,
+                          st.ident, n, end - start, self._gen, st.epoch]
+        with self._lock:
+            if len(self._segments) == self._segments.maxlen:
+                self._count_drop()
+            self._segments.append(rec)
+
+    def _count_drop(self) -> None:  # koordlint: guarded-by(self._lock)
+        from koordinator_tpu import metrics
+
+        self.dropped += 1
+        metrics.timeline_segments_dropped.inc()
 
     def add(self, start: float, end: float, cause: str,
-            name: str = "", tenant: str = "") -> None:
-        """Record one finished segment (perf_counter timestamps)."""
+            name: str = "", tenant: str = "", n: int = 1,
+            merge: bool = True) -> None:
+        """Record one finished span (perf_counter timestamps) of ``n``
+        members under the span open on this thread.  ``merge=False``
+        keeps the span a record of its own: for the few whose exact
+        intervals a gauge equals by construction."""
         if not self._enabled or end <= start:
             return
-        with self._lock:
-            self._segments.append((start, end, cause, name, tenant))
+        try:
+            st = self._tls.state
+        except AttributeError:
+            st = self._thread()
+        top = st.stack[-1]
+        node = top.kids.get(name)
+        if node is None:
+            node = top.kids[name] = _Node(name, top.name)
+        self._record(st, node, start, end, cause, tenant, n, merge)
+
+    def open(self, name: str) -> float:
+        """Per-event form of :meth:`section`: push ``name`` as this
+        thread's open span and read the clock.  Returns 0.0 when
+        disabled; hand the value to :meth:`close`."""
+        if not self._enabled:
+            return 0.0
+        try:
+            st = self._tls.state
+        except AttributeError:
+            st = self._thread()
+        stack = st.stack
+        top = stack[-1]
+        node = top.kids.get(name)
+        if node is None:
+            node = top.kids[name] = _Node(name, top.name)
+        now = _perf_counter()
+        if top is st.root and now - st.root_end > COALESCE_S:
+            # the thread stood idle: its runs end here, children's too
+            st.epoch += 1
+            st.root_end = now
+        stack.append(node)
+        return now
+
+    def close(self, t0: float, cause: str, tenant: str = "",
+              n: int = 1) -> None:
+        """Pop the span :meth:`open` pushed and record it."""
+        if not t0:
+            return
+        t1 = _perf_counter()
+        st = self._tls.state
+        node = st.stack.pop()
+        if self._enabled and t1 > t0:
+            self._record(st, node, t0, t1, cause, tenant, n)
 
     @contextlib.contextmanager
-    def section(self, cause: str, name: str = "", tenant: str = ""):
-        """Time a block as one segment; near-free when disabled."""
+    def section(self, cause: str, name: str = "", tenant: str = "",
+                n: int = 1):
+        """Time a block as one span of ``n`` members, a parent to what
+        it encloses; near-free when disabled."""
         if not self._enabled:
             yield
             return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(t0, time.perf_counter(), cause, name, tenant)
+        with _annotation(name or cause):
+            t0 = self.open(name)
+            try:
+                yield
+            finally:
+                self.close(t0, cause, tenant, n)
 
-    # -- cycle reconstruction -------------------------------------------
+    # -- window reconstruction ------------------------------------------
 
     def _window(self, t0: float, t1: float) -> list[dict]:
+        """The records overlapping [t0, t1], clipped; a straddling
+        run's ``n`` and ``busy_s`` are cut pro rata."""
         with self._lock:
-            raw = [s for s in self._segments if s[1] > t0 and s[0] < t1]
-            # prune consumed history: segments entirely before this
-            # window belong to no future cycle (inter-cycle applies
-            # attribute nowhere by design)
-            while self._segments and self._segments[0][1] <= t1:
-                self._segments.popleft()
-        return [{"start": max(s, t0), "end": min(e, t1), "cause": c,
-                 "name": n, "tenant": t}
-                for s, e, c, n, t in raw]
+            raw = [list(r) for r in self._segments
+                   if r[_END] > t0 and r[_START] < t1]
+        out = []
+        for r in raw:
+            start, end = max(r[_START], t0), min(r[_END], t1)
+            whole = start == r[_START] and end == r[_END]
+            share = 1.0 if whole else (end - start) / (r[_END] - r[_START])
+            out.append({"start": start, "end": end, "cause": r[_CAUSE],
+                        "name": r[_NAME], "tenant": r[_TENANT],
+                        "parent": r[_PARENT], "thread": r[_THREAD],
+                        "n": r[_N] if whole else r[_N] * share,
+                        "busy_s": r[_BUSY] * share})
+        return out
 
-    def finish_cycle(self, cycle: int, t0: float, t1: float,
-                     mode: str = "cycle", publish: bool = True) -> dict | None:
-        """Reconstruct the window [t0, t1]: clip segments, attribute
-        wall time, derive device idle, name the critical path; append
-        the cycle doc to the ring and (by default) republish the
-        ``host_wait_attribution`` / ``device_idle_fraction`` /
-        ``critical_path_seconds`` gauges.  Returns the doc (None when
-        disabled or the window is degenerate)."""
-        if not self._enabled or t1 <= t0:
-            return None
+    def _doc(self, cycle: int, t0: float, t1: float, mode: str) -> dict:
         wall = t1 - t0
         segments = self._window(t0, t1)
         totals, chain = sweep_attribution(segments, t0, t1)
@@ -261,16 +481,15 @@ class TimelineRecorder:
                  if c != UNATTRIBUTED and s > 0.0}
         critical_cause = (max(named, key=named.get) if named
                           else UNATTRIBUTED)
-        doc = {
+        return {
             "cycle": cycle,
             "mode": mode,
             "start": t0,
             "wall_s": wall,
             "segments": [
-                {"start": s["start"] - t0, "end": s["end"] - t0,
-                 "cause": s["cause"], "name": s["name"],
-                 "tenant": s["tenant"]}
+                dict(s, start=s["start"] - t0, end=s["end"] - t0)
                 for s in sorted(segments, key=lambda s: s["start"])],
+            "by_name": by_name(segments),
             "attribution": attribution,
             "attribution_s": totals,
             "unattributed_fraction": attribution[UNATTRIBUTED],
@@ -284,8 +503,36 @@ class TimelineRecorder:
             "critical_cause": critical_cause,
             "critical_seconds": totals.get(critical_cause, 0.0),
         }
+
+    def finish_cycle(self, cycle: int, t0: float, t1: float,
+                     mode: str = "cycle", publish: bool = True) -> dict | None:
+        """Reconstruct the window [t0, t1]: clip segments, attribute
+        wall time, derive device idle, name the critical path; append
+        the cycle doc to the ring and (by default) republish the
+        ``host_wait_attribution`` / ``device_idle_fraction`` /
+        ``critical_path_seconds`` gauges.  The wall since the previous
+        window goes before it as a doc of its own (``mode="ingest"``,
+        never published).  Returns the cycle's doc (None when disabled
+        or the window is degenerate)."""
+        if not self._enabled or t1 <= t0:
+            return None
         with self._lock:
-            self._cycles.append(doc)
+            # seal every run: what a window was handed is not extended
+            self._gen += 1
+            last_end = self._last_end
+        docs = []
+        if last_end is not None and last_end < t0:
+            docs.append(self._doc(cycle, last_end, t0, INGEST))
+        doc = self._doc(cycle, t0, t1, mode)
+        docs.append(doc)
+        with self._lock:
+            self._cycles.extend(docs)
+            # consumed history goes; a record still running past t1
+            # stays and the next window takes the rest of it
+            kept = [r for r in self._segments if r[_END] > t1]
+            self._segments.clear()
+            self._segments.extend(kept)
+            self._last_end = max(t1, last_end or t1)
         if publish:
             self._publish(doc)
         return doc
@@ -303,7 +550,9 @@ class TimelineRecorder:
         metrics.device_idle_fraction.set(doc["device_idle_fraction"])
 
     def cycles(self, limit: int = 8) -> list[dict]:
-        """Newest-first cycle docs (the /debug/timeline body)."""
+        """Newest-first window docs (the /debug/timeline body): rounds,
+        cycles and the ingest windows between them, told apart by
+        ``mode``."""
         with self._lock:
             out = list(self._cycles)[-max(limit, 0):]
         out.reverse()
@@ -311,8 +560,19 @@ class TimelineRecorder:
 
     def reset_for_tests(self) -> None:
         with self._lock:
-            self._segments.clear()
+            self._forget()
             self._cycles.clear()
+            self.dropped = 0
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation("koord:<name>")`` when jax is
+    already loaded in the process, else a no-op context."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    return profiler.TraceAnnotation(f"koord:{name}")
 
 
 #: process-wide recorder; KOORD_TIMELINE=0 disables at import (the env

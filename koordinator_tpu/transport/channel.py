@@ -22,11 +22,12 @@ import queue
 import socket
 import socketserver
 import threading
+import time
 from typing import Callable, Optional
 
 import numpy as np
 
-from koordinator_tpu import tracing
+from koordinator_tpu import timeline, tracing
 from koordinator_tpu.transport.wire import (
     Frame,
     FrameType,
@@ -39,6 +40,12 @@ from koordinator_tpu.transport.wire import (
 
 Handler = Callable[[dict, dict[str, np.ndarray]],
                    tuple[dict, dict[str, np.ndarray] | None]]
+
+#: timeline span names per frame type, made once (taken on every frame):
+#: the server's span runs decode -> handler -> reply queued, the
+#: client's runs encode -> send -> ``rpc.wait`` -> decode
+_SERVER_SPANS = {t: f"rpc.{t.name}" for t in FrameType}
+_CLIENT_SPANS = {t: f"rpc.call.{t.name}" for t in FrameType}
 
 #: the connection whose frame is currently being dispatched on THIS
 #: thread — handlers are (doc, arrays) -> (doc, arrays) with no
@@ -317,6 +324,7 @@ def _dispatch_one(server: "RpcServer", conn: _Conn, frame: Frame,
                         encode_payload(
                             {"message": f"no handler for {frame.type}"})))
         return
+    tl_t0 = timeline.RECORDER.open(_SERVER_SPANS[frame.type])
     try:
         doc, arrays = decode_payload(frame.payload)
         # typed request schemas: version/shape skew between
@@ -378,6 +386,8 @@ def _dispatch_one(server: "RpcServer", conn: _Conn, frame: Frame,
     except Exception as e:  # handler bug: fail the call, not conn
         conn.send(Frame(FrameType.ERROR, frame.request_id,
                         encode_payload({"message": repr(e)})))
+    finally:
+        timeline.RECORDER.close(tl_t0, "host_other")
 
 
 _RESPONSE_TYPE = {
@@ -661,6 +671,16 @@ class RpcClient:
              arrays: dict[str, np.ndarray] | None = None,
              deadline_ms: float | None = None,
              ) -> tuple[FrameType, dict, dict[str, np.ndarray]]:
+        tl_t0 = timeline.RECORDER.open(_CLIENT_SPANS[ftype])
+        try:
+            return self._call(ftype, doc, arrays, deadline_ms)
+        finally:
+            timeline.RECORDER.close(tl_t0, timeline.RPC_CLIENT)
+
+    def _call(self, ftype: FrameType, doc: dict,
+              arrays: dict[str, np.ndarray] | None,
+              deadline_ms: float | None,
+              ) -> tuple[FrameType, dict, dict[str, np.ndarray]]:
         sock = self._sock
         if sock is None:
             raise RpcError("not connected")
@@ -719,7 +739,11 @@ class RpcClient:
         wait = self.timeout
         if deadline_ms is not None:
             wait = min(wait, float(deadline_ms) / 1000.0)
-        if not waiter.event.wait(wait):
+        tl_wait = time.perf_counter()
+        answered = waiter.event.wait(wait)
+        timeline.RECORDER.add(tl_wait, time.perf_counter(),
+                              timeline.RPC_CLIENT, "rpc.wait")
+        if not answered:
             with self._pending_lock:
                 self._pending.pop(req_id, None)
             if deadline_ms is not None and wait < self.timeout:

@@ -23,7 +23,6 @@ host->device path stays a scatter of K rows, never a rebuild
 
 from __future__ import annotations
 
-import contextlib
 import threading
 from collections import deque
 from typing import Optional
@@ -375,16 +374,23 @@ class StateSyncService:
         broadcast only enqueues to bounded per-connection queues — a
         stalled peer drops frames and gets poisoned, it cannot wedge the
         service (channel._Conn.send)."""
-        with self._lock:
-            store_fn()
-            rv = self._commit_locked(event, arrays)
-        # apply OUTSIDE the service lock: bindings block on the scheduler
-        # lock (a long solve), and holding _lock through that would stall
-        # every HELLO/push/broadcast behind it.  The queue was filled in
-        # rv order under _lock; draining FIFO under _binding_lock keeps
-        # that order even when two pushers race to drain.
-        if self._local_bindings:
-            self._drain_bindings()
+        # one sync.store span per event (store, delta log, v2 pack and
+        # broadcast, local apply); the sync.<kind> applies nest under it
+        tl_t0 = timeline.RECORDER.open("sync.store")
+        try:
+            with self._lock:
+                store_fn()
+                rv = self._commit_locked(event, arrays)
+            # apply OUTSIDE the service lock: bindings block on the
+            # scheduler lock (a long solve), and holding _lock through
+            # that would stall every HELLO/push/broadcast behind it.  The
+            # queue was filled in rv order under _lock; draining FIFO
+            # under _binding_lock keeps that order even when two pushers
+            # race to drain.
+            if self._local_bindings:
+                self._drain_bindings()
+        finally:
+            timeline.RECORDER.close(tl_t0, "deltasync_apply")
         return rv
 
     def _commit_locked(self, event: dict,
@@ -525,7 +531,8 @@ class StateSyncService:
         event: dict = {"kind": NODE_USAGE, "name": name}
         if report_time is not None:
             event["usage_time"] = float(report_time)
-        with self._lock:
+
+        def store():
             entry = self.nodes.get(name)
             if entry is None:
                 raise UnknownNodeError(
@@ -536,10 +543,8 @@ class StateSyncService:
                 # replays the ORIGINAL report time, not the apply time
                 entry["doc"] = dict(entry["doc"],
                                     usage_time=float(report_time))
-            rv = self._commit_locked(event, arrays)
-        if self._local_bindings:
-            self._drain_bindings()
-        return rv
+
+        return self._store_and_commit(store, event, arrays)
 
     def update_node_allocatable(self, name: str,
                                 allocatable: np.ndarray) -> int:
@@ -554,17 +559,16 @@ class StateSyncService:
         this event merges.  Unknown node -> WireSchemaError, same rule
         as node_usage."""
         arrays = {"allocatable": np.asarray(allocatable, np.int32)}
-        with self._lock:
+
+        def store():
             entry = self.nodes.get(name)
             if entry is None:
                 raise UnknownNodeError(
                     f"node_allocatable for unknown node {name!r}")
             entry["arrays"] = dict(entry["arrays"], **arrays)
-            rv = self._commit_locked(
-                {"kind": NODE_ALLOC, "name": name}, arrays)
-        if self._local_bindings:
-            self._drain_bindings()
-        return rv
+
+        return self._store_and_commit(
+            store, {"kind": NODE_ALLOC, "name": name}, arrays)
 
     def update_node_devices(self, name: str,
                             devices: dict[str, list[dict]]) -> int:
@@ -1067,6 +1071,12 @@ class StateSyncClient:
 #: the per-event route)
 _RUN_METHODS = {NODE_USAGE: "node_usage_run", POD_ADD: "pod_add_run"}
 
+#: event kind -> its apply's timeline span name (made once: the name is
+#: taken on every event)
+_SPAN_NAMES = {kind: f"sync.{kind}" for kind in (
+    NODE_UPSERT, NODE_USAGE, NODE_ALLOC, NODE_DEVICES, NODE_REMOVE,
+    POD_ADD, POD_REMOVE, RSV_UPSERT, RSV_REMOVE)}
+
 
 def _dispatch_events(binding, items: list[tuple[dict, dict]]) -> None:
     """Route an ORDERED event list, batching contiguous same-kind runs
@@ -1076,8 +1086,8 @@ def _dispatch_events(binding, items: list[tuple[dict, dict]]) -> None:
     per-event ``sync.<kind>`` span (and its position relative to its
     neighbors — runs never cross it, so apply order is exactly the
     per-event order).  A run of K events costs one scheduler-lock
-    round-trip and one ``deltasync_apply`` timeline segment instead of
-    K of each; the batched appliers perform the same per-event mutation
+    round-trip and one ``sync.<kind>`` timeline span of K members
+    instead of K of each; the batched appliers perform the same per-event mutation
     in the same order, so the resulting state is bit-identical."""
     i, n = 0, len(items)
     while i < n:
@@ -1095,14 +1105,12 @@ def _dispatch_events(binding, items: list[tuple[dict, dict]]) -> None:
         if j - i == 1:
             _dispatch_event(binding, entry, arrs)
         else:
-            run = items[i:j]
-            tl = (timeline.RECORDER.section(
-                      "deltasync_apply",
-                      f"sync.{entry['kind']}_run[{j - i}]")
-                  if timeline.RECORDER.enabled
-                  else contextlib.nullcontext())
-            with tl:
-                run_fn(run)
+            # one sync.<kind> span of j - i members
+            tl_t0 = timeline.RECORDER.open(_SPAN_NAMES[entry["kind"]])
+            try:
+                run_fn(items[i:j])
+            finally:
+                timeline.RECORDER.close(tl_t0, "deltasync_apply", n=j - i)
             # staleness watchdog feed: one mark covers the run — the
             # watchdog reads only the latest timestamp
             mark = getattr(binding, "note_sync_event", None)
@@ -1123,25 +1131,25 @@ def _dispatch_event(binding, entry: dict,
     pod's trace to the original submitter's span.  The entry is read,
     never mutated: the same dict may live in the service's stored state
     and replay log."""
-    # timeline segment (ISSUE 18): one deltasync_apply span per routed
-    # event — the binding holds scheduler.lock while it applies, so
-    # this is exactly the host work that contends with solve rounds
-    tl = (timeline.RECORDER.section(
-              "deltasync_apply", f"sync.{entry['kind']}")
-          if timeline.RECORDER.enabled else contextlib.nullcontext())
-    ctx = tracing.TraceContext.from_doc(entry.get(tracing.TRACE_DOC_KEY))
-    if ctx is None:
-        with tl:
+    # timeline span: the binding holds scheduler.lock while it applies,
+    # so this is exactly the host work that contends with solve rounds
+    kind = entry["kind"]
+    tl_t0 = timeline.RECORDER.open(_SPAN_NAMES.get(kind) or f"sync.{kind}")
+    try:
+        ctx = tracing.TraceContext.from_doc(
+            entry.get(tracing.TRACE_DOC_KEY))
+        if ctx is None:
             _route_event(binding, entry, arrs)
-        return
-    with tracing.TRACER.span(
-            f"sync.{entry['kind']}",
-            service=getattr(binding, "service_name", None),
-            parent=ctx,
-            attributes={"name": entry.get("name"),
-                        "rv": entry.get("rv")}):
-        with tl:
+            return
+        with tracing.TRACER.span(
+                f"sync.{kind}",
+                service=getattr(binding, "service_name", None),
+                parent=ctx,
+                attributes={"name": entry.get("name"),
+                            "rv": entry.get("rv")}):
             _route_event(binding, entry, arrs)
+    finally:
+        timeline.RECORDER.close(tl_t0, "deltasync_apply")
 
 
 def _route_event(binding, entry: dict,

@@ -167,14 +167,46 @@ def test_unknown_quota_name_does_not_crash_bind():
     assert binds
 
 
-def test_monitor_collects_phase_stats():
+def test_monitor_collects_phase_timings():
+    """The round's per-phase walls (what the flight record snapshots)."""
     sched, _ = mk_scheduler([node("n1")])
     sched.enqueue(pod("p1"))
     sched.schedule_round()
-    stats = sched.monitor.stats()
+    timings = sched.monitor.round_timings
     for phase in ("PreEnqueue", "BatchBuild", "Solve", "Bind"):
-        assert phase in stats
-        assert stats[phase]["count"] >= 1
+        assert timings[phase] > 0
+    assert sched.flight_recorder.last().phase_s == timings
+
+
+def test_second_pass_places_a_leftover_pod():
+    """A gangless batch round whose SECOND pass places a pod: pass 1's
+    assignments are written into on the host, so they must be a copy
+    (``np.asarray`` of a device array is a read-only view and raised
+    ``assignment destination is read-only`` here, on the CPU too)."""
+    nodes = [node("n1", usage_cpu=0), node("n2", usage_cpu=4_000),
+             node("n3", usage_cpu=8_000)]
+    sched, _ = mk_scheduler(nodes, batch_solver_threshold=1)
+    assert sched.gang_passes == 2
+    # one propose/accept round a pass: every pod proposes the emptiest
+    # node, the first in priority order takes it, the rest are left over
+    sched.solve_rounds = 1
+    second_pass = []
+    pass2 = sched._pass2
+
+    def spy(*args, **kwargs):
+        out = pass2(*args, **kwargs)
+        second_pass.append(np.asarray(out[0]))
+        return out
+
+    sched._pass2 = spy
+    for i, prio in enumerate((9_000, 8_000, 7_000)):
+        sched.enqueue(pod(f"p{i}", cpu=10_000, priority=prio))
+    res = sched.schedule_round()
+    assert sched.last_solver == "batch"
+    assert second_pass and (second_pass[0] >= 0).any()
+    assert sorted(res.assignments.values()) == ["n1", "n2", "n3"]
+    assert res.assignments["p0"] == "n1"
+    assert not res.failures
 
 
 def test_diagnosis_message_shape():

@@ -237,6 +237,17 @@ class TestRecorder:
         assert first["attribution_s"]["bind_commit"] == pytest.approx(1.0)
         again = rec.finish_cycle(2, 0.0, 2.0, publish=False)
         assert again["attribution_s"]["bind_commit"] == 0.0
+        # what happens between two windows is not pruned any more: it
+        # lands in the ingest doc before the next round, once
+        rec.add(2.5, 3.5, "deltasync_apply", "sync.store")
+        third = rec.finish_cycle(3, 4.0, 5.0, publish=False)
+        assert third["attribution_s"]["deltasync_apply"] == 0.0
+        ingest = rec.cycles(2)[1]
+        assert ingest["mode"] == timeline.INGEST
+        assert ingest["attribution_s"]["deltasync_apply"] == pytest.approx(1.0)
+        fourth = rec.finish_cycle(4, 6.0, 7.0, publish=False)
+        assert fourth["by_name"] == {}
+        assert rec.cycles(2)[1]["by_name"] == {}
 
     def test_disabled_recorder_is_inert(self):
         rec = timeline.TimelineRecorder(enabled=False)
@@ -260,6 +271,300 @@ class TestRecorder:
         rec.add(5.0, 4.0, "host_other")
         doc = rec.finish_cycle(1, 0.0, 10.0, publish=False)
         assert doc["segments"] == []
+
+
+class TestSpansAndWindows:
+    """ISSUE 24: parents and self time, runs, the ingest window."""
+
+    def test_parent_and_self_time_across_nesting(self):
+        rec = timeline.TimelineRecorder()
+        outer = rec.open("sync.store")
+        inner = rec.open("sync.pod_add")
+        rec.add(10.0, 10.2, "host_other", "enqueue")
+        rec.close(inner, "deltasync_apply")
+        rec.close(outer, "deltasync_apply")
+        by = {r[timeline._NAME]: r for r in rec._segments}
+        assert by["enqueue"][timeline._PARENT] == "sync.pod_add"
+        assert by["sync.pod_add"][timeline._PARENT] == "sync.store"
+        assert by["sync.store"][timeline._PARENT] == ""
+        # self time = busy minus what the children on the thread cover
+        segs = [
+            {"cause": "host_other", "name": "a", "parent": "", "thread": 1,
+             "n": 1, "busy_s": 10.0},
+            {"cause": "host_other", "name": "b", "parent": "a", "thread": 1,
+             "n": 4, "busy_s": 6.0},
+            {"cause": "host_other", "name": "c", "parent": "b", "thread": 1,
+             "n": 4, "busy_s": 1.5},
+            {"cause": timeline.RPC_CLIENT, "name": "rpc.wait", "parent": "a",
+             "thread": 1, "n": 1, "busy_s": 2.0},
+            {"cause": timeline.DEVICE_BUSY, "name": "solve", "parent": "",
+             "thread": 1, "n": 1, "busy_s": 9.0},
+        ]
+        got = timeline.by_name(segs)
+        assert got["a"]["self_s"] == pytest.approx(2.0)
+        assert got["b"]["self_s"] == pytest.approx(4.5)
+        assert got["c"] == {"n": 4, "busy_s": 1.5, "self_s": 1.5,
+                            "wait": False}
+        assert got["rpc.wait"]["wait"] is True
+        assert "solve" not in got          # device occupancy is no span
+
+    def test_parents_and_self_time_keep_to_their_thread(self):
+        import threading
+
+        rec = timeline.TimelineRecorder()
+        ready, go = threading.Event(), threading.Event()
+
+        def server():
+            t0 = rec.open("rpc.STATE_PUSH")
+            ready.set()
+            go.wait(5.0)
+            rec.add(1.0, 1.5, "deltasync_apply", "sync.store")
+            rec.close(t0, "host_other")
+
+        worker = threading.Thread(target=server)
+        worker.start()
+        assert ready.wait(5.0)
+        # the client thread has its own stack: the server's open span
+        # is no parent of what the client records
+        t0 = rec.open("rpc.call.STATE_PUSH")
+        rec.add(1.0, 1.4, timeline.RPC_CLIENT, "rpc.wait")
+        rec.close(t0, timeline.RPC_CLIENT)
+        go.set()
+        worker.join(5.0)
+        assert not worker.is_alive()
+        recs = {r[timeline._NAME]: r for r in rec._segments}
+        assert recs["rpc.wait"][timeline._PARENT] == "rpc.call.STATE_PUSH"
+        assert recs["sync.store"][timeline._PARENT] == "rpc.STATE_PUSH"
+        assert (recs["rpc.wait"][timeline._THREAD]
+                != recs["sync.store"][timeline._THREAD])
+        # a child on another thread takes nothing off a span's self time
+        got = timeline.by_name([
+            {"cause": "host_other", "name": "a", "parent": "", "thread": 1,
+             "n": 1, "busy_s": 3.0},
+            {"cause": "host_other", "name": "b", "parent": "a", "thread": 2,
+             "n": 1, "busy_s": 2.0}])
+        assert got["a"]["self_s"] == pytest.approx(3.0)
+
+    def test_a_wave_is_one_record_whatever_the_ring(self):
+        from koordinator_tpu import metrics
+
+        before = metrics.timeline_segments_dropped.value()
+        rec = timeline.TimelineRecorder(max_segments=4)
+        n, step, width = 50_000, 1e-4, 6e-5
+        want = 0.0
+        for i in range(n):
+            start = 100.0 + i * step
+            rec.add(start, start + width, "deltasync_apply", "sync.store")
+            want += (start + width) - start
+        assert len(rec._segments) == 1
+        (run,) = rec._segments
+        assert run[timeline._N] == n
+        assert run[timeline._BUSY] == want          # exact, not approx
+        assert run[timeline._START] == 100.0
+        assert rec.dropped == 0
+        assert metrics.timeline_segments_dropped.value() == before
+        # a gap over COALESCE_S, another parent or another tenant each
+        # start a record of their own
+        rec.add(200.0, 200.1, "deltasync_apply", "sync.store")
+        rec.add(200.1, 200.2, "deltasync_apply", "sync.store", tenant="b")
+        outer = rec.open("rpc.STATE_PUSH")
+        rec.add(200.2, 200.3, "deltasync_apply", "sync.store", tenant="b")
+        rec.close(outer, "host_other")
+        assert [r[timeline._NAME] for r in rec._segments].count(
+            "sync.store") == 3              # of 4: the ring holds 4 records
+        # ... and what the ring does push out is counted
+        assert rec.dropped == 1
+        assert metrics.timeline_segments_dropped.value() == before + 1
+
+    def test_a_childs_run_goes_on_while_its_thread_is_never_idle(
+            self, monkeypatch):
+        """500 frames of 3.7 ms each: the ``wire.decode`` of each lies
+        3.7 ms after the last one's, and is still ONE run, because the
+        thread was inside ``rpc.STATE_PUSH`` in between.  A pause of
+        the frames themselves ends the children's runs with theirs."""
+        clock = [100.0]
+        monkeypatch.setattr(timeline, "_perf_counter", lambda: clock[0])
+        rec = timeline.TimelineRecorder()
+
+        def frame(gap):
+            clock[0] += gap
+            t0 = rec.open("rpc.STATE_PUSH")
+            rec.add(clock[0], clock[0] + 1e-5, "json_codec", "wire.decode")
+            clock[0] += 3.7e-3
+            rec.close(t0, "host_other")
+
+        for _ in range(500):
+            frame(2e-4)                     # handover: 0.2 ms between
+        names = [r[timeline._NAME] for r in rec._segments]
+        assert sorted(names) == ["rpc.STATE_PUSH", "wire.decode"]
+        decode = next(r for r in rec._segments
+                      if r[timeline._NAME] == "wire.decode")
+        assert decode[timeline._N] == 500
+        assert decode[timeline._BUSY] == pytest.approx(500 * 1e-5)
+        frame(5e-3)                         # the feeder paused 5 ms
+        names = [r[timeline._NAME] for r in rec._segments]
+        assert names.count("rpc.STATE_PUSH") == 2
+        assert names.count("wire.decode") == 2
+        # one name under two parents is two runs, not a broken one
+        for _ in range(3):
+            t0 = rec.open("rpc.STATE_PUSH")
+            t1 = rec.open("sync.store")
+            rec.add(clock[0], clock[0] + 1e-5, "json_codec", "wire.encode")
+            clock[0] += 1e-4
+            rec.close(t1, "deltasync_apply")
+            rec.add(clock[0], clock[0] + 1e-5, "json_codec", "wire.encode")
+            clock[0] += 1e-4
+            rec.close(t0, "host_other")
+        encodes = [r for r in rec._segments
+                   if r[timeline._NAME] == "wire.encode"]
+        assert sorted((r[timeline._PARENT], r[timeline._N])
+                      for r in encodes) == [("rpc.STATE_PUSH", 3),
+                                            ("sync.store", 3)]
+
+    def test_a_run_holds_its_extent_at_its_density(self):
+        # 10 s extent, 2 s busy, inside a phase: the phase's cause
+        # gets the gaps between the run's members, not the run's
+        segs = [dict(_seg(0.0, 10.0, "host_other", "phase.Diagnose"),
+                     busy_s=10.0),
+                dict(_seg(0.0, 10.0, "json_codec", "wire.decode"),
+                     busy_s=2.0)]
+        totals, chain = timeline.sweep_attribution(segs, 0.0, 10.0)
+        assert totals["json_codec"] == pytest.approx(2.0)
+        assert totals["host_other"] == pytest.approx(8.0)
+        assert totals[timeline.UNATTRIBUTED] == pytest.approx(0.0)
+        assert [c["cause"] for c in chain] == ["json_codec"]
+        # at the root, what its members do not fill is nobody's
+        totals, _ = timeline.sweep_attribution(segs[1:], 0.0, 10.0)
+        assert totals["json_codec"] == pytest.approx(2.0)
+        assert totals[timeline.UNATTRIBUTED] == pytest.approx(8.0)
+        # clipping keeps the density: half the run is half its busy time
+        totals, _ = timeline.sweep_attribution(segs[1:], 5.0, 10.0)
+        assert totals["json_codec"] == pytest.approx(1.0)
+
+    def test_unmerged_spans_keep_their_own_intervals(self):
+        rec = timeline.TimelineRecorder()
+        rec.add(1.0, 1.1, "device_block", "block_until_ready", merge=False)
+        rec.add(1.1004, 1.2, "device_block", "block_until_ready",
+                merge=False)
+        assert len(rec._segments) == 2
+
+    def test_ingest_doc_lies_between_two_rounds_and_walls_tile(self):
+        rec = timeline.TimelineRecorder()
+        rec.add(10.0, 10.5, "host_other", "phase.Solve")
+        first = rec.finish_cycle(1, 10.0, 11.0, mode="round", publish=False)
+        assert [d["mode"] for d in rec.cycles(8)] == ["round"]
+        # between the rounds: a run of applies, then the next round
+        for i in range(100):
+            rec.add(11.5 + i * 0.01, 11.505 + i * 0.01, "deltasync_apply",
+                    "sync.store")
+        rec.add(13.0, 13.2, "bind_commit", "phase.Bind")
+        second = rec.finish_cycle(2, 13.0, 14.0, mode="round", publish=False)
+        newest_first = rec.cycles(8)
+        assert [d["mode"] for d in newest_first] == [
+            "round", timeline.INGEST, "round"]
+        ingest = newest_first[1]
+        assert ingest["cycle"] == 2
+        assert ingest["start"] == first["start"] + first["wall_s"]
+        assert ingest["start"] + ingest["wall_s"] == second["start"]
+        assert (first["wall_s"] + ingest["wall_s"] + second["wall_s"]
+                == pytest.approx(14.0 - 10.0))
+        run = ingest["by_name"]["sync.store"]
+        assert run["n"] == 100
+        assert run["busy_s"] == pytest.approx(0.5)
+        assert "sync.store" not in second["by_name"]
+        # the same shape as a round's doc
+        assert set(ingest) == set(second)
+
+    def test_a_run_straddling_a_window_edge_is_cut_pro_rata(self):
+        rec = timeline.TimelineRecorder()
+        rec.finish_cycle(1, 0.0, 1.0, publish=False)
+        for i in range(10):                 # one run over [1.5, 2.5]
+            rec.add(1.5 + i * 0.1, 1.6 + i * 0.1, "deltasync_apply",
+                    "sync.store")
+        rec.finish_cycle(2, 2.0, 3.0, publish=False)
+        round_doc, ingest = rec.cycles(2)
+        assert ingest["by_name"]["sync.store"]["n"] == pytest.approx(5.0)
+        assert round_doc["by_name"]["sync.store"]["n"] == pytest.approx(5.0)
+        assert (ingest["by_name"]["sync.store"]["busy_s"]
+                + round_doc["by_name"]["sync.store"]["busy_s"]
+                == pytest.approx(1.0))
+
+    def test_by_name_self_times_sum_to_the_attributed_wall(
+            self, monkeypatch):
+        ticks = iter(range(100, 200))
+        monkeypatch.setattr(timeline, "_perf_counter",
+                            lambda: float(next(ticks)))
+        rec = timeline.TimelineRecorder()
+        rec.finish_cycle(1, 0.0, 99.0, publish=False)
+        with rec.section("bind_commit", "phase.Bind"):          # 100
+            with rec.section("bind_commit", "bind.registry"):   # 101-102
+                pass
+            with rec.section("bind_commit", "bind.surfaces"):   # 103
+                rec.add(103.25, 103.5, "bind_commit", "bind.explain")
+                rec.add(103.5, 103.75, "bind_commit", "bind.explain")
+            # closes at 104, phase.Bind at 105
+        rec.add(107.0, 108.0, "host_other", "diagnose.explain")
+        doc = rec.finish_cycle(2, 99.0, 110.0, publish=False)
+        by = doc["by_name"]
+        assert by["bind.explain"] == {"n": 2, "busy_s": 0.5, "self_s": 0.5,
+                                      "wait": False}
+        assert by["bind.surfaces"]["self_s"] == pytest.approx(0.5)
+        assert by["phase.Bind"]["self_s"] == pytest.approx(5.0 - 1.0 - 1.0)
+        selfs = sum(v["self_s"] for v in by.values() if not v["wait"])
+        assert selfs == pytest.approx(
+            doc["wall_s"] - doc["attribution_s"][timeline.UNATTRIBUTED])
+        assert selfs == pytest.approx(6.0)
+
+    def test_gauges_and_soak_residual_ignore_an_idle_ingest_doc(self):
+        from koordinator_tpu import metrics
+        from soak_report import attach_host_wait
+
+        rec = timeline.TimelineRecorder()
+        rec.add(0.0, 1.0, "host_other", "phase.Solve")
+        rec.finish_cycle(1, 0.0, 1.0, mode="round")
+        assert metrics.host_wait_attribution.value(
+            labels={"cause": timeline.UNATTRIBUTED}) == 0.0
+        # 99 s of nothing, then a fully attributed round
+        rec.add(100.0, 101.0, "host_other", "phase.Solve")
+        rec.finish_cycle(2, 100.0, 101.0, mode="round")
+        ingest = rec.cycles(2)[1]
+        assert ingest["mode"] == timeline.INGEST
+        assert ingest["unattributed_fraction"] == pytest.approx(1.0)
+        # the gauges still read the round, not the idle window before it
+        assert metrics.host_wait_attribution.value(
+            labels={"cause": timeline.UNATTRIBUTED}) == 0.0
+        assert metrics.device_idle_fraction.value() == pytest.approx(1.0)
+        verdict = {"green": True}
+        hw = attach_host_wait(
+            verdict, {"enabled": True, "cycles": rec.cycles(8)})
+        assert hw["cycles"] == 2            # the rounds, not the ingest doc
+        assert hw["unattributed_wall_fraction"] == 0.0
+        assert verdict["green"] is True
+
+    def test_section_annotates_the_profiler_when_jax_is_loaded(self):
+        import jax  # noqa: F401 — the served process has it loaded
+
+        seen = []
+
+        class Probe:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        rec = timeline.TimelineRecorder()
+        real = jax.profiler.TraceAnnotation
+        jax.profiler.TraceAnnotation = Probe
+        try:
+            with rec.section("host_other", "round.prepare"):
+                rec.add(1.0, 2.0, "host_other", "enqueue")
+        finally:
+            jax.profiler.TraceAnnotation = real
+        assert seen == ["koord:round.prepare"]      # add() annotates nothing
 
 
 # ---------------------------------------------------------------------------
@@ -364,6 +669,265 @@ class TestPhaseAccountingInvariant:
         assert tenants == {"a", "b"}
 
 
+#: every span of ISSUE 24's table (part 2); ``kit.load:`` is a prefix
+SPAN_TABLE = (
+    "rpc.STATE_PUSH", "rpc.SOLVE_REQUEST", "wire.decode", "wire.encode",
+    "rpc.call.STATE_PUSH", "rpc.call.SOLVE_REQUEST", "rpc.wait",
+    "sync.store", "sync.node_upsert", "sync.pod_add", "sync.pod_remove",
+    "enqueue", "release.unreserve", "release.fine_grained",
+    "snapshot.flush", "bind.registry", "bind.quota", "bind.surfaces",
+    "bind.explain", "bind.emit", "bind.journey", "diagnose.explain",
+    "phase.Bind", "phase.Solve", "round.dispatch", "block_until_ready",
+    "audit.attempts", "diagnose.persist", "round.introspection",
+)
+
+
+class _Served:
+    """A tiny scheduler on a socket: sync service (wire + in-process
+    binding), solve service, explanation store, a quota tree, and one
+    synchronous wire client — the served path's layers, each once
+    (store and auditor as ``koord-scheduler`` assembles them)."""
+
+    def __init__(self, kit, sock, capacity):
+        from koordinator_tpu.quota.tree import UNBOUNDED, QuotaTree
+        from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
+        from koordinator_tpu.scheduler.explanation import (
+            ExplanationStore,
+            WorkloadAuditor,
+        )
+        from koordinator_tpu.transport import RpcClient, RpcServer
+        from koordinator_tpu.transport.deltasync import (
+            SchedulerBinding,
+            StateSyncService,
+        )
+        from koordinator_tpu.transport.services import SolveService
+
+        tree = QuotaTree(_vector(32_000, 131_072).astype(np.int64))
+        unbounded = np.full(len(_vector(0, 0)), UNBOUNDED, np.int64)
+        tree.add("team", min=np.zeros_like(unbounded), max=unbounded)
+        self.scheduler = Scheduler(
+            ClusterSnapshot(capacity=capacity), mesh="off", solver_kit=kit,
+            quota_tree=tree, explanations=ExplanationStore(),
+            auditor=WorkloadAuditor())
+        self.server = RpcServer(sock)
+        self.sync = StateSyncService()
+        self.sync.attach(self.server)
+        self.sync.attach_binding(SchedulerBinding(self.scheduler))
+        SolveService(self.scheduler).attach(self.server)
+        self.server.start()
+        self.client = RpcClient(sock, timeout=300.0)
+        self.client.connect()
+
+    def push(self, doc, arrays=None):
+        from koordinator_tpu.transport.wire import FrameType
+
+        self.client.call(FrameType.STATE_PUSH, doc, arrays)
+
+    def solve(self):
+        from koordinator_tpu.transport.services import solve_remote
+
+        return solve_remote(self.client)
+
+    def close(self):
+        self.client.close()
+        self.server.stop()
+
+
+def _vector(cpu, memory):
+    from koordinator_tpu.api.resources import resource_vector
+
+    return resource_vector(cpu=cpu, memory=memory)
+
+
+def _drive_served(served):
+    """Two nodes, a pod that binds (charged to the quota), one that fits
+    nowhere, one round, then the bound pod leaves."""
+    for i in range(2):
+        served.push({"kind": "node_upsert", "name": f"n{i}"},
+                    {"allocatable": _vector(16_000, 32_768),
+                     "usage": _vector(500, 1_024)})
+    served.push({"kind": "pod_add", "name": "fits", "priority": 5_000,
+                 "quota": "team"}, {"requests": _vector(1_000, 1_024)})
+    served.push({"kind": "pod_add", "name": "whale", "priority": 5_000},
+                {"requests": _vector(900_000, 1_024)})
+    answer = served.solve()
+    served.push({"kind": "pod_remove", "name": "fits"})
+    return answer
+
+
+class TestSpansOfTheServedPath:
+    def test_every_span_of_the_table_is_produced(self, kit_off, tmp_path):
+        timeline.RECORDER.reset_for_tests()
+        # a node capacity no other test of this module compiles for, so
+        # the round's first calls grow the jit cache: kit.load:<fn>
+        served = _Served(kit_off, str(tmp_path / "s.sock"), capacity=128)
+        try:
+            # an earlier window, so that the pushes land in an ingest doc
+            now = timeline._perf_counter()
+            timeline.RECORDER.finish_cycle(0, now - 1e-3, now,
+                                           publish=False)
+            answer = _drive_served(served)
+            assert answer["assignments"] == {"fits": "n0"} or (
+                answer["assignments"] == {"fits": "n1"})
+            assert set(answer["failures"]) == {"whale"}
+            assert "fits" not in served.scheduler.bound
+            now = timeline._perf_counter()
+            timeline.RECORDER.finish_cycle(99, now - 1e-6, now,
+                                           publish=False)
+        finally:
+            served.close()
+        docs = timeline.RECORDER.cycles(16)
+        assert [d["mode"] for d in docs] == [
+            "cycle", "ingest", "round", "ingest", "cycle"]
+        seen: dict[str, dict] = {}
+        for doc in docs:
+            for name, row in doc["by_name"].items():
+                seen.setdefault(name, row)
+        missing = [n for n in SPAN_TABLE if n not in seen]
+        assert not missing, (missing, sorted(seen))
+        loads = [n for n in seen if n.startswith("kit.load:")]
+        assert loads, sorted(seen)
+        assert seen["rpc.wait"]["wait"] is True
+        # the arrivals lie in the ingest doc before the round, nested:
+        # frame -> store -> apply -> enqueue
+        before = docs[3]
+        parents = {s["name"]: s["parent"] for s in before["segments"]}
+        assert parents["enqueue"] == "sync.pod_add"
+        assert parents["sync.pod_add"] == "sync.store"
+        assert parents["sync.store"] == "rpc.STATE_PUSH"
+        assert parents["rpc.wait"] == "rpc.call.STATE_PUSH"
+        assert before["by_name"]["rpc.STATE_PUSH"]["n"] == 4
+        assert before["by_name"]["enqueue"]["n"] == 2
+        # the round: the store's share of the commit nests under Bind
+        round_doc = docs[2]
+        parents = {s["name"]: s["parent"] for s in round_doc["segments"]}
+        assert parents["bind.explain"] == "bind.surfaces"
+        assert parents["bind.surfaces"] == "phase.Bind"
+        assert parents["phase.Bind"] == "rpc.SOLVE_REQUEST"
+        assert parents[loads[0]] in ("round.dispatch", "phase.Solve",
+                                     "phase.Diagnose", "phase.BatchBuild",
+                                     "rpc.SOLVE_REQUEST")
+        assert round_doc["by_name"]["diagnose.explain"]["n"] == 1
+        # the release lies in the ingest doc after it
+        after = docs[1]
+        parents = {s["name"]: s["parent"] for s in after["segments"]}
+        assert parents["release.unreserve"] == "sync.pod_remove"
+        assert parents["release.fine_grained"] == "sync.pod_remove"
+        assert timeline.RECORDER.dropped == 0
+
+    def test_load_seconds_counter_follows_the_recompile_counter(
+            self, kit_off):
+        from koordinator_tpu import metrics
+
+        # whatever this module compiled so far: a fn with a recompile
+        # has load seconds, and no other fn has
+        sched = _lone_scheduler(kit_off, capacity=256, seed=3)
+        _enqueue_pods(sched, 3, seed=77)
+        sched.schedule_round()
+        compiled = {labels["fn"]
+                    for labels, v in metrics.solver_recompiles.items() if v}
+        loaded = {labels["fn"]: v
+                  for labels, v in metrics.solver_load_seconds.items()}
+        assert compiled and set(loaded) == compiled
+        assert all(v > 0 for v in loaded.values())
+
+
+class TestDeviceStageNames:
+    """``jax.named_scope`` stage names (ISSUE 24 part 3) reach the
+    lowered program: metadata a profiler trace shows, nothing else."""
+
+    @pytest.fixture(scope="class")
+    def problem(self):
+        from koordinator_tpu.ops.assignment import ScoringConfig
+        from koordinator_tpu.ops.gang import GangInfo
+        from koordinator_tpu.quota.admission import QuotaDeviceState
+        from koordinator_tpu.quota.tree import UNBOUNDED, QuotaTree
+        from koordinator_tpu.state.cluster_state import (
+            ClusterState,
+            PodBatch,
+        )
+
+        state = ClusterState.from_arrays(
+            np.stack([_vector(16_000, 32_768)] * 8))
+        pods = PodBatch.build(np.stack([_vector(1_000, 1_024)] * 4))
+        tree = QuotaTree(_vector(32_000, 131_072).astype(np.int64))
+        unbounded = np.full(len(_vector(0, 0)), UNBOUNDED, np.int64)
+        tree.add("team", min=np.zeros_like(unbounded), max=unbounded)
+        quota, _ = QuotaDeviceState.from_tree(tree)
+        return {"state": state, "pods": pods, "quota": quota,
+                "cfg": ScoringConfig.default(),
+                "gangs": GangInfo.build(np.array([1], np.int32))}
+
+    @staticmethod
+    def _scopes(fn, *args) -> set[str]:
+        import re
+
+        import jax
+
+        text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+        found = set()
+        for path in re.findall(r'loc\("([^"/][^"]*)"', text):
+            found.update(path.split("/"))   # op-name paths, not file paths
+        return found
+
+    def test_gang_assign_names_its_stages(self, problem):
+        from koordinator_tpu.ops.gang import gang_assign
+
+        p = problem
+        found = self._scopes(
+            lambda s, b, g, q: gang_assign(s, b, p["cfg"], g, q, passes=2,
+                                           solver="batch"),
+            p["state"], p["pods"], p["gangs"], p["quota"])
+        for scope in ("gang_pass", "score", "select", "assign_rounds",
+                      "propose", "prefix_accept", "quota_accept",
+                      "quota_admission"):
+            assert scope in found, (scope, sorted(found)[:40])
+
+    def test_incremental_entries_name_their_stages(self, problem):
+        import jax.numpy as jnp
+
+        from koordinator_tpu.ops import batch_assign as ba
+        from koordinator_tpu.ops.explain import explain_counts
+
+        p = problem
+        key, node, score = ba.select_candidates(
+            p["state"], p["pods"], p["cfg"], k=4, with_scores=True)
+        cache = ba.CandidateCache.build(key, node, score)
+        dirty = jnp.zeros(2, jnp.int32)
+        valid = jnp.array([True, False])
+        assert {"refresh", "score"} <= self._scopes(
+            lambda s, b, c: ba.refresh_candidates(
+                s, b, p["cfg"], c, dirty, valid, k=4),
+            p["state"], p["pods"], cache)
+        assert "scatter_rows" in self._scopes(
+            ba.scatter_candidate_rows, cache, jnp.zeros(1, jnp.int32),
+            key[:1], node[:1], score[:1])
+        assert {"assign_rounds", "propose", "prefix_accept"} <= self._scopes(
+            lambda s, b, k_, n_: ba.assign_round_pass(
+                s, b, None, k_, n_, p["cfg"]),
+            p["state"], p["pods"], key, node)
+        assert "explain_reduce" in self._scopes(
+            lambda s, b: explain_counts(s, b, p["cfg"]),
+            p["state"], p["pods"])
+
+    def test_sharded_twins_name_the_same_stages(self, problem):
+        import jax
+
+        from koordinator_tpu.parallel import sharded
+        from koordinator_tpu.parallel.mesh import solver_mesh
+
+        p = problem
+        mesh = solver_mesh(jax.devices()[:2])
+        program = sharded._gang_program(
+            mesh, p["state"].capacity, p["pods"].capacity, 2, "batch", 4,
+            (5, 15), 12)
+        found = self._scopes(program, p["state"], p["pods"], p["cfg"],
+                             p["gangs"], p["quota"])
+        for scope in ("gang_pass", "score", "select", "assign_rounds",
+                      "propose", "prefix_accept", "quota_accept"):
+            assert scope in found, (scope, sorted(found)[:40])
+
+
 # ---------------------------------------------------------------------------
 # debug surfaces
 # ---------------------------------------------------------------------------
@@ -458,6 +1022,42 @@ class TestKillSwitch:
         assert on_assign == off_assign
         assert on_fail == off_fail
         assert on_cycles == 1 and off_cycles == 0
+
+    def test_served_decisions_bit_identical_with_recorder_off(
+            self, kit_off, tmp_path):
+        """The spans of ISSUE 24 (frames, store, enqueue, release, bind.*,
+        explain, kit.load) are timing only: the served round, what the
+        scheduler holds after a release, and the store's contents are the
+        same with the recorder off, and off records nothing."""
+        def run(enabled, sock):
+            timeline.RECORDER.reset_for_tests()
+            was = timeline.RECORDER.enabled
+            timeline.RECORDER.set_enabled(enabled)
+            served = _Served(kit_off, sock, capacity=128)
+            try:
+                answer = _drive_served(served)
+                sched = served.scheduler
+                with sched.lock:
+                    sched.snapshot.flush()
+                    requested = np.asarray(
+                        sched.snapshot.state.node_requested).copy()
+                return (answer, sorted(sched.pending), sorted(sched.bound),
+                        requested,
+                        sorted(e.pod_name
+                               for e in sched.explanations._queue),
+                        len(timeline.RECORDER.cycles()),
+                        len(timeline.RECORDER._segments))
+            finally:
+                served.close()
+                timeline.RECORDER.set_enabled(was)
+
+        on = run(True, str(tmp_path / "on.sock"))
+        off = run(False, str(tmp_path / "off.sock"))
+        assert on[0] == off[0]
+        assert on[1:3] == off[1:3] and on[4] == off[4]
+        np.testing.assert_array_equal(on[3], off[3])
+        assert on[5] == 1 and on[6] > 0
+        assert off[5] == 0 and off[6] == 0
 
     def test_recording_overhead_under_3pct(self, kit_off):
         """The recorder's whole per-cycle cost — every segment add plus

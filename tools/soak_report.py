@@ -67,8 +67,8 @@ UNATTRIBUTED_RED_FRACTION = 0.05
 
 
 def host_wait_attribution(cycle_docs: list[dict], top: int = 4) -> dict:
-    """Aggregate ``/debug/timeline`` cycle docs into the verdict's
-    host-wait section: per-tenant top causes by attributed seconds
+    """Aggregate ``/debug/timeline`` round and cycle docs into the
+    verdict's host-wait section: per-tenant top causes by attributed seconds
     (tenant-tagged segments; the untenanted scheduler's segments land
     under ``-``) and the WALL-WEIGHTED unattributed residual across
     cycles.  Wall-weighted, not a plain mean of per-cycle fractions:
@@ -78,6 +78,9 @@ def host_wait_attribution(cycle_docs: list[dict], top: int = 4) -> dict:
     per_tenant: dict[str, dict[str, float]] = {}
     resid_s = 0.0
     wall_s = 0.0
+    # the wall between rounds (mode "ingest") is not judged: what no
+    # span covers there is the program standing idle, not a residual
+    cycle_docs = [c for c in cycle_docs if c.get("mode") != "ingest"]
     for cyc in cycle_docs:
         wall = float(cyc.get("wall_s", 0.0))
         wall_s += wall
@@ -85,7 +88,10 @@ def host_wait_attribution(cycle_docs: list[dict], top: int = 4) -> dict:
         for seg in cyc.get("segments", []):
             tenant = seg.get("tenant") or "-"
             causes = per_tenant.setdefault(tenant, {})
-            dur = float(seg["end"]) - float(seg["start"])
+            # a run of back-to-back spans is one segment: its busy time,
+            # not its extent (older docs carry no busy_s)
+            dur = float(seg.get("busy_s",
+                                float(seg["end"]) - float(seg["start"])))
             causes[seg["cause"]] = causes.get(seg["cause"], 0.0) + dur
     mean_resid = (resid_s / wall_s) if wall_s > 0 else 0.0
     return {
@@ -246,7 +252,8 @@ def export_training_records(round_docs: list[dict],
     inputs yield byte-identical output (sorted keys, stable record
     order is the caller's contract).  Returns lines written."""
     by_cycle = {int(c["cycle"]): c for c in cycle_docs
-                if c.get("cycle") is not None}
+                if c.get("cycle") is not None
+                and c.get("mode") != "ingest"}
     slo_snapshot = {
         name: {"breaches_total": s.get("breaches_total", 0),
                "peak_burn_fast": (s.get("peak_burn") or {}).get("fast"),
