@@ -537,6 +537,12 @@ timeline_segments_dropped = SCHEDULER.counter(
     "Timeline records the recorder's full ring pushed out before any "
     "window read them; back-to-back spans of one name are one record, "
     "so a steady scheduler sits at zero")
+explanation_queue_purged = SCHEDULER.counter(
+    "explanation_queue_purged_total",
+    "QUEUED ScheduleExplanation entries that ExplanationStore.delete / "
+    "delete_many removed because their pod bound before a drain wrote "
+    "them: the only case in which a delete walks the queue.  Zero means "
+    "no pod that bound had a failure waiting in the queue")
 
 # -- pod-journey ledger (journey.py, ISSUE 20) --
 pod_journey_latency_seconds = SCHEDULER.gauge(

@@ -16,8 +16,9 @@ import dataclasses
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Optional
+from typing import Iterable, Optional
 
+from koordinator_tpu import metrics
 from koordinator_tpu.api.crds import ScheduleExplanation
 from koordinator_tpu.scheduler.diagnosis import PodDiagnosis
 
@@ -127,6 +128,10 @@ class ExplanationStore:
         self.clock = clock
         self._lock = threading.Lock()
         self._queue: deque[ScheduleExplanation] = deque()
+        #: pod name -> entries of that name in ``_queue``; a name with
+        #: nothing queued has no key, so a delete never walks the queue
+        #: to learn that
+        self._queued: dict[str, int] = {}
         self._store: OrderedDict[str, ScheduleExplanation] = OrderedDict()
         self.dropped = 0
 
@@ -155,16 +160,33 @@ class ExplanationStore:
                 self.dropped += 1  # queue full: drop, never block scheduling
                 return
             self._queue.append(explanation)
+            self._queued[pod_name] = self._queued.get(pod_name, 0) + 1
 
     def delete(self, pod_name: str) -> None:
         """Pod scheduled (or removed): its explanation is stale — purge the
         store AND any queued-but-undrained entry, or a later drain would
         resurrect a failure explanation for a bound pod."""
+        self.delete_many((pod_name,))
+
+    def delete_many(self, pod_names: Iterable[str]) -> None:
+        """:meth:`delete` for a round's whole bind set under one lock:
+        the store pops, and one pass over the queue only when one of the
+        names really has a queued entry (``explanation_queue_purged_total``
+        counts the entries such a pass removed)."""
+        purged = 0
         with self._lock:
-            self._store.pop(pod_name, None)
-            if any(e.pod_name == pod_name for e in self._queue):
+            stale = set()
+            for name in pod_names:
+                self._store.pop(name, None)
+                n = self._queued.pop(name, 0)
+                if n:
+                    stale.add(name)
+                    purged += n
+            if stale:
                 self._queue = deque(
-                    e for e in self._queue if e.pod_name != pod_name)
+                    e for e in self._queue if e.pod_name not in stale)
+        if purged:
+            metrics.explanation_queue_purged.inc(purged)
 
     # -- worker side --------------------------------------------------------
 
@@ -173,7 +195,13 @@ class ExplanationStore:
         n = 0
         with self._lock:
             while self._queue and (max_items is None or n < max_items):
-                self._write(self._queue.popleft())
+                explanation = self._queue.popleft()
+                left = self._queued[explanation.pod_name] - 1
+                if left:
+                    self._queued[explanation.pod_name] = left
+                else:
+                    del self._queued[explanation.pod_name]
+                self._write(explanation)
                 n += 1
         return n
 
@@ -220,26 +248,52 @@ class WorkloadAuditor:
         self.ring_size = ring_size
         self.clock = clock
         self._lock = threading.Lock()
-        self._records: dict[str, deque[AuditEvent]] = {}
+        #: a ring is a list trimmed to ``ring_size``, not a
+        #: ``deque(maxlen=...)``: a deque takes its first 64-slot block
+        #: (528 bytes, past pymalloc) from the system allocator when it
+        #: is built, 15 us a ring in the scheduler's process and 0.8 s of
+        #: a round that meets 50,000 new keys (PERF.md, PR 25)
+        self._records: dict[str, list[AuditEvent]] = {}
         self._attempts: dict[str, int] = {}
         self._gated: dict[str, bool] = {}
 
-    def _ring(self, key: str) -> deque[AuditEvent]:
-        return self._records.setdefault(key, deque(maxlen=self.ring_size))
+    def _append(self, key: str, event: AuditEvent) -> None:
+        ring = self._records.get(key)
+        if ring is None:
+            ring = self._records[key] = []
+        ring.append(event)
+        if len(ring) > self.ring_size:
+            del ring[0]
 
     def record(self, key: str, record_type: str, message: str = "") -> None:
+        self.record_many(record_type, ((key, message),))
+
+    def record_many(self, record_type: str,
+                    events: Iterable[tuple[str, str]]) -> None:
+        """One ``record_type`` event per (key, message) pair, in order,
+        under one lock and ONE clock read: the events of a batch (a
+        round's binds, a round's failures) are one instant."""
         if not self.enabled:
             return
         with self._lock:
-            self._ring(key).append(
-                AuditEvent(self.clock(), record_type, message))
+            now = self.clock()
+            for key, message in events:
+                self._append(key, AuditEvent(now, record_type, message))
 
     def record_attempt(self, key: str) -> None:
+        self.record_attempts((key,))
+
+    def record_attempts(self, keys: Iterable[str]) -> None:
+        """One attempt per key under one lock; a round's attempts are one
+        instant, so the keys share one (frozen) timestamped event."""
         if not self.enabled:
             return
         with self._lock:
-            self._attempts[key] = self._attempts.get(key, 0) + 1
-            self._ring(key).append(AuditEvent(self.clock(), RECORD_ATTEMPT))
+            event = AuditEvent(self.clock(), RECORD_ATTEMPT)
+            attempts = self._attempts
+            for key in keys:
+                attempts[key] = attempts.get(key, 0) + 1
+                self._append(key, event)
 
     def record_gating(self, key: str, gated: bool) -> None:
         """Only gating *transitions* are recorded (RecordPodGating)."""
@@ -249,7 +303,7 @@ class WorkloadAuditor:
             if self._gated.get(key) == gated:
                 return
             self._gated[key] = gated
-            self._ring(key).append(AuditEvent(
+            self._append(key, AuditEvent(
                 self.clock(), RECORD_GATED, "gated" if gated else "ungated"))
 
     def delete(self, key: str) -> None:

@@ -1698,12 +1698,11 @@ class Scheduler:
             # reserve-pods are not workloads
             keys = {pod.gang or pod.name for pod in pods
                     if not pod.name.startswith(RSV_POD_PREFIX)}
-            # between two monitor phases: without a span of its own the
-            # loop is 1.2 s of a 50,000-pod round that nothing names
+            # between two monitor phases: without a span of its own
+            # this is time of the round that nothing names
             with timeline.RECORDER.section("host_other", "audit.attempts",
                                            self.tenant, n=len(keys)):
-                for key in keys:
-                    self.auditor.record_attempt(key)
+                self.auditor.record_attempts(keys)
 
         with self.monitor.phase("BatchBuild"):
             self.snapshot.flush()
@@ -2195,6 +2194,7 @@ class Scheduler:
 
     # koordlint: guarded-by(self.lock)
     def _persist_failures(self, pods, result: SchedulingResult) -> None:
+        failed: list[tuple[str, str]] = []
         for pod in pods:
             if pod.name.startswith(RSV_POD_PREFIX):
                 # an unplaced reservation retries next round; it is not
@@ -2209,8 +2209,9 @@ class Scheduler:
                     tl_t0, time.perf_counter(), "host_other",
                     "diagnose.explain", self.tenant)
                 if self.auditor is not None:
-                    self.auditor.record(pod.gang or pod.name,
-                                        "ScheduleFailed", diag.message())
+                    failed.append((pod.gang or pod.name, diag.message()))
+        if failed:
+            self.auditor.record_many("ScheduleFailed", failed)
 
     # -- solve-quality mode (ISSUE 13) --------------------------------------
 
@@ -2790,10 +2791,11 @@ class Scheduler:
         and int64 never rounds, so the grouped totals are bit-identical
         to the sequential charges (the reserve_batch precedent).  Per-
         pod surfaces — ``resource_status``, trace stamping, fine-grained
-        allocation, explanations, auditor records — are preserved
-        exactly, in bind order.  ``bind_batch_fn`` (when set) receives
-        the whole set once: the seam for one deltasync emission per
-        round instead of one frame per pod."""
+        allocation — are preserved exactly, in bind order; the
+        explanation store and the auditor take the bind set as one
+        batched call each, in that order too.  ``bind_batch_fn`` (when
+        set) receives the whole set once: the seam for one deltasync
+        emission per round instead of one frame per pod."""
         if not binds:
             return
         commit_t0 = time.perf_counter()
@@ -2851,16 +2853,18 @@ class Scheduler:
                     self.resource_status.setdefault(pod.name, {})[
                         tracing.TRACE_ANNOTATION] = (
                             sp.context().to_annotation())
-                if self.explanations is not None:
-                    # one bind.explain run under bind.surfaces: the
-                    # store's share of the commit, n = deletes
-                    tl_t0 = time.perf_counter()
-                    self.explanations.delete(pod.name)
-                    tl.add(tl_t0, time.perf_counter(), "bind_commit",
-                           "bind.explain", tenant)
-                if self.auditor is not None:
-                    self.auditor.record(pod.gang or pod.name,
-                                        "ScheduleSuccess", node)
+            # the store and the auditor share no state with the loop
+            # above, so each takes the round's binds as one batch
+            if self.explanations is not None:
+                # the store's share of the commit, n = deletes
+                with tl.section("bind_commit", "bind.explain", tenant,
+                                n=len(binds)):
+                    self.explanations.delete_many(
+                        pod.name for pod, _node in binds)
+            if self.auditor is not None:
+                self.auditor.record_many(
+                    "ScheduleSuccess",
+                    ((pod.gang or pod.name, node) for pod, node in binds))
         with tl.section("bind_commit", "bind.emit", tenant):
             if self.bind_batch_fn is not None:
                 self.bind_batch_fn(

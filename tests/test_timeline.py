@@ -815,6 +815,65 @@ class TestSpansOfTheServedPath:
         assert parents["release.fine_grained"] == "sync.pod_remove"
         assert timeline.RECORDER.dropped == 0
 
+    def test_a_round_batches_its_store_and_audit_bookkeeping(self, kit_off):
+        """ISSUE 25: one ``bind.explain`` record of n = binds under
+        ``bind.surfaces`` and one ``audit.attempts`` record of n =
+        workload keys, whatever the round's size; the store is purged of
+        every bound name and every key has its events."""
+        from koordinator_tpu import metrics
+        from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
+        from koordinator_tpu.scheduler.diagnosis import PodDiagnosis
+        from koordinator_tpu.scheduler.explanation import (
+            ExplanationStore,
+            WorkloadAuditor,
+        )
+        from koordinator_tpu.scheduler.scheduler import GangRecord
+        from koordinator_tpu.scheduler.snapshot import PodSpec
+
+        store, auditor = ExplanationStore(), WorkloadAuditor()
+        sched = Scheduler(ClusterSnapshot(capacity=32), mesh="off",
+                          solver_kit=kit_off, explanations=store,
+                          auditor=auditor)
+        _feed_nodes(sched, seed=3)
+        _enqueue_pods(sched, 5, seed=4)
+        sched.register_gang(GangRecord(name="g", min_member=2))
+        for member in ("g-0", "g-1"):
+            sched.enqueue(PodSpec(name=member, gang="g", priority=5_000,
+                                  requests=_vector(500, 512)))
+        # an earlier round's failure still waits in the queue for a pod
+        # of this round, and one for a pod that is not in it
+        failed = PodDiagnosis(total_nodes=8, feasible_nodes=0,
+                              insufficient_resources=8,
+                              usage_over_threshold=0, affinity_mismatch=0,
+                              quota_rejected=False, invalid=0)
+        store.record("p4-2", failed)
+        store.record("elsewhere", failed)
+        purged = metrics.explanation_queue_purged.value()
+
+        timeline.RECORDER.reset_for_tests()
+        result = sched.schedule_round()
+        bound = set(result.assignments)
+        assert bound == {f"p4-{j}" for j in range(5)} | {"g-0", "g-1"}
+        doc = timeline.RECORDER.cycles(1)[0]
+        explain = [s for s in doc["segments"] if s["name"] == "bind.explain"]
+        assert [(s["n"], s["parent"]) for s in explain] == [
+            (len(bound), "bind.surfaces")]
+        attempts = [s for s in doc["segments"]
+                    if s["name"] == "audit.attempts"]
+        keys = (bound - {"g-0", "g-1"}) | {"g"}
+        assert [s["n"] for s in attempts] == [len(keys)]   # a gang: once
+
+        assert metrics.explanation_queue_purged.value() == purged + 1
+        assert store.drain() == 1                 # "elsewhere" alone
+        assert all(store.get(name) is None for name in bound)
+        for key in keys:
+            assert auditor.attempts(key) == 1
+            types = [e.record_type for e in auditor.events(key)]
+            assert types == ["Attempt"] + ["ScheduleSuccess"] * (
+                2 if key == "g" else 1), (key, types)
+        assert {e.message for e in auditor.events("g")[1:]} == {
+            result.assignments["g-0"], result.assignments["g-1"]}
+
     def test_load_seconds_counter_follows_the_recompile_counter(
             self, kit_off):
         from koordinator_tpu import metrics
