@@ -50,6 +50,10 @@ class Assembled:
     #: warm-restart checkpoint writer (drills.checkpoint.CheckpointWriter)
     #: when --checkpoint-path is set; stop() writes a final cut
     checkpointer: Optional[Any] = None
+    #: descheduler.migration.MigrationController, when koord-descheduler
+    #: was assembled beside a scheduler: its evictions are migration jobs
+    #: and whoever runs the loop calls ``migration.reconcile()``
+    migration: Optional[Any] = None
 
     def stop(self) -> None:
         """Tear down whatever this binary opened (sockets, gateway, the
@@ -1199,7 +1203,14 @@ def build_descheduler_parser() -> argparse.ArgumentParser:
 
 
 def main_koord_descheduler(argv: list[str], pods_fn=None,
-                           lease_store=None) -> Assembled:
+                           lease_store=None, scheduler=None) -> Assembled:
+    """``scheduler``: the assembled koord-scheduler (:class:`Assembled`)
+    this descheduler runs beside, in one process over one device-resident
+    state.  With one, LowNodeLoad can be asked for by flag or config: it
+    reads that scheduler's state, its evictions become PodMigrationJobs,
+    and the migration controller (``Assembled.migration``) reserves the
+    replacements' capacity through that scheduler, one round a reconcile,
+    before it evicts through it."""
     from koordinator_tpu.descheduler.framework import (
         Descheduler,
         Evictor,
@@ -1230,12 +1241,28 @@ def main_koord_descheduler(argv: list[str], pods_fn=None,
     restart_threshold = (args.pod_restart_threshold
                          if args.pod_restart_threshold is not None
                          else component.pod_restart_threshold or 100)
+    migration = None
+    evictor = Evictor()
+    if scheduler is not None:
+        from koordinator_tpu.descheduler import plugins as dplugins
+        from koordinator_tpu.descheduler.migration import MigrationController
+
+        sched = scheduler.component
+        migration = MigrationController(
+            limits=component.migration_limits,
+            reserve_many=dplugins.scheduler_reserve_many(sched),
+            evict_fn=dplugins.scheduler_migration_evict_fn(sched),
+            controller_finder=dplugins.BoundOwnersFinder(sched))
+        evictor = Evictor(evict_fn=dplugins.migration_evict_fn(migration))
+        if pods_fn is None:
+            pods_fn = dplugins.bound_pods_fn(sched)
     evictor_filter = EvictorFilter(
         evict_system_critical=(args.evict_system_critical
                                or component.evict_system_critical),
         evict_local_storage=(args.evict_local_storage_pods
                              or component.evict_local_storage_pods),
         priority_threshold=priority_threshold,
+        migrating_fn=migration.migrating_pods if migration else None,
     )
     # upstream-port plugins selectable by name, derived from the single
     # upstream.PLUGINS registry (the reference's profile pluginConfig).
@@ -1273,6 +1300,10 @@ def main_koord_descheduler(argv: list[str], pods_fn=None,
         requested.append((raw, from_config))
     for raw, from_config in requested:
         name = raw.lower()
+        if name == "lownodeload" and scheduler is not None:
+            balance_plugins.append(dplugins.LowNodeLoadPlugin(
+                scheduler=sched, args=component.lownodeload))
+            continue
         if name in shell_wired:
             if from_config:
                 continue   # shell reads asm.component_config and wires it
@@ -1295,7 +1326,7 @@ def main_koord_descheduler(argv: list[str], pods_fn=None,
         deschedule_plugins=deschedule_plugins,
         balance_plugins=balance_plugins,
         evictor_filter=evictor_filter,
-        evictor=Evictor(),
+        evictor=evictor,
         max_evictions_per_round=max_evictions,
     )
     elector = build_elector(args, lease_store)
@@ -1306,7 +1337,7 @@ def main_koord_descheduler(argv: list[str], pods_fn=None,
     )
     return Assembled(name="koord-descheduler", args=args,
                      component=descheduler, elector=elector,
-                     component_config=component,
+                     component_config=component, migration=migration,
                      telemetry=build_self_telemetry(
                          args, "koord-descheduler"))
 

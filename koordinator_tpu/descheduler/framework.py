@@ -94,6 +94,7 @@ class EvictorFilter:
         priority_threshold: Optional[int] = None,
         pdbs: Optional[list[PDB]] = None,
         extra_filters: Optional[list[Callable[[PodInfo], bool]]] = None,
+        migrating_fn: Optional[Callable[[], set]] = None,
     ):
         self.evict_system_critical = evict_system_critical
         self.evict_local_storage = evict_local_storage
@@ -101,10 +102,17 @@ class EvictorFilter:
         self.priority_threshold = priority_threshold
         self.pdbs = list(pdbs or [])
         self.extra_filters = list(extra_filters or [])
+        #: uids of the pods a live PodMigrationJob already names: such a
+        #: pod is not evicted again (the migration controller's
+        #: filterExistingPodMigrationJob)
+        self.migrating_fn = migrating_fn
 
     def _pdb_for(self, pod: PodInfo) -> Optional[PDB]:
+        return self._pdb_for_labels(pod.labels)
+
+    def _pdb_for_labels(self, labels: dict) -> Optional[PDB]:
         for pdb in self.pdbs:
-            if all(pod.labels.get(k) == v for k, v in pdb.selector.items()):
+            if all(labels.get(k) == v for k, v in pdb.selector.items()):
                 return pdb
         return None
 
@@ -125,10 +133,52 @@ class EvictorFilter:
         pdb = self._pdb_for(pod)
         if pdb is not None and pdb.disruptions_allowed <= 0:
             return False, "PDB exhausted"
+        if self.migrating_fn is not None and pod.uid in self.migrating_fn():
+            return False, "migration job exists"
         for fn in self.extra_filters:
             if not fn(pod):
                 return False, "plugin filter"
         return True, ""
+
+    def mask(self, cols, pod_at: Callable[[int], PodInfo]) -> "np.ndarray":
+        """:meth:`filter` over the bound pods' columns
+        (``scheduler/bound_columns.BoundColumns``): (size,) bool, the same
+        answer per live slot as ``filter(pod_at(slot))[0]``, with no call
+        per pod.  ``extra_filters`` are callables on a pod, so they alone
+        are asked pod by pod, of the pods everything else lets through."""
+        import numpy as np
+
+        from koordinator_tpu.scheduler import bound_columns as bc
+
+        n = cols.size
+        ok = cols.live[:n].copy()
+        flags, priority = cols.flags[:n], cols.priority[:n]
+        if not self.evict_daemonsets:
+            ok &= (flags & bc.DAEMONSET) == 0
+        if not self.evict_local_storage:
+            ok &= (flags & bc.LOCAL_STORAGE) == 0
+        if not self.evict_system_critical:
+            ok &= priority < 2_000_000_000
+        if self.priority_threshold is not None:
+            ok &= priority < self.priority_threshold
+        ok &= (flags & bc.EVICT_FORBIDDEN) == 0
+        if self.pdbs:
+            # pods of one label set meet the same (first matching) PDB
+            exhausted = np.zeros(len(cols.labelsets), bool)
+            for ls in np.unique(cols.labelset_id[:n][ok]):
+                pdb = self._pdb_for_labels(cols.labels_of(int(ls)))
+                exhausted[ls] = (pdb is not None
+                                 and pdb.disruptions_allowed <= 0)
+            ok &= ~exhausted[cols.labelset_id[:n]]
+        if self.migrating_fn is not None:
+            slots = [cols.slot_of[uid] for uid in self.migrating_fn()
+                     if uid in cols.slot_of]
+            ok[slots] = False
+        if self.extra_filters:
+            for slot in np.flatnonzero(ok):
+                pod = pod_at(int(slot))
+                ok[slot] = all(fn(pod) for fn in self.extra_filters)
+        return ok
 
     def consume_budget(self, pod: PodInfo) -> None:
         pdb = self._pdb_for(pod)

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
@@ -128,33 +129,24 @@ def eviction_budget(
     return jnp.sum(jnp.where(under[:, None] & (high >= 0), room, 0), axis=0)
 
 
-def select_victims(
-    usage: jnp.ndarray,        # (N, R) node usage
-    capacity: jnp.ndarray,     # (N, R)
-    node_valid: jnp.ndarray,   # (N,)
-    pod_node: jnp.ndarray,     # (P,) int32 — node each pod runs on, -1 none
-    pod_usage: jnp.ndarray,    # (P, R) — per-pod usage
-    pod_priority: jnp.ndarray, # (P,) int32
-    pod_evictable: jnp.ndarray,# (P,) bool — passed the eviction filters (PDB,
-                               #   owner kind, QoS policy...) computed host-side
-    anomaly_counters: jnp.ndarray,  # (N,) int32
-    args: LowNodeLoadArgs,
-) -> jnp.ndarray:
-    """(P,) bool victim mask.
-
-    Evicts lowest-priority pods first from anomalous overutilized nodes, while
-    (a) the node remains above its high threshold and (b) the underutilized
-    pool still has head-room for the pod (balancePods/evictPods semantics).
-    """
+def _node_half(usage, capacity, node_valid, args):
+    """What victim selection needs of the nodes, before the anomaly gate:
+    (overutilized (N,) bool, pool budget (R,), high thresholds (R,), high
+    quantity (N, R))."""
     pct = usage_percent(usage, capacity)
     low, high = effective_thresholds(args, pct, node_valid)
     under, over = _classify(pct, low, high, node_valid)
-    abnormal = over & (anomaly_counters >= args.anomaly_rounds)
     budget = eviction_budget(usage, capacity, under, high)
-
     high_quant = _high_quantity(capacity, high, jnp.int32(2**30))
+    return over, budget, high, high_quant
 
-    # cheapest (lowest priority, then smallest cpu usage) pods first
+
+def _walk_cheapest_first(usage, budget, high, high_quant, abnormal,
+                         pod_node, pod_usage, pod_priority, pod_evictable):
+    """(P,) bool victim mask over the pods given, cheapest (lowest
+    priority, then smallest cpu usage) first, one at a time: a pod goes
+    while its node stays above its high quantity on some configured dim
+    and the pool's head-room covers it on every one."""
     p = pod_node.shape[0]
     order = jnp.lexsort((pod_usage[:, 0], pod_priority))
 
@@ -177,5 +169,121 @@ def select_victims(
         return (node_usage, budget), candidate
 
     (_, _), victims_in_order = jax.lax.scan(step, (usage, budget), order)
-    victims = jnp.zeros(p, bool).at[order].set(victims_in_order)
-    return victims
+    return jnp.zeros(p, bool).at[order].set(victims_in_order)
+
+
+def select_victims(
+    usage: jnp.ndarray,        # (N, R) node usage
+    capacity: jnp.ndarray,     # (N, R)
+    node_valid: jnp.ndarray,   # (N,)
+    pod_node: jnp.ndarray,     # (P,) int32 — node each pod runs on, -1 none
+    pod_usage: jnp.ndarray,    # (P, R) — per-pod usage
+    pod_priority: jnp.ndarray, # (P,) int32
+    pod_evictable: jnp.ndarray,# (P,) bool — passed the eviction filters (PDB,
+                               #   owner kind, QoS policy...) computed host-side
+    anomaly_counters: jnp.ndarray,  # (N,) int32
+    args: LowNodeLoadArgs,
+) -> jnp.ndarray:
+    """(P,) bool victim mask.
+
+    Evicts lowest-priority pods first from anomalous overutilized nodes, while
+    (a) the node remains above its high threshold and (b) the underutilized
+    pool still has head-room for the pod (balancePods/evictPods semantics).
+
+    Traceable, and one scan step per pod given: a caller with the whole
+    cluster's pods in hand goes through :class:`SourceNodeSelector`, which
+    walks the pods of abnormal nodes only.
+    """
+    over, budget, high, high_quant = _node_half(
+        usage, capacity, node_valid, args)
+    abnormal = over & (anomaly_counters >= args.anomaly_rounds)
+    return _walk_cheapest_first(usage, budget, high, high_quant, abnormal,
+                                pod_node, pod_usage, pod_priority,
+                                pod_evictable)
+
+
+def _observe(usage, capacity, node_valid, anomaly_counters, args):
+    """One round's node half: (counters after this round, abnormal, budget,
+    high, high quantity)."""
+    with jax.named_scope("desched/classify"):
+        over, budget, high, high_quant = _node_half(
+            usage, capacity, node_valid, args)
+        counters = update_anomaly_counters(anomaly_counters, over)
+        abnormal = over & (counters >= args.anomaly_rounds)
+        return counters, abnormal, budget, high, high_quant
+
+
+def _walk_candidates(usage, budget, high, high_quant, abnormal,
+                     cand_node, cand_usage, cand_priority, cand_valid):
+    with jax.named_scope("desched/select"):
+        return _walk_cheapest_first(usage, budget, high, high_quant,
+                                    abnormal, cand_node, cand_usage,
+                                    cand_priority, cand_valid)
+
+
+class SourceNodeSelector:
+    """Victim selection at the size of the source nodes.
+
+    Same answers as :func:`select_victims` on every input: a pod that is
+    not evictable or not on an abnormal node never changes the walk's
+    carry, and a stable sort keeps the order of those that stay.  So the
+    node half runs on the device, its (N,) abnormal mask comes to the
+    host, the pods of abnormal nodes are gathered there and the walk runs
+    over them alone, padded to a power-of-two bucket.  The bucket never
+    shrinks: a round with fewer candidates than an earlier one reuses the
+    earlier program, and only growth compiles.
+
+    Holds the anomaly counters ((N,) int32, on the device) between rounds.
+    """
+
+    #: the smallest bucket a walk is padded to
+    MIN_BUCKET = 64
+
+    def __init__(self, args: LowNodeLoadArgs):
+        from koordinator_tpu.ops import introspection as insp
+
+        self.args = args
+        self.bucket = self.MIN_BUCKET
+        self.counters = None
+        self._observe = insp.instrument(
+            jax.jit(_observe), "lownodeload_observe",
+            shape_of=lambda a, k: f"N{a[0].shape[0]}")
+        self._walk = insp.instrument(
+            jax.jit(_walk_candidates), "lownodeload_walk",
+            shape_of=lambda a, k: f"C{a[5].shape[0]}xN{a[0].shape[0]}")
+
+    def observe(self, usage, capacity, node_valid):
+        """Advance the anomaly counters by this round's classification;
+        returns the round's (abnormal (N,) bool on the host, device
+        handles for :meth:`walk`)."""
+        n = usage.shape[0]
+        if self.counters is None or self.counters.shape[0] != n:
+            self.counters = jnp.zeros(n, jnp.int32)
+        self.counters, abnormal, budget, high, high_quant = self._observe(
+            usage, capacity, node_valid, self.counters, self.args)
+        return np.asarray(abnormal), (usage, budget, high, high_quant,
+                                      abnormal)
+
+    def walk(self, handles, pod_node, pod_usage, pod_priority, candidates):
+        """(len(candidates),) bool: which of ``candidates`` (indices into
+        the host pod columns, all evictable and on abnormal nodes) are
+        victims.  Equally cheap candidates are walked in the order given."""
+        c = len(candidates)
+        if c == 0:
+            return np.zeros(0, bool)
+        while self.bucket < c:
+            self.bucket *= 2
+        b = self.bucket
+        node = np.full(b, -1, np.int32)
+        node[:c] = pod_node[candidates]
+        cand_usage = np.zeros((b, pod_usage.shape[1]), np.int32)
+        cand_usage[:c] = pod_usage[candidates]
+        priority = np.zeros(b, np.int32)
+        priority[:c] = pod_priority[candidates]
+        valid = np.zeros(b, bool)
+        valid[:c] = True
+        usage, budget, high, high_quant, abnormal = handles
+        victims = self._walk(usage, budget, high, high_quant, abnormal,
+                             jnp.asarray(node), jnp.asarray(cand_usage),
+                             jnp.asarray(priority), jnp.asarray(valid))
+        return np.asarray(jax.block_until_ready(victims))[:c]

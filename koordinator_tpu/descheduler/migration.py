@@ -16,7 +16,10 @@ Semantics from ``pkg/descheduler/controllers/migration``:
 
 This is control-plane protocol machinery, so it stays host-side Python; the
 expensive part — choosing where replacements go — is delegated to the TPU
-solver through the ``reserve_fn`` callback.
+solver through the ``reserve_many`` callback: ALL the jobs a reconcile lets
+run are handed over at once, so that against the scheduler they are one
+batched round of reserve-pods and not a round per job.  One job is a batch
+of one.
 """
 
 from __future__ import annotations
@@ -132,19 +135,25 @@ class MigrationController:
     def __init__(
         self,
         limits: ArbitrationLimits | None = None,
-        reserve_fn: Callable[[MigrationJob], str | None] | None = None,
+        reserve_many: Callable[[list[MigrationJob]],
+                               dict[str, str | None]] | None = None,
         evict_fn: Callable[[MigrationJob], bool] | None = None,
         workload_unavailable_fn: Callable[[str], int] | None = None,
         controller_finder: ControllerFinder | None = None,
         clock: Callable[[], float] = time.monotonic,
     ):
         self.limits = limits or ArbitrationLimits()
-        self.reserve_fn = reserve_fn
+        #: ``reserve_many(jobs) -> {job name: reservation name | None}``:
+        #: replacement capacity for every job of one reconcile, secured
+        #: BEFORE any of them evicts; None fails the job
+        self.reserve_many = reserve_many
         self.evict_fn = evict_fn
         self.workload_unavailable_fn = workload_unavailable_fn
         self.controller_finder = controller_finder
         self.clock = clock
         self.jobs: dict[str, MigrationJob] = {}
+        #: pod -> how many live (Pending or Running) jobs name it
+        self._live: Counter = Counter()
 
     def _workload_budgets(self, ref: str) -> tuple[int, int, int]:
         """(max_migrating, max_unavailable, already_unavailable) for the
@@ -179,6 +188,15 @@ class MigrationController:
         if job.name in self.jobs:
             raise ValueError(f"migration job {job.name!r} already exists")
         self.jobs[job.name] = job
+        if job.phase in (MigrationJobPhase.PENDING, MigrationJobPhase.RUNNING):
+            self._live[job.pod] += 1
+
+    def _finish(self, job: MigrationJob, phase: MigrationJobPhase,
+                reason: str) -> None:
+        job.phase, job.reason = phase, reason
+        self._live[job.pod] -= 1
+        if self._live[job.pod] <= 0:
+            del self._live[job.pod]
 
     def running(self) -> list[MigrationJob]:
         return [j for j in self.jobs.values()
@@ -187,6 +205,12 @@ class MigrationController:
     def pending(self) -> list[MigrationJob]:
         return [j for j in self.jobs.values()
                 if j.phase is MigrationJobPhase.PENDING]
+
+    def migrating_pods(self):
+        """The pods a live (Pending or Running) job names, as a set-like
+        view kept as jobs come and end: the evictor filter keeps them out
+        of the next round's victims (filterExistingPodMigrationJob)."""
+        return self._live.keys()
 
     # -- arbitration (sort + filter) ---------------------------------------
 
@@ -206,58 +230,82 @@ class MigrationController:
 
     def arbitrate(self) -> list[MigrationJob]:
         """Pick pending jobs allowed to run this round (sort then filter)."""
+        from koordinator_tpu import metrics
+
         node, ns, workload = self._group_counts(self.running())
         allowed: list[MigrationJob] = []
+        outcomes = Counter()
+        lim = self.limits
         for job in self._sorted_candidates():
-            lim = self.limits
             if node[job.node] >= lim.max_migrating_per_node:
+                outcomes["node"] += 1
                 continue
             if ns[job.namespace] >= lim.max_migrating_per_namespace:
+                outcomes["namespace"] += 1
                 continue
             if job.workload:
                 max_migrating, max_unavailable, already_unavailable = (
                     self._workload_budgets(job.workload))
-                if workload[job.workload] >= max_migrating:
-                    continue
                 # migrating pods count as unavailable (filter.go:484
                 # mergeUnavailableAndMigratingPods)
-                if (already_unavailable + workload[job.workload]
+                if (workload[job.workload] >= max_migrating
+                        or already_unavailable + workload[job.workload]
                         >= max_unavailable):
+                    outcomes["workload"] += 1
                     continue
             allowed.append(job)
             node[job.node] += 1
             ns[job.namespace] += 1
             if job.workload:
                 workload[job.workload] += 1
+        outcomes["allowed"] = len(allowed)
+        for outcome, count in outcomes.items():
+            if count:
+                metrics.migration_jobs_arbitrated.inc(
+                    count, labels={"outcome": outcome})
         return allowed
 
     # -- reconcile ---------------------------------------------------------
 
     def reconcile(self) -> None:
         """One controller round: arbitrate, reserve, evict, expire."""
-        now = self.clock()
+        from koordinator_tpu import timeline
 
-        for job in self.arbitrate():
-            # reservation-first: secure replacement capacity before evicting
-            if self.reserve_fn is not None:
-                reservation = self.reserve_fn(job)
+        tl = timeline.RECORDER
+        with tl.section("host_other", "migrate.reconcile"):
+            self._reconcile(tl)
+
+    def _reconcile(self, tl) -> None:
+        now = self.clock()
+        with tl.section("host_other", "migrate.arbitrate",
+                        n=len(self.pending())):
+            allowed = self.arbitrate()
+
+        # reservation-first: secure replacement capacity before evicting,
+        # for all of this round's jobs in one call
+        reservations: dict[str, str | None] = {}
+        if self.reserve_many is not None and allowed:
+            with tl.section("host_other", "migrate.reserve", n=len(allowed)):
+                reservations = self.reserve_many(allowed)
+        for job in allowed:
+            if self.reserve_many is not None:
+                reservation = reservations.get(job.name)
                 if reservation is None:
-                    job.phase = MigrationJobPhase.FAILED
-                    job.reason = "ReservationFailed"
+                    self._finish(job, MigrationJobPhase.FAILED,
+                                 "ReservationFailed")
                     continue
                 job.reservation = reservation
             job.phase = MigrationJobPhase.RUNNING
             job.start_time = now
 
-        for job in self.running():
-            if self.evict_fn is not None:
-                if self.evict_fn(job):
-                    job.phase = MigrationJobPhase.SUCCEEDED
-                    job.reason = "Complete"
-                    continue
-            if job.start_time is not None and now - job.start_time > job.timeout_sec:
-                job.phase = MigrationJobPhase.FAILED
-                job.reason = "Timeout"
+        running = self.running()
+        with tl.section("host_other", "migrate.evict", n=len(running)):
+            for job in running:
+                if self.evict_fn is not None and self.evict_fn(job):
+                    self._finish(job, MigrationJobPhase.SUCCEEDED, "Complete")
+                elif (job.start_time is not None
+                        and now - job.start_time > job.timeout_sec):
+                    self._finish(job, MigrationJobPhase.FAILED, "Timeout")
 
         from koordinator_tpu import metrics
 

@@ -170,7 +170,7 @@ class _Arm:
             self.controller = MigrationController(
                 limits=ArbitrationLimits(max_migrating_per_node=4,
                                          max_migrating_per_namespace=256),
-                reserve_fn=self._reserve, evict_fn=self._evict)
+                reserve_many=self._reserve_many, evict_fn=self._evict)
             self.rebalancer = ProactiveRebalancer(
                 self.plane, self.controller,
                 pods_fn=self._victim_universe,
@@ -200,15 +200,21 @@ class _Arm:
 
     # -- migration seams (reservation-first) ---------------------------------
 
-    def _reserve(self, job) -> str | None:
-        dest = self._move_dest.get(job.name)
-        if dest is None:
-            return None
-        room = (self.cfg.high_quant - self._ls_now[dest]
-                - int(self.be_used()[dest]))
-        if room < self.cfg.be_pod_cpu_milli:
-            return None          # destination filled up since staging
-        return f"rsv-{job.name}"
+    def _reserve_many(self, jobs) -> dict:
+        """A reservation for every job whose staged destination still
+        has room for one BE pod under the high threshold (nothing moves
+        before the evictions, so one reading of the books serves all)."""
+        be_used = self.be_used()
+        out = {}
+        for job in jobs:
+            dest = self._move_dest.get(job.name)
+            room = (-1 if dest is None else
+                    self.cfg.high_quant - self._ls_now[dest]
+                    - int(be_used[dest]))
+            # None: never staged, or the destination filled up since
+            out[job.name] = (f"rsv-{job.name}"
+                             if room >= self.cfg.be_pod_cpu_milli else None)
+        return out
 
     def _evict(self, job) -> bool:
         dest = self._move_dest.pop(job.name, None)

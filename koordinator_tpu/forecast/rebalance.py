@@ -21,7 +21,7 @@ spike and the moves happen while they are still cheap:
   threshold, with sequential capacity feedback;
 - gated moves become reservation-first
   :class:`~koordinator_tpu.descheduler.migration.MigrationJob`\\ s: the
-  controller reserves replacement capacity (``reserve_fn``) before any
+  controller reserves replacement capacity (``reserve_many``) before any
   eviction fires, so a pre-staged pod is never left homeless.
 """
 
@@ -64,7 +64,7 @@ class ProactiveRebalancer:
     ``(names, pod_node (P,), pod_usage (P, R), pod_priority (P,),
     pod_evictable (P,))`` — the same shape ``select_victims`` takes;
     ``node_name_fn(row)`` resolves destination rows.  The controller's
-    ``reserve_fn``/``evict_fn`` stay the caller's seams (a real stack
+    ``reserve_many``/``evict_fn`` stay the caller's seams (a real stack
     wires the scheduler's reservation API; the A/B harness books its
     simulated capacity).
     """

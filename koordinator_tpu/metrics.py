@@ -784,3 +784,14 @@ descheduler_evictions_total = DESCHEDULER.counter(
     "pod_evictions_total", "Descheduler evictions by profile/reason")
 migration_jobs = DESCHEDULER.gauge(
     "migration_jobs", "PodMigrationJobs by phase")
+descheduler_victims_total = DESCHEDULER.counter(
+    "victims_total", "Pods a balance plugin chose to move (label: plugin)")
+migration_reserve_rounds = DESCHEDULER.counter(
+    "migration_reserve_rounds_total",
+    "Scheduling rounds run to place migration reservations: one per "
+    "reconcile that let any job run, however many jobs")
+migration_jobs_arbitrated = DESCHEDULER.counter(
+    "migration_jobs_arbitrated_total",
+    "Pending PodMigrationJobs by what arbitration did with them (label: "
+    "outcome=allowed|node|namespace|workload: let run, or held back by "
+    "that group's limit)")

@@ -271,12 +271,40 @@ class ReservationCache:
 
     def match_matrix(self, pods: list[PodSpec], pod_capacity: int,
                      rsv_capacity: int) -> np.ndarray:
-        """(P, V) bool owner-match matrix for the Available set."""
-        avail = self.available()
+        """(P, V) bool owner-match matrix for the Available set.
+
+        A matcher reads a pod's labels and owner and nothing else, so pods
+        that agree on both match alike (one sample of each kind is asked),
+        and a matcher with labels can only match the kinds that carry every
+        one of its pairs (the others are never asked)."""
+        avail = self.available()[:rsv_capacity]
+        pods = pods[:pod_capacity]
         out = np.zeros((pod_capacity, rsv_capacity), bool)
-        for j, spec in enumerate(avail[:rsv_capacity]):
-            for i, pod in enumerate(pods[:pod_capacity]):
-                out[i, j] = any(m.matches(pod) for m in spec.owners)
+        if not avail or not pods:
+            return out
+        kinds: dict[tuple, int] = {}
+        samples: list[PodSpec] = []
+        pod_kind = np.zeros(len(pods), np.int64)
+        carrying: dict[tuple, set[int]] = {}
+        for i, pod in enumerate(pods):
+            labels = getattr(pod, "labels", None) or {}
+            key = (tuple(sorted(labels.items())), getattr(pod, "owner", None))
+            kind = kinds.get(key)
+            if kind is None:
+                kind = kinds[key] = len(samples)
+                samples.append(pod)
+                for pair in key[0]:
+                    carrying.setdefault(pair, set()).add(kind)
+            pod_kind[i] = kind
+        for j, spec in enumerate(avail):
+            column = np.zeros(len(samples), bool)
+            for m in spec.owners:
+                likely = (set.intersection(*(carrying.get(pair, set())
+                                             for pair in m.labels.items()))
+                          if m.labels else range(len(samples)))
+                for kind in likely:
+                    column[kind] = column[kind] or m.matches(samples[kind])
+            out[: len(pods), j] = column[pod_kind]
         return out
 
     def commit_allocations(

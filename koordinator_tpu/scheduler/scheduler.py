@@ -38,6 +38,8 @@ from koordinator_tpu.ops.network_topology import (
 )
 from koordinator_tpu.quota.admission import QuotaDeviceState
 from koordinator_tpu.quota.tree import QuotaTree
+from koordinator_tpu.scheduler import bound_columns
+from koordinator_tpu.scheduler.bound_columns import BoundRegistry
 from koordinator_tpu.scheduler.diagnosis import PodDiagnosis, explain_pod
 from koordinator_tpu.scheduler.monitor import SchedulerMonitor
 from koordinator_tpu.scheduler.snapshot import ClusterSnapshot, PodSpec
@@ -89,6 +91,14 @@ class BoundPod:
     #: fresh instance (it starts clean; the churn suite drove
     #: node_requested negative before this stamp existed)
     node_generation: int = 0
+    #: what the descheduler's view of the pod needs beyond the above
+    #: (scheduler/bound_columns.py): QoS code, owning workload
+    #: ("Kind/name"; a DaemonSet's pods are never evicted), annotations
+    #: (the eviction-cost one) and whether the pod holds local storage
+    qos: int = 0
+    owner: str | None = None
+    annotations: dict[str, str] = dataclasses.field(default_factory=dict)
+    local_storage: bool = False
 
 
 @dataclasses.dataclass
@@ -264,6 +274,10 @@ class Scheduler:
         #: host-side arrays of the last batch build, for row-level reuse
         #: when the queue changes incrementally (see _build_batch)
         self._batch_host: dict | None = None
+        #: (capacity,) rows of this round's batch that the reservation
+        #: pre-pass settled (bound, or a reserve-pod); None when it
+        #: settled none.  The incremental dispatch takes it
+        self._prepass_settled: np.ndarray | None = None
         # -- the shared solver kit (ISSUE 11) --
         # every jitted entry point lives in a SolverKit (solve mesh
         # included): a standalone scheduler builds its own, a tenant of
@@ -416,7 +430,9 @@ class Scheduler:
         )
         #: called as preempt_fn(victim_name, preemptor_name) on each eviction
         self.preempt_fn = preempt_fn
-        self.bound: dict[str, BoundPod] = {}
+        #: name -> BoundPod, and the same pods as columns
+        #: (``self.bound.columns``) for preemption and the descheduler
+        self.bound: BoundRegistry = BoundRegistry(self.snapshot.dims)
         self.pdbs: dict[str, PdbRecord] = {}
         #: preemptor pod -> nominated node name (nominatedNodeName semantics)
         self.nominations: dict[str, str] = {}
@@ -686,6 +702,14 @@ class Scheduler:
                 self.remove_bound_pod(name)
                 self._charge_quota_used(bound, sign=-1)
 
+    def set_pod_usage(self, names, usage: np.ndarray) -> None:
+        """Per-pod real usage, (k, R) for the bound pods ``names``: the
+        descheduler's victim universe reads it from the bound pods'
+        columns.  There is no wire kind for it; the embedding deployment
+        sets it.  A pod none was set for reads its request."""
+        with self.lock:
+            self.bound.columns.set_usage(names, usage)
+
     def enable_overuse_revoke(self, revoke_fn,
                               delay_evict_sec: float = 5.0) -> None:
         """Turn on the elastic-quota overuse revoke loop
@@ -787,17 +811,29 @@ class Scheduler:
                 self._pending_rev += 1
 
     def _reservation_prepass(self, pods, batch, quota, result):  # koordlint: guarded-by(self.lock)
-        """Reservation-first exact solve over owner-matched pods (plugin.go
-        Reserve + nominator semantics): matched pods allocate from their
-        reservation's remainder before the general solve sees them.  Returns
-        the (possibly shrunk) batch and quota."""
+        """Reservation-first exact solve (plugin.go Reserve + nominator
+        semantics) over two kinds of pods.  Owner-matched pods allocate
+        from their reservation's remainder before the general solve sees
+        them.  Reserve-pods are placed here too, by the same exact pass:
+        it carries the LoadAware estimate of every pod it places onto the
+        next one, which the batch engine's rounds do not, so a reservation
+        is only opened on a node that will still admit its owner — however
+        many reservations one round places.  (Through the batch engine a
+        round of several hundred reserve-pods piled dozens onto the
+        emptiest node, and the exact pass then turned their owners away
+        from it.)  Returns the (possibly shrunk) batch and quota."""
         avail = self.reservations.available()
-        if not avail:
-            return batch, quota
-        # fully-consumed reservations have nothing to lend — skip the
-        # whole pre-pass (and its O(P) host-side owner matching)
-        if not any(np.any(s.requests > s.allocated) for s in avail
-                   if s.allocated is not None):
+        # fully-consumed reservations have nothing to lend — skip their
+        # O(P) host-side owner matching
+        lendable = any(np.any(s.requests > s.allocated) for s in avail
+                       if s.allocated is not None)
+        reserve_pods = np.zeros(batch.capacity, bool)
+        # only a Pending reservation has a reserve-pod in the queue: with
+        # none, the queue is not walked
+        if self.reservations.pending():
+            reserve_pods[: len(pods)] = [
+                p.name.startswith(RSV_POD_PREFIX) for p in pods]
+        if not lendable and not reserve_pods.any():
             return batch, quota
         rsv_set, names = self.reservations.build_set(self.snapshot)
         # the O(pods x reservations) python owner matching is cached
@@ -811,7 +847,9 @@ class Scheduler:
                 tuple(p.name for p in pods),
                 tuple(s.generation for s in avail))
         cached = self._rsv_match_cache
-        if cached is not None and cached[0] == mkey:
+        if not lendable:
+            match = np.zeros((batch.capacity, rsv_set.capacity), bool)
+        elif cached is not None and cached[0] == mkey:
             match = cached[1]          # read-only below: no defensive copy
         else:
             match = self.reservations.match_matrix(
@@ -822,7 +860,8 @@ class Scheduler:
                 if pod.name.startswith(RSV_POD_PREFIX) or pod.gang:
                     match[i] = False
             self._rsv_match_cache = (mkey, match)
-        matched = np.asarray(batch.valid) & match.any(axis=1)
+        matched = np.asarray(batch.valid) & (match.any(axis=1)
+                                             | reserve_pods)
         if not matched.any():
             return batch, quota
         if int(matched.sum()) > self.rsv_prepass_cap:
@@ -845,8 +884,12 @@ class Scheduler:
         drawn = self.reservations.commit_allocations(names, sub_pods, a_r, rc)
         bound_rows = [int(idx[j]) for j in range(len(sub_pods))
                       if int(a_r[j]) >= 0]
+        now = self.clock()
         for j, pod in enumerate(sub_pods):
-            if int(a_r[j]) >= 0:
+            if int(a_r[j]) >= 0 and pod.name.startswith(RSV_POD_PREFIX):
+                self._commit_reserve_pod(
+                    pod, self.snapshot.node_name(int(a_r[j])), result, now)
+            elif int(a_r[j]) >= 0:
                 r = int(rc[j])
                 rname = (names[r] if 0 <= r < len(names)
                          and drawn[j] is not None else None)
@@ -856,10 +899,15 @@ class Scheduler:
                     pod, self.snapshot.node_name(int(a_r[j])), result,
                     reservation=rname, rsv_drawn=drawn[j],
                     rsv_generation=(rspec.generation if rspec else 0))
-        if bound_rows:
-            mask = np.zeros(batch.capacity, bool)
-            mask[bound_rows] = True
-            batch = batch.replace(valid=batch.valid & ~jnp.asarray(mask))
+        # settled here, so out of the general solve: the pods bound above,
+        # and every reserve-pod — one this exact pass found no node for, or
+        # one past the cap, waits in the queue for the next round's
+        # pre-pass; the batch engine never places one
+        settled = reserve_pods.copy()
+        settled[bound_rows] = True
+        if settled.any():
+            batch = batch.replace(valid=batch.valid & ~jnp.asarray(settled))
+            self._prepass_settled = settled
         return batch, (new_quota if new_quota is not None else quota)
 
     # koordlint: guarded-by(self.lock)
@@ -1758,6 +1806,7 @@ class Scheduler:
                 # carry_s) — the synthetic latency regression the SLO
                 # engine's burn windows must catch (tests/test_slo_monitor)
                 self.faults.on_solve()
+            self._prepass_settled = None
             if len(self.reservations):
                 batch, quota = self._reservation_prepass(
                     pods, batch, quota, result)
@@ -2426,6 +2475,7 @@ class Scheduler:
         # consumed exactly once per cache rebuild/refresh — both branches
         # below leave a cache that reflects post-consume state
         dirty_rows = [r for r in snap.consume_candidate_dirty() if r < n]
+        settled, self._prepass_settled = self._prepass_settled, None
 
         path = "full_cold"
         cache = None
@@ -2436,6 +2486,10 @@ class Scheduler:
             map_ok = np.zeros(batch.capacity, bool)
             changed = np.zeros(batch.capacity, bool)
             for i, pod in enumerate(pods):
+                if settled is not None and settled[i]:
+                    # the reservation pre-pass settled it: it takes no
+                    # part in this solve, so it is no dirty row of it
+                    continue
                 j = row_of.get(pod.name)
                 if j is not None and specs.get(pod.name) is pod:
                     map_rows[i] = j
@@ -2486,9 +2540,15 @@ class Scheduler:
         # walk (the driver only runs on non-hinted batches, which always
         # populate _batch_host)
         host = self._batch_host
+        row_of = host["row_of"]
+        if settled is not None:
+            # no candidates were kept for a settled row: were it to come
+            # back (a reserve-pod that waits), it must read as new
+            row_of = {pod.name: i for i, pod in enumerate(pods)
+                      if not settled[i]}
         self._cand_cache = {
             "cache": cache,
-            "row_of": host["row_of"],
+            "row_of": row_of,
             "specs": host["specs"],
             "n": n, "k": k, "spread": self.cand_spread,
             "method": method, "cfg": self.config,
@@ -2722,7 +2782,7 @@ class Scheduler:
             name=pod.name, node=node, requests=pod.requests,
             priority=pod.priority, quota=pod.quota,
             non_preemptible=pod.non_preemptible,
-            labels=pod.labels, gang=pod.gang,
+            labels=pod.labels, gang=pod.gang, qos=pod.qos, owner=pod.owner,
             reservation=reservation, rsv_drawn=rsv_drawn,
             rsv_generation=rsv_generation,
             node_generation=self.snapshot.node_generation.get(node, 0),
@@ -2814,7 +2874,8 @@ class Scheduler:
                     name=pod.name, node=node, requests=pod.requests,
                     priority=pod.priority, quota=pod.quota,
                     non_preemptible=pod.non_preemptible,
-                    labels=pod.labels, gang=pod.gang,
+                    labels=pod.labels, gang=pod.gang, qos=pod.qos,
+                    owner=pod.owner,
                     node_generation=self.snapshot.node_generation.get(
                         node, 0),
                 )
@@ -3087,54 +3148,48 @@ class Scheduler:
         return names, allowed
 
     def _build_scheduled(self, quota_index: dict[str, int]):
-        """Flatten self.bound into a ScheduledPods tensor (+ name order)."""
+        """The bound pods' columns as a ScheduledPods tensor (+ name
+        order).  Rows go in name order, as preemption always saw them."""
         from koordinator_tpu.ops.preemption import ScheduledPods
 
         pdb_names, _ = self._pdb_arrays()
-        pdb_index = {n: i for i, n in enumerate(pdb_names)}
+        cols = self.bound.columns
         names = sorted(self.bound)
         v = len(names)
-        req = np.zeros((max(v, 1), self.snapshot.dims), np.int32)
-        node = np.full(max(v, 1), -1, np.int32)
-        pri = np.zeros(max(v, 1), np.int32)
-        qid = np.full(max(v, 1), -1, np.int32)
-        nonp = np.zeros(max(v, 1), bool)
-        pdb = np.full(max(v, 1), -1, np.int32)
-        for i, name in enumerate(names):
-            bp = self.bound[name]
-            row = self.snapshot.node_index.get(bp.node)
-            if (row is not None
-                    and self.snapshot.node_generation.get(bp.node, 0)
-                    != bp.node_generation):
-                # bound to a PREVIOUS instance of a re-added node: its
-                # capacity was never charged to the current row, so it
-                # must not be a victim candidate — "evicting" it would
-                # let the solve assume freed capacity that was never
-                # there and nominate a preemptor past allocatable
-                # (caught by the preemption churn suite)
-                row = None
-            req[i] = bp.requests
-            node[i] = row if row is not None else -1
-            pri[i] = bp.priority
-            if bp.quota is not None and bp.quota in quota_index:
-                qid[i] = quota_index[bp.quota]
-            nonp[i] = bp.non_preemptible
-            # a pod matching several PDBs carries its most-constraining one
-            # (smallest remaining budget) for the violating classification;
-            # eviction decrements every matching budget (commit path)
-            matches = [
-                pi for pn, pi in pdb_index.items()
-                if self.pdbs[pn].matches(bp.labels)
-            ]
-            if matches:
-                pdb[i] = min(
-                    matches, key=lambda pi: self.pdbs[pdb_names[pi]].allowed
-                )
+        slots = cols.slots(names)
+        req = cols.requests[slots]
+        # a pod bound to a PREVIOUS instance of a re-added node reads -1:
+        # its capacity was never charged to the current row, so it must
+        # not be a victim candidate — "evicting" it would let the solve
+        # assume freed capacity that was never there and nominate a
+        # preemptor past allocatable (caught by the preemption churn
+        # suite)
+        node = cols.node_rows(self.snapshot)[slots]
+        pri = cols.priority[slots]
+        quota_row = np.array([quota_index.get(q, -1) if q is not None else -1
+                              for q in cols.quotas.values], np.int32)
+        qid = quota_row[cols.quota_id[slots]]
+        nonp = (cols.flags[slots] & bound_columns.NON_PREEMPTIBLE) != 0
+        # a pod matching several PDBs carries its most-constraining one
+        # (smallest remaining budget) for the violating classification;
+        # eviction decrements every matching budget (commit path).  Pods
+        # of one label set match alike, so the match runs per label set
+        pdb_of_labelset = np.full(len(cols.labelsets), -1, np.int32)
+        if pdb_names:
+            for ls in np.unique(cols.labelset_id[slots]):
+                labels = cols.labels_of(int(ls))
+                matches = [pi for pi, pn in enumerate(pdb_names)
+                           if self.pdbs[pn].matches(labels)]
+                if matches:
+                    pdb_of_labelset[ls] = min(
+                        matches,
+                        key=lambda pi: self.pdbs[pdb_names[pi]].allowed)
+        pdb = pdb_of_labelset[cols.labelset_id[slots]]
         return ScheduledPods.build(
-            req[:v] if v else req[:0], node[:v] if v else node[:0],
-            priority=pri[:v] if v else None, quota_id=qid[:v] if v else None,
-            non_preemptible=nonp[:v] if v else None,
-            pdb_id=pdb[:v] if v else None,
+            req, node,
+            priority=pri if v else None, quota_id=qid if v else None,
+            non_preemptible=nonp if v else None,
+            pdb_id=pdb if v else None,
         ), names
 
     def _quota_headroom(self, quota_name: str | None) -> np.ndarray | None:

@@ -152,7 +152,7 @@ def test_select_victims_skips_unevictable():
 def test_migration_lifecycle_with_reservation():
     evicted = []
     ctl = MigrationController(
-        reserve_fn=lambda j: f"resv-{j.pod}",
+        reserve_many=lambda jobs: {j.name: f"resv-{j.pod}" for j in jobs},
         evict_fn=lambda j: evicted.append(j.pod) or True,
     )
     ctl.submit(MigrationJob(name="j1", pod="p1", node="n1"))
@@ -164,7 +164,8 @@ def test_migration_lifecycle_with_reservation():
 
 
 def test_migration_reservation_failure():
-    ctl = MigrationController(reserve_fn=lambda j: None)
+    ctl = MigrationController(
+        reserve_many=lambda jobs: {j.name: None for j in jobs})
     ctl.submit(MigrationJob(name="j1", pod="p1", node="n1"))
     ctl.reconcile()
     assert ctl.jobs["j1"].phase is MigrationJobPhase.FAILED

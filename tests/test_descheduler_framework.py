@@ -91,32 +91,39 @@ class TestProfileRound:
         assert d.tick() is not None
 
 
-def make_state(n=4, hot_node=0):
-    r = NUM_RESOURCE_DIMS
-    capacity = np.zeros((n, r), np.int32)
-    capacity[:, ResourceDim.CPU] = 10_000
-    capacity[:, ResourceDim.MEMORY] = 10_000
-    usage = np.zeros((n, r), np.int32)
-    usage[:, ResourceDim.CPU] = 2_000          # cold nodes: 20%
-    usage[hot_node, ResourceDim.CPU] = 9_000   # hot: 90% > high 65%
-    valid = np.ones(n, bool)
-    names = [f"n{i}" for i in range(n)]
-    return usage, capacity, valid, names
+def hot_scheduler():
+    """Four nodes of 10,000 milli-CPU, n0 at 90 % (over high 65 %) and the
+    others at 20 %; ``victim`` runs on n0, ``keeper`` on n1."""
+    from koordinator_tpu.api.resources import resource_vector
+    from koordinator_tpu.scheduler.scheduler import BoundPod, Scheduler
+    from koordinator_tpu.scheduler.snapshot import ClusterSnapshot, NodeSpec
+
+    snap = ClusterSnapshot(capacity=4)
+    for i in range(4):
+        usage = np.zeros(NUM_RESOURCE_DIMS, np.int32)
+        usage[ResourceDim.CPU] = 9_000 if i == 0 else 2_000
+        snap.upsert_node(NodeSpec(
+            name=f"n{i}", usage=usage,
+            allocatable=resource_vector(cpu=10_000, memory=10_000)))
+    sched = Scheduler(snap)
+    for name, node, priority in (("victim", "n0", 3500),
+                                 ("keeper", "n1", 9500)):
+        sched.bound[name] = BoundPod(
+            name=name, node=node, priority=priority,
+            requests=resource_vector(cpu=500, memory=64))
+    sched.set_pod_usage(["victim", "keeper"], np.stack(
+        [resource_vector(cpu=3_000), resource_vector(cpu=500)]))
+    return sched
 
 
 class TestLowNodeLoadPlugin:
     def run_rounds(self, rounds=3):
-        pods = [pod("victim", node="n0", priority=3500),
-                pod("keeper", node="n1", priority=9500)]
+        from koordinator_tpu.descheduler.plugins import bound_pods_fn
 
-        def pod_usage(p):
-            u = np.zeros(NUM_RESOURCE_DIMS, np.int32)
-            u[ResourceDim.CPU] = 3000 if p.uid == "victim" else 500
-            return u
-
-        plugin = LowNodeLoadPlugin(state_fn=make_state, pod_usage_fn=pod_usage)
+        sched = hot_scheduler()
+        plugin = LowNodeLoadPlugin(scheduler=sched)
         profile = Profile(name="ln", balance_plugins=[plugin])
-        d = Descheduler([profile], pods_fn=lambda: pods)
+        d = Descheduler([profile], pods_fn=bound_pods_fn(sched))
         results = [d.run_once() for _ in range(rounds)]
         return results, profile
 
@@ -127,6 +134,33 @@ class TestLowNodeLoadPlugin:
         assert results[1]["ln"] == 0
         assert results[2]["ln"] == 1
         assert profile.evictor.evicted == [("victim", "LowNodeLoad")]
+
+
+    def test_equally_cheap_pods_leave_in_the_order_of_their_names(self):
+        """Two pods of one priority and one usage on the hot node, one
+        eviction needed: the one whose name sorts first goes, wherever
+        the two sit in the bound pods' columns."""
+        from koordinator_tpu.api.resources import resource_vector
+        from koordinator_tpu.descheduler.plugins import bound_pods_fn
+        from koordinator_tpu.scheduler.scheduler import BoundPod
+
+        for first, second in (("twin-z", "twin-a"), ("twin-a", "twin-z")):
+            sched = hot_scheduler()
+            for name in (first, second):    # column order = this order
+                sched.bound[name] = BoundPod(
+                    name=name, node="n0", priority=3000,
+                    requests=resource_vector(cpu=500, memory=64))
+            sched.set_pod_usage([first, second], np.stack(
+                [resource_vector(cpu=2_600)] * 2))
+            cols = sched.bound.columns
+            assert cols.slot_of[first] < cols.slot_of[second]
+            profile = Profile(name="ln", balance_plugins=[
+                LowNodeLoadPlugin(scheduler=sched)])
+            d = Descheduler([profile], pods_fn=bound_pods_fn(sched))
+            for _ in range(3):
+                d.run_once()
+            # 9,000 - 2,600 = 6,400 is under the high quantity 6,500
+            assert profile.evictor.evicted == [("twin-a", "LowNodeLoad")]
 
 
 class TestMigrationSink:

@@ -568,7 +568,9 @@ def _rebalance_fixture(hot_cpu=14_000, under_rows=True):
     reserved, evicted = [], []
     controller = MigrationController(
         limits=ArbitrationLimits(max_migrating_per_node=4),
-        reserve_fn=lambda job: reserved.append(job.pod) or f"rsv-{job.pod}",
+        reserve_many=lambda jobs: {
+            job.name: reserved.append(job.pod) or f"rsv-{job.pod}"
+            for job in jobs},
         evict_fn=lambda job: evicted.append(job.pod) or True)
     args = LowNodeLoadArgs.default()
     args = args.replace(anomaly_rounds=jnp.int32(2))

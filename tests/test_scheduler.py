@@ -595,7 +595,7 @@ class TestMigrationWithReservations:
             MigrationController, MigrationJob,
         )
         from koordinator_tpu.descheduler.plugins import (
-            scheduler_migration_evict_fn, scheduler_reserve_fn,
+            scheduler_migration_evict_fn, scheduler_reserve_many,
         )
 
         # the pod binds while only the (soon-to-be-)hot node exists; the
@@ -608,7 +608,7 @@ class TestMigrationWithReservations:
         sched.snapshot.upsert_node(node("cool", cpu=10_000))
 
         ctl = MigrationController(
-            reserve_fn=scheduler_reserve_fn(sched),
+            reserve_many=scheduler_reserve_many(sched),
             evict_fn=scheduler_migration_evict_fn(sched),
         )
         ctl.submit(MigrationJob(name="j1", pod="web-1", node=src))
