@@ -184,7 +184,7 @@ def recompiles_total() -> int:
 def check_mesh(scheduler, n_devices: int) -> dict:
     """On N > 1 devices the solve must be on the mesh, with the state's
     node tensors on N distinct devices, not everything on device 0."""
-    state = scheduler.snapshot.state
+    state = scheduler.snapshot.resident_state   # placement only
     placed = {s.device.id for s in state.node_allocatable.addressable_shards}
     if n_devices > 1:
         require(scheduler.solver_shard_count == n_devices,
@@ -254,7 +254,8 @@ def served_leg(shape: dict, seed: int, n_devices: int) -> dict:
             def solve(label: str) -> dict:
                 t0 = time.perf_counter()
                 doc = solve_remote(client)
-                jax.block_until_ready(scheduler.snapshot.state)
+                with scheduler.lock:
+                    jax.block_until_ready(scheduler.snapshot.state)
                 wall = time.perf_counter() - t0
                 for pod, node in doc["assignments"].items():
                     require(pod in pending, f"{pod} bound but not pending")
@@ -437,7 +438,7 @@ def gang_quota_leg(shape: dict, seed: int) -> dict:
     t0 = time.perf_counter()
     with scheduler.lock:
         result = scheduler.schedule_round()
-    jax.block_until_ready(scheduler.snapshot.state)
+        jax.block_until_ready(scheduler.snapshot.state)
     wall = time.perf_counter() - t0
     bound = dict(result.assignments)
 

@@ -258,9 +258,8 @@ def restore_into(scheduler, doc: dict,
             # accounting, the quota charge is the caller's
             # (delete_pod releases both)
             scheduler._charge_quota_used(pod, sign=1)
-        # one scatter for the whole bound set (bit-identical to per-pod
-        # reserve; the per-pod path is what makes restore slower than
-        # the re-placement it is supposed to beat)
+        # host-pending like every reserve: the whole bound set reaches
+        # the device in the one fold of the next read of the state
         scheduler.snapshot.reserve_batch(reserve_by_node)
     if sync is not None:
         cursor = doc.get("cursor") or {}

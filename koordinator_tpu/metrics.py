@@ -572,6 +572,19 @@ process_gc_collections = PROCESS.gauge(
     "gc_collections",
     "Cumulative gc collections across generations (label: binary)")
 
+# -- deferred request accounting (scheduler/snapshot.py) --
+snapshot_requested_folds = SCHEDULER.counter(
+    "snapshot_requested_folds_total",
+    "Folds of the host-pending Reserve/Unreserve delta into "
+    "node_requested: one device op each, at the first read of the state "
+    "after a reserve or a release; a read with nothing pending folds "
+    "nothing and is not counted")
+snapshot_requested_deltas_folded = SCHEDULER.counter(
+    "snapshot_requested_deltas_folded_total",
+    "Reserve/Unreserve calls whose vectors those folds carried to the "
+    "device; deltas per fold is how many per-pod device ops one fold "
+    "stood for")
+
 # -- JAX solver introspection (ops/introspection.py) --
 solver_recompiles = SCHEDULER.counter(
     "solver_recompiles_total",

@@ -117,7 +117,8 @@ def resize_pod(
 ) -> tuple[bool, str]:
     """In-place resize of a bound pod (ResizePod/RunResizePod): the delta must
     fit the node's remaining free capacity; growth is charged, shrink is
-    released. Returns (ok, reason)."""
+    released. Returns (ok, reason).  The caller holds the lock of the
+    scheduler that owns ``snapshot``: the read of its state folds."""
     row = snapshot.node_index.get(node)
     if row is None:
         return False, f"node {node} not found"

@@ -133,13 +133,16 @@ class RoundHandle:
     """An in-flight round between its device and host halves (ISSUE 11).
 
     ``round_device`` returns one after DISPATCHING the solve; nothing in
-    it has been blocked on.  ``assignments``/``new_state``/``new_quota``
-    are in-flight device arrays — the dispatched solve DONATED the
-    previous ``snapshot.state`` buffers and the snapshot was re-pointed
-    at ``new_state`` before dispatch returned (the blessed swap), so the
-    pre-dispatch buffers are dead and must never be stashed on a handle.
-    The handle is only valid under the same ``scheduler.lock`` hold that
-    produced it."""
+    it has been blocked on.  ``assignments``/``new_quota`` are in-flight
+    device arrays — the dispatched solve DONATED the previous
+    ``snapshot.state`` buffers and the snapshot was re-pointed at the
+    solve's in-flight state before dispatch returned (the blessed swap),
+    so the pre-dispatch buffers are dead and must never be stashed on a
+    handle.  The post-dispatch state is not stashed either: the host
+    half reads it back from the snapshot, so a reserve or a release
+    taken between the halves is in what it adopts, whether or not
+    anything read the state in between.  The handle is only valid under
+    the same ``scheduler.lock`` hold that produced it."""
 
     result: SchedulingResult
     #: the round finished entirely in the device half (elector/barrier
@@ -153,7 +156,6 @@ class RoundHandle:
     quota: object = None                 # post-prepass device quota
     solver: str = "greedy"
     assignments: object = None           # in-flight device array
-    new_state: object = None             # in-flight donated-swap state
     new_quota: object = None
     #: incremental-path finish context (None = full/greedy path)
     inc: dict | None = None
@@ -648,7 +650,12 @@ class Scheduler:
         back to the reservation remainder (the reserved capacity stays
         charged to the node, hidden from non-owners) and frees only its
         spill; once the reservation is gone/consumed, the drawn backing
-        charge frees with the pod."""
+        charge frees with the pod.
+
+        Host work only: the freed vector joins the snapshot's pending
+        delta, and the next read of ``snapshot.state`` (the next round's
+        dispatch, as a rule) folds every release since the last one into
+        ``node_requested`` in one device op."""
         with self.lock:
             pod = self.bound.pop(name, None)
             if pod is not None:
@@ -657,7 +664,9 @@ class Scheduler:
     def _release_bound_capacity(self, bp: BoundPod) -> None:
         """Shared freeing for a bound pod leaving the cluster (informer
         delete, eviction, preemption): fine-grained allocations, then the
-        reservation-aware node unreserve."""
+        reservation-aware node unreserve.  Both spans time host work
+        alone; the unreserve's device op is the snapshot's next fold
+        (span ``snapshot.fold``)."""
         tl_t0 = time.perf_counter()
         self._release_fine_grained(bp.name, bp.node)
         tl_t1 = time.perf_counter()
@@ -1328,6 +1337,7 @@ class Scheduler:
         self._refresh_quota_tree()
         return QuotaDeviceState.from_tree(self.quota_tree)
 
+    # koordlint: guarded-by(self.lock)
     def _apply_topology_plans(
         self, batch: PodBatch, gang_index: dict[str, int]
     ) -> PodBatch:
@@ -1647,8 +1657,7 @@ class Scheduler:
         live and nothing is rebuilt.)  The conservative rebuild keeps
         the scheduler alive and never-overcommitting; a sync resync
         restores exact accounting."""
-        if any(getattr(leaf, "is_deleted", lambda: False)()
-               for leaf in jax.tree.leaves(self.snapshot.state)):
+        if self.snapshot.state_buffers_deleted():
             self.snapshot.rebuild_conservative()
         self._cand_cache = None
 
@@ -1666,7 +1675,10 @@ class Scheduler:
         this method returns — the blessed swap.  The PRE-dispatch state
         must never be stashed (koordlint's donation-safety corpus seeds
         both sides of this idiom); reads of ``snapshot.state`` between
-        the halves are safe and simply block until the solve lands.
+        the halves are safe and simply block until the solve lands.  A
+        reserve or a release taken between the halves stays in the
+        snapshot's pending delta and folds into the ADOPTED state at the
+        host half, which reads the state back from the snapshot.
 
         Internally ``prepare`` (through BatchBuild) and ``dispatch``
         are separate steps so the tenancy front-end can gather every
@@ -1869,7 +1881,6 @@ class Scheduler:
                 # cache's top-k is stale against the new accounting
                 self._cand_cache = None
                 handle.assignments = assignments
-                handle.new_state = new_state
                 handle.new_quota = new_quota
                 handle.quality = {"iters": qiters,
                                   "slack_before": slack_before}
@@ -1877,7 +1888,6 @@ class Scheduler:
                 handle.inc = self._dispatch_batch_incremental(
                     pods, batch, quota)
                 handle.assignments = handle.inc["a"]
-                handle.new_state = handle.inc["state"]
                 handle.new_quota = handle.inc["quota"]
             else:
                 if solver == "batch":
@@ -1911,7 +1921,6 @@ class Scheduler:
                 # result immediately so nothing can read the dead ones
                 self.snapshot.state = new_state
                 handle.assignments = assignments
-                handle.new_state = new_state
                 handle.new_quota = new_quota
         except Exception:
             self._recover_solve_failure()
@@ -1966,9 +1975,8 @@ class Scheduler:
         snap.state = new_state
         handle.solver = "batch"
         handle.assignments = a
-        handle.new_state = new_state
         handle.new_quota = new_quota
-        handle.inc = {"a": a, "state": new_state, "quota": new_quota,
+        handle.inc = {"a": a, "quota": new_quota,
                       "est_accum": est_accum, "batch": handle.batch,
                       "k": k, "method": method, "use_mesh": False}
         handle.result.round_pods = len(handle.pods)
@@ -1995,7 +2003,6 @@ class Scheduler:
         self._cand_cache = None
         handle.solver = "batch"
         handle.assignments = a
-        handle.new_state = new_state
         handle.new_quota = new_quota
         handle.quality = {"iters": qiters,
                           "slack_before": slack_before}
@@ -2014,7 +2021,7 @@ class Scheduler:
         gangs, quota, solver = handle.gangs, handle.quota, handle.solver
         now = handle.now
         assignments = handle.assignments
-        new_state, new_quota = handle.new_state, handle.new_quota
+        new_quota = handle.new_quota
         try:
             with self.monitor.phase("Solve",
                                     carry_s=self._solve_carry_s):
@@ -2022,6 +2029,10 @@ class Scheduler:
                 if handle.inc is not None:
                     assignments, new_state, new_quota = (
                         self._finish_batch_incremental(handle.inc))
+                else:
+                    # the solve's in-flight state, read back from where
+                    # dispatch swapped it in (see RoundHandle)
+                    new_state = self.snapshot.state
                 a = np.asarray(self._block_timed(assignments))
                 leftover = np.asarray(batch.valid) & (a < 0)
                 if solver == "batch" and bool(leftover[: len(pods)].any()):
@@ -2383,7 +2394,9 @@ class Scheduler:
                                 if self.mesh is not None else 1),
             "shard_min_nodes": self.shard_min_nodes,
             "device_bytes_by_shard": {
-                "cluster_state": _by_shard(self.snapshot.state),
+                # a scrape holds no lock, so it reads the tensors as they
+                # stand: ``snapshot.state`` would fold, which is a write
+                "cluster_state": _by_shard(self.snapshot.resident_state),
                 "candidate_cache": _by_shard(
                     cand["cache"] if cand else None),
             },
@@ -2572,7 +2585,7 @@ class Scheduler:
         except Exception:
             self._cand_cache = None
             raise
-        return {"a": a, "state": state, "quota": quota,
+        return {"a": a, "quota": quota,
                 "est_accum": est_accum, "batch": batch, "k": k,
                 "method": method, "use_mesh": use_mesh}
 
@@ -2583,7 +2596,9 @@ class Scheduler:
         identical decisions to the one-call form, dispatch point aside."""
         snap = self.snapshot
         batch = ctx["batch"]
-        state, quota, est_accum = ctx["state"], ctx["quota"], ctx["est_accum"]
+        # pass 1's in-flight state, as dispatch left it in the snapshot,
+        # with whatever was reserved or released since folded in
+        state, quota, est_accum = snap.state, ctx["quota"], ctx["est_accum"]
         k, method, use_mesh = ctx["k"], ctx["method"], ctx["use_mesh"]
         try:
             # a copy: np.asarray of a device array is a read-only view,
@@ -3071,7 +3086,7 @@ class Scheduler:
             node, pod.requests, self._nomination_gen.pop(pod.name, 0))
         self._charge_quota_used(pod, sign=-1)
 
-    def _nominated_fit(self, pod: PodSpec, row: int) -> bool:
+    def _nominated_fit(self, pod: PodSpec, row: int) -> bool:  # koordlint: guarded-by(self.lock)
         """Re-run Filter for a nominated pod on its nominated node (with the
         pod's own assumed accounting already released by the caller)."""
         from koordinator_tpu.ops.assignment import score_pods
@@ -3091,7 +3106,7 @@ class Scheduler:
             )
         return True
 
-    def _resolve_nominations(self, result: SchedulingResult) -> None:
+    def _resolve_nominations(self, result: SchedulingResult) -> None:  # koordlint: guarded-by(self.lock)
         """Fast-path for preemptors nominated in an earlier round.
 
         A nominated pod's resources were assumed (node reservation + quota
@@ -3211,7 +3226,7 @@ class Scheduler:
         )
         return np.clip(hr, -HEADROOM_CLAMP, HEADROOM_CLAMP).astype(np.int32)
 
-    def _run_preemption(self, pods, batch, result: SchedulingResult) -> None:
+    def _run_preemption(self, pods, batch, result: SchedulingResult) -> None:  # koordlint: guarded-by(self.lock)
         """PostFilter: for each still-unschedulable pod, find a min-cost
         victim set, evict, and nominate.  Gang members preempt all-or-nothing
         (job-level preemption, coscheduling preemption.go:206); quota-rejected

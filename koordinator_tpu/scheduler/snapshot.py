@@ -6,6 +6,11 @@ cluster resident on device and applies *deltas*: the host maintains
 name -> row maps and dirty-row buffers, and ``flush()`` ships only changed rows
 (``ClusterState.scatter_update``). Capacity grows by power-of-two buckets so
 recompilation is O(log N) over cluster life (SURVEY.md section 7 hard part (a)/(b)).
+
+Request accounting (Reserve / Unreserve) is deferred the same way: a call
+adds its signed vector to a host-side pending delta, and the next read of
+``ClusterSnapshot.state`` folds everything pending into ``node_requested``
+in one device op (``ClusterState.fold_requested``).
 """
 
 from __future__ import annotations
@@ -14,7 +19,7 @@ import dataclasses
 
 import numpy as np
 
-from koordinator_tpu import timeline
+from koordinator_tpu import metrics, timeline
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
 from koordinator_tpu.state.cluster_state import ClusterState, _bucket
 
@@ -71,11 +76,35 @@ class PodSpec:
 
 
 class ClusterSnapshot:
-    """Name-indexed view over the device-resident ClusterState."""
+    """Name-indexed view over the device-resident ClusterState.
+
+    The spec side (allocatable, usage, validity, class) is host-pending
+    in ``_dirty`` until ``flush()``.  The request accounting is
+    host-pending in ``_pending``: ``reserve`` / ``unreserve`` /
+    ``unreserve_instance`` / ``reserve_batch`` touch no device, and
+    reading ``state`` folds what they accumulated into
+    ``node_requested`` first, so no reader ever sees a state that lacks
+    a delta.  Integer adds commute: ``node_requested`` at every read is
+    bit for bit what one device op per call would have left.
+
+    A read may therefore write: every read of ``state`` and every call
+    here is under the owning scheduler's lock.  A thread without it
+    (a debug scrape) reads ``resident_state``, which folds nothing.
+    The fold leaves the pre-fold buffers alive, so a state taken
+    earlier and held across a later reserve stays readable, and stale,
+    exactly as it did when each call was a device op of its own.
+    """
 
     def __init__(self, capacity: int = 64, dims: int = NUM_RESOURCE_DIMS):
         self.dims = dims
-        self.state = ClusterState.zeros(capacity, dims)
+        self._state = ClusterState.zeros(capacity, dims)
+        #: (N, R) int32 sum of the Reserve / Unreserve vectors taken since
+        #: the last fold, by row; None when nothing is pending (the read
+        #: path's whole cost then).  ``_pending_calls`` counts, by row,
+        #: the calls summed in it, so a row that dies takes its count
+        #: along and a fold reports only what reached the device.
+        self._pending: np.ndarray | None = None
+        self._pending_calls: dict[int, int] = {}
         self.node_index: dict[str, int] = {}
         self._row_to_name: dict[int, str] = {}
         self.node_specs: dict[str, NodeSpec] = {}
@@ -136,8 +165,8 @@ class ClusterSnapshot:
     def _apply_solver_sharding(self) -> None:
         if self.solver_sharding_active:
             ns = self._solver_sharding
-            self.state = jax.tree.map(
-                lambda x: jax.device_put(x, ns), self.state)
+            self._state = jax.tree.map(
+                lambda x: jax.device_put(x, ns), self._state)
 
     def mark_sync(self, now: float) -> None:
         """Stamp feed liveness (monotonic under the writer's clock)."""
@@ -188,7 +217,59 @@ class ClusterSnapshot:
 
     @property
     def capacity(self) -> int:
-        return self.state.capacity
+        return self._state.capacity
+
+    @property
+    def state(self) -> ClusterState:
+        """The device state with every Reserve / Unreserve folded in.
+        Caller holds the owning scheduler's lock."""
+        if self._pending is not None:
+            self._fold()
+        return self._state
+
+    @property
+    def resident_state(self) -> ClusterState:
+        """The device tensors as they stand, nothing folded and nothing
+        written: for readers of metadata (shapes, byte sizes, shardings)
+        that do not hold the scheduler's lock.  Its ``node_requested``
+        may lack what is pending, and its buffers may be donated to a
+        solve in flight."""
+        return self._state
+
+    @state.setter
+    def state(self, state: ClusterState) -> None:
+        # what is pending stays pending and folds into the NEW state: a
+        # delta taken while a solve is in flight lands on its result
+        self._state = state
+
+    def _fold(self) -> None:
+        delta, n = self._pending, sum(self._pending_calls.values())
+        with timeline.RECORDER.section("host_other", "snapshot.fold", n=n):
+            self._state = self._state.fold_requested(delta)
+        # the transfer owns ``delta`` now: the next delta gets a fresh array
+        self._drop_pending()
+        metrics.snapshot_requested_folds.inc()
+        metrics.snapshot_requested_deltas_folded.inc(n)
+
+    def _drop_pending(self) -> None:
+        self._pending = None
+        self._pending_calls.clear()
+
+    def _defer(self, row: int, requests: np.ndarray, sign: int) -> None:
+        """Add one signed request vector to the row's pending delta."""
+        if self._pending is None:
+            self._pending = np.zeros(self._state.node_requested.shape,
+                                     np.int32)
+        self._pending[row] += sign * np.asarray(requests, np.int32)
+        self._pending_calls[row] = self._pending_calls.get(row, 0) + 1
+        self._cand_dirty.add(row)
+
+    def state_buffers_deleted(self) -> bool:
+        """Has a donated-then-failed solve consumed the state's buffers?
+        Probes the resident state: a fold into deleted buffers would
+        raise."""
+        return any(leaf.is_deleted()
+                   for leaf in jax.tree.leaves(self._state))
 
     # -- node lifecycle -----------------------------------------------------
 
@@ -206,9 +287,10 @@ class ClusterSnapshot:
                 # make_available, a cross-scheduler nomination), whose
                 # later generation-checked release would then drive
                 # node_requested negative
+                # (the row's pending delta died with the node)
                 self._reset_requested.discard(row)
-                self.state = self.state.replace(
-                    node_requested=self.state.node_requested.at[row].set(0))
+                self._state = self._state.replace(
+                    node_requested=self._state.node_requested.at[row].set(0))
             self.node_index[spec.name] = row
             self._row_to_name[row] = spec.name
             self.node_generation[spec.name] = (
@@ -229,18 +311,26 @@ class ClusterSnapshot:
         self._dirty.add(row)
         self._cand_dirty.add(row)
         self._reset_requested.add(row)
+        if self._pending_calls.pop(row, 0):
+            # a delta taken against the dead instance must not land on
+            # the row's next tenant: it goes with the row's accounting,
+            # which the reset above zeroes, and with nothing else
+            # pending there is nothing left to fold
+            self._pending[row] = 0
+            if not self._pending_calls:
+                self._pending = None
 
     def _grow(self) -> None:
         old_cap = self.capacity
         new_cap = _bucket(old_cap + 1)
-        old = self.state
+        old = self._state
 
         def pad(a):
             out = np.zeros((new_cap,) + a.shape[1:], a.dtype)
             out[:old_cap] = np.asarray(a)
             return jnp.asarray(out)
 
-        self.state = ClusterState(
+        self._state = ClusterState(
             node_allocatable=pad(old.node_allocatable),
             node_requested=pad(old.node_requested),
             node_usage=pad(old.node_usage),
@@ -249,6 +339,10 @@ class ClusterSnapshot:
             node_valid=pad(old.node_valid),
             node_class=pad(old.node_class),
         )
+        if self._pending is not None:
+            grown = np.zeros((new_cap,) + self._pending.shape[1:], np.int32)
+            grown[:old_cap] = self._pending
+            self._pending = grown
         self._free_rows = list(range(new_cap - 1, old_cap - 1, -1)) + self._free_rows
         self._apply_solver_sharding()
 
@@ -269,8 +363,11 @@ class ClusterSnapshot:
         if self._reset_requested:
             reset = jnp.asarray(sorted(self._reset_requested), dtype=jnp.int32)
             self._reset_requested.clear()
-            self.state = self.state.replace(
-                node_requested=self.state.node_requested.at[reset].set(0)
+            # rows freed and not reused: nothing is pending on them
+            # (remove_node dropped it, and a row takes deltas again only
+            # through upsert_node, which takes it out of this set)
+            self._state = self._state.replace(
+                node_requested=self._state.node_requested.at[reset].set(0)
             )
         k = len(rows)
         alloc = np.zeros((k, self.dims), np.int32)
@@ -294,7 +391,7 @@ class ClusterSnapshot:
         idx = jnp.asarray(np.asarray(rows, np.int32))
         # donate=True: the snapshot owns its state exclusively, so the
         # (N, R) tensors update in place instead of reallocating per flush
-        self.state = self.state.scatter_update(
+        self._state = self._state.scatter_update(
             idx,
             donate=True,
             node_allocatable=jnp.asarray(alloc),
@@ -308,36 +405,24 @@ class ClusterSnapshot:
     # -- accounting ---------------------------------------------------------
 
     def reserve(self, node: str, requests: np.ndarray) -> None:
-        """Account a binding onto a node (Reserve)."""
-        row = self.node_index[node]
-        self._cand_dirty.add(row)
-        self.state = self.state.add_pod(
-            jnp.asarray(np.int32(row)), jnp.asarray(requests.astype(np.int32))
-        )
+        """Account a binding onto a node (Reserve): host-pending until
+        the next read of ``state``."""
+        self._defer(self.node_index[node], requests, 1)
 
     def reserve_batch(self, requests_by_node) -> None:
-        """Account many bindings in ONE device op (startup informer
-        replay, warm-restart checkpoint restore).  Bit-identical to
-        sequential :meth:`reserve` — integer adds commute — but the
-        scatter cost is paid once instead of per pod, which is what
-        makes a checkpoint restore cheaper than re-placing the same
+        """Account many bindings (startup informer replay, warm-restart
+        checkpoint restore), all or none: every node is resolved before
+        the first vector is taken, so an unknown name raises KeyError
+        with nothing accounted.  Otherwise the accumulation of
+        :meth:`reserve`: a restore of any size is one fold at the next
+        read, which is what makes it cheaper than re-placing the same
         pods through rounds."""
-        if not requests_by_node:
-            return
-        add = np.zeros(self.state.node_requested.shape, dtype=np.int32)
-        for node, requests in requests_by_node.items():
-            row = self.node_index[node]
-            self._cand_dirty.add(row)
-            add[row] += requests.astype(np.int32)
-        self.state = self.state.replace(
-            node_requested=self.state.node_requested + jnp.asarray(add))
+        rows = [self.node_index[node] for node in requests_by_node]
+        for row, requests in zip(rows, requests_by_node.values()):
+            self._defer(row, requests, 1)
 
     def unreserve(self, node: str, requests: np.ndarray) -> None:
-        row = self.node_index[node]
-        self._cand_dirty.add(row)
-        self.state = self.state.remove_pod(
-            jnp.asarray(np.int32(row)), jnp.asarray(requests.astype(np.int32))
-        )
+        self._defer(self.node_index[node], requests, -1)
 
     def unreserve_instance(self, node: str, requests: np.ndarray,
                            generation: int) -> None:
@@ -367,7 +452,7 @@ class ClusterSnapshot:
             self._cand_dirty.update(self.node_index.values())
         else:
             self._cand_dirty.update(int(r) for r in changed_rows)
-        self.state = state
+        self._state = state
 
     def rebuild_conservative(self) -> None:
         """Disaster recovery for a DONATED-then-failed device state: a
@@ -380,16 +465,19 @@ class ClusterSnapshot:
         sync resync (SchedulerBinding.reset + bootstrap) or node churn
         restores exact accounting.  Releases stay safe: true bookings
         are always <= allocatable, so subtracting a released pod keeps
-        the conservative row >= the true remaining bookings."""
-        self.state = ClusterState.zeros(self.capacity, self.dims)
+        the conservative row >= the true remaining bookings.  What was
+        pending goes with the lost tensor: a reserve is covered by the
+        full booking, and a release dropped only leaves a row fuller."""
+        self._drop_pending()
+        self._state = ClusterState.zeros(self.capacity, self.dims)
         self._apply_solver_sharding()
         self._reset_requested.clear()
         self._dirty.update(self.node_index.values())
         self._cand_dirty.update(self.node_index.values())
         self.flush()
-        self.state = self.state.replace(
-            node_requested=jnp.where(self.state.node_valid[:, None],
-                                     self.state.node_allocatable,
+        self._state = self._state.replace(
+            node_requested=jnp.where(self._state.node_valid[:, None],
+                                     self._state.node_allocatable,
                                      0))
 
     def consume_candidate_dirty(self) -> list[int]:

@@ -687,6 +687,8 @@ class TenantScheduler:
     def _dispatch_tenant_axis_inner(self, live) -> None:
         from koordinator_tpu.ops import batch_assign as ba
 
+        # every live tenant's round lock is held (_acquire): the reads
+        # fold what each tenant has pending
         states = [t.scheduler.snapshot.state for t, _ in live]
         batches = [h.batch for _, h in live]
         quotas = [h.quota for _, h in live]

@@ -29,6 +29,7 @@ import numpy as np
 from flax import struct
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
+from koordinator_tpu.ops import introspection as insp
 
 
 #: Per-dimension quantity bound: integer score/percentage math multiplies by
@@ -58,6 +59,19 @@ def _bucket(n: int, minimum: int = 64) -> int:
 #: flush-sized (N, R) tensor is updated in place instead of reallocated
 _row_set_donating = jax.jit(
     lambda cur, rows, value: cur.at[rows].set(value), donate_argnums=(0,))
+
+#: the one device op of the request accounting: a dense (N, R) delta
+#: added.  Its shape hangs on the capacity alone, never on how many rows
+#: changed, so it compiles once a capacity bucket; instrumented like the
+#: solver's entry points, so a compile inside a steady window shows in
+#: solver_recompiles_total{fn="fold_requested"}.  What it donates is the
+#: DELTA, which nobody else holds: the sum takes over that buffer, so a
+#: fold allocates nothing, and the pre-fold ``node_requested`` stays alive
+#: for whoever still holds the pre-fold state (a round's handle, a
+#: preemption pass), as it did when each call was a device op of its own
+_requested_fold = insp.instrument(
+    jax.jit(lambda cur, delta: cur + delta, donate_argnums=(1,)),
+    "fold_requested")
 
 
 @struct.dataclass
@@ -181,17 +195,18 @@ class ClusterState:
             node_class=self.node_class[rows],
         )
 
-    def add_pod(self, node_idx: jax.Array, request: jax.Array) -> "ClusterState":
-        """Account a pod's request onto a node (Reserve semantics)."""
-        return self.replace(
-            node_requested=self.node_requested.at[node_idx].add(request)
-        )
-
-    def remove_pod(self, node_idx: jax.Array, request: jax.Array) -> "ClusterState":
-        """Unreserve (scheduling failure / pod deletion)."""
-        return self.replace(
-            node_requested=self.node_requested.at[node_idx].add(-request)
-        )
+    def fold_requested(self, delta: np.ndarray) -> "ClusterState":
+        """``node_requested + delta`` in ONE device op: the whole of the
+        host-accumulated Reserve / Unreserve accounting since the last
+        fold (``ClusterSnapshot``).  ``delta`` is a host (N, R) int32
+        array the caller hands over for good (the transfer may read it
+        after this returns); it is placed like ``node_requested``, so a
+        node-axis-sharded state stays sharded and the add needs no
+        collective.  ``self`` is left as it was: the op donates the
+        placed delta, not the state."""
+        placed = jax.device_put(delta, self.node_requested.sharding)
+        return self.replace(node_requested=_requested_fold(
+            self.node_requested, placed))
 
 
 @struct.dataclass
