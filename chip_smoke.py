@@ -185,12 +185,12 @@ def check_mesh(scheduler, n_devices: int) -> dict:
     """On N > 1 devices the solve must be on the mesh, with the state's
     node tensors on N distinct devices, not everything on device 0."""
     state = scheduler.snapshot.resident_state   # placement only
+    kit = scheduler.kit
     placed = {s.device.id for s in state.node_allocatable.addressable_shards}
     if n_devices > 1:
-        require(scheduler.solver_shard_count == n_devices,
-                f"solver_shard_count {scheduler.solver_shard_count} != "
-                f"{n_devices} devices")
-        require(scheduler.snapshot.solver_sharding_active,
+        require(kit.shards == n_devices,
+                f"solver shards {kit.shards} != {n_devices} devices")
+        require(kit.sharding_active_for(state.capacity),
                 "solver sharding not active on a multi-device host")
         require(len(placed) == n_devices,
                 f"node tensors on {len(placed)} device(s), want {n_devices}")
@@ -198,7 +198,7 @@ def check_mesh(scheduler, n_devices: int) -> dict:
                 for s in state.node_allocatable.addressable_shards}
         require(rows == {state.capacity // n_devices},
                 f"uneven node shards: {sorted(rows)}")
-    return {"shards": scheduler.solver_shard_count if n_devices > 1 else 1,
+    return {"shards": kit.shards if n_devices > 1 else 1,
             "devices_holding_nodes": len(placed)}
 
 

@@ -897,7 +897,8 @@ def main_koord_scheduler(argv: list[str],
             sched.attach_forecast_plane(ForecastPlane(
                 snap.capacity,
                 base_horizon_s=args.forecast_horizon_seconds,
-                mesh=(sched.mesh if snap.solver_sharding_active
+                mesh=(sched.kit.mesh
+                      if sched.kit.sharding_active_for(snap.capacity)
                       else None)))
     server = None
     sync_service = None

@@ -360,6 +360,13 @@ class _RoundCarry:
 CANDIDATE_METHODS = ("auto", "exact", "approx", "chunked",
                      "chunked_exact")
 
+#: the candidate parameters' one home: every entry below, their twins in
+#: parallel/sharded.py and the SolverKit default to these, so the full,
+#: incremental, tenant-axis and sharded rounds solve the same problem
+CAND_K = 32                 # candidates kept per pod
+CAND_SPREAD_BITS = (5, 15)  # stratified quantization (select_candidates)
+SOLVE_ROUNDS = 12           # propose/accept rounds per pass
+
 
 def resolve_candidate_method(method: str) -> str:
     """The one home of the ``"auto"`` rule: the concrete candidate method
@@ -379,9 +386,9 @@ def batch_assign(
     pods: PodBatch,
     cfg: ScoringConfig,
     quota: QuotaDeviceState | None = None,
-    k: int = 32,
-    rounds: int = 12,
-    spread_bits=(5, 15),
+    k: int = CAND_K,
+    rounds: int = SOLVE_ROUNDS,
+    spread_bits=CAND_SPREAD_BITS,
     method: str = "auto",
 ):
     """Assign a pending batch in data-parallel propose/accept rounds.
@@ -414,8 +421,8 @@ def select_candidates(
     state: ClusterState,
     pods: PodBatch,
     cfg: ScoringConfig,
-    k: int = 32,
-    spread_bits=(5, 15),
+    k: int = CAND_K,
+    spread_bits=CAND_SPREAD_BITS,
     method: str = "auto",
     with_scores: bool = False,
 ):
@@ -753,8 +760,8 @@ def refresh_candidates(
     cache: CandidateCache,
     dirty_rows: jnp.ndarray,   # (D,) int32, padded; global node rows
     dirty_valid: jnp.ndarray,  # (D,) bool — real (non-pad) entries
-    k: int = 32,
-    spread_bits=(5, 15),
+    k: int = CAND_K,
+    spread_bits=CAND_SPREAD_BITS,
 ) -> tuple[jnp.ndarray, CandidateCache]:
     """Segmented per-stratum top-k merge of fresh dirty-COLUMN candidates
     into an (aligned) candidate cache.
@@ -849,7 +856,7 @@ def assign_round_pass(
     cand_key: jnp.ndarray,
     cand_node: jnp.ndarray,
     cfg: ScoringConfig,
-    rounds: int = 12,
+    rounds: int = SOLVE_ROUNDS,
 ):
     """First solve pass over precomputed candidates, with the est-usage
     accumulation and quota recharge :func:`~koordinator_tpu.ops.gang.
@@ -882,9 +889,9 @@ def assign_followup_pass(
     pods: PodBatch,
     quota: QuotaDeviceState | None,
     cfg: ScoringConfig,
-    k: int = 32,
-    rounds: int = 12,
-    spread_bits=(5, 15),
+    k: int = CAND_K,
+    rounds: int = SOLVE_ROUNDS,
+    spread_bits=CAND_SPREAD_BITS,
     method: str = "auto",
 ):
     """A later gang_assign pass over the (compacted) leftover pods:

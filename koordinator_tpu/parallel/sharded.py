@@ -221,8 +221,8 @@ def _select_program(mesh, n_total, k, strata):
         out_specs=(_PODS, _PODS, _PODS), check_vma=False))
 
 
-def sharded_select_candidates(mesh, state, pods, cfg, k: int = 32,
-                              spread_bits=(5, 15),
+def sharded_select_candidates(mesh, state, pods, cfg, k: int = ba.CAND_K,
+                              spread_bits=ba.CAND_SPREAD_BITS,
                               with_scores: bool = False):
     """``select_candidates`` over the 2-D mesh (recall-exact).
 
@@ -357,7 +357,7 @@ def _rounds_program(mesh, n_total, rounds):
 
 
 def sharded_assign_rounds(mesh, state, pods, quota, cand_key, cand_node,
-                          rounds: int = 12):
+                          rounds: int = ba.SOLVE_ROUNDS):
     """``_assign_rounds`` over the mesh: (assignments, new_state, quota)."""
     n_total = state.capacity
     check_shardable(n_total, mesh)
@@ -403,7 +403,8 @@ def _round_pass_program(mesh, n_total, rounds):
 
 
 def sharded_assign_round_pass(mesh, state, pods, quota, cand_key,
-                              cand_node, cfg, rounds: int = 12):
+                              cand_node, cfg,
+                              rounds: int = ba.SOLVE_ROUNDS):
     """``assign_round_pass`` over the mesh: first solve pass over
     precomputed candidates with est-usage accumulation and whole-batch
     quota recharge.  Returns (assignments, new_state, new_quota,
@@ -461,8 +462,9 @@ def _followup_program(mesh, n_total, k, strata, rounds):
 
 
 def sharded_assign_followup_pass(mesh, state, est_accum, pods, quota, cfg,
-                                 k: int = 32, rounds: int = 12,
-                                 spread_bits=(5, 15)):
+                                 k: int = ba.CAND_K,
+                                 rounds: int = ba.SOLVE_ROUNDS,
+                                 spread_bits=ba.CAND_SPREAD_BITS):
     """``assign_followup_pass`` over the mesh (selection is always
     recall-exact here).  Returns (assignments, new_state, new_quota,
     est_accum')."""
@@ -556,8 +558,8 @@ def _refresh_program(mesh, n_total, k, strata):
 
 
 def sharded_refresh_candidates(mesh, state, pods, cfg, cache, dirty_rows,
-                               dirty_valid, k: int = 32,
-                               spread_bits=(5, 15)):
+                               dirty_valid, k: int = ba.CAND_K,
+                               spread_bits=ba.CAND_SPREAD_BITS):
     """``refresh_candidates`` over the mesh: dirty columns rescore on
     their owning (pod, node) tile, the merge re-ranks per pod row.
     Returns (cand_key, new_cache) like the single-device refresh, both
@@ -761,8 +763,9 @@ def _gang_program(mesh, n_total, p_total, passes, solver, k, strata,
 
 def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
                         passes: int = 2, solver: str = "greedy",
-                        k: int = 32, rounds: int = 12,
-                        spread_bits=(5, 15)):
+                        k: int = ba.CAND_K,
+                        rounds: int = ba.SOLVE_ROUNDS,
+                        spread_bits=ba.CAND_SPREAD_BITS):
     """``ops/gang.gang_assign`` over the 2-D mesh — the explicit
     shard_map twin of the GSPMD-placed gang path, for both per-pass
     engines (``solver="batch"`` propose/accept rounds and
@@ -797,8 +800,9 @@ def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
 # koordlint: shape[state: NxR i32 nodes, reserve: NxR i32 nodes]
 def sharded_forecast_gang_assign(mesh, state, reserve, pods, cfg, gangs,
                                  quota=None, passes: int = 2,
-                                 solver: str = "greedy", k: int = 32,
-                                 rounds: int = 12, spread_bits=(5, 15)):
+                                 solver: str = "greedy", k: int = ba.CAND_K,
+                                 rounds: int = ba.SOLVE_ROUNDS,
+                                 spread_bits=ba.CAND_SPREAD_BITS):
     """:func:`sharded_gang_assign` with the forecast-headroom reserve
     charged for the duration of the solve — the sharded twin of
     ``forecast/kernels.forecast_gang_assign``.

@@ -204,8 +204,6 @@ class Scheduler:
         faults=None,
         explain: bool = True,
         flight_ring_size: int = 256,
-        mesh="auto",
-        shard_min_nodes: int = 1024,
         tenant: str = "",
         solver_kit=None,
         quality_mode: str = "off",
@@ -280,30 +278,17 @@ class Scheduler:
         #: pre-pass settled (bound, or a reserve-pod); None when it
         #: settled none.  The incremental dispatch takes it
         self._prepass_settled: np.ndarray | None = None
-        # -- the shared solver kit (ISSUE 11) --
-        # every jitted entry point lives in a SolverKit (solve mesh
-        # included): a standalone scheduler builds its own, a tenant of
-        # a TenantScheduler is handed the front-end's shared kit so T
-        # tenants multiplex onto ONE compiled solver (one jit cache, one
-        # recompile ledger) instead of compiling T copies.
+        # -- the solver kit --
+        # every jitted entry point lives in a SolverKit, which alone
+        # decides where a solve runs (mesh or one device), with which
+        # candidate method and parameters: a standalone scheduler builds
+        # its own, a tenant of a TenantScheduler is handed the
+        # front-end's shared kit so T tenants multiplex onto ONE compiled
+        # solver (one jit cache, one recompile ledger)
         from koordinator_tpu.scheduler.solver_kit import SolverKit
 
-        self.kit = (solver_kit if solver_kit is not None
-                    else SolverKit(mesh=mesh,
-                                   shard_min_nodes=shard_min_nodes))
-        self.mesh = self.kit.mesh
-        self.shard_min_nodes = self.kit.shard_min_nodes
-        self.solver_shard_count = self.kit.shards
-        if self.mesh is not None:
-            self.snapshot.set_solver_sharding(
-                self.kit.node_sharding, self.solver_shard_count,
-                min_nodes=self.shard_min_nodes)
-        self._solve = self.kit.solve
-        #: explicit shard_map gang/greedy twin (ISSUE 14): engaged for
-        #: factored-feasibility batches whenever the mesh is active and
-        #: both capacities divide over their axes; hinted (dense-mask)
-        #: rounds keep the GSPMD-placed entry
-        self._solve_sh = self.kit.solve_sh
+        self.kit = solver_kit if solver_kit is not None else SolverKit()
+        self.snapshot.set_state_placement(self.kit.place)
 
         # -- incremental delta-driven solve (no-gang batch rounds) --
         #: steady-state rounds refresh a device-resident (P, k) candidate
@@ -312,13 +297,6 @@ class Scheduler:
         #: the dirty fraction crosses incremental_dirty_threshold
         self.incremental_solve = incremental_solve
         self.incremental_dirty_threshold = 0.25
-        #: candidate-selection knobs — MUST mirror batch_assign's defaults
-        #: (gang_assign's full path uses them), or the incremental and
-        #: full rounds would solve different problems
-        self.cand_k = 32
-        self.cand_spread = (5, 15)
-        self.cand_method = "auto"
-        self.solve_rounds = 12
         self._cand_cache: dict | None = None
         #: which candidate path the last batch round took
         #: (incremental | full_cold | full_fallback | full_gang |
@@ -328,16 +306,8 @@ class Scheduler:
         #: its candidate tie-break rotation when the queue shifts around it
         self._rot_ids: dict[str, int] = {}
         self._rot_counter = 0
-        self._select_scored = self.kit.select_scored
         self._align_cands = self.kit.align_cands
-        self._refresh_cands = self.kit.refresh_cands
         self._scatter_cands = self.kit.scatter_cands
-        self._pass1 = self.kit.pass1
-        self._pass2 = self.kit.pass2
-        self._select_scored_sh = self.kit.select_scored_sh
-        self._refresh_cands_sh = self.kit.refresh_cands_sh
-        self._pass1_sh = self.kit.pass1_sh
-        self._pass2_sh = self.kit.pass2_sh
 
         # -- solve-quality mode (ISSUE 13) --
         #: "off" = today's greedy path exactly; "lp" = every eligible
@@ -353,8 +323,6 @@ class Scheduler:
                              f"one of {QUALITY_MODES}")
         self.quality_mode = quality_mode
         self.quality_slack_threshold = quality_slack_threshold
-        self._quality_solve = self.kit.quality_solve
-        self._quality_solve_sh = self.kit.quality_solve_sh
         #: auto-mode escalation latch, recomputed from every round's
         #: resulting per-dim slack (MIN over provisioned dims vs the
         #: threshold: every dimension must have headroom worth winning
@@ -380,8 +348,6 @@ class Scheduler:
                              f"one of {FORECAST_MODES}")
         self.forecast_mode = forecast_mode
         self.forecast_plane = None
-        self._forecast_solve = self.kit.forecast_solve
-        self._forecast_solve_sh = self.kit.forecast_solve_sh
         #: per-round admission cap (tenancy weighted-fair admission sets
         #: it per cycle; None = admit the whole active queue).  Applied
         #: in priority order AFTER the PreEnqueue gates, so a capped
@@ -564,7 +530,7 @@ class Scheduler:
         with self.lock:
             if plane.capacity < self.snapshot.capacity:
                 plane.grow(self.snapshot.capacity)
-            if self.mesh is not None and self.snapshot.solver_sharding_active:
+            if self.kit.sharding_active_for(self.snapshot.capacity):
                 plane.set_sharding(self.kit.node_sharding)
             plane.metric_labels = dict(self._tl() or {})
             self.forecast_plane = plane
@@ -1260,24 +1226,10 @@ class Scheduler:
             quota_id=quota_id, non_preemptible=non_preempt,
             node_capacity=n_cap, capacity=cap, rot_id=rot, **mask_kw,
         )
-        if (not hinted and self.mesh is not None
-                and self.kit.pod_shards > 1
-                and self.snapshot.solver_sharding_active
-                and self.kit.pods_shardable(batch.capacity)):
-            # pin the batch under the 2-D mesh's pod-axis NamedSharding:
-            # the cached batch is reused across steady-state rounds, so
-            # the sharded entries consume it in place instead of paying
-            # a host->device reshard per call.  Gated on the SAME
-            # solver_sharding_active predicate as the solves — a mesh
-            # present but inactive (capacity below the min-nodes floor)
-            # runs single-device entries, which must not receive a
-            # mesh-committed batch.  Donation-safe: no solve entry
-            # donates the batch (only the state and the refresh's cache
-            # donate — koordlint's donation-flow rule polices it).
-            from koordinator_tpu.parallel import mesh as pmesh
-
-            batch = pmesh.shard_pod_batch(batch, self.mesh)
         if not hinted:
+            # reused across steady-state rounds: the kit pins it where
+            # its sharded entries read it in place
+            batch = self.kit.place_batch(batch, n_cap)
             self._batch_cache = (key, batch)
             self._batch_host = {
                 "row_of": {pod.name: i for i, pod in enumerate(pods)},
@@ -1608,9 +1560,8 @@ class Scheduler:
         # sharded-solve introspection: the active nodes-axis
         # width plus the per-device slice of each persistent
         # tensor (a lopsided shard is a placement bug)
-        active = (self.mesh is not None
-                  and self.snapshot.solver_sharding_active)
-        active_shards = self.solver_shard_count if active else 1
+        active = self.kit.sharding_active_for(self.snapshot.capacity)
+        active_shards = self.kit.shards if active else 1
         pod_shards = self.kit.pod_shards if active else 1
         metrics.solver_shard_count.set(float(active_shards))
         # per-axis split of the 2-D mesh (ISSUE 14): the flat
@@ -1627,7 +1578,7 @@ class Scheduler:
             ):
                 for (pi, ni), nbytes in (
                         insp.device_bytes_by_mesh_shard(
-                            tree, self.mesh).items()):
+                            tree, self.kit.mesh).items()):
                     metrics.solver_device_bytes.set(
                         float(nbytes),
                         labels={"kind": kind,
@@ -1863,18 +1814,14 @@ class Scheduler:
                 self.last_solve_path = "quality_lp"
                 metrics.incremental_solve_total.inc(
                     labels={"path": "quality_lp"})
-                use_mesh = (self.mesh is not None
-                            and self.snapshot.solver_sharding_active
-                            and self._quality_solve_sh is not None)
-                qfn = (self._quality_solve_sh if use_mesh
-                       else self._quality_solve)
                 # pre-solve slack (async device sums, blocked on in the
                 # host half): the quality_slack_recovered baseline.
                 # Dispatched BEFORE the donating solve consumes the
                 # state buffers.
                 slack_before = self._slack_sums(self.snapshot.state)
-                assignments, new_state, new_quota, qiters = qfn(
-                    self.snapshot.state, batch, self.config, quota)
+                assignments, new_state, new_quota, qiters = (
+                    self.kit.quality_solve(
+                        self.snapshot.state, batch, self.config, quota))
                 # the blessed swap (see the full-path branch below)
                 self.snapshot.state = new_state
                 # the LP solve re-packed everything: the candidate
@@ -1900,22 +1847,15 @@ class Scheduler:
                     metrics.incremental_solve_total.inc(labels={
                         "path": self.last_solve_path})
                 if forecast_reserve is not None:
-                    solve_fn = (self._forecast_solve_sh
-                                if self._use_sharded_solve(batch)
-                                else self._forecast_solve)
-                    assignments, new_state, new_quota = solve_fn(
-                        self.snapshot.state, forecast_reserve, batch,
-                        self.config, gangs, quota,
-                        passes=self.gang_passes, solver=solver,
-                    )
+                    assignments, new_state, new_quota = (
+                        self.kit.forecast_solve(
+                            self.snapshot.state, forecast_reserve, batch,
+                            self.config, gangs, quota,
+                            passes=self.gang_passes, solver=solver))
                 else:
-                    solve_fn = (self._solve_sh
-                                if self._use_sharded_solve(batch)
-                                else self._solve)
-                    assignments, new_state, new_quota = solve_fn(
+                    assignments, new_state, new_quota = self.kit.solve(
                         self.snapshot.state, batch, self.config, gangs,
-                        quota, passes=self.gang_passes, solver=solver,
-                    )
+                        quota, passes=self.gang_passes, solver=solver)
                 # the blessed swap: the jitted solve donated the old
                 # state buffers; the snapshot re-points at the in-flight
                 # result immediately so nothing can read the dead ones
@@ -1943,8 +1883,7 @@ class Scheduler:
     # koordlint: guarded-by(self.lock)
     # koordlint: shape[a: P i32 rep, new_state: NxR i32 nodes]
     def round_adopt_batched(self, handle: RoundHandle, a, new_state,
-                            new_quota, est_accum, cache, k: int,
-                            method: str) -> RoundHandle:
+                            new_quota, est_accum, cache) -> RoundHandle:
         """Adopt one tenant's slice of a TENANT-AXIS batched solve as
         this round's dispatched pass 1 (tenancy front-end;
         ``tenancy._batched_dispatch`` ran one ``vmap``-batched
@@ -1966,8 +1905,9 @@ class Scheduler:
             "cache": cache,
             "row_of": host["row_of"],
             "specs": host["specs"],
-            "n": snap.capacity, "k": k, "spread": self.cand_spread,
-            "method": method, "cfg": self.config,
+            # the batched program is the single-device selection, vmapped
+            "n": snap.capacity, "method": self.kit.method,
+            "cfg": self.config,
         }
         # the blessed swap, batched form: the stacked program consumed a
         # COPY of the per-tenant states (stacking copies), so the old
@@ -1977,8 +1917,7 @@ class Scheduler:
         handle.assignments = a
         handle.new_quota = new_quota
         handle.inc = {"a": a, "quota": new_quota,
-                      "est_accum": est_accum, "batch": handle.batch,
-                      "k": k, "method": method, "use_mesh": False}
+                      "est_accum": est_accum, "batch": handle.batch}
         handle.result.round_pods = len(handle.pods)
         return handle
 
@@ -2066,23 +2005,16 @@ class Scheduler:
                         # charged accounting as its main solve — an
                         # uncharged rescue would re-admit exactly the
                         # pods the reserve just filtered
-                        rescue_fn = (self._forecast_solve_sh
-                                     if self._use_sharded_solve(small)
-                                     else self._forecast_solve)
-                        r_small, new_state, new_quota = rescue_fn(
-                            new_state, handle.forecast_reserve, small,
-                            self.config, gangs, new_quota,
-                            passes=self.gang_passes, solver="greedy",
-                        )
+                        r_small, new_state, new_quota = (
+                            self.kit.forecast_solve(
+                                new_state, handle.forecast_reserve, small,
+                                self.config, gangs, new_quota,
+                                passes=self.gang_passes, solver="greedy"))
                     else:
-                        rescue_fn = (self._solve_sh
-                                     if self._use_sharded_solve(small)
-                                     else self._solve)
-                        r_small, new_state, new_quota = rescue_fn(
+                        r_small, new_state, new_quota = self.kit.solve(
                             new_state, small, self.config, gangs,
                             new_quota,
-                            passes=self.gang_passes, solver="greedy",
-                        )
+                            passes=self.gang_passes, solver="greedy")
                     self.snapshot.state = new_state
                     r_full = np.full(batch.capacity, -1, np.int32)
                     r_full[idx] = np.asarray(
@@ -2368,31 +2300,27 @@ class Scheduler:
         per-mesh-shape compile regression reads straight off this
         document (and off ``solver_recompiles_total{shape}``)."""
         from koordinator_tpu.ops import introspection as insp
-        from koordinator_tpu.parallel.mesh import NODES_AXIS, PODS_AXIS
+        from koordinator_tpu.parallel import mesh as pmesh
 
         cand = self._cand_cache
+        mesh = self.kit.mesh
 
         def _by_shard(tree):
             # keyed by (pod_shard, node_shard) mesh coordinate when the
             # mesh exists (ISSUE 14), flat device id otherwise
-            if self.mesh is not None:
+            if mesh is not None:
                 return {f"p{pi}n{ni}": b for (pi, ni), b in
                         insp.device_bytes_by_mesh_shard(
-                            tree, self.mesh).items()}
+                            tree, mesh).items()}
             return {str(d): b for d, b in
                     insp.device_bytes_by_shard(tree).items()}
 
         return {
-            "solver_shard_count": (self.solver_shard_count
-                                   if self.mesh is not None else 1),
-            "active": bool(self.mesh is not None
-                           and self.snapshot.solver_sharding_active),
-            "mesh": ({"pods": int(self.mesh.shape[PODS_AXIS]),
-                      "nodes": int(self.mesh.shape[NODES_AXIS])}
-                     if self.mesh is not None else None),
-            "pod_shard_count": (self.kit.pod_shards
-                                if self.mesh is not None else 1),
-            "shard_min_nodes": self.shard_min_nodes,
+            "solver_shard_count": self.kit.shards,
+            "active": self.kit.sharding_active_for(self.snapshot.capacity),
+            "mesh": pmesh.mesh_axes(mesh),
+            "pod_shard_count": self.kit.pod_shards,
+            "shard_min_nodes": self.kit.shard_min_nodes,
             "device_bytes_by_shard": {
                 # a scrape holds no lock, so it reads the tensors as they
                 # stand: ``snapshot.state`` would fold, which is a write
@@ -2404,17 +2332,6 @@ class Scheduler:
                 f"{lbl.get('fn', '?')}[{lbl.get('shape', '?')}]": int(v)
                 for lbl, v in metrics.solver_recompiles.items()},
         }
-
-    def _use_sharded_solve(self, batch: PodBatch) -> bool:  # koordlint: guarded-by(self.lock)
-        """Should this batch run the explicit shard_map gang/greedy twin
-        (``kit.solve_sh``)?  Yes when the mesh is active for the current
-        node capacity, the batch carries the factored selector-mask
-        feasibility form (a dense (P, N) mask cannot tile over the 2-D
-        mesh), and the batch capacity divides over the pods axis."""
-        return (self._solve_sh is not None
-                and self.snapshot.solver_sharding_active
-                and batch.selector_mask is not None
-                and self.kit.pods_shardable(batch.capacity))
 
     def _solve_batch_incremental(self, pods, batch: PodBatch, quota):  # koordlint: guarded-by(self.lock)
         """One-call form of the incremental solve (dispatch + finish):
@@ -2449,38 +2366,14 @@ class Scheduler:
 
         snap = self.snapshot
         n = snap.capacity
-        k = min(self.cand_k, n)
-        method = ba.resolve_candidate_method(self.cand_method)
-        # sharded-by-default: when the solver mesh is active for this
-        # capacity, selection/refresh/passes run the shard_map entries
-        # (recall-exact selection; bit-identical acceptance) and the
-        # state donates in place under its node-axis NamedSharding; the
-        # batch capacity must additionally divide over the pods axis
-        # (always true for power-of-two axis sizes)
-        use_mesh = (self.mesh is not None and snap.solver_sharding_active
-                    and self.kit.pods_shardable(batch.capacity))
-        if use_mesh:
-            method = "sharded"
-
-            def _select(st, b):
-                return self._select_scored_sh(
-                    st, b, self.config, k=k, spread_bits=self.cand_spread,
-                    with_scores=True)
-        else:
-            def _select(st, b):
-                return self._select_scored(
-                    st, b, self.config, k=k, spread_bits=self.cand_spread,
-                    method=method, with_scores=True)
-        refresh_fn = (self._refresh_cands_sh if use_mesh
-                      else self._refresh_cands)
-        pass1_fn = self._pass1_sh if use_mesh else self._pass1
+        # which selection (sharded, or a single-device method) the kit
+        # runs for these shapes: a cache another selection built is cold
+        selection = self.kit.selection(n, batch)
         meta = self._cand_cache
         cache_ok = (
             meta is not None
             and meta["n"] == n
-            and meta["k"] == k
-            and meta["spread"] == self.cand_spread
-            and meta["method"] == method
+            and meta["method"] == selection
             # identity via the OBJECT, not id(): a freed config's address
             # can be reused by its replacement (CPython free lists)
             and meta["cfg"] is self.config
@@ -2530,13 +2423,13 @@ class Scheduler:
             self._last_dirty_pod_frac = pod_frac
             if max(node_frac, pod_frac) <= self.incremental_dirty_threshold:
                 path = "incremental"
-                cand_key, cache = refresh_fn(
+                cand_key, cache = self.kit.refresh_cands(
                     snap.state, batch, self.config, aligned,
-                    jnp.asarray(drows), jnp.asarray(dvalid),
-                    k=k, spread_bits=self.cand_spread)
+                    jnp.asarray(drows), jnp.asarray(dvalid))
                 if dirty_pods.any():
                     small, idx = batch.compact(dirty_pods)
-                    sk, sn, ss = _select(snap.state, small)
+                    sk, sn, ss = self.kit.select_scored(
+                        snap.state, small, self.config)
                     rows_pad = np.full(small.capacity, batch.capacity,
                                        np.int32)
                     rows_pad[: len(idx)] = idx
@@ -2545,7 +2438,8 @@ class Scheduler:
             else:
                 path = "full_fallback"
         if cache is None:
-            ck, cn, cs = _select(snap.state, batch)
+            ck, cn, cs = self.kit.select_scored(
+                snap.state, batch, self.config)
             cache = ba.CandidateCache(ck, cn, cs)
         metrics.incremental_solve_total.inc(labels={"path": path})
         # the batch build already computed this round's name→row / spec
@@ -2563,8 +2457,7 @@ class Scheduler:
             "cache": cache,
             "row_of": row_of,
             "specs": host["specs"],
-            "n": n, "k": k, "spread": self.cand_spread,
-            "method": method, "cfg": self.config,
+            "n": n, "method": selection, "cfg": self.config,
         }
         self.last_solve_path = path
 
@@ -2578,16 +2471,15 @@ class Scheduler:
         # failure the cache is dropped so the next round re-warms
         # instead of trusting un-bookkept state.
         try:
-            a, state, quota, est_accum = pass1_fn(
+            a, state, quota, est_accum = self.kit.pass1(
                 snap.state, batch, quota, cache.cand_key, cache.cand_node,
-                self.config, rounds=self.solve_rounds)
+                self.config)
             snap.state = state
         except Exception:
             self._cand_cache = None
             raise
-        return {"a": a, "quota": quota,
-                "est_accum": est_accum, "batch": batch, "k": k,
-                "method": method, "use_mesh": use_mesh}
+        return {"a": a, "quota": quota, "est_accum": est_accum,
+                "batch": batch}
 
     def _finish_batch_incremental(self, ctx: dict):  # koordlint: guarded-by(self.lock)
         """HOST half of the incremental solve: block on pass 1, then
@@ -2599,7 +2491,6 @@ class Scheduler:
         # pass 1's in-flight state, as dispatch left it in the snapshot,
         # with whatever was reserved or released since folded in
         state, quota, est_accum = snap.state, ctx["quota"], ctx["est_accum"]
-        k, method, use_mesh = ctx["k"], ctx["method"], ctx["use_mesh"]
         try:
             # a copy: np.asarray of a device array is a read-only view,
             # and a later pass writes what it placed into it
@@ -2609,16 +2500,8 @@ class Scheduler:
                 if not leftover.any():
                     break
                 small, idx = batch.compact(leftover)
-                if use_mesh:
-                    a2, state, quota, est_accum = self._pass2_sh(
-                        state, est_accum, small, quota, self.config, k=k,
-                        rounds=self.solve_rounds,
-                        spread_bits=self.cand_spread)
-                else:
-                    a2, state, quota, est_accum = self._pass2(
-                        state, est_accum, small, quota, self.config, k=k,
-                        rounds=self.solve_rounds,
-                        spread_bits=self.cand_spread, method=method)
+                a2, state, quota, est_accum = self.kit.pass2(
+                    state, est_accum, small, quota, self.config)
                 snap.state = state
                 a2_np = np.asarray(self._block_timed(a2))[: len(idx)]
                 placed = a2_np >= 0

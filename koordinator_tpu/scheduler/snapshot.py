@@ -137,36 +137,17 @@ class ClusterSnapshot:
         #: the AGE of this stamp — a stalled feed means every usage- and
         #: batch-allocatable-derived row here is untrustworthy.
         self.last_sync_time: float | None = None
-        #: solver-mesh placement (scheduler-owned): when set, the state's
-        #: node tensors live node-axis-sharded over the mesh so the
-        #: sharded solve entries donate them IN PLACE instead of
-        #: resharding per call.  Applied lazily — only once the capacity
-        #: both divides over the shard count and reaches the min-nodes
-        #: floor (sharding a tiny cluster is pure collective overhead).
-        self._solver_sharding = None
-        self._solver_shards = 1
-        self._solver_shard_min_nodes = 0
+        #: where the solver wants the state (``SolverKit.place``): applied
+        #: to every state built here, so a solve donates it in place
+        #: instead of resharding it per call
+        self._place = lambda state: state
 
-    def set_solver_sharding(self, sharding, shards: int,
-                            min_nodes: int = 0) -> None:
-        """Install the solver mesh's node-axis placement (see above)."""
-        self._solver_sharding = sharding
-        self._solver_shards = max(int(shards), 1)
-        self._solver_shard_min_nodes = int(min_nodes)
-        self._apply_solver_sharding()
-
-    @property
-    def solver_sharding_active(self) -> bool:
-        """True when the CURRENT capacity solves on the sharded path."""
-        return (self._solver_sharding is not None
-                and self.capacity % self._solver_shards == 0
-                and self.capacity >= self._solver_shard_min_nodes)
-
-    def _apply_solver_sharding(self) -> None:
-        if self.solver_sharding_active:
-            ns = self._solver_sharding
-            self._state = jax.tree.map(
-                lambda x: jax.device_put(x, ns), self._state)
+    def set_state_placement(self, place) -> None:
+        """Install ``place(state) -> state`` and apply it to the state
+        that stands; growth and the conservative rebuild apply it to
+        theirs."""
+        self._place = place
+        self._state = place(self._state)
 
     def mark_sync(self, now: float) -> None:
         """Stamp feed liveness (monotonic under the writer's clock)."""
@@ -344,7 +325,7 @@ class ClusterSnapshot:
             grown[:old_cap] = self._pending
             self._pending = grown
         self._free_rows = list(range(new_cap - 1, old_cap - 1, -1)) + self._free_rows
-        self._apply_solver_sharding()
+        self._state = self._place(self._state)
 
     # -- delta flush ---------------------------------------------------------
 
@@ -469,8 +450,8 @@ class ClusterSnapshot:
         pending goes with the lost tensor: a reserve is covered by the
         full booking, and a release dropped only leaves a row fuller."""
         self._drop_pending()
-        self._state = ClusterState.zeros(self.capacity, self.dims)
-        self._apply_solver_sharding()
+        self._state = self._place(
+            ClusterState.zeros(self.capacity, self.dims))
         self._reset_requested.clear()
         self._dirty.update(self.node_index.values())
         self._cand_dirty.update(self.node_index.values())

@@ -1,97 +1,82 @@
-"""Shared jitted solver entries: ONE compiled solver for N tenants.
+"""The device half's toolbox: every jitted solver entry, and the ONE
+place that decides where and how a solve runs.
 
-Before the tenancy subsystem (ISSUE 11) every :class:`Scheduler` built
-its own ``jax.jit`` wrappers in ``__init__``, so two schedulers in one
-process compiled two identical copies of every solve program.  A
-multi-tenant front-end (``scheduler/tenancy.py``) runs one ``Scheduler``
-per cluster — T tenants must multiplex onto ONE solver, sharing the jit
-caches, the recompile accounting, and the mesh, exactly the way the
-paper's shared-capacity argument sizes one pool to aggregate demand.
+A :class:`SolverKit` holds the compiled programs of the batch solver
+(gang/greedy solve, candidate selection / refresh / scatter, the
+propose/accept passes, the LP packing solve, the forecast-charged
+solve, the reservation pre-pass, preemption, explain / slack
+reductions) and is shareable: the tenants of a ``TenantScheduler``
+multiplex onto one kit, so T clusters share one jit cache, one
+recompile ledger and one mesh.
 
-The kit owns:
+Three decisions live here and nowhere above:
 
-- the solve mesh (``parallel/mesh.resolve_solver_mesh`` — sharded by
-  default, ``KOORD_SOLVER_MESH``/``KOORD_SOLVER_MESH_MIN_NODES``
-  overrides);
-- every instrumented jitted entry point of the batch solver (full
-  gang_assign, candidate selection/refresh/scatter, the propose/accept
-  passes and their sharded twins, the reservation pre-pass solve, the
-  preemption kernels, explain/slack reductions).
+- **placement**: each twinned stage exists as a single-device program
+  and as an explicit ``shard_map`` program over the solve mesh
+  (``parallel/sharded.py``).  A caller calls ONE entry per stage
+  (``solve``, ``forecast_solve``, ``quality_solve``, ``select_scored``,
+  ``refresh_cands``, ``pass1``, ``pass2``); the entry picks its program
+  from its own arguments through :meth:`SolverKit._sharded`.  Which
+  program ran shows only in the recompile label's ``@Nshard`` /
+  ``@PxNshard`` suffix.  :meth:`place` / :meth:`place_batch` put the
+  state and the cached batch where those programs read them in place.
+- **the candidate method**: ``"auto"`` resolved once, through
+  ``ops/batch_assign.resolve_candidate_method`` (the rule's one home).
+- **the candidate parameters**: ``ops/batch_assign``'s ``CAND_K``,
+  ``CAND_SPREAD_BITS``, ``SOLVE_ROUNDS``.
 
-Shape buckets in the recompile accounting derive the ``@Nshard`` suffix
-from the ARGUMENTS (state capacity vs the mesh floor), not from any one
-scheduler's snapshot, so a shared kit labels each tenant's compiles
-correctly even when tenants straddle the sharding floor.
+The mesh itself is a deployment setting (``KOORD_SOLVER_MESH``,
+``KOORD_SOLVER_MESH_PODS``: ``parallel/mesh.resolve_solver_mesh``).
 """
 
 from __future__ import annotations
 
-import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from koordinator_tpu.ops import batch_assign as _ba
+from koordinator_tpu.parallel import mesh as pmesh
+
 
 class SolverKit:
-    """The device half's toolbox, shareable across Scheduler instances.
+    """Construction is cheap (wrapping, not compiling); compilation
+    happens per (entry, shape bucket) on first use and is shared by
+    every scheduler holding the kit.
 
-    Construction is cheap (wrapping, not compiling); compilation happens
-    per (entry, shape bucket) on first use and is shared by every
-    scheduler holding the kit.
+    ``mesh``: a ``Mesh``, ``"auto"`` (every visible device on the nodes
+    axis when there is more than one) or ``None`` / ``"off"``.
+    ``shard_min_nodes``: capacities under it stay single-device (sharding
+    a 64-node problem is pure collective overhead).
     """
 
     def __init__(self, mesh="auto", shard_min_nodes: int = 1024):
-        from koordinator_tpu.ops import batch_assign as _ba
         from koordinator_tpu.ops import explain as _ex
         from koordinator_tpu.ops import introspection as insp
         from koordinator_tpu.ops.gang import gang_assign
         from koordinator_tpu.ops.preemption import preempt_chain, preempt_one
         from koordinator_tpu.ops.reservation import reservation_greedy_assign
-        from koordinator_tpu.parallel import mesh as pmesh
         from koordinator_tpu.parallel import sharded as psharded
         from koordinator_tpu.quality.lp_pack import lp_pack_assign
         from koordinator_tpu.quality.topo_gang import gang_topo_diameter
 
-        # -- sharded-by-default solve mesh (ISSUE 10, 2-D since ISSUE 14) --
-        # the node axis of the batch solve shards over every visible
-        # device (a pods axis splits off via KOORD_SOLVER_MESH=PxN /
-        # KOORD_SOLVER_MESH_PODS); tiny clusters stay single-device —
-        # sharding a 64-node problem is pure collective overhead — via
-        # the min-nodes floor.
         self.mesh = pmesh.resolve_solver_mesh(mesh)
-        self.shard_min_nodes = int(os.environ.get(
-            "KOORD_SOLVER_MESH_MIN_NODES", shard_min_nodes))
+        self.shard_min_nodes = int(shard_min_nodes)
         self.shards = pmesh.nodes_shard_count(self.mesh)
         self.pod_shards = pmesh.pods_shard_count(self.mesh)
         self.node_sharding = (pmesh.node_sharding(self.mesh)
                               if self.mesh is not None else None)
-        self.pod_sharding = (pmesh.pod_sharding(self.mesh)
-                             if self.mesh is not None else None)
-
-        def _active(n_cap: int) -> bool:
-            """Does THIS capacity solve on the sharded path?  The same
-            predicate ``ClusterSnapshot.solver_sharding_active`` applies
-            to its own capacity — derived from the args so a shared kit
-            labels each tenant correctly."""
-            return (self.mesh is not None
-                    and n_cap % self.shards == 0
-                    and n_cap >= self.shard_min_nodes)
-
-        self.sharding_active_for = _active
-
-        def _pods_shardable(p_cap: int) -> bool:
-            """Does THIS pod-batch capacity split over the pods axis?
-            Power-of-two batch bucketing guarantees it for power-of-two
-            pods_axis sizes; an odd env-forced axis just falls back."""
-            return self.mesh is not None and p_cap % self.pod_shards == 0
-
-        self.pods_shardable = _pods_shardable
+        #: candidate method of the single-device programs (the sharded
+        #: selection is recall-exact and takes none)
+        self.method = _ba.resolve_candidate_method("auto")
+        #: propose/accept rounds of the incremental passes
+        self.rounds = _ba.SOLVE_ROUNDS
 
         def _sfx(n_cap: int) -> str:
-            if not _active(n_cap):
+            if not self.sharding_active_for(n_cap):
                 return ""
-            # the pods=1 form keeps the historical label so recompile
-            # dashboards don't fork a new shape bucket on upgrade
+            # the pods=1 form stays "@Nshard": dashboards key on it
             if self.pod_shards > 1:
                 return f"@{self.pod_shards}x{self.shards}shard"
             return f"@{self.shards}shard"
@@ -100,174 +85,134 @@ class SolverKit:
             return (f"P{args[1].capacity}xN{args[0].capacity}"
                     f"{_sfx(args[0].capacity)}")
 
-        # solve-state donation: the caller's snapshot.state is dead the
-        # moment the call starts (XLA updates the (N, R) accounting in
-        # place) and must be replaced wholesale by the returned state.
         # Every jitted entry point is wrapped for recompile accounting
         # (ops/introspection): a cache miss lands in
-        # solver_recompiles_total{fn, shape}.
-        self.solve = insp.instrument(
+        # solver_recompiles_total{fn, shape}.  The twins of one stage
+        # share the ``fn`` label; the shape's suffix tells them apart.
+        # Solve-state donation: the caller's snapshot.state is dead the
+        # moment the call starts (XLA updates the (N, R) accounting in
+        # place, under its NamedSharding placement on the mesh) and must
+        # be replaced wholesale by the returned state.  Without a mesh
+        # a twin is False: ``_sharded`` never picks it.
+        sh = self.mesh is not None
+
+        # the gang/greedy solve.  The single-device program is GSPMD-
+        # placed when handed a sharded state: the fallback for dense-
+        # feasibility (hinted) batches, which cannot tile over the mesh
+        self._solve_one = insp.instrument(
             jax.jit(gang_assign,
                     static_argnames=("passes", "solver"),
                     donate_argnums=(0,)),
             "gang_assign", shape_of=_pn)
-        # explicit shard_map twin of the gang/greedy solve (ISSUE 14):
-        # same signature prefix as gang_assign, so the scheduler swaps
-        # entries without re-plumbing; the GSPMD-placed self.solve stays
-        # the fallback for dense-feasibility (hinted) batches and
-        # capacities the mesh doesn't divide
-        self.solve_sh = None
-        if self.mesh is not None:
-            from functools import partial as _gpartial
+        self._solve_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_gang_assign, self.mesh),
+                    static_argnames=("passes", "solver", "k",
+                                     "rounds", "spread_bits"),
+                    donate_argnums=(0,)),
+            "gang_assign", shape_of=_pn)
 
-            # koordlint: shape[arg0: NxR i32 nodes]
-            self.solve_sh = insp.instrument(
-                jax.jit(_gpartial(psharded.sharded_gang_assign, self.mesh),
-                        static_argnames=("passes", "solver", "k",
-                                         "rounds", "spread_bits"),
-                        donate_argnums=(0,)),
-                "gang_assign", shape_of=_pn)
-
-        self.select_scored = insp.instrument(
+        # the incremental stages: selection recall-exact on the mesh,
+        # acceptance bit-identical (parallel/sharded.py)
+        self._select_scored_one = insp.instrument(
             jax.jit(_ba.select_candidates,
                     static_argnames=("k", "spread_bits", "method",
                                      "with_scores")),
+            "select_candidates", shape_of=_pn)
+        self._select_scored_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_select_candidates, self.mesh),
+                    static_argnames=("k", "spread_bits", "with_scores")),
             "select_candidates", shape_of=_pn)
         self.align_cands = insp.instrument(
             jax.jit(_ba.align_candidate_cache),
             "align_candidate_cache",
             shape_of=lambda a, k: (f"P{a[1].shape[0]}xN{a[3].shape[0]}"))
-        self.refresh_cands = insp.instrument(
+        self._refresh_cands_one = insp.instrument(
             jax.jit(_ba.refresh_candidates,
                     static_argnames=("k", "spread_bits"),
                     donate_argnums=(3,)),
             "refresh_candidates",
             shape_of=lambda a, k: (f"P{a[1].capacity}xN{a[0].capacity}"
                                    f"xD{a[4].shape[0]}"))
+        self._refresh_cands_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_refresh_candidates, self.mesh),
+                    static_argnames=("k", "spread_bits"),
+                    donate_argnums=(3,)),
+            "refresh_candidates",
+            shape_of=lambda a, k: (
+                f"P{a[1].capacity}xN{a[0].capacity}"
+                f"xD{a[4].shape[0]}{_sfx(a[0].capacity)}"))
         self.scatter_cands = insp.instrument(
             jax.jit(_ba.scatter_candidate_rows, donate_argnums=(0,)),
             "scatter_candidate_rows",
             shape_of=lambda a, k: (f"P{a[0].cand_key.shape[0]}"
                                    f"xS{a[1].shape[0]}"))
-        # the shape annotations on the pass entries are specflow seed
-        # contracts (tools/koordlint/specflow): arg0 is ONE tenant's
-        # (N, R) state — a tenant-stacked (T, N, R) tensor reaching
-        # these bindings is a tenant-axis finding, not a solve
-        # koordlint: shape[arg0: NxR i32 nodes]
-        self.pass1 = insp.instrument(
+        self._pass1_one = insp.instrument(
             jax.jit(_ba.assign_round_pass,
                     static_argnames=("rounds",),
                     donate_argnums=(0,)),
             "assign_round_pass", shape_of=_pn)
-        # koordlint: shape[arg0: NxR i32 nodes, arg1: NxR i32 nodes]
-        self.pass2 = insp.instrument(
+        self._pass1_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_assign_round_pass, self.mesh),
+                    static_argnames=("rounds",),
+                    donate_argnums=(0,)),
+            "assign_round_pass", shape_of=_pn)
+        self._pass2_one = insp.instrument(
             jax.jit(_ba.assign_followup_pass,
                     static_argnames=("k", "rounds", "spread_bits",
                                      "method"),
                     donate_argnums=(0, 1)),
             "assign_followup_pass",
             shape_of=lambda a, k: f"P{a[2].capacity}xN{a[0].capacity}")
+        self._pass2_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_assign_followup_pass,
+                            self.mesh),
+                    static_argnames=("k", "rounds", "spread_bits"),
+                    donate_argnums=(0, 1)),
+            "assign_followup_pass",
+            shape_of=lambda a, k: (
+                f"P{a[2].capacity}"
+                f"xN{a[0].capacity}{_sfx(a[0].capacity)}"))
 
-        # sharded twins (selection recall-exact on the mesh; acceptance
-        # bit-identical — parallel/sharded.py).  Donation mirrors the
-        # unsharded bindings: the state (and the refresh's cache)
-        # updates in place under its NamedSharding placement.
-        self.select_scored_sh = self.refresh_cands_sh = None
-        self.pass1_sh = self.pass2_sh = None
-        if self.mesh is not None:
-            from functools import partial as _partial
-
-            self.select_scored_sh = insp.instrument(
-                jax.jit(_partial(psharded.sharded_select_candidates,
-                                 self.mesh),
-                        static_argnames=("k", "spread_bits",
-                                         "with_scores")),
-                "select_candidates", shape_of=_pn)
-            self.refresh_cands_sh = insp.instrument(
-                jax.jit(_partial(psharded.sharded_refresh_candidates,
-                                 self.mesh),
-                        static_argnames=("k", "spread_bits"),
-                        donate_argnums=(3,)),
-                "refresh_candidates",
-                shape_of=lambda a, k: (
-                    f"P{a[1].capacity}xN{a[0].capacity}"
-                    f"xD{a[4].shape[0]}{_sfx(a[0].capacity)}"))
-            # koordlint: shape[arg0: NxR i32 nodes]
-            self.pass1_sh = insp.instrument(
-                jax.jit(_partial(psharded.sharded_assign_round_pass,
-                                 self.mesh),
-                        static_argnames=("rounds",),
-                        donate_argnums=(0,)),
-                "assign_round_pass", shape_of=_pn)
-            # koordlint: shape[arg0: NxR i32 nodes, arg1: NxR i32 nodes]
-            self.pass2_sh = insp.instrument(
-                jax.jit(_partial(psharded.sharded_assign_followup_pass,
-                                 self.mesh),
-                        static_argnames=("k", "rounds", "spread_bits"),
-                        donate_argnums=(0, 1)),
-                "assign_followup_pass",
-                shape_of=lambda a, k: (
-                    f"P{a[2].capacity}"
-                    f"xN{a[0].capacity}{_sfx(a[0].capacity)}"))
-
-        # -- quality mode (ISSUE 13): the LP-relaxation packing solve,
-        # the second solver backend behind the kit.  Same donation
-        # contract as the greedy entries: arg0 (the snapshot state) is
-        # consumed and must be replaced by the blessed swap.
-        # koordlint: shape[arg0: NxR i32 nodes]
-        self.quality_solve = insp.instrument(
+        # quality mode: the LP-relaxation packing solve, the second
+        # solver backend.  Same donation contract as the greedy entries.
+        self._quality_solve_one = insp.instrument(
             jax.jit(lp_pack_assign,
                     static_argnames=("ascent_iters", "rounding_iters"),
                     donate_argnums=(0,)),
             "lp_pack_assign", shape_of=_pn)
-        self.quality_solve_sh = None
-        if self.mesh is not None:
-            from functools import partial as _qpartial
-
-            # koordlint: shape[arg0: NxR i32 nodes]
-            self.quality_solve_sh = insp.instrument(
-                jax.jit(_qpartial(psharded.sharded_lp_pack_assign,
-                                  self.mesh),
-                        static_argnames=("ascent_iters",
-                                         "rounding_iters"),
-                        donate_argnums=(0,)),
-                "lp_pack_assign", shape_of=_pn)
+        self._quality_solve_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_lp_pack_assign, self.mesh),
+                    static_argnames=("ascent_iters", "rounding_iters"),
+                    donate_argnums=(0,)),
+            "lp_pack_assign", shape_of=_pn)
         #: topology diameter of a placed slot set (quality/topo_gang) —
         #: the rank-aware gang observable bench_recall and the quality
         #: planner report
         self.topo_diameter = jax.jit(gang_topo_diameter)
 
-        # -- forecast plane (ISSUE 15): predictive admission — the
-        # gang/greedy solve with the forecast-headroom reserve charged
-        # for the round (charge -> solve -> release inside ONE jitted
-        # program; forecast/kernels).  Donation mirrors gang_assign:
-        # arg0 (the snapshot state) is consumed and replaced by the
-        # blessed swap; the (N, R) reserve at arg1 stays live for the
-        # host half's rescue pass.
+        # forecast plane: predictive admission — the gang/greedy solve
+        # with the forecast-headroom reserve charged for the round
+        # (charge -> solve -> release inside ONE jitted program;
+        # forecast/kernels).  Donation mirrors gang_assign; the (N, R)
+        # reserve at arg1 stays live for the host half's rescue pass.
         from koordinator_tpu.forecast.kernels import forecast_gang_assign
 
         def _fpn(args, kwargs):
             return (f"P{args[2].capacity}xN{args[0].capacity}"
                     f"{_sfx(args[0].capacity)}")
 
-        # koordlint: shape[arg0: NxR i32 nodes, arg1: NxR i32 nodes]
-        self.forecast_solve = insp.instrument(
+        self._forecast_solve_one = insp.instrument(
             jax.jit(forecast_gang_assign,
                     static_argnames=("passes", "solver"),
                     donate_argnums=(0,)),
             "forecast_gang_assign", shape_of=_fpn)
-        self.forecast_solve_sh = None
-        if self.mesh is not None:
-            from functools import partial as _fpartial
-
-            # koordlint: shape[arg0: NxR i32 nodes, arg1: NxR i32 nodes]
-            self.forecast_solve_sh = insp.instrument(
-                jax.jit(_fpartial(psharded.sharded_forecast_gang_assign,
-                                  self.mesh),
-                        static_argnames=("passes", "solver", "k",
-                                         "rounds", "spread_bits"),
-                        donate_argnums=(0,)),
-                "forecast_gang_assign", shape_of=_fpn)
+        self._forecast_solve_sh = sh and insp.instrument(
+            jax.jit(partial(psharded.sharded_forecast_gang_assign,
+                            self.mesh),
+                    static_argnames=("passes", "solver", "k",
+                                     "rounds", "spread_bits"),
+                    donate_argnums=(0,)),
+            "forecast_gang_assign", shape_of=_fpn)
 
         self.rsv_solve = insp.instrument(
             jax.jit(reservation_greedy_assign, donate_argnums=(0,)),
@@ -296,3 +241,144 @@ class SolverKit:
                 ).astype(jnp.float32), axis=0))),
             "capacity_slack",
             shape_of=lambda a, k: f"N{a[0].capacity}")
+
+    # -- where a solve runs ---------------------------------------------------
+
+    def sharding_active_for(self, n_cap: int) -> bool:
+        """Do solves over a state of THIS node capacity run on the mesh?
+        Derived from the capacity, not from any one scheduler, so a
+        shared kit answers for each tenant."""
+        return (self.mesh is not None
+                and n_cap % self.shards == 0
+                and n_cap >= self.shard_min_nodes)
+
+    def _sharded(self, n_cap: int, batch=None, factored=False) -> bool:
+        """The placement choice, made here and nowhere else: does a
+        stage over (a state of ``n_cap`` rows, ``batch``) run its
+        ``shard_map`` program?  The stages differ in what the program
+        needs besides an active mesh:
+
+        - the LP packing twin replicates pods: nothing (``batch`` None);
+        - the incremental stages split pods over the pods axis: a batch
+          capacity the axis divides (power-of-two buckets always do for
+          power-of-two axes; an odd env-forced axis falls back);
+        - the gang/greedy twin besides needs the ``factored`` selector
+          mask: a dense (P, N) feasibility mask cannot tile over the
+          2-D mesh.
+        """
+        return (self.sharding_active_for(n_cap)
+                and (batch is None
+                     or batch.capacity % self.pod_shards == 0)
+                and (not factored or batch.selector_mask is not None))
+
+    def place(self, state):
+        """Put a cluster state where its solves read and donate it in
+        place: node-axis-sharded over the mesh when its capacity solves
+        there, untouched otherwise.  The snapshot applies it to every
+        state it builds (``ClusterSnapshot.set_state_placement``)."""
+        if not self.sharding_active_for(state.capacity):
+            return state
+        return pmesh.shard_cluster_state(state, self.mesh)
+
+    def place_batch(self, batch, n_cap: int):
+        """Pin a batch that is reused across rounds under the 2-D
+        mesh's pod-axis sharding, so the sharded entries consume it in
+        place instead of resharding it per call.  Only where the
+        gang/greedy twin would take it: a single-device entry must not
+        receive a mesh-committed batch.  No entry donates the batch."""
+        if self.pod_shards > 1 and self._sharded(n_cap, batch, True):
+            return pmesh.shard_pod_batch(batch, self.mesh)
+        return batch
+
+    def selection(self, n_cap: int, batch) -> str:
+        """Which candidate selection :meth:`select_scored` runs for
+        these shapes: ``"sharded"`` or the single-device method's name.
+        A candidate cache is valid only for the selection that built
+        it."""
+        return "sharded" if self._sharded(n_cap, batch) else self.method
+
+    # -- one entry per stage --------------------------------------------------
+    # Each hands its arguments to ONE of its two programs; ``state`` (and
+    # pass 2's ``est_accum``, the refresh's ``cache``) is donated either
+    # way.  The shape annotations are specflow seed contracts
+    # (tools/koordlint): ``state`` is ONE tenant's (N, R) tensors — a
+    # tenant-stacked (T, N, R) tensor reaching an entry is a finding.
+
+    # koordlint: shape[state: NxR i32 nodes]
+    def solve(self, state, batch, config, gangs, quota, *, passes, solver):
+        """``ops/gang.gang_assign``: (assignments, state, quota)."""
+        if self._sharded(state.capacity, batch, True):
+            return self._solve_sh(state, batch, config, gangs, quota,
+                                  passes=passes, solver=solver)
+        return self._solve_one(state, batch, config, gangs, quota,
+                               passes=passes, solver=solver)
+
+    # koordlint: shape[state: NxR i32 nodes, reserve: NxR i32 nodes]
+    def forecast_solve(self, state, reserve, batch, config, gangs, quota,
+                       *, passes, solver):
+        """``forecast/kernels.forecast_gang_assign``: :meth:`solve` with
+        ``reserve`` charged for the duration of the solve."""
+        if self._sharded(state.capacity, batch, True):
+            return self._forecast_solve_sh(
+                state, reserve, batch, config, gangs, quota,
+                passes=passes, solver=solver)
+        return self._forecast_solve_one(
+            state, reserve, batch, config, gangs, quota,
+            passes=passes, solver=solver)
+
+    # koordlint: shape[state: NxR i32 nodes]
+    def quality_solve(self, state, batch, config, quota):
+        """``quality/lp_pack.lp_pack_assign``: (assignments, state,
+        quota, iterations)."""
+        if self._sharded(state.capacity):
+            return self._quality_solve_sh(state, batch, config, quota)
+        return self._quality_solve_one(state, batch, config, quota)
+
+    def select_scored(self, state, batch, config):
+        """Full candidate selection: (cand_key, cand_node, cand_score)."""
+        k = min(_ba.CAND_K, state.capacity)
+        if self._sharded(state.capacity, batch):
+            return self._select_scored_sh(
+                state, batch, config, k=k,
+                spread_bits=_ba.CAND_SPREAD_BITS, with_scores=True)
+        return self._select_scored_one(
+            state, batch, config, k=k, spread_bits=_ba.CAND_SPREAD_BITS,
+            method=self.method, with_scores=True)
+
+    def refresh_cands(self, state, batch, config, cache, dirty_rows,
+                      dirty_valid):
+        """Re-score an aligned candidate cache against the dirty node
+        rows: (cand_key, cache)."""
+        k = min(_ba.CAND_K, state.capacity)
+        if self._sharded(state.capacity, batch):
+            return self._refresh_cands_sh(
+                state, batch, config, cache, dirty_rows, dirty_valid,
+                k=k, spread_bits=_ba.CAND_SPREAD_BITS)
+        return self._refresh_cands_one(
+            state, batch, config, cache, dirty_rows, dirty_valid,
+            k=k, spread_bits=_ba.CAND_SPREAD_BITS)
+
+    # koordlint: shape[state: NxR i32 nodes]
+    def pass1(self, state, batch, quota, cand_key, cand_node, config):
+        """First propose/accept pass over given candidates:
+        (assignments, state, quota, est_accum)."""
+        if self._sharded(state.capacity, batch):
+            return self._pass1_sh(state, batch, quota, cand_key,
+                                  cand_node, config, rounds=self.rounds)
+        return self._pass1_one(state, batch, quota, cand_key, cand_node,
+                               config, rounds=self.rounds)
+
+    # koordlint: shape[state: NxR i32 nodes, est_accum: NxR i32 nodes]
+    def pass2(self, state, est_accum, batch, quota, config):
+        """A later pass: full selection over a compacted leftover batch
+        against the est-usage-augmented state, then accept:
+        (assignments, state, quota, est_accum)."""
+        k = min(_ba.CAND_K, state.capacity)
+        if self._sharded(state.capacity, batch):
+            return self._pass2_sh(
+                state, est_accum, batch, quota, config, k=k,
+                rounds=self.rounds, spread_bits=_ba.CAND_SPREAD_BITS)
+        return self._pass2_one(
+            state, est_accum, batch, quota, config, k=k,
+            rounds=self.rounds, spread_bits=_ba.CAND_SPREAD_BITS,
+            method=self.method)

@@ -105,7 +105,6 @@ class TenantScheduler:
     def __init__(self, cycle_pod_budget: int = 4096,
                  pipeline: bool = True,
                  batch_tenant_axis: bool = True,
-                 mesh="auto", shard_min_nodes: int = 1024,
                  scheduler_defaults: dict | None = None,
                  solver_kit=None):
         from koordinator_tpu.scheduler.solver_kit import SolverKit
@@ -120,9 +119,7 @@ class TenantScheduler:
         self.scheduler_defaults = dict(scheduler_defaults or {})
         #: a passed kit is SHARED (e.g. bench_stages times serial vs
         #: pipelined fronts on one jit cache); otherwise build our own
-        self.kit = (solver_kit if solver_kit is not None
-                    else SolverKit(mesh=mesh,
-                                   shard_min_nodes=shard_min_nodes))
+        self.kit = solver_kit if solver_kit is not None else SolverKit()
         #: front-end lock: serializes cycles (SolveService acquires it
         #: the way it acquires a Scheduler's round lock)
         self.lock = threading.RLock()
@@ -449,20 +446,20 @@ class TenantScheduler:
         return jax.tree.map(lambda x: x[i], tree)
 
     def _batched_fn(self, key: tuple):
-        """The jitted tenant-axis program for one (k, spread, method,
-        rounds, has_quota) signature: vmap of candidate selection + the
+        """The jitted tenant-axis program for one (method, rounds,
+        has_quota) signature: vmap of candidate selection + the
         first propose/accept pass.  The stacked state is donated (it is
         a stacking COPY — the per-tenant originals stay live until each
         scheduler's blessed swap in round_adopt_batched)."""
         fn = self._batched_fns.get(key)
         if fn is not None:
             return fn
-        k, spread, method, rounds, has_quota = key
+        method, rounds, has_quota = key
         from koordinator_tpu.ops import batch_assign as ba
 
         def one_tenant(state, batch, quota, cfg):
             ck, cn, cs = ba.select_candidates(
-                state, batch, cfg, k=k, spread_bits=spread,
+                state, batch, cfg, k=min(ba.CAND_K, state.capacity),
                 method=method, with_scores=True)
             a, st, q, est = ba.assign_round_pass(
                 state, batch, quota, ck, cn, cfg, rounds=rounds)
@@ -530,19 +527,17 @@ class TenantScheduler:
                     # quality mode)
                     or (sched.forecast_mode != "off"
                         and sched.forecast_plane is not None)
-                    or (sched.mesh is not None
-                        and sched.snapshot.solver_sharding_active)):
+                    # the batched program is the single-device one
+                    or self.kit.sharding_active_for(
+                        sched.snapshot.capacity)):
                 return False
-            # the ONE batched program broadcasts tenant 0's config and
-            # solve knobs over the tenant axis: every live tenant must
-            # share them (config by IDENTITY — add_tenant hands tenants
-            # a shared default), or its slice would be solved with
-            # someone else's scoring and break per-tenant bit-identity
-            if (sched.config is not sched0.config
-                    or sched.cand_k != sched0.cand_k
-                    or sched.cand_spread != sched0.cand_spread
-                    or sched.cand_method != sched0.cand_method
-                    or sched.solve_rounds != sched0.solve_rounds):
+            # the ONE batched program broadcasts tenant 0's config over
+            # the tenant axis: every live tenant must share it (by
+            # IDENTITY — add_tenant hands tenants a shared default), or
+            # its slice would be solved with someone else's scoring and
+            # break per-tenant bit-identity.  The solve parameters are
+            # the shared kit's, so they cannot differ.
+            if sched.config is not sched0.config:
                 return False
             caps.add(sched.snapshot.capacity)
             pcaps.add(h.batch.capacity)
@@ -693,14 +688,8 @@ class TenantScheduler:
         batches = [h.batch for _, h in live]
         quotas = [h.quota for _, h in live]
         has_quota = quotas[0] is not None
-        sched0 = live[0][0].scheduler
-        n = sched0.snapshot.capacity
-        k = min(sched0.cand_k, n)
-        spread = sched0.cand_spread
-        method = ba.resolve_candidate_method(sched0.cand_method)
-        rounds = sched0.solve_rounds
-        cfg = sched0.config
-        fn = self._batched_fn((k, spread, method, rounds, has_quota))
+        cfg = live[0][0].scheduler.config
+        fn = self._batched_fn((self.kit.method, self.kit.rounds, has_quota))
         stacked_state = self._stack(states)
         stacked_batch = self._stack(batches)
         stacked_quota = self._stack(quotas) if has_quota else None
@@ -714,7 +703,7 @@ class TenantScheduler:
                 handle,
                 self._unstack(a, i), self._unstack(st, i),
                 self._unstack(q, i) if has_quota else None,
-                self._unstack(est, i), cache, k, method)
+                self._unstack(est, i), cache)
 
     def _quality_batched_fn(self, has_quota: bool):
         """The jitted quality tenant-axis program: vmap of the full

@@ -29,6 +29,7 @@ from koordinator_tpu.prediction.histogram import (
     percentile,
 )
 from koordinator_tpu.prediction.predictor import pod_reclaimable
+from koordinator_tpu.scheduler.solver_kit import SolverKit
 from koordinator_tpu.state.cluster_state import ClusterState, MAX_QUANTITY
 
 R = NUM_RESOURCE_DIMS
@@ -372,7 +373,8 @@ def _scheduler(mode="off", quota=False):
         snap.upsert_node(NodeSpec(
             name=f"n{i}",
             allocatable=resource_vector(cpu=16_000, memory=65_536)))
-    return Scheduler(snap, forecast_mode=mode, mesh=None, quota_tree=tree)
+    return Scheduler(snap, forecast_mode=mode,
+                     solver_kit=SolverKit(mesh=None), quota_tree=tree)
 
 
 def _enqueue(s, n=6, cpu=4_000):
@@ -477,7 +479,7 @@ class TestSchedulerForecastMode:
         from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
 
         s = Scheduler(ClusterSnapshot(capacity=8), forecast_mode="admit",
-                      mesh=None, tenant="t7")
+                      solver_kit=SolverKit(mesh=None), tenant="t7")
         plane = _fed_plane()
         s.attach_forecast_plane(plane)
         assert plane.metric_labels == {"tenant": "t7"}

@@ -37,6 +37,7 @@ from koordinator_tpu.ops.batch_assign import (
     select_candidates,
 )
 from koordinator_tpu.scheduler.scheduler import Scheduler
+from koordinator_tpu.scheduler.solver_kit import SolverKit
 from koordinator_tpu.scheduler.snapshot import (
     ClusterSnapshot,
     NodeSpec,
@@ -153,15 +154,17 @@ def test_refresh_matches_full_selection_random_deltas(seed):
                 ).all(axis=-1)[np.asarray(ist.node_valid)].all()
 
 
-def _mk_sched(incremental: bool, quota_tree=None, **kw):
+def _mk_sched(incremental: bool, quota_tree=None, mesh="off",
+              shard_min_nodes=1024, **kw):
     # mesh="off" keeps this module's parity pairs on the single-device
     # path; tests/test_sharded_solve.py overrides with mesh="auto" +
     # shard_min_nodes=0 to run the same drivers over the 8-way mesh
-    kw.setdefault("mesh", "off")
     sched = Scheduler(ClusterSnapshot(capacity=32),
                       quota_tree=quota_tree,
                       batch_solver_threshold=1,   # force the batch engine
                       incremental_solve=incremental,
+                      solver_kit=SolverKit(
+                          mesh=mesh, shard_min_nodes=shard_min_nodes),
                       **kw)
     return sched
 

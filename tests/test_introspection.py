@@ -16,6 +16,7 @@ from koordinator_tpu.api.resources import resource_vector
 from koordinator_tpu.ops import introspection as insp
 from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
 from koordinator_tpu.scheduler.snapshot import NodeSpec, PodSpec
+from koordinator_tpu.scheduler.solver_kit import SolverKit
 
 
 def recompile_totals() -> dict:
@@ -315,8 +316,8 @@ class TestShardedIntrospection:
         from koordinator_tpu.scheduler.services import debug_slo_body
 
         snap = ClusterSnapshot(capacity=64)
-        sched = Scheduler(snap, shard_min_nodes=0)
-        assert sched.solver_shard_count == len(jax.devices())
+        sched = Scheduler(snap, solver_kit=SolverKit(shard_min_nodes=0))
+        assert sched.kit.shards == len(jax.devices())
         report = sched.sharding_report()
         assert report["active"] and report["mesh"]["nodes"] == 8
         assert "cluster_state" in report["device_bytes_by_shard"]
@@ -325,7 +326,8 @@ class TestShardedIntrospection:
         body = debug_slo_body(sched)
         assert body["sharding"]["solver_shard_count"] == 8
         # mesh off => the report says so and the gauge path reads 1
-        single = Scheduler(ClusterSnapshot(capacity=64), mesh="off")
+        single = Scheduler(ClusterSnapshot(capacity=64),
+                           solver_kit=SolverKit(mesh="off"))
         rep = single.sharding_report()
         assert rep["solver_shard_count"] == 1 and rep["mesh"] is None
 
@@ -335,7 +337,7 @@ class TestShardedIntrospection:
             name="n0", allocatable=resource_vector(cpu=10_000,
                                                    memory=10_000)))
         sched = Scheduler(snap, batch_solver_threshold=1,
-                          shard_min_nodes=0)
+                          solver_kit=SolverKit(shard_min_nodes=0))
         sched.enqueue(PodSpec(
             name="p0", requests=resource_vector(cpu=100, memory=64)))
         sched.schedule_round()

@@ -339,9 +339,10 @@ class TestTenantAxisCorpus:
             corpus("tenant_axis", "bad", ("pkg",)))
         messages = "\n".join(f.message for f in findings)
         assert "still carries the leading tenant axis" in messages
-        # the kit-entry contract from the binding's shape annotation
-        assert "per-tenant contract" in messages
-        assert len(findings) == 5
+        # the kit-entry contract from the shape annotation: on a jit
+        # binding (argN) and on a kit method's named parameter
+        assert messages.count("per-tenant contract") == 2
+        assert len(findings) == 6
 
     def test_good_corpus_is_clean(self):
         # every slice _unstack'd (or [i]-indexed) before the sink

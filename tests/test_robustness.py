@@ -751,7 +751,7 @@ def test_degraded_forces_full_pass_over_incremental_cache():
     # small static round count: the propose/accept passes unroll per
     # round, and this test exercises PATH SELECTION, not solve quality —
     # 12 unrolled rounds would triple the jit compile for nothing
-    sched.solve_rounds = 2
+    sched.kit.rounds = 2
     for i in range(4):
         sched.snapshot.upsert_node(NodeSpec(
             name=f"n{i}",

@@ -189,16 +189,16 @@ def test_second_pass_places_a_leftover_pod():
     assert sched.gang_passes == 2
     # one propose/accept round a pass: every pod proposes the emptiest
     # node, the first in priority order takes it, the rest are left over
-    sched.solve_rounds = 1
+    sched.kit.rounds = 1
     second_pass = []
-    pass2 = sched._pass2
+    pass2 = sched.kit.pass2
 
     def spy(*args, **kwargs):
         out = pass2(*args, **kwargs)
         second_pass.append(np.asarray(out[0]))
         return out
 
-    sched._pass2 = spy
+    sched.kit.pass2 = spy
     for i, prio in enumerate((9_000, 8_000, 7_000)):
         sched.enqueue(pod(f"p{i}", cpu=10_000, priority=prio))
     res = sched.schedule_round()

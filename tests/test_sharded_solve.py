@@ -455,8 +455,8 @@ def test_scheduler_two_axis_end_to_end():
     sharded = _mk_sched(True, mesh=_mesh2d(2, 2), shard_min_nodes=0)
     single = _mk_sched(True, mesh="off")
     assert sharded.kit.pod_shards == 2
-    assert sharded.solver_shard_count == 2
-    assert sharded._solve_sh is not None
+    assert sharded.kit.shards == 2
+    assert sharded.kit.mesh is not None
     for sched in (sharded, single):
         sched.incremental_dirty_threshold = 1.0
     _feed_nodes(sharded, rng, n=12)
@@ -611,8 +611,8 @@ def test_scheduler_sharded_rounds_equal_single_device():
     rng = np.random.default_rng(3)
     sharded = _mk_sched(True, mesh="auto", shard_min_nodes=0)
     single = _mk_sched(True, mesh="off")
-    assert sharded.mesh is not None and sharded.solver_shard_count == 8
-    assert single.mesh is None
+    assert sharded.kit.mesh is not None and sharded.kit.shards == 8
+    assert single.kit.mesh is None
     for sched in (sharded, single):
         sched.incremental_dirty_threshold = 1.0
     rng2 = np.random.default_rng(3)
@@ -631,7 +631,7 @@ def test_scheduler_sharded_rounds_equal_single_device():
         assert set(ra.failures) == set(rb.failures), f"round {rnd}"
         if sharded.last_solve_path == "incremental":
             took_incremental = True
-    assert sharded.snapshot.solver_sharding_active
+    assert sharded.kit.sharding_active_for(sharded.snapshot.capacity)
     assert took_incremental, "incremental path never engaged while sharded"
     _assert_no_overcommit(sharded)
     np.testing.assert_array_equal(

@@ -223,13 +223,13 @@ def test_delta_between_dispatch_and_collect_lands_in_adopted_state(
 def test_fold_keeps_node_axis_sharding_and_the_single_device_sum(capacity):
     import jax
 
-    from koordinator_tpu.parallel import mesh as pmesh
+    from koordinator_tpu.scheduler.solver_kit import SolverKit
 
-    mesh = pmesh.solver_mesh()
-    sharding = pmesh.node_sharding(mesh)
+    kit = SolverKit(shard_min_nodes=0)
+    sharding = kit.node_sharding
     sharded, single = ClusterSnapshot(capacity), ClusterSnapshot(capacity)
-    sharded.set_solver_sharding(sharding, len(jax.devices()))
-    assert sharded.solver_sharding_active
+    sharded.set_state_placement(kit.place)
+    assert kit.sharding_active_for(sharded.capacity)
     rng = np.random.default_rng(capacity)
     names = [f"n{i}" for i in range(capacity - 3)]
     for snap in (sharded, single):

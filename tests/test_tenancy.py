@@ -217,7 +217,8 @@ class TestPipelineBitIdentity:
             _seed_tenants(front, base=3)
             for t in front.tenants():
                 t.scheduler.incremental_dirty_threshold = 1.0
-                assert t.scheduler.snapshot.solver_sharding_active
+                assert kit_mesh.sharding_active_for(
+                    t.scheduler.snapshot.capacity)
         assert _binds(serial.schedule_cycle()) == \
             _binds(piped.schedule_cycle())
         _delta_tenants(serial, base=4)
@@ -410,8 +411,7 @@ class TestSurfaces:
             gateway.stop()
         assert gw_body == body
 
-        lone = Scheduler(ClusterSnapshot(capacity=16), mesh="off",
-                         solver_kit=kit_off)
+        lone = Scheduler(ClusterSnapshot(capacity=16), solver_kit=kit_off)
         assert DebugService(lone).handle("/debug/tenants")[0] == 501
 
     def test_flight_records_stamp_tenant_and_half(self, kit_off):
@@ -494,12 +494,12 @@ class TestSharedSolverKit:
         a = front.tenant("a").scheduler
         b = front.tenant("b").scheduler
         assert a.kit is b.kit is kit_off
-        assert a._pass1 is b._pass1
-        assert a._solve is b._solve
+        assert a.kit.pass1 == b.kit.pass1
+        assert a.kit.solve == b.kit.solve
 
     def test_standalone_scheduler_builds_its_own_kit(self):
         from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
 
-        s1 = Scheduler(ClusterSnapshot(capacity=16), mesh="off")
-        s2 = Scheduler(ClusterSnapshot(capacity=16), mesh="off")
+        s1 = Scheduler(ClusterSnapshot(capacity=16))
+        s2 = Scheduler(ClusterSnapshot(capacity=16))
         assert s1.kit is not s2.kit     # the pre-tenancy default

@@ -91,8 +91,7 @@ def _enqueue_pods(scheduler, n, seed=0):
 def _lone_scheduler(kit, capacity=32, seed=3):
     from koordinator_tpu.scheduler import ClusterSnapshot, Scheduler
 
-    sched = Scheduler(ClusterSnapshot(capacity=capacity), mesh="off",
-                      solver_kit=kit)
+    sched = Scheduler(ClusterSnapshot(capacity=capacity), solver_kit=kit)
     _feed_nodes(sched, seed=seed)
     return sched
 
@@ -706,7 +705,7 @@ class _Served:
         unbounded = np.full(len(_vector(0, 0)), UNBOUNDED, np.int64)
         tree.add("team", min=np.zeros_like(unbounded), max=unbounded)
         self.scheduler = Scheduler(
-            ClusterSnapshot(capacity=capacity), mesh="off", solver_kit=kit,
+            ClusterSnapshot(capacity=capacity), solver_kit=kit,
             quota_tree=tree, explanations=ExplanationStore(),
             auditor=WorkloadAuditor())
         self.server = RpcServer(sock)
@@ -831,7 +830,7 @@ class TestSpansOfTheServedPath:
         from koordinator_tpu.scheduler.snapshot import PodSpec
 
         store, auditor = ExplanationStore(), WorkloadAuditor()
-        sched = Scheduler(ClusterSnapshot(capacity=32), mesh="off",
+        sched = Scheduler(ClusterSnapshot(capacity=32),
                           solver_kit=kit_off, explanations=store,
                           auditor=auditor)
         _feed_nodes(sched, seed=3)

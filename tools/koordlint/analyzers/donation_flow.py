@@ -9,12 +9,15 @@ runs the specflow dataflow over the whole call graph:
 
 - **binding resolution through the kit.**  Donating jit bindings are
   found not just at ``self._x = jax.jit(...)`` sites but through typed
-  attributes (``self.kit = SolverKit(...)`` ⇒ ``self._pass1 =
-  self.kit.pass1`` inherits SolverKit.pass1's donate_argnums), local
-  aliases (``pass1_fn = self._pass1_sh if use_mesh else self._pass1``
-  donates the union), and factory summaries (a function whose return
-  value is a donating jit — tenancy's ``_batched_fn`` — makes
-  ``fn = self._batched_fn(key); fn(state, ...)`` a donating call).
+  attributes (``self.kit = SolverKit(...)`` ⇒ ``self.kit.pass1(...)``
+  and the alias ``self._rsv_solve = self.kit.rsv_solve`` resolve in
+  SolverKit), donating-parameter summaries (a function that hands its
+  parameter N to a donating position donates its parameter N — the
+  kit's one-entry-per-stage methods, which pick between two jitted
+  programs, donate ``state`` either way), and factory summaries (a
+  function whose return value is a donating jit — tenancy's
+  ``_batched_fn`` — makes ``fn = self._batched_fn(key); fn(state,
+  ...)`` a donating call).
 - **⊥ after dispatch.**  A donated argument path's abstract value
   becomes ⊥ (dead) at the call; a *store* to the same path (the blessed
   swap) revives it.  Any load of a dead path — directly, or through a
@@ -59,6 +62,9 @@ class Summary:
     kills: frozenset[str] = frozenset()        # dead at exit
     reads_first: frozenset[str] = frozenset()  # read before any store
     stores_first: frozenset[str] = frozenset()  # stored before any read
+    #: own parameters (``self`` not counted) handed on at a donating
+    #: position: a call of this function donates those arguments
+    donates: tuple[int, ...] = ()
 
 
 class DonationFlowAnalyzer(Analyzer):
@@ -214,19 +220,33 @@ class DonationFlowAnalyzer(Analyzer):
             dedup.setdefault((f.path, f.line, f.message), f)
         return sorted(dedup.values(), key=lambda f: (f.path, f.line))
 
-    def _donated_positions(self, index, fn, cls, call,
-                           local_callables) -> tuple[int, ...]:
+    def _donated_positions(self, index, fn, cls, call, local_callables,
+                           summaries) -> tuple[int, ...]:
         f = call.func
         if isinstance(f, ast.Name) and f.id in local_callables:
             return local_callables[f.id]
-        if (isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
-                and f.value.id == "self" and cls):
-            return self._class_b.get((f"{fn.module}.{cls}", f.attr), ())
+        if isinstance(f, ast.Attribute) and cls:
+            # self.x(...) in the own class; self.kit.x(...) in the class
+            # of the typed attribute
+            owner = None
+            if isinstance(f.value, ast.Name) and f.value.id == "self":
+                owner = f"{fn.module}.{cls}"
+            elif (isinstance(f.value, ast.Attribute)
+                  and isinstance(f.value.value, ast.Name)
+                  and f.value.value.id == "self"):
+                owner = self._attr_cls.get(
+                    (f"{fn.module}.{cls}", f.value.attr))
+            if owner:
+                method = summaries.get(f"{owner}.{f.attr}")
+                return (self._class_b.get((owner, f.attr))
+                        or (method.donates if method else ()))
         resolved = index.resolve(fn.module, f)
         if resolved:
             if "." not in resolved:
                 resolved = f"{fn.module}.{resolved}"
-            return self._name_b.get(resolved, ())
+            target = summaries.get(resolved)
+            return (self._name_b.get(resolved)
+                    or (target.donates if target else ()))
         return ()
 
     def _callee_fq(self, index, fn, cls, call) -> Optional[str]:
@@ -251,6 +271,11 @@ class DonationFlowAnalyzer(Analyzer):
         dead: dict[str, int] = {}           # path -> donating line
         dead_names: set[str] = set()
         first_event: dict[str, str] = {}    # path -> "read" | "store"
+        params = [a.arg for a in
+                  fn.node.args.posonlyargs + fn.node.args.args]
+        if cls and params[:1] in (["self"], ["cls"]):
+            params = params[1:]
+        donates: set[int] = set()
 
         def canon(path: Optional[str]) -> Optional[str]:
             if path is None:
@@ -308,6 +333,24 @@ class DonationFlowAnalyzer(Analyzer):
                     return False
             return False
 
+        def own_param(call: ast.Call, arg: ast.AST) -> Optional[int]:
+            """Index of the own parameter ``arg`` still names at
+            ``call``: a bare parameter name not assigned in an earlier
+            statement (``state = f(state)`` itself is the hand-on)."""
+            if not (isinstance(arg, ast.Name) and arg.id in params):
+                return None
+            stmt: ast.AST = call
+            while stmt in parents and not isinstance(stmt, ast.stmt):
+                stmt = parents[stmt]
+            for line, _, kind, node in events:
+                if line >= stmt.lineno:
+                    break
+                if kind == "assign" and any(
+                        isinstance(t, ast.Name) and t.id == arg.id
+                        for tgt in node.targets for t in ast.walk(tgt)):
+                    return None
+            return params.index(arg.id)
+
         def report(line: int, msg: str, hint: str) -> None:
             if findings is not None:
                 findings.append(Finding(self.name, fn.sf.path, line,
@@ -322,11 +365,14 @@ class DonationFlowAnalyzer(Analyzer):
             elif kind == "call":
                 end = getattr(node, "end_lineno", line)
                 donated = self._donated_positions(
-                    index, fn, cls, node, local_callables)
+                    index, fn, cls, node, local_callables, summaries)
                 if donated:
                     for pos in donated:
                         if pos >= len(node.args):
                             continue
+                        own = own_param(node, node.args[pos])
+                        if own is not None:
+                            donates.add(own)
                         p = canon(dotted_path(node.args[pos]))
                         if p is None:
                             continue
@@ -416,7 +462,8 @@ class DonationFlowAnalyzer(Analyzer):
             reads_first=frozenset(p for p, k in first_event.items()
                                   if k == "read"),
             stores_first=frozenset(p for p, k in first_event.items()
-                                   if k == "store"))
+                                   if k == "store"),
+            donates=tuple(sorted(donates)))
 
     def _handle_assign(self, index, fn, node, prefix_alias, stash_alias,
                        local_callables, dead, dead_names, first_event,
@@ -424,8 +471,8 @@ class DonationFlowAnalyzer(Analyzer):
         if len(node.targets) != 1:
             return
         target = node.targets[0]
-        # donating-callable locals: jax.jit directly, a self-binding, a
-        # ternary of self-bindings, or a factory call
+        # donating-callable locals: jax.jit directly, a self-binding, or
+        # a factory call
         if isinstance(target, ast.Name):
             d = self._local_callable(index, fn, node.value)
             if d:
@@ -462,8 +509,6 @@ class DonationFlowAnalyzer(Analyzer):
         cls = fn.qualname.rsplit(".", 1)[0] if "." in fn.qualname else None
 
         def of(node) -> tuple[int, ...]:
-            if isinstance(node, ast.IfExp):
-                return tuple(sorted(set(of(node.body) + of(node.orelse))))
             if (isinstance(node, ast.Attribute)
                     and isinstance(node.value, ast.Name)
                     and node.value.id == "self" and cls):

@@ -178,6 +178,11 @@ class DonationSafetyAnalyzer(Analyzer):
                         f"also passed at position {j}: XLA would alias "
                         "one buffer to both",
                         "pass an independent copy, or drop the donation"))
+        if self._returned(fn, call):
+            # `return f(x, ...)`: nothing after it runs on this path (an
+            # entry that picks one of two donating programs returns
+            # from each arm)
+            return findings
         end = getattr(call, "end_lineno", call.lineno)
         for pos, p in paths.items():
             if self._rebinds(fn, call, p):
@@ -185,6 +190,14 @@ class DonationSafetyAnalyzer(Analyzer):
                 # immediately rebound to the result — the intended idiom
             findings += self._reads_after(fn, p, end, call.lineno)
         return findings
+
+    def _returned(self, fn, call: ast.Call) -> bool:
+        """Is the statement holding the donating call a ``return``?"""
+        parents = self._parents(fn)
+        node: ast.AST = call
+        while node in parents and not isinstance(node, ast.stmt):
+            node = parents[node]
+        return isinstance(node, ast.Return)
 
     def _rebinds(self, fn, call: ast.Call, path: str) -> bool:
         """Does the statement holding the donating call assign the

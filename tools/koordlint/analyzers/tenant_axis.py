@@ -19,8 +19,9 @@ over another's).  specflow tracks the tenant axis as a taint:
 
 Findings fire when a stacked value reaches a per-tenant sink: the
 configured sink names (``round_adopt_batched``), or a SolverKit entry
-whose binding carries a per-tenant ``shape`` annotation (``argN`` dims
-not T-leading) — the kit's compiled programs are per-tenant contracts,
+that carries a per-tenant ``shape`` annotation (dims not T-leading, on
+the method's named parameter or a binding's ``argN``) — the kit's
+compiled programs are per-tenant contracts,
 and feeding them a stacked tensor solves every tenant with tenant 0's
 capacity row.  Scoped to the tenancy front-end module(s).
 """
@@ -66,10 +67,19 @@ class TenantAxisAnalyzer(Analyzer):
 
     def _kit_contracts(self, index) -> dict[str, set[int]]:
         """``attr -> per-tenant arg positions`` from ``shape``
-        annotations on ``self.<attr> = ...`` jit-binding assigns whose
-        ``argN`` dims are NOT T-leading (the SolverKit entry-point
-        seeds the issue names)."""
+        annotations whose dims are NOT T-leading: ``argN`` on a
+        ``self.<attr> = ...`` jit-binding assign, or a named parameter
+        on a method (the SolverKit's one-entry-per-stage methods)."""
         out: dict[str, set[int]] = {}
+        for fn in index.functions.values():
+            if "." not in fn.qualname:
+                continue
+            params = [a.arg for a in fn.node.args.args][1:]   # less self
+            for name, seed in shape_seeds_for(fn.sf, fn.node).items():
+                if (name in params and seed.dims is not None
+                        and seed.dims[0] != "T"):
+                    out.setdefault(fn.node.name, set()).add(
+                        params.index(name))
         for mod, sf in index.modules.items():
             if sf.tree is None or "koordlint: shape" not in sf.text:
                 continue

@@ -16,21 +16,29 @@ def _pass1_impl(state, batch):
 
 class SolverKit:
     def __init__(self):
-        self.pass1 = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self._pass1_one = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self._pass1_sh = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self.sharded = False
+
+    def pass1(self, state, batch):
+        # one entry per stage: the kit picks the program, and either
+        # one donates the caller's ``state``
+        if self.sharded:
+            return self._pass1_sh(state, batch)
+        return self._pass1_one(state, batch)
 
 
 class Pipeline:
     def __init__(self, snapshot):
+        # the typed kit attribute: a call of ``self.kit.pass1`` donates
+        # through the entry's donating-parameter summary
         self.kit = SolverKit()
-        # binding alias through the typed kit attribute — donation
-        # contracts must survive this hop
-        self.solve = self.kit.pass1
         self.snapshot = snapshot
 
     def dispatch_without_swap(self, batch):
         # BAD: donates snapshot.state and never re-points it — the
         # buffer is dead at exit and every caller inherits ⊥
-        a, _ = self.solve(self.snapshot.state, batch)
+        a, _ = self.kit.pass1(self.snapshot.state, batch)
         return a
 
     def round(self, batch):
@@ -45,7 +53,7 @@ class Pipeline:
         # BAD (the tenancy anti-idiom): the pre-dispatch stash keeps
         # pointing at the consumed buffer even after the blessed swap
         old = self.snapshot.state
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         self.snapshot.state = new_state
         return old.mean(), a
 
@@ -54,7 +62,7 @@ class Pipeline:
         # store, so `snap.state = ...` is NOT the blessed swap — the
         # real self.snapshot.state stays dead at the read
         snap = self.snapshot
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         snap = fresh
         snap.state = new_state
         return self.snapshot.state, a

@@ -9,19 +9,27 @@ def _pass1_impl(state, batch):
 
 class SolverKit:
     def __init__(self):
-        self.pass1 = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self._pass1_one = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self._pass1_sh = jax.jit(_pass1_impl, donate_argnums=(0,))
+        self.sharded = False
+
+    def pass1(self, state, batch):
+        # one entry per stage: the kit picks the program, and either
+        # one donates the caller's ``state``
+        if self.sharded:
+            return self._pass1_sh(state, batch)
+        return self._pass1_one(state, batch)
 
 
 class Pipeline:
     def __init__(self, snapshot):
         self.kit = SolverKit()
-        self.solve = self.kit.pass1
         self.snapshot = snapshot
 
     def dispatch(self, batch):
         # the blessed swap: re-point the snapshot at the in-flight
         # result before anything can read the dead buffers
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         self.snapshot.state = new_state
         return a
 
@@ -34,7 +42,7 @@ class Pipeline:
         return self.snapshot.state, a
 
     def metadata_survives(self, batch):
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         rows = self.snapshot.state.shape  # metadata outlives donation
         self.snapshot.state = new_state
         return a, rows
@@ -42,14 +50,14 @@ class Pipeline:
     def swap_through_method(self, batch):
         # the swap may live inside the owning object's method
         # (Scheduler._reservation_prepass adopts through the snapshot)
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         self.snapshot.adopt_state(new_state)
         return self.snapshot.state, a
 
     def rebind_idiom(self, state, batch):
         # `x = f(x, ...)`: the donated name is dead and immediately
         # rebound to the result — the intended idiom
-        batch2, state = self.solve(state, batch)
+        batch2, state = self.kit.pass1(state, batch)
         return state, batch2
 
     def rebound_alias_is_fresh(self, batch, fresh):
@@ -57,7 +65,7 @@ class Pipeline:
         # different object before the read: its attrs are not the dead
         # path (the alias map must drop the binding at the rebind)
         snap = self.snapshot
-        a, new_state = self.solve(self.snapshot.state, batch)
+        a, new_state = self.kit.pass1(self.snapshot.state, batch)
         snap = fresh
         scratch = snap.state
         self.snapshot.state = new_state

@@ -1,6 +1,7 @@
 """Seeded known-GOOD corpus for donation-safety: the intended idioms —
 one fresh buffer per pytree field, immediate rebind of the donated
-name, metadata reads after donation, reads before the call."""
+name, metadata reads after donation, reads before the call, one donating
+call returned from each arm of a choice."""
 import jax
 import jax.numpy as jnp
 from flax import struct
@@ -25,6 +26,7 @@ def _solve(state, batch):
 
 
 solve = jax.jit(_solve, donate_argnums=(0,))
+solve_wide = jax.jit(_solve, donate_argnums=(0,))
 
 
 class Scheduler:
@@ -37,6 +39,13 @@ class Scheduler:
         self.state = solve(self.state, self.batch)  # ok: rebind idiom
         n = self.state.shape[0]           # ok: reads the NEW buffer
         return before, n
+
+    def either_program(self, state, wide):
+        # ok: each arm returns its donating call, so the second arm's
+        # read of `state` never follows the first arm's donation
+        if wide:
+            return solve_wide(state, self.batch)
+        return solve(state, self.batch)
 
     def rebind_local(self):
         state = self.state

@@ -12,6 +12,16 @@ class Kit:
     def __init__(self):
         # koordlint: shape[arg0: NxR i32 nodes]
         self.pass1 = jax.jit(_pass1, donate_argnums=(0,))
+        self._pass2_one = jax.jit(_pass1, donate_argnums=(0,))
+        self._pass2_sh = jax.jit(_pass1, donate_argnums=(0,))
+        self.sharded = False
+
+    # koordlint: shape[state: NxR i32 nodes]
+    def pass2(self, state, batch):
+        # one entry per stage: the contract sits on the method
+        if self.sharded:
+            return self._pass2_sh(state, batch)
+        return self._pass2_one(state, batch)
 
 
 class Front:
@@ -37,6 +47,12 @@ class Front:
         # BAD: the kit binding's shape annotation declares a per-tenant
         # arg0 but the call hands it the whole stacked tensor
         return kit.pass1(stacked_state, batches)
+
+    def cycle_kit_entry(self, states, batches, kit):
+        stacked_state = self._stack(states)
+        # BAD: the same through a kit METHOD whose named parameter
+        # carries the per-tenant annotation
+        return kit.pass2(stacked_state, batches)
 
     # koordlint: shape[state: TxNxR i32]
     def adopt_annotated(self, state, tenants):

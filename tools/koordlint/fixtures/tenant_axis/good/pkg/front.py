@@ -12,6 +12,16 @@ class Kit:
     def __init__(self):
         # koordlint: shape[arg0: NxR i32 nodes]
         self.pass1 = jax.jit(_pass1, donate_argnums=(0,))
+        self._pass2_one = jax.jit(_pass1, donate_argnums=(0,))
+        self._pass2_sh = jax.jit(_pass1, donate_argnums=(0,))
+        self.sharded = False
+
+    # koordlint: shape[state: NxR i32 nodes]
+    def pass2(self, state, batch):
+        # one entry per stage: the contract sits on the method
+        if self.sharded:
+            return self._pass2_sh(state, batch)
+        return self._pass2_one(state, batch)
 
 
 class Front:
@@ -37,6 +47,7 @@ class Front:
         for i, state in enumerate(states):
             # per-tenant dispatch feeds per-tenant shapes
             kit.pass1(state, batches[i])
+            kit.pass2(state, batches[i])
 
     # koordlint: shape[state: TxNxR i32]
     def adopt_annotated(self, state, tenants):
