@@ -773,6 +773,14 @@ sync_binding_backlog_peak = TRANSPORT.gauge(
     "High-water mark of the local-binding backlog since process start "
     "(the watermark the steady-state soak bounds and the trend engine "
     "watches)")
+sync_delta_frames_total = TRANSPORT.counter(
+    "sync_delta_frames_total",
+    "Committed deltasync events by what became of their DELTA frame "
+    "(label: outcome=built|no_recipient — built means a live watcher "
+    "was connected and the frame was packed and encoded for it, once "
+    "per wire form; no_recipient that nobody was, so nothing was "
+    "built: a later HELLO serves the event from the log or the "
+    "snapshot).  Counted only where a server is attached")
 sync_resyncs_total = TRANSPORT.counter(
     "sync_resyncs_total",
     "Server-requested resyncs honored by a reconnecting client (ERROR "

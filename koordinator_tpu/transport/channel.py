@@ -505,20 +505,26 @@ class RpcServer:
             if conn in self._conns:
                 self._conns.remove(conn)
 
-    def broadcast(self, ftype: FrameType, doc: dict,
-                  arrays: dict[str, np.ndarray] | None = None,
-                  min_proto: int = 0, legacy=None) -> int:
+    def broadcast(self, ftype: FrameType, payload, min_proto: int = 0,
+                  legacy=None) -> int:
         """Push a frame (request_id 0 = unsolicited) to all live
         connections — the informer watch-event fan-out. Never blocks:
         frames go through each connection's bounded queue.
 
+        ``payload`` and ``legacy`` are zero-arg callables returning
+        ``(doc, arrays)``, each called at most once and only when the
+        loop meets a live connection that is to receive its frame: a
+        message nobody is connected to receive is never packed or
+        encoded (a late watcher is served from the delta log or the
+        snapshot at its HELLO).  Returns the number of connections sent
+        to; 0 means neither callable ran.
+
         Mixed-version fan-out: when ``min_proto`` > 0, only peers that
         negotiated at least that message protocol get the primary
         payload; older peers (including never-HELLO'd ones at proto 0)
-        get the ``legacy`` payload instead — a zero-arg callable
-        returning ``(doc, arrays)``, encoded LAZILY so an all-v2 fleet
-        never pays the v1 encode.  ``legacy=None`` with ``min_proto``
-        set skips old peers entirely (their resync machinery recovers)."""
+        get the ``legacy`` payload instead, so an all-v2 fleet never
+        pays the v1 encode.  ``legacy=None`` with ``min_proto`` set
+        skips old peers entirely (their resync machinery recovers)."""
         frame: Optional[Frame] = None
         legacy_frame: Optional[Frame] = None
         with self._conn_lock:
@@ -531,13 +537,12 @@ class RpcServer:
                 if legacy is None:
                     continue
                 if legacy_frame is None:
-                    ldoc, larrays = legacy()
                     legacy_frame = Frame(
-                        ftype, 0, encode_payload(ldoc, larrays))
+                        ftype, 0, encode_payload(*legacy()))
                 conn.send(legacy_frame)
             else:
                 if frame is None:
-                    frame = Frame(ftype, 0, encode_payload(doc, arrays))
+                    frame = Frame(ftype, 0, encode_payload(*payload()))
                 conn.send(frame)
             sent += 1
         return sent

@@ -309,6 +309,10 @@ def _validate_devices(devices: dict | None, context: str) -> None:
                         f"field {field!r} must be an integer")
 
 
+_FRAME_BUILT = {"outcome": "built"}
+_FRAME_NO_RECIPIENT = {"outcome": "no_recipient"}
+
+
 class StateSyncService:
     """Informer-side state authority + wire handlers.
 
@@ -374,8 +378,8 @@ class StateSyncService:
         broadcast only enqueues to bounded per-connection queues — a
         stalled peer drops frames and gets poisoned, it cannot wedge the
         service (channel._Conn.send)."""
-        # one sync.store span per event (store, delta log, v2 pack and
-        # broadcast, local apply); the sync.<kind> applies nest under it
+        # one sync.store span per event (store, delta log, broadcast,
+        # local apply); the sync.<kind> applies nest under it
         tl_t0 = timeline.RECORDER.open("sync.store")
         try:
             with self._lock:
@@ -413,20 +417,20 @@ class StateSyncService:
         rv = self.rv
         self.log.append(rv, event, arrays)
         if self._server is not None:
+            # the DELTA frame is built by the first live connection that
+            # is to receive it (RpcServer.broadcast calls these at most
+            # once each): the columnar frame for v4+ peers, the v1 frame
+            # for negotiated-down ones, and for a kind without a code
+            # (_pack_events_v2 -> None) the v1 form for everyone.  With
+            # no watcher connected nothing is packed; one that connects
+            # later gets the event from the log or the snapshot
             batch = [(rv, event, arrays)]
-            packed = _pack_events_v2(batch)
-            if packed is None:
-                # unknown kind: everyone gets the v1 JSON form
-                doc, stacked = _pack_events(batch)
-                self._server.broadcast(FrameType.DELTA, doc, stacked)
-            else:
-                # columnar frame to v4+ peers; legacy encodes the v1
-                # frame lazily, ONLY if some negotiated-down peer is
-                # actually connected (a pure-v4 fleet never pays it)
-                doc, stacked = packed
-                self._server.broadcast(
-                    FrameType.DELTA, doc, stacked, min_proto=4,
-                    legacy=lambda: _pack_events(batch))
+            sent = self._server.broadcast(
+                FrameType.DELTA,
+                lambda: _pack_events_v2(batch) or _pack_events(batch),
+                min_proto=4, legacy=lambda: _pack_events(batch))
+            metrics.sync_delta_frames_total.inc(
+                labels=_FRAME_BUILT if sent else _FRAME_NO_RECIPIENT)
         if self._local_bindings:
             self._binding_queue.append((event, arrays))
             # backlog watermark (ISSUE 9): depth sampled at append (the

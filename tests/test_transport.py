@@ -19,7 +19,12 @@ from koordinator_tpu.transport import (
     StateSyncClient,
     StateSyncService,
 )
-from koordinator_tpu.transport.deltasync import DeltaLog, ResyncRequired, SchedulerBinding
+from koordinator_tpu.transport.deltasync import (
+    DeltaLog,
+    ResyncRequired,
+    SchedulerBinding,
+    _pack_events,
+)
 from koordinator_tpu.transport.services import (
     HookService,
     SolveService,
@@ -27,6 +32,8 @@ from koordinator_tpu.transport.services import (
     solve_remote,
 )
 from koordinator_tpu.transport.wire import (
+    MIN_PROTOCOL_VERSION,
+    PROTOCOL_VERSION,
     FrameType,
     decode_payload,
     encode_payload,
@@ -1112,3 +1119,214 @@ def test_conn_close_with_full_queue_does_not_leak_sender_thread():
     sender.join(5)
     assert not sender.is_alive(), \
         "sender thread leaked: blocked on queue.get() with no poison"
+
+
+# -- the DELTA frame of an event is built for its first live recipient ------
+
+
+def _count_packs(monkeypatch):
+    """Count the calls of the two event codecs (module globals, so the
+    commit's closures and the HELLO handler both go through the wrappers)."""
+    from koordinator_tpu.transport import deltasync
+
+    calls = {"v2": 0, "v1": 0}
+    pack_v2, pack_v1 = deltasync._pack_events_v2, deltasync._pack_events
+
+    def counted_v2(events):
+        calls["v2"] += 1
+        return pack_v2(events)
+
+    def counted_v1(events):
+        calls["v1"] += 1
+        return pack_v1(events)
+
+    monkeypatch.setattr(deltasync, "_pack_events_v2", counted_v2)
+    monkeypatch.setattr(deltasync, "_pack_events", counted_v1)
+    return calls, pack_v2, pack_v1
+
+
+def _frame_counts():
+    from koordinator_tpu import metrics
+
+    return {labels["outcome"]: int(value)
+            for labels, value in metrics.sync_delta_frames_total.items()}
+
+
+def _mutate(service, mix: str, n: int) -> None:
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    for i in range(n - 1):
+        kind = mix if mix != "mixed" else ("pods", "usage", "removes")[i % 3]
+        if kind == "pods":
+            service.add_pod(f"p{i}", resource_vector(cpu=100 + i, memory=64),
+                            priority=i % 3, labels={"app": f"a{i % 2}"})
+        elif kind == "usage":
+            service.update_node_usage(
+                "n0", resource_vector(cpu=10 * i, memory=i))
+        else:
+            service.remove_pod(f"p{i}")
+
+
+@pytest.mark.parametrize("mix", ["pods", "usage", "mixed"])
+def test_event_with_no_watcher_builds_no_delta_frame(rpc, monkeypatch, mix):
+    """A server is attached and listening but nobody is connected: N
+    mutations pack nothing, the log holds all N, and a watcher that says
+    HELLO afterwards is served every one of them from the log."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    calls, pack_v2, _ = _count_packs(monkeypatch)
+    n = 40
+    _mutate(service, mix, n)
+    assert calls == {"v2": 0, "v1": 0}
+    assert _frame_counts() == {"no_recipient": n}
+    assert service.rv == n
+    assert [rv for rv, _, _ in service.log.since(0)] == list(range(1, n + 1))
+
+    client = connect(server, clients)
+    ftype, doc, arrays = client.call(
+        FrameType.HELLO, {"last_rv": 0, "proto": PROTOCOL_VERSION})
+    assert ftype is FrameType.DELTA
+    want_doc, want_arrays = pack_v2(service.log.since(0))
+    assert doc == dict(want_doc, rv=n, proto=PROTOCOL_VERSION,
+                       instance=service.instance)
+    assert sorted(arrays) == sorted(want_arrays)
+    for key, want in want_arrays.items():
+        assert arrays[key].dtype == want.dtype
+        assert np.array_equal(arrays[key], want)
+
+
+@pytest.mark.parametrize("peers, packs, outcome", [
+    (("v4", "v3"), {"v2": 1, "v1": 1}, "built"),
+    (("v4",), {"v2": 1, "v1": 0}, "built"),
+    (("v3",), {"v2": 0, "v1": 1}, "built"),
+    (("no_hello",), {"v2": 0, "v1": 1}, "built"),
+    (("v4", "v4", "v3", "v3"), {"v2": 1, "v1": 1}, "built"),
+    (("dead",), {"v2": 0, "v1": 0}, "no_recipient"),
+    (("dead", "v4"), {"v2": 1, "v1": 0}, "built"),
+], ids=lambda v: "+".join(v) if isinstance(v, tuple) else None)
+def test_connected_peers_get_the_eager_paths_bytes(rpc, monkeypatch, peers,
+                                                   packs, outcome):
+    """Every recipient connected at an event receives, in rv order, the
+    bytes the eager path sent: the columnar frame at proto >= 4, the v1
+    frame below it (a peer that never said HELLO included), each packed
+    once per event however many peers share it.  A connection that is
+    still listed but no longer alive receives and builds nothing."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    got: dict[int, list[tuple]] = {}
+    for i, peer in enumerate(peers):
+        got[i] = []
+        client = connect(
+            server, clients,
+            on_push=lambda frame, i=i: got[i].append(
+                (frame.type, frame.request_id, frame.payload)))
+        if peer in ("v4", "v3"):
+            client.call(FrameType.HELLO, {
+                "last_rv": -1, "proto": (PROTOCOL_VERSION if peer == "v4"
+                                         else MIN_PROTOCOL_VERSION)})
+        # listed before the next peer dials, so the list is in dial order
+        wait_until(lambda: len(server._conns) == i + 1)
+    for conn, peer in zip(list(server._conns), peers):
+        if peer == "dead":
+            conn.alive = False
+    calls, pack_v2, pack_v1 = _count_packs(monkeypatch)
+    n = 12
+    _mutate(service, "mixed", n)
+    assert calls == {k: v * n for k, v in packs.items()}
+    assert _frame_counts() == {outcome: n}
+
+    batches = [[event] for event in service.log.since(0)]
+    want = {
+        "v4": [(FrameType.DELTA, 0, encode_payload(*pack_v2(b)))
+               for b in batches],
+        "v3": [(FrameType.DELTA, 0, encode_payload(*pack_v1(b)))
+               for b in batches],
+        "dead": [],
+    }
+    want["no_hello"] = want["v3"]
+    for i, peer in enumerate(peers):
+        wait_until(lambda: len(got[i]) >= len(want[peer]))
+        assert got[i] == want[peer], f"peer {i} ({peer})"
+
+
+def test_event_of_a_kind_without_a_code_reaches_every_peer_as_v1(rpc):
+    """_pack_events_v2 answers None for a kind it has no code for: v4
+    and v3 peers alike then get the v1 frame, still built on demand."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    event = {"kind": "mystery_kind", "name": "x", "payload": {"a": 1}}
+    arrays = {"vec": np.arange(4, dtype=np.int32)}
+    service._store_and_commit(lambda: None, dict(event), arrays)
+    assert _frame_counts() == {"no_recipient": 1}
+
+    got: dict[int, list[bytes]] = {0: [], 1: []}
+    for i, proto in enumerate((PROTOCOL_VERSION, MIN_PROTOCOL_VERSION)):
+        client = connect(
+            server, clients,
+            on_push=lambda frame, i=i: got[i].append(frame.payload))
+        client.call(FrameType.HELLO, {"last_rv": 1, "proto": proto})
+    service._store_and_commit(lambda: None, dict(event), arrays)
+    want = encode_payload(*_pack_events(service.log.since(1)))
+    assert b'"events"' in want and b"events_v2" not in want
+    for i in got:
+        wait_until(lambda: len(got[i]) == 1)
+        assert got[i] == [want]
+    assert _frame_counts() == {"no_recipient": 1, "built": 1}
+
+
+@pytest.mark.parametrize("attached", [False, True],
+                         ids=["no_server", "server_no_watcher"])
+def test_backlog_peak_after_a_burst_from_two_threads(rpc, attached):
+    """The binding-backlog watermark is what it was: one pusher parked
+    inside a binding apply, two more threads each commit an event behind
+    it (depth 1, then 2) and wait for the binding lock; once the gate
+    opens everything applies in rv order, the live gauge falls back to 0
+    and the peak keeps 2, the same with and without a server attached."""
+    from koordinator_tpu import metrics
+
+    server, _clients = rpc
+    gate = threading.Event()
+    entered = threading.Event()
+    applied: list[str] = []
+
+    class Stuck:
+        def pod_add(self, entry, arrs):
+            if entry["name"] == "first":
+                entered.set()
+                assert gate.wait(10), "test gate never opened"
+            applied.append(entry["name"])
+
+    service = StateSyncService()
+    server.start()
+    if attached:
+        service.attach(server)
+    service.attach_binding(Stuck())
+    req = resource_vector(cpu=100, memory=64)
+    first = threading.Thread(
+        target=lambda: service.add_pod("first", req), daemon=True)
+    first.start()
+    assert entered.wait(5), "binding apply never started"
+    burst = [threading.Thread(
+        target=lambda t=t: [service.add_pod(f"t{t}_{i}", req)
+                            for i in range(20)], daemon=True)
+        for t in range(2)]
+    for thread in burst:
+        thread.start()
+    wait_until(lambda: len(service._binding_queue) == 2)
+    assert metrics.sync_binding_backlog.value() == 2.0
+    gate.set()
+    for thread in [first, *burst]:
+        thread.join(10)
+        assert not thread.is_alive()
+    assert len(applied) == 41 and applied[0] == "first"
+    by_rv = [e["name"] for _, e, _ in service.log.since(0)]
+    assert applied == by_rv
+    assert service._backlog_peak == 2
+    assert metrics.sync_binding_backlog_peak.value() == 2.0
+    assert metrics.sync_binding_backlog.value() == 0.0
+    assert _frame_counts() == ({"no_recipient": 41} if attached else {})
