@@ -293,7 +293,7 @@ def build_koordlet_parser() -> argparse.ArgumentParser:
 
 def main_koordlet(argv: list[str], device_report_fn=None,
                   pod_resources_upstream_fn=None,
-                  node_info_fn=None) -> Assembled:
+                  node_info_fn=None, clock=None) -> Assembled:
     """``device_report_fn(Device)`` is the deployment shell's Device-CR
     sink (apiserver client / StateSyncService.upsert_node devices=...);
     None disables the in-agent reporting tick.
@@ -301,7 +301,11 @@ def main_koordlet(argv: list[str], device_report_fn=None,
     PodResourcesProxy enriches; None serves koord allocations only.
     ``node_info_fn() -> NodeInfo`` is the shell's Node watch (the
     states_node informer); it registers as the 'node' informer the
-    kubelet pods informer depends on."""
+    kubelet pods informer depends on.
+    ``clock`` (default ``time.time``) is the daemon's clock: collectors
+    take their rates over it and reports are dated by it."""
+    import time
+
     from koordinator_tpu.features import KOORDLET_GATES
     from koordinator_tpu.koordlet.daemon import Daemon
     from koordinator_tpu.koordlet.system.config import SystemConfig
@@ -321,7 +325,8 @@ def main_koordlet(argv: list[str], device_report_fn=None,
                     informer_sync_interval_seconds=(
                         args.informer_sync_interval_seconds),
                     device_report_interval_seconds=(
-                        args.device_report_interval_seconds))
+                        args.device_report_interval_seconds),
+                    clock=clock or time.time)
     if node_info_fn is not None:
         from koordinator_tpu.koordlet.statesinformer import CallbackInformer
 
@@ -1017,8 +1022,16 @@ def build_manager_parser() -> argparse.ArgumentParser:
     return parser
 
 
-def main_koord_manager(argv: list[str], lease_store=None) -> Assembled:
+def main_koord_manager(argv: list[str], lease_store=None,
+                       clock=None) -> Assembled:
+    """``clock`` (default ``time.time``) is the report clock of an
+    embedding shell that steps time itself: it dates the noderesource
+    reconcile (degrade window, time-gap rule) and the watch's undated
+    usage reports."""
+    import time
     import types
+
+    clock = clock or time.time
 
     from koordinator_tpu.features import SCHEDULER_GATES  # manager+scheduler
     from koordinator_tpu.manager.nodemetric import NodeMetricController
@@ -1060,7 +1073,7 @@ def main_koord_manager(argv: list[str], lease_store=None) -> Assembled:
     component = types.SimpleNamespace(
         nodemetric=NodeMetricController(),
         nodeslo=NodeSLOController(config_data=config_data or None),
-        noderesource=NodeResourceController(config=colocation),
+        noderesource=NodeResourceController(config=colocation, clock=clock),
         pod_mutating=PodMutatingWebhook(),
         pod_validating=PodValidatingWebhook(),
         node_mutating=NodeMutatingWebhook(),
@@ -1105,7 +1118,7 @@ def main_koord_manager(argv: list[str], lease_store=None) -> Assembled:
         from koordinator_tpu.transport import StateSyncClient
         from koordinator_tpu.transport.wire import FrameType
 
-        binding = ManagerSyncBinding()
+        binding = ManagerSyncBinding(clock=clock)
         sync = StateSyncClient(binding)
 
         def bootstrap_watch(client):

@@ -28,12 +28,14 @@ koordlet's device inventory the way a full node_upsert would.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, Optional
 
 import numpy as np
 
+from koordinator_tpu import metrics, timeline
 from koordinator_tpu.api import crds
 from koordinator_tpu.api.resources import ResourceDim
 from koordinator_tpu.manager.noderesource_controller import (
@@ -93,6 +95,18 @@ class ManagerSyncBinding:
             self.nodes.clear()
             self.records.clear()
 
+    @contextlib.contextmanager
+    def _watched(self):
+        """One node delta applied to the view: a ``colo.watch`` span and
+        a count, under the binding's lock."""
+        t0 = timeline.RECORDER.open("colo.watch")
+        try:
+            with self.lock:
+                yield
+        finally:
+            timeline.RECORDER.close(t0, "deltasync_apply")
+            metrics.colocation_watch_events_total.inc()
+
     def _merge_usage(self, view: _NodeView, entry: dict,
                      arrs: dict) -> None:
         """ONE copy of the usage-field merge for live node_usage deltas
@@ -116,7 +130,7 @@ class ManagerSyncBinding:
                            else self.clock())
 
     def node_upsert(self, entry: dict, arrs: dict) -> None:
-        with self.lock:
+        with self._watched():
             view = self.nodes.setdefault(entry["name"], _NodeView())
             view.allocatable = np.asarray(arrs["allocatable"], np.int32)
             view.labels = dict(entry.get("labels", {}))
@@ -135,7 +149,7 @@ class ManagerSyncBinding:
             self.records.pop(entry["name"], None)
 
     def node_usage(self, entry: dict, arrs: dict) -> None:
-        with self.lock:
+        with self._watched():
             view = self.nodes.get(entry["name"])
             if view is None:
                 return
@@ -145,14 +159,14 @@ class ManagerSyncBinding:
         # our own patches echo back as deltas; base capacity dims
         # (CPU/MEMORY) are untouched by the batch/mid patch, so applying
         # the echo cannot feed back into the formula
-        with self.lock:
+        with self._watched():
             view = self.nodes.get(entry["name"])
             if view is None:
                 return
             view.allocatable = np.asarray(arrs["allocatable"], np.int32)
 
     def node_remove(self, name: str) -> None:
-        with self.lock:
+        with self._watched():
             self.nodes.pop(name, None)
             self.records.pop(name, None)
 
@@ -207,8 +221,16 @@ class ColocationLoop:
         self._stop = threading.Event()
 
     def _build_records(self) -> list[NodeRecord]:
+        t0 = timeline.RECORDER.open("colo.records")
+        records: list[NodeRecord] = []
+        try:
+            self._fill_records(records)
+        finally:
+            timeline.RECORDER.close(t0, "host_other", n=len(records))
+        return records
+
+    def _fill_records(self, records: list[NodeRecord]) -> None:
         cpu, mem = int(ResourceDim.CPU), int(ResourceDim.MEMORY)
-        records = []
         with self.binding.lock:
             for name, view in self.binding.nodes.items():
                 if view.allocatable is None:
@@ -261,7 +283,6 @@ class ColocationLoop:
             # now, and the plane holds its own lock for the host copy
             for record in records:
                 self.forecast.apply(record)
-        return records
 
     def tick(self) -> int:
         """One reconcile round; returns the number of patches pushed.
@@ -271,17 +292,18 @@ class ColocationLoop:
         context rides the STATE_PUSH frame to the sidecar (the RPC
         client injects the active context), so a scheduler can see WHICH
         manager tick changed a node's batch allocatable."""
-        from koordinator_tpu import metrics, tracing
+        from koordinator_tpu import tracing
 
         self.ticks += 1
-        with tracing.TRACER.span(
-                "manager.colocation_tick", service="manager",
-                attributes={"tick": self.ticks}) as tick_span:
-            pushed = self._tick_traced(metrics, tracing)
+        with timeline.RECORDER.section("host_other", "colo.tick"), \
+                tracing.TRACER.span(
+                    "manager.colocation_tick", service="manager",
+                    attributes={"tick": self.ticks}) as tick_span:
+            pushed = self._tick_traced(tracing)
             tick_span.set_attribute("pushed", pushed)
         return pushed
 
-    def _tick_traced(self, metrics, tracing) -> int:
+    def _tick_traced(self, tracing) -> int:
         if self.ensure_fn is not None:
             try:
                 self.ensure_fn()
@@ -291,6 +313,11 @@ class ColocationLoop:
                 metrics.colocation_connect_failures_total.inc()
         records = self._build_records()
         patches = self.controller.reconcile(records)
+        with timeline.RECORDER.section("host_other", "colo.push",
+                                       n=len(patches)):
+            return self._push(patches, tracing)
+
+    def _push(self, patches, tracing) -> int:
         pushed = 0
         for patch in patches:
             with self.binding.lock:

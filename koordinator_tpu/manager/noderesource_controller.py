@@ -62,6 +62,8 @@ class NodeRecord:
     last_mid_mem: int = -1
     last_device_resources: Optional[Mapping[str, int]] = None
     last_degraded: bool = False
+    #: controller clock at the last sync (the time-gap rule's input)
+    last_sync_time: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,8 +147,14 @@ class NodeResourceController:
         degraded nodes)."""
         if not nodes:
             return []
+        from koordinator_tpu import timeline
+
+        with timeline.RECORDER.section("host_other", "colo.reconcile",
+                                       n=len(nodes)):
+            return self._reconcile(nodes, timeline.RECORDER)
+
+    def _reconcile(self, nodes: list[NodeRecord], tl) -> list[NodePatch]:
         now = self.clock()
-        n = len(nodes)
 
         def col(fn) -> np.ndarray:
             return np.asarray([fn(r) for r in nodes], np.int32)
@@ -194,9 +202,10 @@ class NodeResourceController:
             "reclaim_cpu": jnp.asarray(col(lambda r: r.prod_reclaimable_cpu_milli)),
             "reclaim_mem": jnp.asarray(col(lambda r: r.prod_reclaimable_mem_mib)),
         }
-        batch_cpu, batch_mem, mid_cpu, mid_mem = map(
-            np.asarray, self._batched(inputs, self._strategy())
-        )
+        with tl.section("host_other", "colo.solve"):
+            batch_cpu, batch_mem, mid_cpu, mid_mem = map(
+                np.asarray, self._batched(inputs, self._strategy())
+            )
 
         from koordinator_tpu import metrics
 
@@ -218,19 +227,25 @@ class NodeResourceController:
                                       "resource": "batch-memory"})
             metrics.node_metric_expired.set(
                 1.0 if degraded else 0.0, labels={"node": record.name})
-            if degraded and record.last_degraded:
+            if degraded:
                 # already zeroed — but device info comes from the Device CR,
                 # independent of metric freshness, so device changes still sync
-                if record.last_device_resources == devres:
+                if (record.last_degraded
+                        and record.last_device_resources == devres):
                     continue
-            elif not degraded and not self._needs_sync(
-                record, b_cpu, b_mem, m_cpu, m_mem, devres
-            ):
-                continue
+                reason = "degraded"
+            else:
+                reason = self._sync_reason(
+                    record, now, b_cpu, b_mem, m_cpu, m_mem, devres)
+                if reason is None:
+                    continue
+            metrics.colocation_sync_reason_total.inc(
+                labels={"reason": reason})
             record.last_batch_cpu, record.last_batch_mem = b_cpu, b_mem
             record.last_mid_cpu, record.last_mid_mem = m_cpu, m_mem
             record.last_device_resources = dict(devres)
             record.last_degraded = degraded
+            record.last_sync_time = now
             patches.append(NodePatch(
                 name=record.name,
                 batch_cpu_milli=b_cpu, batch_mem_mib=b_mem,
@@ -276,17 +291,23 @@ class NodeResourceController:
         age = now - record.metric.update_time
         return age > self.config.degrade_time_minutes * 60
 
-    def _needs_sync(self, record: NodeRecord, b_cpu: int, b_mem: int,
-                    m_cpu: int, m_mem: int,
-                    devres: Mapping[str, int]) -> bool:
-        """diff-threshold suppression (isResourceDiff): skip the patch when
-        the relative change of every dimension is below the threshold and
-        mid/device resources are unchanged. A node recovering from degrade
-        always syncs."""
+    def _sync_reason(self, record: NodeRecord, now: float, b_cpu: int,
+                     b_mem: int, m_cpu: int, m_mem: int,
+                     devres: Mapping[str, int]) -> Optional[str]:
+        """Why a fresh node is patched this tick, or None when it is not
+        (plugins/batchresource/plugin.go isBatchResourceNeedSync): its
+        ``first`` sync (also a node recovering from degrade), a
+        ``time_gap`` (the last sync is older than
+        updateTimeThresholdSeconds), or a ``diff``: the relative change of
+        a dimension is above resourceDiffThreshold, or the device
+        resources changed."""
         if record.last_batch_cpu < 0 or record.last_degraded:
-            return True
+            return "first"
+        if (now - record.last_sync_time
+                > self.config.update_time_threshold_seconds):
+            return "time_gap"
         if record.last_device_resources != devres:
-            return True
+            return "diff"
         threshold = self.config.resource_diff_threshold
 
         def differs(old: int, new: int) -> bool:
@@ -295,12 +316,12 @@ class NodeResourceController:
             base = max(old, 1)
             return abs(new - old) / base > threshold
 
-        return (
-            differs(record.last_batch_cpu, b_cpu)
-            or differs(record.last_batch_mem, b_mem)
-            or differs(record.last_mid_cpu, m_cpu)
-            or differs(record.last_mid_mem, m_mem)
-        )
+        if (differs(record.last_batch_cpu, b_cpu)
+                or differs(record.last_batch_mem, b_mem)
+                or differs(record.last_mid_cpu, m_cpu)
+                or differs(record.last_mid_mem, m_mem)):
+            return "diff"
+        return None
 
     def _device_resources(self, record: NodeRecord) -> dict[str, int]:
         """gpudeviceresource/rdmadevicereource NodeSync: Device CR ->

@@ -12,6 +12,7 @@ from __future__ import annotations
 import hashlib
 from typing import Mapping, Optional
 
+from koordinator_tpu import timeline
 from koordinator_tpu.api import crds, extension as ext
 from koordinator_tpu.api.priority import (
     PRIORITY_BATCH_MAX, PRIORITY_BATCH_MIN, PriorityClass, priority_class_of,
@@ -61,11 +62,16 @@ class PodMutatingWebhook:
     def mutate(self, pod: dict,
                namespace_labels: Mapping[str, str] | None = None) -> dict:
         """Admission mutate: returns the (mutated) pod dict."""
-        for profile in self.profiles:
-            if not self._profile_matches(profile, pod, namespace_labels or {}):
-                continue
-            self._apply_profile(profile, pod)
-        self._translate_batch_resources(pod)
+        t0 = timeline.RECORDER.open("colo.admit")
+        try:
+            for profile in self.profiles:
+                if not self._profile_matches(profile, pod,
+                                             namespace_labels or {}):
+                    continue
+                self._apply_profile(profile, pod)
+            self._translate_batch_resources(pod)
+        finally:
+            timeline.RECORDER.close(t0, "host_other")
         return pod
 
     def _profile_matches(self, profile: crds.ClusterColocationProfile,

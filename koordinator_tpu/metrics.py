@@ -737,6 +737,13 @@ colocation_push_failures_total = MANAGER.counter(
 colocation_connect_failures_total = MANAGER.counter(
     "colocation_connect_failures_total",
     "colocation-loop sidecar reconnect attempts that failed")
+colocation_sync_reason_total = MANAGER.counter(
+    "colocation_sync_reason_total",
+    "noderesource patches by why the node was synced (label: "
+    "reason=first|time_gap|diff|degraded)")
+colocation_watch_events_total = MANAGER.counter(
+    "colocation_watch_events_total",
+    "node deltas the manager's watch applied to its view")
 
 rpc_deadline_shed_total = TRANSPORT.counter(
     "rpc_deadline_shed_total",

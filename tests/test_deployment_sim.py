@@ -417,7 +417,19 @@ def test_colocation_loop_binary_to_binary(tmp_path):
         result = solve_remote(solve_client)
         assert "be-1" in result["failures"], result
 
-        # koordlet binary reports usage over the wire
+        # koordlet binary reports usage over the wire.  The collector's
+        # cpu rate is jiffies-delta / clock-delta: the koordlet and the
+        # manager run on a clock this test steps (one second a tick,
+        # starting at the wall's now, so reports stay fresh to the
+        # scheduler), and a reading is then a pure function of the
+        # jiffies written, whatever the machine's load does to the wall
+        # between two ticks — the BE pod must be gated on BATCH
+        # CAPACITY, not on usage pressure
+        now = [time.time()]
+
+        def clock() -> float:
+            return now[0]
+
         write_proc(0)
         koordlet_asm = main_koordlet([
             "--cgroup-root-dir", cfg.cgroup_root,
@@ -426,20 +438,16 @@ def test_colocation_loop_binary_to_binary(tmp_path):
             "--scheduler-sidecar-addr", str(tmp_path / "colo.sock"),
             "--node-name", "n-colo",
             "--nodemetric-report-interval-seconds", "0",
-        ])
+        ], clock=clock)
         daemon = koordlet_asm.component
         daemon.tick()
-        # the collector's cpu rate is jiffies-delta / wall-delta: keep
-        # the burn small and the gap large so the reported usage stays
-        # WELL under the loadaware threshold regardless of test-run
-        # timing (40 jiffies / >=0.5s <= 0.8 cores of 16) — the BE pod
-        # must be gated on BATCH CAPACITY, not on usage pressure
-        time.sleep(0.5)
+        # 40 jiffies over exactly one second: 0.4 cores of 16
         write_proc(40)
         # bounded by the loop's own steps, not by seconds: a tick
         # collects, its report goes out on a thread, and a slow host
         # only makes a step longer
         for _ in range(400):
+            now[0] += 1.0
             daemon.tick()
             time.sleep(0.05)
             stored = sched_asm.state_sync.nodes["n-colo"]["arrays"]
@@ -452,21 +460,18 @@ def test_colocation_loop_binary_to_binary(tmp_path):
         # manager binary: watches the same sidecar, reconciles, pushes
         manager_asm = main_koord_manager([
             "--scheduler-sidecar-addr", str(tmp_path / "colo.sock"),
-        ])
+        ], clock=clock)
         manager = manager_asm.component
         # the sidecar client dials lazily: the first tick bootstraps the
-        # watch and reconciles.  A transient cpu-rate spike (the jiffies
-        # delta over a tiny wall gap right after startup) can make the
-        # first reconcile legitimately compute batch=0 — the REAL system
-        # corrects on the next report+reconcile cadence, so the test
-        # keeps the whole loop ticking (fresh usage samples decay the
-        # rate, the manager re-pushes past the diff threshold) until the
-        # scheduler's device-resident allocatable carries the capacity.
+        # watch and reconciles; the test keeps the whole loop ticking
+        # (report, reconcile, push) until the scheduler's
+        # device-resident allocatable carries the capacity.
         row = scheduler.snapshot.node_index["n-colo"]
         batch_cpu = 0
         for _ in range(300):
             if batch_cpu >= 2_000:
                 break
+            now[0] += 1.0
             daemon.tick()
             manager.colocation_loop.tick()
             scheduler.snapshot.flush()
@@ -481,6 +486,12 @@ def test_colocation_loop_binary_to_binary(tmp_path):
 
         # and the BE pod now schedules — over the same solve socket
         result = solve_remote(solve_client)
+        if result["assignments"].get("be-1") != "n-colo":
+            # what the sidecar holds of the node, in full (pytest cuts a
+            # long assertion message)
+            for key, value in sched_asm.state_sync.nodes["n-colo"][
+                    "arrays"].items():
+                print("n-colo", key, np.asarray(value).tolist())
         assert result["assignments"].get("be-1") == "n-colo", result
         solve_client.close()
     finally:
