@@ -782,12 +782,25 @@ sync_binding_backlog_peak = TRANSPORT.gauge(
     "watches)")
 sync_delta_frames_total = TRANSPORT.counter(
     "sync_delta_frames_total",
-    "Committed deltasync events by what became of their DELTA frame "
-    "(label: outcome=built|no_recipient — built means a live watcher "
-    "was connected and the frame was packed and encoded for it, once "
-    "per wire form; no_recipient that nobody was, so nothing was "
-    "built: a later HELLO serves the event from the log or the "
-    "snapshot).  Counted only where a server is attached")
+    "Committed deltasync EVENTS by whether anyone was there to be sent "
+    "them (label: outcome=built|no_recipient — built means a live "
+    "watcher was connected at the commit and was told there is news; "
+    "no_recipient that nobody was, so nothing was queued or built: a "
+    "later HELLO serves the event from the log or the snapshot).  "
+    "Counted only where a server is attached.  The frames themselves "
+    "are sync_delta_frames_sent_total")
+sync_delta_frames_sent_total = TRANSPORT.counter(
+    "sync_delta_frames_sent_total",
+    "Live DELTA frames handed to a connection's socket, summed over "
+    "connections: the committer's ready single-event frame for a "
+    "watcher that is caught up and idle, or the ONE frame a "
+    "connection's sender thread builds from the delta log for the "
+    "whole run of events the connection lacked when it got there")
+sync_delta_events_sent_total = TRANSPORT.counter(
+    "sync_delta_events_sent_total",
+    "Events carried by the frames of sync_delta_frames_sent_total, "
+    "summed over connections; events / frames is the run length (1 "
+    "where every watcher keeps up with every commit)")
 sync_resyncs_total = TRANSPORT.counter(
     "sync_resyncs_total",
     "Server-requested resyncs honored by a reconnecting client (ERROR "

@@ -10,9 +10,10 @@ to the caller, matching the proxy's fail-open dispatch).
 
 Every connection writes through a bounded outbound queue drained by a
 dedicated sender thread, so a stalled peer can never block a handler or a
-broadcaster — it just starts dropping (and is reaped when its socket
-dies), the same backpressure posture as an apiserver watch that a slow
-client falls off of.
+committer — its replies pile up and it is poisoned, its pushes wait in
+the source's log until its cursor falls out of it (and it is reaped when
+its socket dies), the same backpressure posture as an apiserver watch
+that a slow client falls off of.
 """
 
 from __future__ import annotations
@@ -51,7 +52,7 @@ _CLIENT_SPANS = {t: f"rpc.call.{t.name}" for t in FrameType}
 #: thread — handlers are (doc, arrays) -> (doc, arrays) with no
 #: connection parameter, but protocol negotiation (HELLO) must stamp
 #: the NEGOTIATED message protocol onto the connection so later
-#: broadcasts pick the right event encoding per peer.  Dispatch workers
+#: pushes pick the right event encoding per peer.  Dispatch workers
 #: are per-connection threads, so a threadlocal is race-free.
 _DISPATCH = threading.local()
 
@@ -64,15 +65,27 @@ def set_conn_proto(proto: int) -> None:
     if conn is not None:
         conn.proto = int(proto)
 
-#: Outbound frames buffered per connection before the peer is declared
-#: stalled (poison + forced resync).  Sized to the DeltaLog retention
-#: window (deltasync.DeltaLog, 4096): a burst the delta log could replay
-#: WITHOUT a full-snapshot resync must not poison the wire first — with a
-#: tight producer loop the sender thread drains in ~5ms GIL slices, and
-#: the r5 deltasync bench measured a 1,024-event NodeMetric burst
-#: overflowing the old 256-deep queue at event 256, killing the watch.
-#: Poison now triggers exactly when falling behind means a resync is
-#: unavoidable anyway.
+
+def set_conn_cursor(rv: int) -> None:
+    """Set the push cursor of the connection whose request is currently
+    being dispatched: the reply being built serves the peer up to
+    ``rv``, so its live stream resumes after it.  The caller holds the
+    lock its push source reads the cursor under.  No-op outside
+    dispatch, like :func:`set_conn_proto`."""
+    conn = getattr(_DISPATCH, "conn", None)
+    if conn is not None:
+        conn.cursor = int(rv)
+
+
+#: Outbound items buffered per connection before the peer is declared
+#: stalled (poison + forced resync).  The live DELTA stream takes at
+#: most two slots however long a burst is (one ready frame and the push
+#: notice behind it, deltasync.StateSyncService._announce): what a
+#: watcher lacks waits in the delta log, not here, and a watcher is too
+#: far behind when its cursor has left the log's retained window
+#: (deltasync.DeltaLog, 4096 events), which is exactly when a resync is
+#: unavoidable anyway.  The rest of the queue holds replies: a peer
+#: that lets thousands of them pile up has stopped reading.
 SEND_QUEUE_DEPTH = 4096
 
 #: Inbound frames buffered per connection between the read loop and the
@@ -136,40 +149,62 @@ def _recv_exact(sock: socket.socket, faults=None):
 
 
 class _Conn:
-    """One server-side connection: bounded outbound queue + sender thread."""
+    """One server-side connection: bounded outbound queue + sender thread.
+
+    A queue item is a ready :class:`Frame` (a reply), ``None`` (the
+    poison), or a push NOTICE: a callable the sender thread calls with
+    this connection when it reaches it, for the frame to send then
+    (``None``: nothing to send).  A notice keeps its place in the FIFO,
+    so what it yields still leaves before every reply queued after it."""
 
     def __init__(self, sock: socket.socket, faults=None):
         self.sock = sock
         self.faults = faults
-        self.queue: "queue.Queue[Optional[Frame]]" = queue.Queue(
+        self.queue: "queue.Queue[Frame | Callable | None]" = queue.Queue(
             SEND_QUEUE_DEPTH)
         self.alive = True
         self.dropped = 0
         #: negotiated message protocol for this peer (stamped by the
         #: HELLO handler via set_conn_proto); 0 = never negotiated —
-        #: broadcasts treat it as a legacy peer (JSON event lists)
+        #: pushes treat it as a legacy peer (JSON event lists)
         self.proto = 0
+        #: the push source's position for this peer (deltasync: the
+        #: resource version it has been sent up to); None until the
+        #: connection first becomes a recipient.  Read and written under
+        #: the push source's lock only (the committer, the notice
+        #: itself, set_conn_cursor)
+        self.cursor: Optional[int] = None
+        #: a push notice is outstanding in the queue (same lock)
+        self.notified = False
         #: reorder-fault hold slot: a push pulled out of order, emitted
         #: after the next outbound frame (or on poison)
         self._held: Optional[bytes] = None
         self._sender = threading.Thread(target=self._drain, daemon=True)
         self._sender.start()
 
-    def send(self, frame: Frame) -> None:
+    def send(self, item) -> None:
         """Enqueue; never blocks the caller. A full queue (stalled peer)
-        drops the frame and poisons the connection so the peer resyncs on
+        drops the item and poisons the connection so the peer resyncs on
         reconnect instead of silently missing one event."""
         if not self.alive:
             return
         try:
-            self.queue.put_nowait(frame)
+            self.queue.put_nowait(item)
         except queue.Full:
             self.dropped += 1
-            self.alive = False
-            try:
-                self.sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass
+            self._sever()
+
+    def idle(self) -> bool:
+        """Nothing is queued: whatever this connection was handed, its
+        sender has taken."""
+        return self.queue.empty()
+
+    def _sever(self) -> None:
+        self.alive = False
+        try:
+            self.sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass
 
     def close(self) -> None:
         self.alive = False
@@ -197,8 +232,8 @@ class _Conn:
 
     def _drain(self) -> None:
         while True:
-            frame = self.queue.get()
-            if frame is None:
+            item = self.queue.get()
+            if item is None:
                 # poison AFTER the backlog: already-queued frames (e.g.
                 # a response to an in-flight call whose side effect
                 # already applied) still reach the peer, THEN the wire
@@ -214,13 +249,25 @@ class _Conn:
                 except OSError:
                     pass
                 return
+            if isinstance(item, Frame):
+                frame = item
+            else:
+                # a push notice: the frame is made now, from what the
+                # source holds for this peer at this moment
+                try:
+                    frame = item(self)
+                except Exception:
+                    # the source cannot serve this peer from where it
+                    # stands (deltasync: its cursor left the retained
+                    # log): poison, and it resyncs at its next HELLO
+                    self.dropped += 1
+                    self._sever()
+                    return
+                if frame is None:
+                    continue
             try:
                 if not self._send_one(frame):
-                    self.alive = False
-                    try:
-                        self.sock.shutdown(socket.SHUT_RDWR)
-                    except OSError:
-                        pass
+                    self._sever()
                     return
             except OSError:
                 self.alive = False
@@ -505,47 +552,12 @@ class RpcServer:
             if conn in self._conns:
                 self._conns.remove(conn)
 
-    def broadcast(self, ftype: FrameType, payload, min_proto: int = 0,
-                  legacy=None) -> int:
-        """Push a frame (request_id 0 = unsolicited) to all live
-        connections — the informer watch-event fan-out. Never blocks:
-        frames go through each connection's bounded queue.
-
-        ``payload`` and ``legacy`` are zero-arg callables returning
-        ``(doc, arrays)``, each called at most once and only when the
-        loop meets a live connection that is to receive its frame: a
-        message nobody is connected to receive is never packed or
-        encoded (a late watcher is served from the delta log or the
-        snapshot at its HELLO).  Returns the number of connections sent
-        to; 0 means neither callable ran.
-
-        Mixed-version fan-out: when ``min_proto`` > 0, only peers that
-        negotiated at least that message protocol get the primary
-        payload; older peers (including never-HELLO'd ones at proto 0)
-        get the ``legacy`` payload instead, so an all-v2 fleet never
-        pays the v1 encode.  ``legacy=None`` with ``min_proto`` set
-        skips old peers entirely (their resync machinery recovers)."""
-        frame: Optional[Frame] = None
-        legacy_frame: Optional[Frame] = None
+    def live_conns(self) -> list[_Conn]:
+        """The connections a push would reach now — the informer watch
+        fan-out's recipients (listed and not poisoned).  With nobody
+        connected this is all a committed event costs."""
         with self._conn_lock:
-            conns = list(self._conns)
-        sent = 0
-        for conn in conns:
-            if not conn.alive:
-                continue
-            if min_proto and conn.proto < min_proto:
-                if legacy is None:
-                    continue
-                if legacy_frame is None:
-                    legacy_frame = Frame(
-                        ftype, 0, encode_payload(*legacy()))
-                conn.send(legacy_frame)
-            else:
-                if frame is None:
-                    frame = Frame(ftype, 0, encode_payload(*payload()))
-                conn.send(frame)
-            sent += 1
-        return sent
+            return [conn for conn in self._conns if conn.alive]
 
 
 class RpcClient:

@@ -77,11 +77,19 @@ class DeltaLog:
 
     def since(self, rv: int) -> list[tuple[int, dict, dict[str, np.ndarray]]]:
         """All events with rv' > rv. Raises ResyncRequired when rv is
-        before the retained window."""
+        before the retained window.  Reads the tail from the right, so
+        it costs the events it returns, not the retention: a connection
+        that is one event behind pays for one."""
         oldest = self.oldest_rv()
         if oldest is not None and rv < oldest - 1:
             raise ResyncRequired(f"rv {rv} < retained window start {oldest}")
-        return [(v, e, a) for v, e, a in self._events if v > rv]
+        tail = []
+        for entry in reversed(self._events):
+            if entry[0] <= rv:
+                break
+            tail.append(entry)
+        tail.reverse()
+        return tail
 
 
 def _pack_events(
@@ -309,6 +317,15 @@ def _validate_devices(devices: dict | None, context: str) -> None:
                         f"field {field!r} must be an integer")
 
 
+def _pack_for(events: list[tuple[int, dict, dict[str, np.ndarray]]],
+              v4: bool) -> tuple[dict, dict[str, np.ndarray]]:
+    """``events`` packed in a peer's negotiated form: columnar at proto
+    >= 4, v1 below it and for a kind without a code (_pack_events_v2 ->
+    None)."""
+    packed = _pack_events_v2(events) if v4 else None
+    return packed or _pack_events(events)
+
+
 _FRAME_BUILT = {"outcome": "built"}
 _FRAME_NO_RECIPIENT = {"outcome": "no_recipient"}
 
@@ -361,24 +378,23 @@ class StateSyncService:
         sidecar binary whose solver lives in the same process as its
         sync service sees pushed state immediately — no socket loop, no
         eventual-consistency window.  Remote sync clients keep the
-        broadcast path."""
+        watch stream."""
         self._local_bindings.append(binding)
 
     def _store_and_commit(self, store_fn, event: dict,
                           arrays: dict[str, np.ndarray]) -> int:
-        """Run a stored-state mutation AND append+broadcast its event
-        under ONE lock acquisition, so rv order, wire order, and stored
-        state always agree (the client's idempotency guard drops any rv
-        it has already passed, so reordered broadcasts would lose
-        events; a store released before the log append lets a racing
-        mutator interleave — e.g. upsert_node(devices={}) vs
-        update_node_devices(X) could log [devices=X, upsert={}] while
+        """Run a stored-state mutation AND append+announce its event
+        under ONE lock acquisition, so rv order, log order, and stored
+        state always agree (the log is what every watcher is served
+        from, live or at HELLO; a store released before the log append
+        lets a racing mutator interleave — e.g. upsert_node(devices={})
+        vs update_node_devices(X) could log [devices=X, upsert={}] while
         storing devices=X, and the stale stored doc would then eat every
         subsequent identical heartbeat as 'unchanged').  Safe to hold:
-        broadcast only enqueues to bounded per-connection queues — a
-        stalled peer drops frames and gets poisoned, it cannot wedge the
-        service (channel._Conn.send)."""
-        # one sync.store span per event (store, delta log, broadcast,
+        announcing only puts a frame or a notice on a bounded
+        per-connection queue — a stalled peer gets poisoned, it cannot
+        wedge the service (channel._Conn.send)."""
+        # one sync.store span per event (store, delta log, announce,
         # local apply); the sync.<kind> applies nest under it
         tl_t0 = timeline.RECORDER.open("sync.store")
         try:
@@ -387,7 +403,7 @@ class StateSyncService:
                 rv = self._commit_locked(event, arrays)
             # apply OUTSIDE the service lock: bindings block on the
             # scheduler lock (a long solve), and holding _lock through
-            # that would stall every HELLO/push/broadcast behind it.  The
+            # that would stall every HELLO/push/sender behind it.  The
             # queue was filled in rv order under _lock; draining FIFO
             # under _binding_lock keeps that order even when two pushers
             # race to drain.
@@ -417,20 +433,14 @@ class StateSyncService:
         rv = self.rv
         self.log.append(rv, event, arrays)
         if self._server is not None:
-            # the DELTA frame is built by the first live connection that
-            # is to receive it (RpcServer.broadcast calls these at most
-            # once each): the columnar frame for v4+ peers, the v1 frame
-            # for negotiated-down ones, and for a kind without a code
-            # (_pack_events_v2 -> None) the v1 form for everyone.  With
-            # no watcher connected nothing is packed; one that connects
-            # later gets the event from the log or the snapshot
-            batch = [(rv, event, arrays)]
-            sent = self._server.broadcast(
-                FrameType.DELTA,
-                lambda: _pack_events_v2(batch) or _pack_events(batch),
-                min_proto=4, legacy=lambda: _pack_events(batch))
+            # with no watcher connected nothing happens; one that
+            # connects later gets the event from the log or the
+            # snapshot at its HELLO
+            conns = self._server.live_conns()
+            if conns:
+                self._announce(conns, rv, event, arrays)
             metrics.sync_delta_frames_total.inc(
-                labels=_FRAME_BUILT if sent else _FRAME_NO_RECIPIENT)
+                labels=_FRAME_BUILT if conns else _FRAME_NO_RECIPIENT)
         if self._local_bindings:
             self._binding_queue.append((event, arrays))
             # backlog watermark (ISSUE 9): depth sampled at append (the
@@ -443,6 +453,69 @@ class StateSyncService:
                 self._backlog_peak = depth
                 metrics.sync_binding_backlog_peak.set(float(depth))
         return rv
+
+    def _announce(self, conns, rv: int, event: dict,
+                  arrays: dict[str, np.ndarray]) -> None:
+        """Tell each live connection of the event just logged (caller
+        holds _lock).  A connection met for the first time starts at
+        this event.  One that is caught up and idle (it has been sent
+        everything before rv and its sender has taken all it was
+        handed: a pusher waiting for its reply, a watcher that keeps
+        up) is handed the ready single-event frame, built here once per
+        wire form and shared, and the event costs its sender one
+        ``sendall``.  Any other is behind: it gets ONE notice, and its
+        sender takes the run of events after its cursor from the log
+        when it gets there (_next_delta); with a notice outstanding it
+        gets nothing more, so a burst of any length holds two slots of
+        its queue."""
+        ready: dict[bool, wire.Frame] = {}
+        handed = 0
+        for conn in conns:
+            if conn.cursor is None:
+                conn.cursor = rv - 1
+            if conn.notified:
+                continue
+            if conn.cursor == rv - 1 and conn.idle():
+                v4 = conn.proto >= 4
+                frame = ready.get(v4)
+                if frame is None:
+                    frame = ready[v4] = wire.Frame(
+                        FrameType.DELTA, 0, wire.encode_payload(
+                            *_pack_for([(rv, event, arrays)], v4)))
+                conn.cursor = rv
+                conn.send(frame)
+                handed += 1
+            else:
+                conn.notified = True
+                conn.send(self._next_delta)
+        if handed:
+            metrics.sync_delta_frames_sent_total.inc(float(handed))
+            metrics.sync_delta_events_sent_total.inc(float(handed))
+
+    def _next_delta(self, conn) -> Optional[wire.Frame]:
+        """The push notice, run by ``conn``'s sender thread when it
+        reaches it in the queue (channel._Conn): everything the log
+        holds after the connection's cursor as ONE DELTA frame in the
+        peer's negotiated form, or None when the peer is caught up (a
+        HELLO reply served it meanwhile).  Raises ResyncRequired when
+        the cursor has left the retained window: the sender poisons the
+        connection and the peer comes back by snapshot."""
+        tl_t0 = timeline.RECORDER.open("sync.frame")
+        n = 0
+        try:
+            with self._lock:
+                conn.notified = False
+                events = self.log.since(conn.cursor)
+                if not events:
+                    return None
+                conn.cursor = events[-1][0]
+            n = len(events)
+            metrics.sync_delta_frames_sent_total.inc()
+            metrics.sync_delta_events_sent_total.inc(float(n))
+            return wire.Frame(FrameType.DELTA, 0, wire.encode_payload(
+                *_pack_for(events, conn.proto >= 4)))
+        finally:
+            timeline.RECORDER.close(tl_t0, "deltasync_apply", n=n)
 
     def _drain_bindings(self) -> None:
         # drain the WHOLE backlog, then route it as one ordered batch so
@@ -856,16 +929,12 @@ class StateSyncService:
                 f"local {wire.PROTOCOL_VERSION} (supported "
                 f"{wire.MIN_PROTOCOL_VERSION}..{wire.PROTOCOL_VERSION})")
         proto = min(peer_proto, wire.PROTOCOL_VERSION)
-        # stamp the negotiated version on the live connection: broadcast
-        # uses it to pick the columnar vs legacy frame per peer
+        # stamp the negotiated version on the live connection: its
+        # sender picks the columnar vs legacy frame by it
         channel.set_conn_proto(proto)
 
         def pack(events):
-            if proto >= 4:
-                packed = _pack_events_v2(events)
-                if packed is not None:
-                    return packed
-            return _pack_events(events)
+            return _pack_for(events, proto >= 4)
 
         last_rv = int(doc.get("last_rv", -1))
         # instance-aware resync: a peer that last synced a DIFFERENT
@@ -877,6 +946,10 @@ class StateSyncService:
         peer_instance = doc.get("instance")
         same_instance = peer_instance is None or peer_instance == self.instance
         with self._lock:
+            # whichever reply this is, it serves the peer up to self.rv:
+            # its live stream resumes after it (a notice still in the
+            # connection's queue then finds nothing new and sends nothing)
+            channel.set_conn_cursor(self.rv)
             if last_rv == self.rv and same_instance:
                 return {"__type__": int(FrameType.ACK), "rv": self.rv,
                         "proto": proto, "instance": self.instance}, None
@@ -1005,6 +1078,14 @@ class StateSyncClient:
 
         if frame.type is not FrameType.DELTA:
             return
+        with self._lock:
+            if self.rv < 0 and not self._bootstrapping:
+                # dialed, listed as a recipient, first HELLO not yet
+                # sent: applying this would set rv past everything
+                # before it, and the HELLO would then ask for a replay
+                # from here instead of the snapshot.  Whatever is
+                # committed before that HELLO is in its answer
+                return
         doc, arrays = decode_payload(frame.payload)
         with self._lock:
             if self._bootstrapping:
@@ -1034,7 +1115,7 @@ class StateSyncClient:
                 if (not doc.get("snapshot") and not from_bootstrap
                         and self.rv >= 0 and rv > high + 1):
                     # a WATCH push skipped ahead: every committed rv is
-                    # broadcast in order, so a hole means an event was
+                    # sent in order, so a hole means an event was
                     # lost on the wire (drop/reorder).  Apply what we
                     # have (fresher than nothing) but flag the stream
                     # for resync — the rv guard would otherwise silently

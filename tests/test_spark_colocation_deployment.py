@@ -69,13 +69,25 @@ def run(tmp_path_factory):
                in metrics.colocation_sync_reason_total.items()}
         out["watched"] = metrics.colocation_watch_events_total.value()
         out["patches"] = metrics.colocation_patches_total.value()
+        out["frames_sent"] = metrics.sync_delta_frames_sent_total.value()
+        out["events_sent"] = metrics.sync_delta_events_sent_total.value()
         return out
 
     before = counters()
     try:
         state = kind.setup(dep, params, spans)
         dep.books.window_open = True
-        cycles = [kind.cycle(dep, params, spans) for _ in range(CYCLES)]
+        cycles = [kind.cycle(dep, params, spans) for _ in range(CYCLES - 1)]
+        # the last cycle's wave by itself: what the watch was sent for it
+        dep.step_clock()
+        dep.catch_up()
+        sent = counters()
+        dep.usage_wave(spans)
+        wave = {k: counters()[k] - sent[k]
+                for k in ("frames_sent", "events_sent", "watched")}
+        dep.now -= dep.config["clock"]["report_interval_seconds"]
+        dep.cycle -= 1
+        cycles.append(kind.cycle(dep, params, spans))
         compared = dep.verify()
         ticks = reference.replay_ticks(dep.capacity, dep.tick_log,
                                        dep.colocation)
@@ -88,7 +100,7 @@ def run(tmp_path_factory):
         os.chdir(cwd)
     return {"dep": dep, "state": state, "cycles": cycles,
             "compared": compared, "ticks": ticks, "held": held, "docs": docs,
-            "counted": counted}
+            "counted": counted, "wave": wave}
 
 
 def test_every_compared_number_reads_zero(run):
@@ -107,6 +119,22 @@ def test_patches_equal_the_reference_tick_by_tick(run):
         assert np.array_equal(logged["stored"],
                               np.maximum(want["standing"], 0))
         assert logged["pushed"] == int(want["patched"].sum())
+
+
+def test_a_report_wave_reaches_the_manager_in_runs(run):
+    """The manager's watch is behind the in-process reporter by
+    construction (one interpreter): a wave goes out as fewer DELTA frames
+    than reports, every report is applied, and the ticks patch what the
+    reference patches (above)."""
+    nodes, wave = run["dep"].sizes["nodes"], run["wave"]
+    assert wave["watched"] == nodes
+    # the counters sum over connections: the deployment's own client,
+    # hung up before the wave, stays listed for the first few events
+    assert nodes <= wave["events_sent"] < 2 * nodes
+    assert 1 <= wave["frames_sent"] < nodes
+    counted = run["counted"]
+    assert counted["frames_sent"] < counted["events_sent"]
+    assert run["dep"].manager.component.sync.gaps == 0
 
 
 @pytest.mark.parametrize("reason", ["first", "time_gap", "diff", None])

@@ -3,6 +3,7 @@ vs the reference's deployment seams: apiserver watch streams (LIST+WATCH,
 410-Gone resync), the hook gRPC protocol (api.proto:148), and the sidecar
 solve bridge (SURVEY.md §7 step 4)."""
 
+import collections
 import threading
 import time
 
@@ -169,8 +170,8 @@ def test_delta_burst_within_retention_survives_the_wire(rpc):
     must not poison the connection first: r5's deltasync bench caught a
     1,024-event NodeMetric burst overflowing the old 256-deep per-conn
     send queue at event 256 (the tight producer loop starves the sender
-    thread of GIL slices), silently killing the watch.  SEND_QUEUE_DEPTH
-    is now sized to the DeltaLog retention window."""
+    thread of GIL slices), silently killing the watch.  The live stream
+    now holds one slot of that queue and the burst waits in the log."""
     server, clients = rpc
     service = StateSyncService()
     service.attach(server)
@@ -463,10 +464,12 @@ def test_snapshot_resync_releases_bound_state(rpc):
     sched.bind_fn = lambda p, n: binds.append(p)
     SolveService(sched).attach(server)
     sync = StateSyncClient(SchedulerBinding(sched))
-    client = connect(server, clients, on_push=sync.on_push)
 
     service.upsert_node("n1", resource_vector(cpu=16_000, memory=65_536))
     service.add_pod("p1", resource_vector(cpu=16_000, memory=1_024))
+    # dialed after the two events: a connection listed while they commit
+    # may be a whole 1-event log behind before its sender gets a turn
+    client = connect(server, clients, on_push=sync.on_push)
     sync.bootstrap(client)
     solve_remote(client)
     assert "p1" in sched.bound
@@ -1121,12 +1124,12 @@ def test_conn_close_with_full_queue_does_not_leak_sender_thread():
         "sender thread leaked: blocked on queue.get() with no poison"
 
 
-# -- the DELTA frame of an event is built for its first live recipient ------
+# -- a live DELTA frame is built by its connection's sender, from the log ----
 
 
 def _count_packs(monkeypatch):
     """Count the calls of the two event codecs (module globals, so the
-    commit's closures and the HELLO handler both go through the wrappers)."""
+    senders' frames and the HELLO handler both go through the wrappers)."""
     from koordinator_tpu.transport import deltasync
 
     calls = {"v2": 0, "v1": 0}
@@ -1196,33 +1199,52 @@ def test_event_with_no_watcher_builds_no_delta_frame(rpc, monkeypatch, mix):
         assert np.array_equal(arrays[key], want)
 
 
-@pytest.mark.parametrize("peers, packs, outcome", [
-    (("v4", "v3"), {"v2": 1, "v1": 1}, "built"),
-    (("v4",), {"v2": 1, "v1": 0}, "built"),
-    (("v3",), {"v2": 0, "v1": 1}, "built"),
-    (("no_hello",), {"v2": 0, "v1": 1}, "built"),
-    (("v4", "v4", "v3", "v3"), {"v2": 1, "v1": 1}, "built"),
-    (("dead",), {"v2": 0, "v1": 0}, "no_recipient"),
-    (("dead", "v4"), {"v2": 1, "v1": 0}, "built"),
-], ids=lambda v: "+".join(v) if isinstance(v, tuple) else None)
-def test_connected_peers_get_the_eager_paths_bytes(rpc, monkeypatch, peers,
-                                                   packs, outcome):
-    """Every recipient connected at an event receives, in rv order, the
-    bytes the eager path sent: the columnar frame at proto >= 4, the v1
-    frame below it (a peer that never said HELLO included), each packed
-    once per event however many peers share it.  A connection that is
-    still listed but no longer alive receives and builds nothing."""
-    server, clients = rpc
-    service = StateSyncService()
-    service.attach(server)
-    server.start()
-    got: dict[int, list[tuple]] = {}
+
+def _sent_counts() -> tuple[int, int]:
+    """(live DELTA frames handed to a socket, events they carried)."""
+    from koordinator_tpu import metrics
+
+    return (int(metrics.sync_delta_frames_sent_total.value()),
+            int(metrics.sync_delta_events_sent_total.value()))
+
+
+def _decoded(payloads) -> list[tuple[dict, dict]]:
+    """The events of a peer's DELTA payloads in arrival order, each as
+    (entry without its row manifest, its arrays), whichever codec."""
+    from koordinator_tpu.transport.deltasync import (
+        _decode_events,
+        _unpack_event_arrays,
+    )
+
+    out = []
+    for payload in payloads:
+        doc, arrays = decode_payload(payload)
+        for entry in _decode_events(doc, arrays):
+            arrs = _unpack_event_arrays(entry, arrays)
+            out.append(({k: v for k, v in entry.items()
+                         if not k.startswith("__row_")}, arrs))
+    return out
+
+
+def _assert_same_events(got, logged) -> None:
+    assert [e["rv"] for e, _ in got] == [rv for rv, _, _ in logged]
+    for (entry, arrs), (rv, event, arrays) in zip(got, logged):
+        assert entry == dict(event, rv=rv)
+        assert sorted(arrs) == sorted(arrays)
+        for key, want in arrays.items():
+            assert np.array_equal(arrs[key], want)
+
+
+def _dial_peers(server, clients, peers):
+    """One raw client per entry of ``peers`` ("v4" / "v3" say HELLO at
+    that protocol, "no_hello" and "dead" never do; a "dead" one is
+    marked not alive while still listed).  Returns each one's received
+    push frames."""
+    got: dict[int, list] = {}
     for i, peer in enumerate(peers):
         got[i] = []
-        client = connect(
-            server, clients,
-            on_push=lambda frame, i=i: got[i].append(
-                (frame.type, frame.request_id, frame.payload)))
+        client = connect(server, clients,
+                         on_push=lambda frame, i=i: got[i].append(frame))
         if peer in ("v4", "v3"):
             client.call(FrameType.HELLO, {
                 "last_rv": -1, "proto": (PROTOCOL_VERSION if peer == "v4"
@@ -1232,24 +1254,55 @@ def test_connected_peers_get_the_eager_paths_bytes(rpc, monkeypatch, peers,
     for conn, peer in zip(list(server._conns), peers):
         if peer == "dead":
             conn.alive = False
-    calls, pack_v2, pack_v1 = _count_packs(monkeypatch)
+    return got
+
+
+@pytest.mark.parametrize("peers, forms, outcome", [
+    (("v4", "v3"), {"v2", "v1"}, "built"),
+    (("v4",), {"v2"}, "built"),
+    (("v3",), {"v1"}, "built"),
+    (("no_hello",), {"v1"}, "built"),
+    (("v4", "v4", "v3", "v3"), {"v2", "v1"}, "built"),
+    (("dead",), set(), "no_recipient"),
+    (("dead", "v4"), {"v2"}, "built"),
+], ids=lambda v: "+".join(v) if isinstance(v, tuple) else None)
+def test_connected_peers_get_every_event_once_in_their_own_form(
+        rpc, monkeypatch, peers, forms, outcome):
+    """Every recipient connected at an event receives it exactly once,
+    in rv order, as what was committed: in columnar frames at proto >=
+    4, in v1 frames below it (a peer that never said HELLO included),
+    in no more frames than events.  Only the forms some live peer speaks
+    are ever packed; a connection that is still listed but no longer
+    alive receives and builds nothing.  Both counters of what was sent
+    agree with what arrived."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    got = _dial_peers(server, clients, peers)
+    calls, _, _ = _count_packs(monkeypatch)
     n = 12
     _mutate(service, "mixed", n)
-    assert calls == {k: v * n for k, v in packs.items()}
+    logged = service.log.since(0)
+    live = [i for i, peer in enumerate(peers) if peer != "dead"]
+    for i in live:
+        wait_until(lambda: len(_decoded(f.payload for f in got[i])) >= n)
     assert _frame_counts() == {outcome: n}
-
-    batches = [[event] for event in service.log.since(0)]
-    want = {
-        "v4": [(FrameType.DELTA, 0, encode_payload(*pack_v2(b)))
-               for b in batches],
-        "v3": [(FrameType.DELTA, 0, encode_payload(*pack_v1(b)))
-               for b in batches],
-        "dead": [],
-    }
-    want["no_hello"] = want["v3"]
+    assert {form for form, k in calls.items() if k} == forms
     for i, peer in enumerate(peers):
-        wait_until(lambda: len(got[i]) >= len(want[peer]))
-        assert got[i] == want[peer], f"peer {i} ({peer})"
+        if peer == "dead":
+            assert got[i] == []
+            continue
+        assert all(f.type is FrameType.DELTA and f.request_id == 0
+                   for f in got[i])
+        assert 1 <= len(got[i]) <= n
+        marker = b"events_v2" if peer == "v4" else b'"events"'
+        assert all(marker in f.payload for f in got[i])
+        _assert_same_events(_decoded(f.payload for f in got[i]), logged)
+    assert _sent_counts() == (sum(len(got[i]) for i in live), n * len(live))
+    # a ready single-event frame is packed once per wire form, a run once
+    # per connection
+    assert sum(calls.values()) <= _sent_counts()[0]
 
 
 def test_event_of_a_kind_without_a_code_reaches_every_peer_as_v1(rpc):
@@ -1263,6 +1316,7 @@ def test_event_of_a_kind_without_a_code_reaches_every_peer_as_v1(rpc):
     arrays = {"vec": np.arange(4, dtype=np.int32)}
     service._store_and_commit(lambda: None, dict(event), arrays)
     assert _frame_counts() == {"no_recipient": 1}
+    assert _sent_counts() == (0, 0)
 
     got: dict[int, list[bytes]] = {0: [], 1: []}
     for i, proto in enumerate((PROTOCOL_VERSION, MIN_PROTOCOL_VERSION)):
@@ -1277,6 +1331,411 @@ def test_event_of_a_kind_without_a_code_reaches_every_peer_as_v1(rpc):
         wait_until(lambda: len(got[i]) == 1)
         assert got[i] == [want]
     assert _frame_counts() == {"no_recipient": 1, "built": 1}
+    assert _sent_counts() == (2, 2)
+
+
+class MirrorBinding:
+    """Keeps what the events say, by name: enough to compare a watcher's
+    view with the service's stored state, and to see an event applied
+    twice (``applies``) or a snapshot taken (``resets``)."""
+
+    def __init__(self):
+        self.resets = 0
+        self.reset()
+        self.resets = 0
+
+    def reset(self):
+        self.resets += 1
+        self.nodes: dict[str, list[int]] = {}
+        self.pods: dict[str, list[int]] = {}
+        self.applies: dict[str, int] = {}
+
+    def node_upsert(self, entry, arrs):
+        self.nodes[entry["name"]] = arrs["usage"].tolist()
+
+    def node_usage(self, entry, arrs):
+        self.nodes[entry["name"]] = arrs["usage"].tolist()
+
+    def pod_add(self, entry, arrs):
+        name = entry["name"]
+        self.pods[name] = arrs["requests"].tolist()
+        self.applies[name] = self.applies.get(name, 0) + 1
+
+    def pod_remove(self, name):
+        self.pods.pop(name, None)
+
+    def equals(self, service) -> bool:
+        return (self.nodes == {k: v["arrays"]["usage"].tolist()
+                               for k, v in service.nodes.items()}
+                and self.pods == {k: v["arrays"]["requests"].tolist()
+                                  for k, v in service.pods.items()})
+
+
+def _watch(server, clients, service, binding=None, **client_kw):
+    """A bootstrapped StateSyncClient on a fresh connection."""
+    sync = StateSyncClient(binding or MirrorBinding())
+    client = connect(server, clients, on_push=sync.on_push, **client_kw)
+    sync.bind_client(client)
+    sync.bootstrap(client)
+    return sync, client
+
+
+def test_burst_behind_a_held_sender_arrives_in_runs(rpc):
+    """(a) N events commit while the watcher's sender cannot read the
+    log (the committer holds the service's lock through the burst, as a
+    tight producer holds the interpreter): the first goes out ready-made
+    while the connection is still idle, the connection's queue then
+    takes ONE notice, and what arrives is far fewer frames than events,
+    in rv order, every event applied once."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    frames: list = []
+    sync = StateSyncClient(MirrorBinding())
+    client = connect(server, clients, on_push=lambda f: (
+        frames.append(f), sync.on_push(f)))
+    sync.bootstrap(client)
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    applied0, n = sync.applied, 500
+    with service._lock:
+        for i in range(n):
+            service.update_node_usage(
+                "n0", resource_vector(cpu=10 + i, memory=i))
+        assert conn.notified and conn.queue.qsize() <= 2
+        assert conn.cursor < service.rv
+    wait_until(lambda: sync.rv == service.rv)
+    assert 1 <= len(frames) <= n // 10
+    assert [e["rv"] for e, _ in _decoded(f.payload for f in frames)] == \
+        list(range(service.rv - n + 1, service.rv + 1))
+    assert sync.applied - applied0 == n and sync.gaps == 0
+    assert sync.skipped == 0
+    assert _sent_counts() == (len(frames), n)
+    assert _frame_counts() == {"no_recipient": 1, "built": n}
+    assert conn.cursor == service.rv and not conn.notified
+    assert sync.binding.equals(service)
+
+
+def test_a_v4_and_a_v3_peer_each_get_the_run_in_their_own_form(rpc):
+    """(b) side by side behind one burst: columnar frames for the v4
+    peer, v1 frames for the v3 peer, the same events in both, each far
+    fewer frames than events."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    got = _dial_peers(server, clients, ("v4", "v3"))
+    n = 60
+    with service._lock:
+        _mutate(service, "mixed", n)
+    logged = service.log.since(0)
+    for i in got:
+        wait_until(lambda: len(_decoded(f.payload for f in got[i])) == n)
+        _assert_same_events(_decoded(f.payload for f in got[i]), logged)
+        assert len(got[i]) <= n // 10
+    assert all(b"events_v2" in f.payload for f in got[0])
+    assert all(b'"events"' in f.payload and b"events_v2" not in f.payload
+               for f in got[1])
+    assert _sent_counts() == (len(got[0]) + len(got[1]), 2 * n)
+
+
+def test_a_pushs_delta_precedes_its_reply_on_the_pushers_connection(rpc):
+    """(c) the notice stands in the same queue as the replies: when a
+    STATE_PUSH call returns, the DELTA that carries its event has already
+    been delivered to the pusher's own watch."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    sync, client = _watch(server, clients, service)
+    other, _ = _watch(server, clients, service)
+    for i in range(50):
+        _, doc, _ = client.call(
+            FrameType.STATE_PUSH, {"kind": "node_usage", "name": "n0"},
+            {"usage": np.asarray(resource_vector(cpu=i, memory=i),
+                                 np.int32)})
+        assert sync.rv >= doc["rv"], f"push {i}: reply overtook its DELTA"
+    wait_until(lambda: other.rv == service.rv)
+    assert sync.gaps == other.gaps == 0
+    assert sync.binding.equals(service) and other.binding.equals(service)
+
+
+def test_hello_racing_live_commits_neither_loses_nor_doubles(rpc):
+    """(d) watchers dial, bootstrap and re-bootstrap while a committer
+    never stops: whatever each HELLO served, the live stream resumes
+    right after it, so every pod is applied exactly once since the
+    watcher's last snapshot and none is missing."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    stop = threading.Event()
+    total = [0]
+
+    def commit():
+        while not stop.is_set() and total[0] < 3_000:
+            service.add_pod(f"p{total[0]}",
+                            resource_vector(cpu=1 + total[0], memory=1))
+            total[0] += 1
+            if total[0] % 7 == 0:
+                time.sleep(0.0005)
+
+    committer = threading.Thread(target=commit, daemon=True)
+    committer.start()
+    watchers = []
+    try:
+        for _ in range(4):
+            sync, client = _watch(server, clients, service)
+            watchers.append(sync)
+            for _ in range(3):
+                time.sleep(0.01)
+                sync.bootstrap(client)   # a re-HELLO on the live stream
+    finally:
+        stop.set()
+        committer.join(10)
+    assert total[0] > 0
+    for sync in watchers:
+        wait_until(lambda: sync.rv == service.rv)
+        assert sync.gaps == 0
+        assert sync.binding.equals(service)
+        assert set(sync.binding.applies.values()) == {1}
+
+
+def test_push_before_the_first_bootstrap_cannot_skip_the_snapshot(rpc):
+    """(d) a connection is a recipient from the moment it is listed: an
+    event committed between a watcher's dial and its first HELLO reaches
+    it as a live frame.  A watcher that has never synced drops it (the
+    HELLO's snapshot holds it) instead of taking its rv for its own."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    _mutate(service, "pods", 20)
+    frames: list = []
+    sync = StateSyncClient(MirrorBinding())
+    client = connect(server, clients, on_push=lambda f: (
+        sync.on_push(f), frames.append(f)))
+    wait_until(lambda: len(server._conns) == 1)
+    service.add_pod("between", resource_vector(cpu=3, memory=3))
+    wait_until(lambda: len(frames) == 1)
+    assert sync.rv == -1 and sync.applied == 0
+    sync.bootstrap(client)
+    assert sync.rv == service.rv and sync.binding.equals(service)
+    assert set(sync.binding.applies.values()) == {1}
+
+
+def test_watcher_behind_the_retained_log_is_poisoned_and_resnapshots(rpc):
+    """(e) what "too far behind" means now: the watcher's cursor has left
+    the log's retained window.  Its sender finds ResyncRequired, poisons
+    the connection, and the watcher's next HELLO is served the snapshot
+    (the HELLO's own rule, untouched)."""
+    server, clients = rpc
+    service = StateSyncService(retention=64)
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    sync, client = _watch(server, clients, service)
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    with service._lock:
+        for i in range(130):    # twice what the log keeps
+            service.update_node_usage(
+                "n0", resource_vector(cpu=10 + i, memory=i))
+    wait_until(lambda: not client.connected)
+    assert not conn.alive and conn.dropped == 1
+    assert _sent_counts()[1] < 64       # the run was never built
+    resets = sync.binding.resets     # the first bootstrap's snapshot
+    assert sync.rv < service.rv
+    service.update_node_usage("n0", resource_vector(cpu=7, memory=7))
+    client = connect(server, clients, on_push=sync.on_push)
+    sync.bootstrap(client)
+    assert sync.binding.resets == resets + 1, "came back by DELTA"
+    assert sync.rv == service.rv and sync.binding.equals(service)
+
+
+def test_burst_of_a_whole_wave_does_not_poison_a_reader_that_keeps_up(rpc):
+    """(e) 10,240 reports in one tight loop, two and a half times what
+    the log retains and what the send queue holds: the sender gets its
+    turns of the interpreter, takes a run each time, and the watcher
+    that reads them is never poisoned."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    names = [f"n{i}" for i in range(64)]
+    for name in names:
+        service.upsert_node(name, resource_vector(cpu=64_000, memory=65_536))
+    sync, client = _watch(server, clients, service)
+    applied0, n = sync.applied, 10_240
+    for i in range(n):
+        service.update_node_usage(
+            names[i % 64], resource_vector(cpu=1 + i, memory=1 + i % 999))
+    wait_until(lambda: sync.rv == service.rv, timeout=60.0)
+    assert client.connected, "the burst poisoned the connection"
+    assert sync.applied - applied0 == n and sync.gaps == 0
+    frames, events = _sent_counts()
+    assert events == n and frames < n
+    assert sync.binding.resets == 1 and sync.binding.equals(service)
+
+
+class ScriptedFaults:
+    """The server-side seam of the fault injector, scripted: the k-th
+    push frame gets ``actions[k]``, everything else goes out clean."""
+
+    def __init__(self, actions):
+        self.actions = list(actions)
+
+    def on_read(self):
+        pass
+
+    def outbound_action(self, is_push):
+        if is_push and self.actions:
+            return self.actions.pop(0)
+        return None
+
+
+@pytest.mark.parametrize("action, gaps", [
+    ("drop", 1), ("reorder", 1), ("duplicate", 0)])
+def test_fault_on_a_many_event_frame_is_caught_and_repaired(tmp_path, action,
+                                                            gaps):
+    """(f) a frame that carries a whole run is lost, doubled or overtaken
+    like any other: a lost or overtaken run shows as an rv gap at the next
+    frame, the watcher severs and its re-HELLO takes the snapshot; a
+    doubled run is dropped event by event by the rv guard.  Either way
+    the watcher ends with the service's state."""
+    server = RpcServer(str(tmp_path / "f.sock"),
+                       faults=ScriptedFaults([action]))
+    clients: list = []
+    try:
+        service = StateSyncService()
+        service.attach(server)
+        server.start()
+        service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+        sync, client = _watch(server, clients, service)
+        wait_until(lambda: len(server._conns) == 1)
+        conn = server._conns[0]
+        n = 40
+        with service._lock:
+            conn.idle = lambda: False         # behind from the first event
+            _mutate(service, "mixed", n)      # one frame of n: the fault's
+            del conn.idle
+        if action == "duplicate":
+            wait_until(lambda: sync.skipped == n)
+        else:
+            # the run is gone (drop) or held back (reorder) until the
+            # next frame goes out
+            time.sleep(0.05)
+            assert sync.rv == 1
+        service.add_pod("after", resource_vector(cpu=5, memory=5))
+        if gaps:
+            wait_until(lambda: not client.connected)
+            assert sync.needs_resync
+            client = connect(server, clients, on_push=sync.on_push)
+            sync.bootstrap(client)
+            assert sync.binding.resets == 2     # the first bootstrap's too
+        wait_until(lambda: sync.rv == service.rv)
+        assert sync.gaps == gaps
+        assert sync.binding.equals(service)
+        assert _sent_counts() == (2, n + 1)
+    finally:
+        for c in clients:
+            c.close()
+        server.stop()
+
+
+class _CountingDeque(collections.deque):
+    """Counts the entries an iteration from either end hands out."""
+
+    touched = 0
+
+    def __iter__(self):
+        for item in super().__iter__():
+            self.touched += 1
+            yield item
+
+    def __reversed__(self):
+        for item in super().__reversed__():
+            self.touched += 1
+            yield item
+
+
+@pytest.mark.parametrize("cursor, want", [
+    (4_000, list(range(4_001, 5_001))),     # the whole retained window
+    (4_999, [5_000]),                       # one behind
+    (4_990, list(range(4_991, 5_001))),
+    (5_000, []),                            # caught up: an empty tail
+    (3_999, None),                          # before the window
+], ids=["full_log", "one_behind", "ten_behind", "empty_tail",
+        "before_window"])
+def test_delta_log_since_reads_only_the_tail_it_returns(cursor, want):
+    """(g) the answers are what the scan of the whole log gave, for the
+    price of the entries returned (and the one that ends the walk)."""
+    log = DeltaLog(retention=1_000)
+    for rv in range(1, 5_001):
+        log.append(rv, {"kind": "e", "name": str(rv)}, {})
+    log._events = _CountingDeque(log._events)
+    assert log.oldest_rv() == 4_001
+    if want is None:
+        with pytest.raises(ResyncRequired):
+            log.since(cursor)
+        assert log._events.touched == 0
+        return
+    scanned = [(v, e, a) for v, e, a in list(log._events) if v > cursor]
+    log._events.touched = 0
+    got = log.since(cursor)
+    assert got == scanned and [v for v, _, _ in got] == want
+    assert log._events.touched <= len(want) + 1
+
+
+@pytest.mark.parametrize("sender_is", ["in_sendall", "reading_the_log"])
+def test_conn_close_with_a_notice_outstanding_leaks_no_sender(rpc, sender_is):
+    """(h) a connection is closed while its DELTA notice is still to be
+    served: queued behind a frame the socket will not take, or already
+    taken with the sender waiting for the log.  The sender finishes the
+    notice and leaves on the poison."""
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    got: list = []
+    client = connect(server, clients, on_push=got.append)
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    usage = resource_vector(cpu=1, memory=1)
+    if sender_is == "in_sendall":
+        release = threading.Event()
+        real_send_one = conn._send_one
+
+        def stuck_send_one(frame):
+            release.wait(5)
+            return real_send_one(frame)
+
+        conn._send_one = stuck_send_one
+        service.update_node_usage("n0", usage)     # taken, stuck in send
+        wait_until(conn.idle)
+        service.update_node_usage("n0", usage)     # a ready frame waits
+        service.update_node_usage("n0", usage)     # and a notice behind it
+        assert conn.notified and conn.queue.qsize() == 2
+        conn.close()
+        release.set()
+    else:
+        with service._lock:
+            conn.idle = lambda: False              # behind: a notice
+            service.update_node_usage("n0", usage)
+            del conn.idle
+            wait_until(conn.idle)                  # the sender has it
+            conn.close()
+    conn._sender.join(5)
+    assert not conn._sender.is_alive(), "sender thread leaked"
+    wait_until(lambda: not client.connected)
+    # what was built before the poison still left, in order
+    assert [e["rv"] for e, _ in _decoded(f.payload for f in got)] == \
+        list(range(2, service.rv + 1))
 
 
 @pytest.mark.parametrize("attached", [False, True],
