@@ -15,8 +15,12 @@ we keep integer exactness by bounding units instead):
     cpu:            milli-cores   (bound 21.4M mcores = 21k cores per node)
     memory:         MiB           (bound 21.4M MiB ~ 20 TiB per node)
     ephemeral:      MiB
-    gpu:            milli-GPU     (koordinator's kubernetes.io/gpu convention)
-    gpu_memory:     MiB
+    gpu:            percent of one device: 100 = a whole GPU (upstream's
+                    koordinator.sh/gpu-core; nvidia.com/gpu: n = n x 100).
+                    At most 100 asks for a share of ONE device, above it
+                    for whole devices (ops/deviceshare.split_request)
+    gpu_memory:     MiB           (koordinator.sh/gpu-memory; split evenly
+                    over the devices of a whole-device ask)
     rdma:           milli-VF
     batch/mid cpu:  milli-cores   (kubernetes.io/batch-cpu etc., apis/extension/resource.go:27-30)
     batch/mid mem:  MiB

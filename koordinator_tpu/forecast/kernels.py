@@ -157,7 +157,8 @@ def admission_reserve(
 
 # koordlint: shape[state: NxR i32 nodes, reserve: NxR i32 nodes]
 def forecast_gang_assign(state, reserve, pods, cfg, gangs, quota=None,
-                         passes: int = 2, solver: str = "greedy"):
+                         passes: int = 2, solver: str = "greedy",
+                         with_grants: bool = False):
     """``gang_assign`` with the forecast-headroom reserve charged for
     the duration of the solve — the predictive-admission SolverKit
     entry.
@@ -171,10 +172,12 @@ def forecast_gang_assign(state, reserve, pods, cfg, gangs, quota=None,
     from koordinator_tpu.ops.gang import gang_assign
 
     charged = state.replace(node_requested=state.node_requested + reserve)
-    a, new_state, new_quota = gang_assign(
-        charged, pods, cfg, gangs, quota, passes=passes, solver=solver)
-    return a, new_state.replace(
-        node_requested=new_state.node_requested - reserve), new_quota
+    a, new_state, new_quota, *grants = gang_assign(
+        charged, pods, cfg, gangs, quota, passes=passes, solver=solver,
+        with_grants=with_grants)
+    return (a, new_state.replace(
+        node_requested=new_state.node_requested - reserve), new_quota,
+        *grants)
 
 
 def reserve_fraction_sums(reserve: jax.Array, state) -> tuple[jax.Array,

@@ -572,6 +572,21 @@ process_gc_collections = PROCESS.gauge(
     "gc_collections",
     "Cumulative gc collections across generations (label: binary)")
 
+# -- DeviceShare inside the batched solve (ops/deviceshare.py) --
+deviceshare_grants = SCHEDULER.counter(
+    "deviceshare_grants_total",
+    "Device-requesting proposals that reached the device stage, by "
+    "outcome: granted (bound with its devices), lost_race (accepted on "
+    "the node's aggregate rows in a round in which a pod ahead of it took "
+    "the device; it proposed again), no_device (left the round unbound)")
+deviceshare_inventory_events = SCHEDULER.counter(
+    "deviceshare_inventory_events_total",
+    "Device inventories applied (node_upsert with devices, node_devices): "
+    "one row of the device table rewritten each")
+deviceshare_whole_free_devices = SCHEDULER.gauge(
+    "deviceshare_whole_free_devices",
+    "Usable GPUs with nothing granted on them, after the last commit")
+
 # -- deferred request accounting (scheduler/snapshot.py) --
 snapshot_requested_folds = SCHEDULER.counter(
     "snapshot_requested_folds_total",

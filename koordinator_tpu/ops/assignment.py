@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
-from koordinator_tpu.ops import filtering, scoring
+from koordinator_tpu.ops import deviceshare, filtering, scoring
 from koordinator_tpu.quota.admission import charge_quota, quota_admission_mask
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
 
@@ -155,6 +155,11 @@ def score_pods(
         state.node_valid[None, :],
         pods.valid[:, None],
     )
+    if state.devices is not None:
+        # DeviceShare Filter: a node whose aggregate GPU row fits but no
+        # device (or too few whole ones) does is not feasible
+        feasible = feasible & deviceshare.device_fit_pods(
+            state.devices, pods.requests)
     scores = _composite_score(
         cfg,
         state.node_allocatable,
@@ -178,8 +183,10 @@ def _greedy_scan(
     """Shared sequential-assignment scan (the single source of truth for both
     plain and reservation-aware greedy assignment).
 
-    Returns (assignments, rsv_choice, new_state, new_rsv, new_quota); the
-    reservation outputs are None when ``rsv`` is None.
+    Returns (assignments, rsv_choice, new_state, new_rsv, new_quota,
+    grants); the reservation outputs are None when ``rsv`` is None, and
+    ``grants`` (a :class:`~koordinator_tpu.ops.deviceshare.DeviceGrants`)
+    is None when the state carries no devices.
     """
     from koordinator_tpu.ops.reservation import (
         allocate_from_reservation,
@@ -197,11 +204,15 @@ def _greedy_scan(
         pods.requests, cfg.estimator_factors, cfg.estimator_defaults
     )
 
+    dev = state.devices
+    dreq = (None if dev is None
+            else deviceshare.pod_device_requests(pods.requests))
+
     def step(carry, idx):
         # est_added accumulates in-flight pods' estimated usage (the
         # reference's pod-assign cache) on top of whichever usage base the
         # threshold policy selects.
-        requested, est_added, cur_rsv, qstate = carry
+        requested, est_added, cur_rsv, qstate, dev_free = carry
         req = pods.requests[idx]          # (R,)
         pod_est = pod_est_all[idx]        # (R,)
         valid = pods.valid[idx]
@@ -227,6 +238,9 @@ def _greedy_scan(
             & state.node_valid
             & valid
         )
+        if dev is not None:
+            feasible = feasible & deviceshare.device_fit_pods(
+                dev, req[None, :], free=dev_free)[0]
         if qstate is not None:
             admitted = quota_admission_mask(
                 qstate, req[None, :], pods.quota_id[idx][None],
@@ -263,11 +277,27 @@ def _greedy_scan(
                 jnp.where(assigned, pods.quota_id[idx], -1),
                 non_preemptible=pods.non_preemptible[idx],
             )
-        return (requested, est_added, cur_rsv, qstate), (node, r_idx)
+        sel = None
+        if dev is not None:
+            # DeviceShare Reserve on the chosen node: feasibility above
+            # was checked against this same free row, so a device pod
+            # that is assigned is granted
+            one = jax.tree.map(lambda a: a[idx][None], dreq)
+            sel, _ = deviceshare.grant_rows(
+                dev_free[best][None], dev.total[best][None],
+                (dev.valid & dev.healthy)[best][None],
+                dev.group[best][None], one)
+            sel = sel[0] & assigned
+            dev_free = dev_free.at[best].add(
+                -(sel[:, None] * one.ask[0][None, :]))
+        return ((requested, est_added, cur_rsv, qstate, dev_free),
+                (node, r_idx, sel))
 
-    (requested, _, new_rsv, new_quota), (nodes_in_order, rsv_in_order) = jax.lax.scan(
+    ((requested, _, new_rsv, new_quota, dev_free),
+     (nodes_in_order, rsv_in_order, sel_in_order)) = jax.lax.scan(
         step,
-        (state.node_requested, jnp.zeros_like(state.node_usage), rsv, quota),
+        (state.node_requested, jnp.zeros_like(state.node_usage), rsv, quota,
+         None if dev is None else dev.free),
         order,
     )
     assignments = jnp.full(pods.capacity, -1, jnp.int32).at[order].set(nodes_in_order)
@@ -277,7 +307,25 @@ def _greedy_scan(
         else None
     )
     new_state = state.replace(node_requested=requested)
-    return assignments, rsv_choice, new_state, new_rsv, new_quota
+    grants = None
+    if dev is not None:
+        new_state = new_state.replace(devices=dev.replace(free=dev_free))
+        grants = deviceshare.DeviceGrants(
+            selection=jnp.zeros((pods.capacity, dev.shape[1]), bool)
+            .at[order].set(sel_in_order),
+            lost_races=jnp.zeros(pods.capacity, jnp.int32))
+    return assignments, rsv_choice, new_state, new_rsv, new_quota, grants
+
+
+def keep_devices(new_state: ClusterState, state: ClusterState) -> ClusterState:
+    """The rule of every solve entry asked for no grants: the device
+    plane comes back as it went in.  Its free tensor moves only together
+    with a grant handed to the caller, who records it; a path that
+    cannot carry the device stage leaves Reserve to the commit
+    (``Scheduler._grant_devices``)."""
+    if state.devices is None:
+        return new_state
+    return new_state.replace(devices=state.devices)
 
 
 def greedy_assign(
@@ -285,6 +333,7 @@ def greedy_assign(
     pods: PodBatch,
     cfg: ScoringConfig,
     quota=None,
+    with_grants: bool = False,
 ):
     """Assign a whole pending batch sequentially in priority order.
 
@@ -299,8 +348,14 @@ def greedy_assign(
 
     Determinism: ties break toward the lowest node index (the reference's
     selectHost randomizes among maxima; we fix the choice for reproducibility).
+
+    ``with_grants=True`` appends the device grants (None for a state
+    without devices) and leaves them taken off ``new_state.devices``;
+    without it the device plane is handed back untouched.
     """
-    assignments, _, new_state, _, new_quota = _greedy_scan(
+    assignments, _, new_state, _, new_quota, grants = _greedy_scan(
         state, pods, cfg, quota=quota
     )
-    return assignments, new_state, new_quota
+    if with_grants:
+        return assignments, new_state, new_quota, grants
+    return assignments, keep_devices(new_state, state), new_quota

@@ -39,7 +39,12 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from koordinator_tpu.ops.assignment import ScoringConfig, score_pods
+from koordinator_tpu.ops import deviceshare
+from koordinator_tpu.ops.assignment import (
+    ScoringConfig,
+    keep_devices,
+    score_pods,
+)
 from koordinator_tpu.quota.admission import (
     QuotaDeviceState,
     charge_quota_batch,
@@ -336,6 +341,11 @@ class _RoundCarry:
     assignments: jax.Array    # (P,)
     active: jax.Array         # (P,)
     quota: QuotaDeviceState | None
+    #: the device stage's share of the carry, None for a state without
+    #: devices: the per-device free tensor beside ``requested``, and what
+    #: was granted (and lost) so far
+    dev_free: jax.Array | None = None       # (N, D, 2)
+    grants: deviceshare.DeviceGrants | None = None
 
 
 #: candidate-selection strategies for ``select_candidates``:
@@ -390,11 +400,13 @@ def batch_assign(
     rounds: int = SOLVE_ROUNDS,
     spread_bits=CAND_SPREAD_BITS,
     method: str = "auto",
+    with_grants: bool = False,
 ):
     """Assign a pending batch in data-parallel propose/accept rounds.
 
     Same signature/returns as ``greedy_assign``: (assignments, new_state,
-    new_quota).  assignments is (P,) int32, -1 = unassigned.
+    new_quota), and the device grants after them with ``with_grants``.
+    assignments is (P,) int32, -1 = unassigned.
 
     ``spread_bits`` controls the candidate-diversity/score trade-off (see
     ``select_candidates``): an int ranks all k candidates by one quantized
@@ -414,7 +426,8 @@ def batch_assign(
     cand_key, cand_node = select_candidates(
         state, pods, cfg, k=k,
         spread_bits=spread_bits, method=method)
-    return _assign_rounds(state, pods, quota, cand_key, cand_node, rounds)
+    return _assign_rounds(state, pods, quota, cand_key, cand_node, rounds,
+                          with_grants=with_grants)
 
 
 def select_candidates(
@@ -603,12 +616,58 @@ def _choose_candidate(cand_key, cand_tb, fits):
         jnp.where(fits & (masked == best_key), cand_tb, -1), axis=1)
 
 
+def _device_accept(dev, dev_free, dreq, choice, accept, order_pos):
+    """DeviceShare Reserve for one round's accepted proposals: every node
+    serves the pods accepted onto it one at a time, in priority order,
+    each against the devices the ones before it left, so two pods of one
+    round never share a grant.  All nodes take their k-th pod in the
+    same step; the loop runs as many steps as the fullest node has
+    accepted device pods.  Returns (dev_free, (P, D) selection, (P,)
+    granted); a pod that is not granted lost its race."""
+    p, n = choice.shape[0], dev_free.shape[0]
+    usable = dev.valid & dev.healthy
+    ask = dreq.ask
+
+    def cond(c):
+        return jnp.any(c[0])
+
+    def body(c):
+        waiting, free, selection, granted = c
+        seg = jnp.where(waiting, choice, n)
+        first = jax.ops.segment_min(
+            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
+        turn = waiting & (order_pos == first[seg])
+        row = jnp.where(turn, choice, 0)
+        sel, ok = deviceshare.grant_rows(
+            free[row], dev.total[row], usable[row], dev.group[row], dreq)
+        ok = ok & turn
+        sel = sel & ok[:, None]
+        free = free.at[row].add(-(sel[:, :, None] * ask[:, None, :]))
+        return waiting & ~turn, free, selection | sel, granted | ok
+
+    _, dev_free, selection, granted = jax.lax.while_loop(
+        cond, body,
+        (accept & dreq.wants, dev_free,
+         jnp.zeros((p, dev.shape[1]), bool), jnp.zeros(p, bool)))
+    return dev_free, selection, granted
+
+
 @jax.named_scope("assign_rounds")
-def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
+def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds,
+                   with_grants: bool = False):
     """The shared propose/accept stage over (P, k) candidates.  The
     ``named_scope`` stage names here and below are metadata only: they
-    name the device ops in a profiler trace and change no operand."""
+    name the device ops in a profiler trace and change no operand.
+
+    Over a state with devices a proposal also needs a device fit at its
+    candidate and, once accepted on the node's aggregate rows, a grant
+    (:func:`_device_accept`); without one it is not accepted and
+    proposes again next round, as a pod that lost a capacity race does.
+    ``with_grants`` appends the grants to (assignments, state, quota)."""
     cand_valid = cand_key >= 0
+    dev = state.devices
+    dreq = (None if dev is None
+            else deviceshare.pod_device_requests(pods.requests))
     cand_tb = (None if _packed_regime(state.capacity)
                else _candidate_tb(cand_node, pods.rot_id, state.capacity))
 
@@ -621,6 +680,15 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
         active=active0,
         quota=quota,
     )
+    if dev is not None:
+        # each pod's place in the priority order, for the device stage
+        order_pos = jnp.zeros(pods.capacity, jnp.int32).at[order].set(
+            jnp.arange(pods.capacity, dtype=jnp.int32))
+        carry = carry.replace(
+            dev_free=dev.free,
+            grants=deviceshare.DeviceGrants(
+                selection=jnp.zeros((pods.capacity, dev.shape[1]), bool),
+                lost_races=jnp.zeros(pods.capacity, jnp.int32)))
 
     def round_body(_, c: _RoundCarry) -> _RoundCarry:
         with jax.named_scope("propose"):
@@ -635,6 +703,9 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
                 | (pods.requests[:, None, :] == 0),
                 axis=-1,
             ) & cand_valid
+            if dev is not None:
+                fits = fits & deviceshare.candidate_device_fit(
+                    dev, c.dev_free, dreq, cand_node)
             best = _choose_candidate(cand_key, cand_tb, fits)
             has = jnp.take_along_axis(fits, best[:, None], axis=1)[:, 0]
             choice = jnp.take_along_axis(
@@ -652,6 +723,15 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
                 accept = accept & _quota_prefix_accept(
                     c.quota, pods.requests, pods, order, act
                 )
+        dev_free, grants = c.dev_free, c.grants
+        if dev is not None:
+            dev_free, sel, granted = _device_accept(
+                dev, dev_free, dreq, choice, accept, order_pos)
+            lost = accept & dreq.wants & ~granted
+            accept = accept & ~lost
+            grants = deviceshare.DeviceGrants(
+                selection=grants.selection | sel,
+                lost_races=grants.lost_races + lost)
 
         safe = jnp.where(accept, choice, 0)
         add = jnp.where(accept[:, None], pods.requests, 0)
@@ -671,6 +751,8 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
             # condition actually converges
             active=act & ~accept,
             quota=new_quota,
+            dev_free=dev_free,
+            grants=grants,
         )
 
     # early-exit loop: most rounds converge long before the bound (pods
@@ -686,7 +768,12 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds):
 
     _, carry = jax.lax.while_loop(cond, body, (jnp.int32(0), carry))
     new_state = state.replace(node_requested=carry.requested)
-    return carry.assignments, new_state, carry.quota
+    if not with_grants:
+        return carry.assignments, new_state, carry.quota
+    if dev is not None:
+        new_state = new_state.replace(
+            devices=dev.replace(free=carry.dev_free))
+    return carry.assignments, new_state, carry.quota, carry.grants
 
 
 # ---------------------------------------------------------------------------
@@ -857,6 +944,7 @@ def assign_round_pass(
     cand_node: jnp.ndarray,
     cfg: ScoringConfig,
     rounds: int = SOLVE_ROUNDS,
+    with_grants: bool = False,
 ):
     """First solve pass over precomputed candidates, with the est-usage
     accumulation and quota recharge :func:`~koordinator_tpu.ops.gang.
@@ -864,11 +952,14 @@ def assign_round_pass(
     first pass over a GANGLESS batch (the incremental scheduler path only
     runs when the round has no gang pods).
 
-    Returns (assignments, new_state, new_quota, est_accum)."""
+    Returns (assignments, new_state, new_quota, est_accum), and the
+    device grants after them with ``with_grants``."""
     from koordinator_tpu.ops.assignment import pod_estimates
 
-    a, new_state, _ = _assign_rounds(state, pods, quota, cand_key,
-                                     cand_node, rounds)
+    a, new_state, _, grants = _assign_rounds(
+        state, pods, quota, cand_key, cand_node, rounds, with_grants=True)
+    if not with_grants:
+        new_state = keep_devices(new_state, state)
     keep = a >= 0
     est = pod_estimates(pods, cfg)
     node = jnp.where(keep, a, 0)
@@ -880,6 +971,8 @@ def assign_round_pass(
         # exactly as gang_assign does after rollback
         new_quota = charge_quota_batch(
             quota, pods.requests, pods.quota_id, keep, pods.non_preemptible)
+    if with_grants:
+        return a, new_state, new_quota, est_accum, grants
     return a, new_state, new_quota, est_accum
 
 
@@ -893,6 +986,7 @@ def assign_followup_pass(
     rounds: int = SOLVE_ROUNDS,
     spread_bits=CAND_SPREAD_BITS,
     method: str = "auto",
+    with_grants: bool = False,
 ):
     """A later gang_assign pass over the (compacted) leftover pods:
     candidates re-selected against the est-augmented state, assignments
@@ -901,24 +995,31 @@ def assign_followup_pass(
     the compacted batch, so solving the compacted leftovers equals
     solving the full batch with everyone else masked invalid.
 
-    Returns (assignments, new_state, new_quota, est_accum')."""
+    Returns (assignments, new_state, new_quota, est_accum'), and the
+    device grants after them with ``with_grants``."""
     from koordinator_tpu.ops.assignment import pod_estimates
 
     solve_state = state.replace(
         node_usage=state.node_usage + est_accum,
         node_agg_usage=state.node_agg_usage + est_accum)
-    a, _, _ = batch_assign(solve_state, pods, cfg, quota, k=k,
-                           rounds=rounds, spread_bits=spread_bits,
-                           method=method)
+    a, _, _, grants = batch_assign(
+        solve_state, pods, cfg, quota, k=k, rounds=rounds,
+        spread_bits=spread_bits, method=method, with_grants=True)
     keep = (a >= 0) & pods.valid
     node = jnp.where(keep, a, 0)
     add = jnp.where(keep[:, None], pods.requests, 0)
     new_state = state.replace(
         node_requested=state.node_requested.at[node].add(add))
+    if with_grants and state.devices is not None:
+        new_state = new_state.replace(devices=deviceshare.apply_grants(
+            state.devices, node, keep, grants.selection,
+            deviceshare.pod_device_requests(pods.requests).ask))
     est = pod_estimates(pods, cfg)
     est_accum = est_accum.at[node].add(jnp.where(keep[:, None], est, 0))
     new_quota = quota
     if quota is not None:
         new_quota = charge_quota_batch(
             quota, pods.requests, pods.quota_id, keep, pods.non_preemptible)
+    if with_grants:
+        return a, new_state, new_quota, est_accum, grants
     return a, new_state, new_quota, est_accum

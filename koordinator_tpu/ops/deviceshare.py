@@ -18,6 +18,15 @@ type; Filter/Score are batched over all nodes, allocation picks device ids on
 the chosen node (same batched-filter / single-node-reserve split as
 ops/numa.py). Joint GPU+NIC allocation prefers devices of both types in one
 topology group (device_allocator.go:208 tryJointAllocate).
+
+The GPU type rides the batched solve itself (``ClusterState.devices``, in
+the snapshot's node rows): :func:`device_fit_pods` joins feasibility,
+:func:`candidate_device_fit` and :func:`grant_rows` the propose/accept
+rounds and the exact scan, and the grant comes back beside the assignment
+as a :class:`DeviceGrants`.  What the solve does NOT exercise: RDMA and
+``joint_allocate``, partition templates, DeviceShare's Score
+(``device_score``) and the spread strategy; those stay single-node host
+calls of their callers.
 """
 
 from __future__ import annotations
@@ -29,8 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from koordinator_tpu.api.resources import ResourceDim
 from koordinator_tpu.ops.select import take_by_rank
-from koordinator_tpu.state.cluster_state import _bucket
 
 #: Per-device resource dims: core (percent, 100 per device) and memory (MiB).
 DEV_CORE = 0
@@ -75,6 +84,9 @@ class DeviceState:
     ) -> "DeviceState":
         """From host records: one dict per device with keys
         core/memory/group/healthy (Device CRD device_types.go:112 entries)."""
+        # at call time: the state module imports this one
+        from koordinator_tpu.state.cluster_state import _bucket
+
         n = len(per_node_devices)
         ncap = node_capacity or _bucket(max(n, 1))
         dmax = max((len(d) for d in per_node_devices), default=1)
@@ -317,3 +329,138 @@ def partition_allocate(
     pick = jnp.argmax(fits)                                # first fitting row
     ok = jnp.any(fits)
     return templates[pick] & ok, ok
+
+
+# ---------------------------------------------------------------------------
+# The device stage of the batched solve
+# ---------------------------------------------------------------------------
+#
+# DeviceShare's Filter and Reserve for the GPU type, over every pod of a
+# batch at once.  A pod's device request is read off its request vector
+# (``ResourceDim.GPU`` in percent of one device, ``GPU_MEMORY`` in MiB:
+# upstream's gpu-core / gpu-memory), split as :func:`split_request` does.
+# The grant rule is ``allocate_on_node``'s at its default (``DEV_BINPACK``,
+# no preferred group), written over a leading pod axis.
+
+#: a device's minor index occupies the low bits of a grant ranking key
+_MINOR_BITS = 6
+MAX_DEVICES_PER_NODE = 1 << _MINOR_BITS
+
+
+@struct.dataclass
+class DeviceGrants:
+    """What the device stage decided for each pod of a solve."""
+
+    selection: jax.Array   # (P, D) bool — the minors granted on its node
+    lost_races: jax.Array  # (P,) int32 — accepts undone for want of a device
+
+
+@struct.dataclass
+class PodDeviceRequests:
+    """A batch's device requests, split per device (all (P,))."""
+
+    n_whole: jax.Array   # int32, 0 = shared (one device)
+    core: jax.Array      # int32 per-device core ask
+    memory: jax.Array    # int32 per-device memory ask
+    wants: jax.Array     # bool — the pod asks for a device at all
+
+    @property
+    def ask(self) -> jax.Array:
+        """(P, 2) per-device amount a grant takes off each chosen device."""
+        return jnp.stack([self.core, self.memory], axis=-1)
+
+
+def pod_device_requests(requests: jnp.ndarray) -> PodDeviceRequests:
+    """:func:`split_request` over a (P, R) request tensor."""
+    core = requests[..., ResourceDim.GPU]
+    memory = requests[..., ResourceDim.GPU_MEMORY]
+    n = jnp.where(core > 100, -(-core // 100), 0)
+    return PodDeviceRequests(
+        n_whole=n.astype(jnp.int32),
+        core=jnp.where(n > 0, 100, core).astype(jnp.int32),
+        memory=jnp.where(n > 0, -(-memory // jnp.maximum(n, 1)),
+                         memory).astype(jnp.int32),
+        wants=(core > 0) | (memory > 0),
+    )
+
+
+def _fit(free, total, usable, req: PodDeviceRequests):
+    """(P, X) bool: the Filter both callers share, of P pods against X
+    device rows each ((P or 1, X, D, 2) / (.., D)); a pod that asks for
+    no device fits everywhere."""
+    core = req.core[:, None, None]
+    memory = req.memory[:, None, None]
+    fits_each = (usable & (free[..., DEV_CORE] >= core)
+                 & (free[..., DEV_MEM] >= memory))
+    whole = (usable & jnp.all(free == total, axis=-1)
+             & (total[..., DEV_CORE] >= core)
+             & (total[..., DEV_MEM] >= memory))
+    shared_ok = jnp.any(fits_each, axis=-1)
+    whole_ok = (jnp.sum(whole.astype(jnp.int32), axis=-1)
+                >= req.n_whole[:, None])
+    ok = jnp.where((req.n_whole > 0)[:, None], whole_ok, shared_ok)
+    return ok | ~req.wants[:, None]
+
+
+@jax.named_scope("deviceshare/fit")
+def device_fit_pods(dev: DeviceState, requests: jnp.ndarray,
+                    free: jnp.ndarray | None = None) -> jnp.ndarray:
+    """(P, N) bool — :func:`device_fit` for every pod of a batch; a pod
+    that asks for no device fits everywhere.  ``free`` stands in for
+    ``dev.free`` where a solve carries its own."""
+    free = dev.free if free is None else free
+    return _fit(free[None], dev.total[None], _usable(dev)[None],
+                pod_device_requests(requests))
+
+
+@jax.named_scope("deviceshare/fit")
+def candidate_device_fit(dev: DeviceState, free: jnp.ndarray,
+                         req: PodDeviceRequests,
+                         cand_node: jnp.ndarray) -> jnp.ndarray:
+    """(P, k) bool — the same Filter at each pod's candidate nodes only,
+    against the free tensor a solve's rounds carry."""
+    return _fit(free[cand_node], dev.total[cand_node],
+                _usable(dev)[cand_node], req)
+
+
+@jax.named_scope("deviceshare/grant")
+def grant_rows(free, total, usable, group,
+               req: PodDeviceRequests) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Reserve for P pods, each on a device row of its own ((P, D, 2) /
+    (P, D)): ((P, D) bool selection, (P,) ok).  The rule is
+    :func:`allocate_on_node`'s default: shared = the fitting device with
+    the least free core, whole = n wholly free devices out of the
+    topology group that satisfies the ask with the least left over; ties
+    to the lowest minor."""
+    d = usable.shape[-1]
+    if d > MAX_DEVICES_PER_NODE:
+        raise ValueError(f"{d} devices a node exceed the grant key's "
+                         f"{MAX_DEVICES_PER_NODE}")
+    core, memory = req.core[:, None], req.memory[:, None]
+    whole = req.n_whole[:, None] > 0
+    fits = usable & (free[..., DEV_CORE] >= core) & (free[..., DEV_MEM] >= memory)
+    wfree = (usable & jnp.all(free == total, axis=-1)
+             & (total[..., DEV_CORE] >= core) & (total[..., DEV_MEM] >= memory))
+    same_group = group[:, :, None] == group[:, None, :]
+    grp_count = jnp.sum(same_group & wfree[:, None, :], axis=-1)
+    eligible = jnp.where(whole, wfree, fits)
+    rank_by = jnp.where(
+        whole,
+        jnp.where(grp_count >= req.n_whole[:, None], grp_count, d + 1),
+        jnp.clip(free[..., DEV_CORE], 0, (1 << 23) - 1))
+    key = ((~eligible).astype(jnp.int32) << 30
+           | rank_by.astype(jnp.int32) << _MINOR_BITS
+           | jnp.arange(d, dtype=jnp.int32)[None, :])
+    rank = jnp.sum(key[:, None, :] < key[:, :, None], axis=-1)
+    k = jnp.where(req.n_whole > 0, req.n_whole, 1)[:, None]
+    selected = (rank < k) & eligible
+    ok = (jnp.sum(selected, axis=-1) >= k[:, 0]) & req.wants
+    return selected & ok[:, None], ok
+
+
+def apply_grants(dev: DeviceState, node: jnp.ndarray, keep: jnp.ndarray,
+                 selection: jnp.ndarray, ask: jnp.ndarray) -> DeviceState:
+    """Take the kept pods' grants off their nodes' devices."""
+    take = (keep[:, None] & selection)[:, :, None] * ask[:, None, :]
+    return dev.replace(
+        free=dev.free.at[jnp.where(keep, node, 0)].add(-take))

@@ -11,8 +11,9 @@ already computes, never materializing the (P, N) reason tensor on host.
 Attribution is FIRST-FAIL in filter order (matching
 ``scheduler/diagnosis.explain_pod``): a node counts against exactly one
 reason — resource fit (per dimension, first failing dim in global dim
-order), then the usage threshold, then affinity/selector.  Invalid node
-rows count separately.  Pod-level gates (elastic-quota admission, the
+order), then the usage threshold, then affinity/selector, then the
+DeviceShare filter (a node whose aggregate GPU rows fit but on which no
+device, or too few whole ones, does).  Invalid node rows count separately.  Pod-level gates (elastic-quota admission, the
 gang barrier, degraded-mode suspension) have no per-node mask: their
 columns exist in the taxonomy for the scheduler to fill host-side when
 it attributes a failure to them (``scheduler/scheduler.py`` Diagnose).
@@ -30,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
-from koordinator_tpu.ops import scoring
+from koordinator_tpu.ops import deviceshare, scoring
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
 
 # ---- reason taxonomy -------------------------------------------------------
@@ -44,17 +45,20 @@ REASON_NODE_INVALID = 0
 REASON_FIT_FIRST = 1
 REASON_USAGE_THRESHOLD = 1 + NUM_RESOURCE_DIMS
 REASON_AFFINITY = 2 + NUM_RESOURCE_DIMS
+#: DeviceShare Filter: zero for a state without a device plane
+REASON_DEVICE = 3 + NUM_RESOURCE_DIMS
 #: pod-level gates (host-filled; the device kernel leaves them zero)
-REASON_QUOTA = 3 + NUM_RESOURCE_DIMS
-REASON_GANG = 4 + NUM_RESOURCE_DIMS
-REASON_DEGRADED = 5 + NUM_RESOURCE_DIMS
-NUM_REASONS = 6 + NUM_RESOURCE_DIMS
+REASON_QUOTA = 4 + NUM_RESOURCE_DIMS
+REASON_GANG = 5 + NUM_RESOURCE_DIMS
+REASON_DEGRADED = 6 + NUM_RESOURCE_DIMS
+NUM_REASONS = 7 + NUM_RESOURCE_DIMS
 
 REASON_NAMES: tuple[str, ...] = (
     "node_invalid",
     *(f"fit_{dim.name.lower()}" for dim in ResourceDim),
     "usage_threshold",
     "affinity",
+    "device_fit",
     "quota",
     "gang_barrier",
     "degraded_suspended",
@@ -112,11 +116,17 @@ def explain_counts(
     thr = _threshold_mask(cfg, state.node_usage, state.node_agg_usage,
                           state.node_allocatable, pod_est)
     aff = pods.feasible_rows(state)
+    ok = base & fit & thr & aff
 
     fit_counts = jnp.sum((base & ~fit)[:, :, None] & ff, axis=1)  # (P, R)
     thr_fail = jnp.sum(base & fit & ~thr, axis=1)                 # (P,)
     aff_fail = jnp.sum(base & fit & thr & ~aff, axis=1)
-    feasible = jnp.sum(base & fit & thr & aff, axis=1)
+    dev_fail = jnp.zeros(pods.capacity, jnp.int32)
+    if state.devices is not None:
+        dev_fit = deviceshare.device_fit_pods(state.devices, pods.requests)
+        dev_fail = jnp.sum(ok & ~dev_fit, axis=1)
+        ok = ok & dev_fit
+    feasible = jnp.sum(ok, axis=1)
     invalid = jnp.where(pod_valid, jnp.sum(~valid_n), 0)
 
     counts = jnp.concatenate(
@@ -125,6 +135,7 @@ def explain_counts(
             fit_counts,
             thr_fail[:, None],
             aff_fail[:, None],
+            dev_fail[:, None],
             jnp.zeros((pods.capacity, 3), jnp.int32),   # quota/gang/degraded
         ],
         axis=1,

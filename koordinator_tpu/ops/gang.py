@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from koordinator_tpu.ops import deviceshare
 from koordinator_tpu.ops.assignment import ScoringConfig, greedy_assign
 from koordinator_tpu.quota.admission import charge_quota_batch
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
@@ -140,11 +141,13 @@ def gang_assign(
     passes: int = 2,
     solver: str = "greedy",
     method: str = "auto",
+    with_grants: bool = False,
 ):
     """Batch assignment with gang all-or-nothing semantics.
 
     Returns (assignments, state, quota) as :func:`greedy_assign` does (quota
-    is None when not given). ``passes`` > 1 re-solves leftover pods after
+    is None when not given), and the device grants after them with
+    ``with_grants``. ``passes`` > 1 re-solves leftover pods after
     failed-gang rollback so freed capacity is reclaimed within the batch.
 
     ``solver`` picks the per-pass assignment engine: ``"greedy"`` is the
@@ -157,7 +160,7 @@ def gang_assign(
     solver's candidate selection (batch_assign.CANDIDATE_METHODS), so
     gang solves can force the chunked/approx paths too.
     """
-    from koordinator_tpu.ops.assignment import pod_estimates
+    from koordinator_tpu.ops.assignment import keep_devices, pod_estimates
     from koordinator_tpu.ops.batch_assign import batch_assign
 
     if solver not in ("greedy", "batch"):
@@ -184,6 +187,15 @@ def gang_assign(
     # overcommit past the load thresholds a single-pass solve would enforce.
     pod_est_all = pod_estimates(pods, cfg)
     est_accum = jnp.zeros_like(state.node_usage)
+    # the device stage's outputs, kept like the assignments: a rolled-
+    # back gang's grants go back with its node accounting
+    grants = None
+    if state.devices is not None:
+        dev_ask = deviceshare.pod_device_requests(pods.requests).ask
+        grants = deviceshare.DeviceGrants(
+            selection=jnp.zeros((pods.capacity, state.devices.shape[1]),
+                                bool),
+            lost_races=jnp.zeros(pods.capacity, jnp.int32))
 
     for _ in range(passes):
         with jax.named_scope("gang_pass"):
@@ -192,15 +204,29 @@ def gang_assign(
                 node_agg_usage=cur_state.node_agg_usage + est_accum,
             )
             if solver == "batch":
-                a, _, _ = batch_assign(solve_state, active_pods, cfg, cur_quota,
-                                       method=method)
+                a, _, _, g = batch_assign(
+                    solve_state, active_pods, cfg, cur_quota,
+                    method=method, with_grants=True)
             else:
-                a, _, _ = greedy_assign(solve_state, active_pods, cfg, cur_quota)
+                a, _, _, g = greedy_assign(
+                    solve_state, active_pods, cfg, cur_quota,
+                    with_grants=True)
 
             final, cur_state, keep, failed = rollback_failed_gangs(
                 a, cur_state, active_pods, gangs, prior_kept=kept_so_far
             )
             node = jnp.where(keep, final, 0)
+            if grants is not None:
+                # rebuilt like node_requested: the state before the pass
+                # plus the kept pods' grants alone
+                cur_state = cur_state.replace(
+                    devices=deviceshare.apply_grants(
+                        cur_state.devices, node, keep, g.selection,
+                        dev_ask))
+                grants = deviceshare.DeviceGrants(
+                    selection=jnp.where(keep[:, None], g.selection,
+                                        grants.selection),
+                    lost_races=grants.lost_races + g.lost_races)
             est_accum = est_accum.at[node].add(
                 jnp.where(keep[:, None], pod_est_all, 0)
             )
@@ -217,4 +243,6 @@ def gang_assign(
                 valid=active_pods.valid & ~keep & ~failed
             )
 
-    return total, cur_state, cur_quota
+    if with_grants:
+        return total, cur_state, cur_quota, grants
+    return total, keep_devices(cur_state, state), cur_quota

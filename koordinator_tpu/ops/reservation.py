@@ -267,8 +267,11 @@ def reservation_greedy_assign(
 
     Returns (assignments, rsv_choice, new_state, new_rsv, new_quota).
     """
-    from koordinator_tpu.ops.assignment import _greedy_scan
+    from koordinator_tpu.ops.assignment import _greedy_scan, keep_devices
 
-    return _greedy_scan(
+    # device feasibility joins the scan; the grants are the commit's
+    # (the pre-pass binds one pod at a time through ``_commit_bind``)
+    a, choice, new_state, new_rsv, new_quota, _ = _greedy_scan(
         state, pods, cfg, quota=quota, rsv=rsv, match=match, rsv_boost=boost
     )
+    return a, choice, keep_devices(new_state, state), new_rsv, new_quota

@@ -17,7 +17,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
-from koordinator_tpu.ops import filtering, scoring
+from koordinator_tpu.ops import deviceshare, filtering, scoring
 from koordinator_tpu.ops.assignment import ScoringConfig
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
 
@@ -41,6 +41,9 @@ class PodDiagnosis:
     #: (per-dim fit, threshold, affinity, plus host-filled pod-level
     #: gates); None when the explain accounting was disabled
     reason_counts: dict[str, int] | None = None
+    #: nodes that passed every other filter and on which no device (or
+    #: too few whole ones) fits the pod's device request (DeviceShare)
+    device_unfit: int = 0
 
     def message(self) -> str:
         msg = self._base_message()
@@ -55,11 +58,18 @@ class PodDiagnosis:
             return "pod rejected by elastic quota admission"
         parts = []
         if self.insufficient_resources:
-            parts.append(f"{self.insufficient_resources} insufficient resources")
+            part = f"{self.insufficient_resources} insufficient resources"
+            # of them, the nodes short on the aggregate gpu rows: a device
+            # pod's diagnosis names the dimension it is waiting for
+            gpu = sum((self.reason_counts or {}).get(name, 0)
+                      for name in ("fit_gpu", "fit_gpu_memory"))
+            parts.append(f"{part} ({gpu} gpu)" if gpu else part)
         if self.usage_over_threshold:
             parts.append(f"{self.usage_over_threshold} usage over threshold")
         if self.affinity_mismatch:
             parts.append(f"{self.affinity_mismatch} didn't match node selector")
+        if self.device_unfit:
+            parts.append(f"{self.device_unfit} insufficient devices (gpu)")
         detail = ", ".join(parts) if parts else "no failure recorded"
         return (f"{self.feasible_nodes}/{self.total_nodes} nodes available: "
                 f"{detail}")
@@ -91,12 +101,16 @@ def explain_pod(
     agg_enabled = bool(jnp.any(cfg.agg_usage_thresholds > 0))
     thr = np.asarray((agg if agg_enabled else inst)[0]) & valid
     aff = np.asarray(pods.feasible_row(state, pod_idx)) & valid
+    dev = np.ones_like(valid)
+    if state.devices is not None:
+        dev = np.asarray(deviceshare.device_fit_pods(state.devices, req)[0])
 
-    feasible = fit & thr & aff
+    feasible = fit & thr & aff & dev
     # first-fail attribution, in filter order: fit -> thresholds -> affinity
     fail_fit = valid & ~fit
     fail_thr = valid & fit & ~thr
     fail_aff = valid & fit & thr & ~aff
+    fail_dev = valid & fit & thr & aff & ~dev
 
     # per-dim first-fail fit counts: the NumPy oracle the device kernel
     # (ops/explain.explain_counts) is tested against
@@ -115,6 +129,7 @@ def explain_pod(
             (fail_fit & ff[:, d]).sum())
     counts["usage_threshold"] = int(fail_thr.sum())
     counts["affinity"] = int(fail_aff.sum())
+    counts["device_fit"] = int(fail_dev.sum())
 
     return PodDiagnosis(
         total_nodes=total,
@@ -125,6 +140,7 @@ def explain_pod(
         quota_rejected=not quota_admitted,
         invalid=int((~valid).sum()),
         reason_counts=counts,
+        device_unfit=int(fail_dev.sum()),
     )
 
 
@@ -154,4 +170,20 @@ def diagnosis_from_counts(
         quota_rejected=not quota_admitted,
         invalid=int(counts[ex.REASON_NODE_INVALID]),
         reason_counts=reason_counts,
+        device_unfit=int(counts[ex.REASON_DEVICE]),
     )
+
+
+def device_refusal(total_nodes: int, node: str) -> PodDiagnosis:
+    """The diagnosis of a bind undone at the commit because no device
+    grant could be made on its node (DeviceShare Reserve failed): the
+    solve that chose the node carried no device stage."""
+    from koordinator_tpu.ops import explain as ex
+
+    counts = {name: 0 for name in ex.REASON_NAMES}
+    counts["device_fit"] = 1
+    return PodDiagnosis(
+        total_nodes=total_nodes, feasible_nodes=0,
+        insufficient_resources=0, usage_over_threshold=0,
+        affinity_mismatch=0, quota_rejected=False, invalid=0,
+        reason_counts=counts, device_unfit=1)

@@ -37,6 +37,8 @@ from koordinator_tpu.ops.network_topology import (
     plan_gang_placement,
 )
 from koordinator_tpu.quota.admission import QuotaDeviceState
+from koordinator_tpu.ops.deviceshare import split_request
+from koordinator_tpu.scheduler.device_manager import SOLVE_DEVICE_TYPE
 from koordinator_tpu.quota.tree import QuotaTree
 from koordinator_tpu.scheduler import bound_columns
 from koordinator_tpu.scheduler.bound_columns import BoundRegistry
@@ -128,6 +130,51 @@ class SchedulingResult:
     )
 
 
+def _wants_device(pod) -> bool:
+    """Does the pod ask for a device (upstream's gpu-core / gpu-memory)?"""
+    from koordinator_tpu.api.resources import ResourceDim
+
+    return bool(pod.requests[ResourceDim.GPU] > 0
+                or pod.requests[ResourceDim.GPU_MEMORY] > 0)
+
+
+class _RoundGrants:
+    """A round's device grants on the host, by batch row: the first
+    solve's, with what the later passes and the rescue scan granted over
+    their compacted rows merged in."""
+
+    def __init__(self, selection: np.ndarray, lost_races: np.ndarray):
+        self.selection = selection      # (P, D) bool
+        self.lost_races = lost_races    # (P,) int32
+
+    @classmethod
+    def of(cls, grants) -> "_RoundGrants | None":
+        """A solve's ``DeviceGrants`` (None: it carried no device stage)."""
+        if grants is None:
+            return None
+        return cls(np.array(grants.selection), np.array(grants.lost_races))
+
+    @staticmethod
+    def merged(current: "_RoundGrants | None", grants, rows: np.ndarray,
+               placed: np.ndarray, capacity: int) -> "_RoundGrants | None":
+        """``current`` with the ``grants`` of a solve over the compacted
+        ``rows`` of a batch of ``capacity`` merged in; ``placed`` marks
+        the rows it assigned.  A first solve that granted nothing (a
+        path without the device stage) starts from an empty sheet: the
+        later solve's grants are on the device all the same."""
+        if grants is None:
+            return current
+        if current is None:
+            current = _RoundGrants(
+                np.zeros((capacity, grants.selection.shape[1]), bool),
+                np.zeros(capacity, np.int32))
+        k = len(rows)
+        current.lost_races[rows] += np.asarray(grants.lost_races)[:k]
+        current.selection[rows[placed]] = np.asarray(
+            grants.selection)[:k][placed]
+        return current
+
+
 @dataclasses.dataclass
 class RoundHandle:
     """An in-flight round between its device and host halves (ISSUE 11).
@@ -157,6 +204,9 @@ class RoundHandle:
     solver: str = "greedy"
     assignments: object = None           # in-flight device array
     new_quota: object = None
+    #: the solve's device grants (``ops/deviceshare.DeviceGrants``, in
+    #: flight), None when it carried no device stage
+    grants: object = None
     #: incremental-path finish context (None = full/greedy path)
     inc: dict | None = None
     #: quality-path finish context (ISSUE 13): the LP solve's in-flight
@@ -371,6 +421,10 @@ class Scheduler:
         #: at bind; annotation payloads surface in resource_status
         self.cpu_manager = cpu_manager
         self.device_manager = device_manager
+        if device_manager is not None:
+            # the device books move into the snapshot's node rows, and
+            # the GPU plane rides the solve (``ClusterState.devices``)
+            self.snapshot.attach_devices(device_manager)
         #: per-node vendor device-plugin lock annotations (the node-object
         #: annotation in the reference; vendors' plugins clear it via
         #: clear_device_node_lock when they finish a pod)
@@ -633,11 +687,10 @@ class Scheduler:
         reservation-aware node unreserve.  Both spans time host work
         alone; the unreserve's device op is the snapshot's next fold
         (span ``snapshot.fold``)."""
-        tl_t0 = time.perf_counter()
+        tl_t0 = timeline.RECORDER.open("release.fine_grained")
         self._release_fine_grained(bp.name, bp.node)
+        timeline.RECORDER.close(tl_t0, "host_other", self.tenant)
         tl_t1 = time.perf_counter()
-        timeline.RECORDER.add(tl_t0, tl_t1, "host_other",
-                              "release.fine_grained", self.tenant)
         self._unreserve_bound(bp)
         timeline.RECORDER.add(tl_t1, time.perf_counter(), "host_other",
                               "release.unreserve", self.tenant)
@@ -1108,6 +1161,7 @@ class Scheduler:
             self.snapshot.capacity,
             self.snapshot.class_count,
             self.batch_capacity_floor,
+            self._has_devices(),
         )
         if (not hinted and self._batch_cache is not None
                 and self._batch_cache[0] == key):
@@ -1229,7 +1283,8 @@ class Scheduler:
         if not hinted:
             # reused across steady-state rounds: the kit pins it where
             # its sharded entries read it in place
-            batch = self.kit.place_batch(batch, n_cap)
+            batch = self.kit.place_batch(batch, n_cap,
+                                         devices=self._has_devices())
             self._batch_cache = (key, batch)
             self._batch_host = {
                 "row_of": {pod.name: i for i, pod in enumerate(pods)},
@@ -1245,6 +1300,10 @@ class Scheduler:
             }
         self.batch_rebuilds += 1
         return batch
+
+    def _has_devices(self) -> bool:
+        """Does the state the next solve meets carry a device plane?"""
+        return self.snapshot.resident_state.devices is not None
 
     def _build_gang_info(self, pods: list[PodSpec]) -> tuple[GangInfo, dict[str, int]]:
         names = sorted({p.gang for p in pods if p.gang is not None})
@@ -1847,21 +1906,23 @@ class Scheduler:
                     metrics.incremental_solve_total.inc(labels={
                         "path": self.last_solve_path})
                 if forecast_reserve is not None:
-                    assignments, new_state, new_quota = (
+                    assignments, new_state, new_quota, grants = (
                         self.kit.forecast_solve(
                             self.snapshot.state, forecast_reserve, batch,
                             self.config, gangs, quota,
                             passes=self.gang_passes, solver=solver))
                 else:
-                    assignments, new_state, new_quota = self.kit.solve(
-                        self.snapshot.state, batch, self.config, gangs,
-                        quota, passes=self.gang_passes, solver=solver)
+                    assignments, new_state, new_quota, grants = (
+                        self.kit.solve(
+                            self.snapshot.state, batch, self.config, gangs,
+                            quota, passes=self.gang_passes, solver=solver))
                 # the blessed swap: the jitted solve donated the old
                 # state buffers; the snapshot re-points at the in-flight
                 # result immediately so nothing can read the dead ones
                 self.snapshot.state = new_state
                 handle.assignments = assignments
                 handle.new_quota = new_quota
+                handle.grants = grants
         except Exception:
             self._recover_solve_failure()
             raise
@@ -1966,12 +2027,13 @@ class Scheduler:
                                     carry_s=self._solve_carry_s):
                 self._solve_carry_s = 0.0
                 if handle.inc is not None:
-                    assignments, new_state, new_quota = (
+                    assignments, new_state, new_quota, grants = (
                         self._finish_batch_incremental(handle.inc))
                 else:
                     # the solve's in-flight state, read back from where
                     # dispatch swapped it in (see RoundHandle)
                     new_state = self.snapshot.state
+                    grants = _RoundGrants.of(handle.grants)
                 a = np.asarray(self._block_timed(assignments))
                 leftover = np.asarray(batch.valid) & (a < 0)
                 if solver == "batch" and bool(leftover[: len(pods)].any()):
@@ -2005,20 +2067,24 @@ class Scheduler:
                         # charged accounting as its main solve — an
                         # uncharged rescue would re-admit exactly the
                         # pods the reserve just filtered
-                        r_small, new_state, new_quota = (
+                        r_small, new_state, new_quota, g_small = (
                             self.kit.forecast_solve(
                                 new_state, handle.forecast_reserve, small,
                                 self.config, gangs, new_quota,
                                 passes=self.gang_passes, solver="greedy"))
                     else:
-                        r_small, new_state, new_quota = self.kit.solve(
-                            new_state, small, self.config, gangs,
-                            new_quota,
-                            passes=self.gang_passes, solver="greedy")
+                        r_small, new_state, new_quota, g_small = (
+                            self.kit.solve(
+                                new_state, small, self.config, gangs,
+                                new_quota,
+                                passes=self.gang_passes, solver="greedy"))
                     self.snapshot.state = new_state
                     r_full = np.full(batch.capacity, -1, np.int32)
                     r_full[idx] = np.asarray(
                         self._block_timed(r_small))[: len(idx)]
+                    grants = _RoundGrants.merged(
+                        grants, g_small, idx, r_full[idx] >= 0,
+                        batch.capacity)
                     assignments = jnp.where(
                         assignments >= 0, assignments, jnp.asarray(r_full))
                     a = np.asarray(assignments)
@@ -2055,6 +2121,7 @@ class Scheduler:
         with self.monitor.phase("Bind"):
             placed_gangs: set[str] = set()
             binds: list[tuple[PodSpec, str]] = []
+            bind_rows: list[int] = []
             for i, pod in enumerate(pods):
                 node_row = int(a[i])
                 if node_row >= 0:
@@ -2063,9 +2130,14 @@ class Scheduler:
                         self._commit_reserve_pod(pod, node, result, now)
                         continue
                     binds.append((pod, node))
+                    bind_rows.append(i)
                     if pod.gang:
                         placed_gangs.add(pod.gang)
-            self._commit_bind_batch(binds, result)
+            self._commit_bind_batch(
+                binds, result,
+                None if grants is None else grants.selection[bind_rows])
+            if grants is not None:
+                self._count_device_outcomes(pods, a, grants)
 
         with self.monitor.phase("Diagnose"):
             admitted = None
@@ -2338,7 +2410,7 @@ class Scheduler:
         kept for callers outside the round pipeline.  Returns
         (assignments, new_state, new_quota) like gang_assign."""
         return self._finish_batch_incremental(
-            self._dispatch_batch_incremental(pods, batch, quota))
+            self._dispatch_batch_incremental(pods, batch, quota))[:3]
 
     def _dispatch_batch_incremental(self, pods, batch: PodBatch, quota) -> dict:  # koordlint: guarded-by(self.lock)
         """The no-gang batch solve with the persistent device-resident
@@ -2368,7 +2440,8 @@ class Scheduler:
         n = snap.capacity
         # which selection (sharded, or a single-device method) the kit
         # runs for these shapes: a cache another selection built is cold
-        selection = self.kit.selection(n, batch)
+        selection = self.kit.selection(n, batch,
+                                       devices=self._has_devices())
         meta = self._cand_cache
         cache_ok = (
             meta is not None
@@ -2471,7 +2544,7 @@ class Scheduler:
         # failure the cache is dropped so the next round re-warms
         # instead of trusting un-bookkept state.
         try:
-            a, state, quota, est_accum = self.kit.pass1(
+            a, state, quota, est_accum, grants = self.kit.pass1(
                 snap.state, batch, quota, cache.cand_key, cache.cand_node,
                 self.config)
             snap.state = state
@@ -2479,7 +2552,7 @@ class Scheduler:
             self._cand_cache = None
             raise
         return {"a": a, "quota": quota, "est_accum": est_accum,
-                "batch": batch}
+                "batch": batch, "grants": grants}
 
     def _finish_batch_incremental(self, ctx: dict):  # koordlint: guarded-by(self.lock)
         """HOST half of the incremental solve: block on pass 1, then
@@ -2495,23 +2568,26 @@ class Scheduler:
             # a copy: np.asarray of a device array is a read-only view,
             # and a later pass writes what it placed into it
             a_np = np.array(self._block_timed(ctx["a"]))
+            grants = _RoundGrants.of(ctx.get("grants"))
             for _ in range(1, self.gang_passes):
                 leftover = np.asarray(batch.valid) & (a_np < 0)
                 if not leftover.any():
                     break
                 small, idx = batch.compact(leftover)
-                a2, state, quota, est_accum = self.kit.pass2(
+                a2, state, quota, est_accum, g2 = self.kit.pass2(
                     state, est_accum, small, quota, self.config)
                 snap.state = state
                 a2_np = np.asarray(self._block_timed(a2))[: len(idx)]
                 placed = a2_np >= 0
+                grants = _RoundGrants.merged(grants, g2, idx, placed,
+                                             batch.capacity)
                 if not placed.any():
                     break
                 a_np[idx[placed]] = a2_np[placed]
         except Exception:
             self._cand_cache = None
             raise
-        return jnp.asarray(a_np), state, quota
+        return jnp.asarray(a_np), state, quota, grants
 
     # -- placement explainability (ISSUE 6) ---------------------------------
 
@@ -2691,6 +2767,8 @@ class Scheduler:
             self._charge_quota_used(pod, sign=1)
         t_quota = time.perf_counter()
         tl.add(t_registry, t_quota, "bind_commit", "bind.quota", tenant)
+        if self._grant_devices([(pod, node)], None, result):
+            return   # no device grant: unreserved, pending again
         self._allocate_fine_grained(pod, node)
         # bind marker in the POD's trace (parented to its enqueue span,
         # linked to the round's trace by attribute), and the trace
@@ -2736,7 +2814,8 @@ class Scheduler:
 
     # koordlint: guarded-by(self.lock)
     def _commit_bind_batch(self, binds: list[tuple[PodSpec, str]],
-                           result: SchedulingResult) -> None:
+                           result: SchedulingResult,
+                           selections: np.ndarray | None = None) -> None:
         """One batched commit for a round's whole bind set (ISSUE 19).
 
         Sequential ``_commit_bind`` re-walks the quota tree and bumps
@@ -2753,7 +2832,11 @@ class Scheduler:
         explanation store and the auditor take the bind set as one
         batched call each, in that order too.  ``bind_batch_fn`` (when
         set) receives the whole set once: the seam for one deltasync
-        emission per round instead of one frame per pod."""
+        emission per round instead of one frame per pod.
+
+        ``selections``: the (len(binds), D) device grants the solve made
+        for these binds (an all-False row: none), None when it carried
+        no device stage; see :meth:`_grant_devices`."""
         if not binds:
             return
         commit_t0 = time.perf_counter()
@@ -2794,6 +2877,13 @@ class Scheduler:
                     if non_preemptible:
                         q.non_preemptible_used = (
                             q.non_preemptible_used + total)
+        # phase 2b: DeviceShare Reserve for the round, in one step; a pod
+        # no grant could be made for is unreserved and leaves ``binds``
+        refused = self._grant_devices(binds, selections, result)
+        if refused:
+            binds = [b for i, b in enumerate(binds) if i not in refused]
+            if not binds:
+                return
         # phase 3: per-pod surfaces, in bind order (fine-grained state
         # mutates per node+pod; trace stamping must follow it because
         # _allocate_fine_grained replaces resource_status wholesale)
@@ -2844,17 +2934,121 @@ class Scheduler:
                                       else commit_t0),
                     commit_perf=commit_t0)
 
+    # koordlint: guarded-by(self.lock)
+    def _grant_devices(self, binds: list[tuple[PodSpec, str]],
+                       selections: np.ndarray | None,
+                       result: SchedulingResult) -> set[int]:
+        """DeviceShare Reserve for a set of just-registered binds, and
+        upstream's rule with it: a pod that asks for a device is bound
+        only together with a grant that satisfies it.
+
+        Where the solve carried the device stage its grant arrives in
+        ``selections`` and all of them are written into the books in ONE
+        call (``DeviceManager.record_grants``): no device op, no per-pod
+        manager call.  A bind from a path without the stage (the sharded
+        twins, the LP and tenant-axis solves, the reservation pre-pass,
+        a nomination) takes its grant here from the host books by the
+        same rule.  When none fits, the bind is undone (Unreserve): node
+        accounting and quota released, the pod pending again with a
+        diagnosis that names the device.  Returns the refused binds'
+        indices."""
+        from koordinator_tpu.api.resources import ResourceDim
+
+        manager = self.device_manager
+        if manager is None or manager.solve_table() is None:
+            return set()   # no node has a device inventory: legacy rows
+        wanting = [i for i, (pod, _node) in enumerate(binds)
+                   if _wants_device(pod)]
+        if not wanting:
+            return set()
+        refused: set[int] = set()
+        t0 = timeline.RECORDER.open("bind.devices")
+        # the solve's grants first, all in one call: a grant taken from
+        # the books below must see them
+        solved: list[tuple[str, str, list[int], int, int]] = []
+        unsolved: list[int] = []
+        for i in wanting:
+            pod, node = binds[i]
+            minors = (np.flatnonzero(selections[i]).tolist()
+                      if selections is not None else [])
+            if minors:
+                _, per_core, per_mem = split_request(
+                    int(pod.requests[ResourceDim.GPU]),
+                    int(pod.requests[ResourceDim.GPU_MEMORY]))
+                solved.append((pod.name, node, minors, per_core, per_mem))
+            else:
+                unsolved.append(i)
+        if solved:
+            manager.record_grants(solved)
+        for i in unsolved:
+            pod, node = binds[i]
+            if manager.allocate(
+                    SOLVE_DEVICE_TYPE, node, pod.name,
+                    int(pod.requests[ResourceDim.GPU]),
+                    int(pod.requests[ResourceDim.GPU_MEMORY])) is None:
+                refused.add(i)
+        for i in refused:
+            self._unbind_for_devices(*binds[i], result)
+        timeline.RECORDER.close(t0, "bind_commit", self.tenant,
+                                n=len(wanting) - len(refused))
+        for i in wanting:
+            if i in refused:
+                continue
+            pod, node = binds[i]
+            status = self.resource_status.setdefault(pod.name, {})
+            status["device-allocated"] = (
+                manager.device_allocated_annotation(node, pod.name))
+            self._adapt_device_plugin(pod, node, status)
+        metrics.deviceshare_whole_free_devices.set(
+            float(manager.whole_free_devices()), labels=self._tl())
+        return refused
+
+    # koordlint: guarded-by(self.lock)
+    def _unbind_for_devices(self, pod: PodSpec, node: str,
+                            result: SchedulingResult) -> None:
+        """Unreserve a bind whose device grant failed at the commit: its
+        node accounting and its quota charge (a converted nomination's
+        too: it was charged when assumed) go back."""
+        from koordinator_tpu.scheduler.diagnosis import device_refusal
+
+        bp = self.bound.pop(pod.name)
+        self._release_bound_capacity(bp)
+        self._charge_quota_used(pod, sign=-1)
+        result.assignments.pop(pod.name, None)
+        self.pending[pod.name] = pod
+        self._pending_rev += 1
+        result.failures[pod.name] = device_refusal(
+            len(self.snapshot.node_index), node)
+        metrics.deviceshare_grants.inc(labels={"outcome": "no_device"})
+
+    def _count_device_outcomes(self, pods, a: np.ndarray,
+                               grants: _RoundGrants) -> None:
+        """``deviceshare_grants_total`` for a round whose solve carried
+        the device stage, per proposal that reached it: granted, undone
+        because a pod ahead in the same round took the device
+        (lost_race), or left unassigned with a device ask (no_device)."""
+        p = len(pods)
+        granted = int(grants.selection[:p].any(axis=1).sum())
+        lost = int(grants.lost_races[:p].sum())
+        wants = np.array([_wants_device(pod) for pod in pods])
+        unplaced = int((wants & (a[:p] < 0)).sum())
+        for outcome, n in (("granted", granted), ("lost_race", lost),
+                           ("no_device", unplaced)):
+            if n:
+                metrics.deviceshare_grants.inc(n, labels={"outcome": outcome})
+
     def _allocate_fine_grained(self, pod: PodSpec, node: str) -> None:
-        """Reserve-phase fine-grained allocation (nodenumaresource Reserve:
-        resource_manager.go:357 allocateCPUSet; deviceshare Reserve +
-        PreBind device-allocated annotation).  An allocation that cannot be
+        """Reserve-phase cpuset allocation (nodenumaresource Reserve:
+        resource_manager.go:357 allocateCPUSet).  A cpuset that cannot be
         satisfied degrades to the shared pool / no pinning rather than
         failing an already-committed bind — the koordlet share-pool hook
-        still applies its per-QoS cpuset."""
+        still applies its per-QoS cpuset.  Devices do NOT degrade: their
+        Reserve is :meth:`_grant_devices`, before this, and a pod it
+        refuses never gets here."""
         from koordinator_tpu.api.qos import QoSClass
         from koordinator_tpu.api.resources import ResourceDim
 
-        status: dict[str, dict] = {}
+        status: dict[str, dict] = self.resource_status.pop(pod.name, {})
         if (self.cpu_manager is not None
                 and int(pod.qos) in (int(QoSClass.LSR), int(QoSClass.LSE))
                 and self.cpu_manager.node(node) is not None):
@@ -2870,17 +3064,6 @@ class Scheduler:
                 if cpus is not None:
                     status["resource-status"] = (
                         self.cpu_manager.resource_status(node, pod.name))
-        if self.device_manager is not None:
-            gpu = int(pod.requests[ResourceDim.GPU])
-            gpu_mem = int(pod.requests[ResourceDim.GPU_MEMORY])
-            if gpu > 0 and self.device_manager.state("gpu") is not None:
-                minors = self.device_manager.allocate(
-                    "gpu", node, pod.name, gpu, gpu_mem)
-                if minors is not None:
-                    status["device-allocated"] = (
-                        self.device_manager.device_allocated_annotation(
-                            node, pod.name))
-                    self._adapt_device_plugin(pod, node, status)
         if status:
             self.resource_status[pod.name] = status
 
@@ -2935,7 +3118,12 @@ class Scheduler:
         if self.cpu_manager is not None:
             self.cpu_manager.release(node, pod_name)
         if self.device_manager is not None:
-            self.device_manager.release(node, pod_name)
+            # DeviceShare Unreserve: the books alone; the device op of
+            # all of a round's releases is the snapshot's next fold
+            t0 = time.perf_counter()
+            if self.device_manager.release(node, pod_name):
+                timeline.RECORDER.add(t0, time.perf_counter(), "host_other",
+                                      "release.devices", self.tenant)
         self.resource_status.pop(pod_name, None)
 
     def _charge_quota_used(self, pod: PodSpec, sign: int) -> None:

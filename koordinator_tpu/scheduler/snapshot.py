@@ -11,6 +11,12 @@ Request accounting (Reserve / Unreserve) is deferred the same way: a call
 adds its signed vector to a host-side pending delta, and the next read of
 ``ClusterSnapshot.state`` folds everything pending into ``node_requested``
 in one device op (``ClusterState.fold_requested``).
+
+The GPU device plane rides the same two paths (``attach_devices``): the
+attached ``DeviceManager``'s books are host numpy in these node rows, the
+flush ships the rows an inventory event rewrote into
+``ClusterState.devices``, and the fold adds what releases and commit-time
+grants changed in its ``free``.
 """
 
 from __future__ import annotations
@@ -25,6 +31,12 @@ from koordinator_tpu.state.cluster_state import ClusterState, _bucket
 
 import jax
 import jax.numpy as jnp
+
+#: the device plane's two device ops: rows of an inventory tensor set
+#: (donating, as the flush's node rows are) and the free delta added
+_row_set = jax.jit(lambda cur, rows, value: cur.at[rows].set(value),
+                   donate_argnums=(0,))
+_free_fold = jax.jit(lambda cur, delta: cur + delta, donate_argnums=(1,))
 
 
 @dataclasses.dataclass
@@ -141,6 +153,16 @@ class ClusterSnapshot:
         #: to every state built here, so a solve donates it in place
         #: instead of resharding it per call
         self._place = lambda state: state
+        #: the attached ``DeviceManager`` (``attach_devices``) and which
+        #: of its tables (identity, ``shape_rev``) ``_state.devices`` copies
+        self.device_manager = None
+        self._dev_seen: tuple = (None, 0)
+
+    def attach_devices(self, manager) -> None:
+        """Keep ``manager``'s books in this snapshot's node rows and its
+        GPU table's device-resident copy in ``state.devices``."""
+        self.device_manager = manager
+        manager.attach(self)
 
     def set_state_placement(self, place) -> None:
         """Install ``place(state) -> state`` and apply it to the state
@@ -206,7 +228,24 @@ class ClusterSnapshot:
         Caller holds the owning scheduler's lock."""
         if self._pending is not None:
             self._fold()
+        if self._state.devices is not None:
+            self._fold_devices()
         return self._state
+
+    def _fold_devices(self) -> None:
+        """Add what releases, commit-time grants and inventory rewrites
+        changed in the device plane's ``free`` since the last read."""
+        table = self.device_manager.solve_table()
+        if table is None or (table, table.shape_rev) != self._dev_seen:
+            return   # the next flush rebuilds the copy from the books
+        delta = table.take_pending()
+        if delta is None:
+            return
+        with timeline.RECORDER.section("host_other", "snapshot.fold_devices"):
+            dev = self._state.devices
+            placed = jax.device_put(delta, dev.free.sharding)
+            self._state = self._state.replace(
+                devices=dev.replace(free=_free_fold(dev.free, placed)))
 
     @property
     def resident_state(self) -> ClusterState:
@@ -276,6 +315,8 @@ class ClusterSnapshot:
             self._row_to_name[row] = spec.name
             self.node_generation[spec.name] = (
                 self.node_generation.get(spec.name, -1) + 1)
+            if self.device_manager is not None:
+                self.device_manager.node_row_added(spec.name)
         self.node_specs[spec.name] = spec
         self._class_of(spec)  # register the equivalence class up front
         self._dirty.add(row)
@@ -292,6 +333,9 @@ class ClusterSnapshot:
         self._dirty.add(row)
         self._cand_dirty.add(row)
         self._reset_requested.add(row)
+        if self.device_manager is not None:
+            # the row's next tenant must not inherit the devices
+            self.device_manager.node_row_removed(row)
         if self._pending_calls.pop(row, 0):
             # a delta taken against the dead instance must not land on
             # the row's next tenant: it goes with the row's accounting,
@@ -320,6 +364,10 @@ class ClusterSnapshot:
             node_valid=pad(old.node_valid),
             node_class=pad(old.node_class),
         )
+        if self.device_manager is not None:
+            # the books grow with the rows; the next flush copies them
+            self.device_manager.resize(new_cap)
+            self._dev_seen = (None, 0)
         if self._pending is not None:
             grown = np.zeros((new_cap,) + self._pending.shape[1:], np.int32)
             grown[:old_cap] = self._pending
@@ -331,6 +379,8 @@ class ClusterSnapshot:
 
     def flush(self) -> int:
         """Ship dirty rows to device in one scatter. Returns rows shipped."""
+        if self.device_manager is not None:
+            self._flush_devices()
         if not self._dirty:
             return 0
         rows = sorted(self._dirty)
@@ -338,6 +388,43 @@ class ClusterSnapshot:
                                        n=len(rows)):
             self._flush_rows(rows)
         return len(rows)
+
+    def _flush_devices(self) -> None:
+        """Bring ``state.devices`` up to the GPU table's inventory: the
+        rows an inventory event rewrote, by row; the whole table when it
+        is new or was re-allocated; None while no node has devices (a
+        cluster without them then runs the programs it always ran)."""
+        table = self.device_manager.solve_table()
+        if table is None:
+            if self._state.devices is not None:
+                self._state = self._state.replace(devices=None)
+            self._dev_seen = (None, 0)
+            return
+        if (table, table.shape_rev) != self._dev_seen:
+            if table.shape[0] != self.capacity:
+                raise ValueError("device table rows are not the snapshot's")
+            table.dirty.clear()
+            table.take_pending()       # the books' ``free`` holds it
+            self._cand_dirty.update(self.node_index.values())
+            self._state = self._place(
+                self._state.replace(devices=table.device_state()))
+            self._dev_seen = (table, table.shape_rev)
+            return
+        if not table.dirty:
+            return
+        rows = np.asarray(sorted(table.dirty), np.int32)
+        table.dirty.clear()
+        self._cand_dirty.update(int(r) for r in rows)
+        # ``free`` is not sent: what a rewrite changed in it is in the
+        # table's pending delta, which adds up with a solve in flight
+        idx = jnp.asarray(rows)
+        dev = self._state.devices
+        self._state = self._state.replace(devices=dev.replace(
+            total=_row_set(dev.total, idx, jnp.asarray(table.total[rows])),
+            valid=_row_set(dev.valid, idx, jnp.asarray(table.valid[rows])),
+            healthy=_row_set(dev.healthy, idx,
+                             jnp.asarray(table.healthy[rows])),
+            group=_row_set(dev.group, idx, jnp.asarray(table.group[rows]))))
 
     def _flush_rows(self, rows: list[int]) -> None:
         self._dirty.clear()
@@ -452,6 +539,9 @@ class ClusterSnapshot:
         self._drop_pending()
         self._state = self._place(
             ClusterState.zeros(self.capacity, self.dims))
+        # the device plane loses nothing: its books are on the host, and
+        # the flush below copies them whole
+        self._dev_seen = (None, 0)
         self._reset_requested.clear()
         self._dirty.update(self.node_index.values())
         self._cand_dirty.update(self.node_index.values())

@@ -25,6 +25,17 @@ Three decisions live here and nowhere above:
 - **the candidate parameters**: ``ops/batch_assign``'s ``CAND_K``,
   ``CAND_SPREAD_BITS``, ``SOLVE_ROUNDS``.
 
+A fourth follows from the first: **where the device stage runs**.  Over
+a state that carries devices (``ClusterState.devices``) the single-
+device programs run DeviceShare's Filter and Reserve inside the solve,
+and ``solve``, ``forecast_solve``, ``pass1`` and ``pass2`` hand the
+grants back as their last value.  The ``shard_map`` twins and the LP
+packing solve know no device: ``_sharded`` keeps a state with devices
+off the twins (GSPMD places the single-device program instead), and
+``quality_solve`` returns no grants, so its binds take theirs at the
+commit (``Scheduler._grant_devices``).  Without devices every entry
+returns ``None`` there and traces the program it always traced.
+
 The mesh itself is a deployment setting (``KOORD_SOLVER_MESH``,
 ``KOORD_SOLVER_MESH_PODS``: ``parallel/mesh.resolve_solver_mesh``).
 """
@@ -73,8 +84,10 @@ class SolverKit:
         #: propose/accept rounds of the incremental passes
         self.rounds = _ba.SOLVE_ROUNDS
 
-        def _sfx(n_cap: int) -> str:
-            if not self.sharding_active_for(n_cap):
+        def _sfx(state) -> str:
+            # a state with devices never meets a twin (``_sharded``)
+            if (state.devices is not None
+                    or not self.sharding_active_for(state.capacity)):
                 return ""
             # the pods=1 form stays "@Nshard": dashboards key on it
             if self.pod_shards > 1:
@@ -83,7 +96,7 @@ class SolverKit:
 
         def _pn(args, kwargs):
             return (f"P{args[1].capacity}xN{args[0].capacity}"
-                    f"{_sfx(args[0].capacity)}")
+                    f"{_sfx(args[0])}")
 
         # Every jitted entry point is wrapped for recompile accounting
         # (ops/introspection): a cache miss lands in
@@ -101,7 +114,7 @@ class SolverKit:
         # feasibility (hinted) batches, which cannot tile over the mesh
         self._solve_one = insp.instrument(
             jax.jit(gang_assign,
-                    static_argnames=("passes", "solver"),
+                    static_argnames=("passes", "solver", "with_grants"),
                     donate_argnums=(0,)),
             "gang_assign", shape_of=_pn)
         self._solve_sh = sh and insp.instrument(
@@ -140,7 +153,7 @@ class SolverKit:
             "refresh_candidates",
             shape_of=lambda a, k: (
                 f"P{a[1].capacity}xN{a[0].capacity}"
-                f"xD{a[4].shape[0]}{_sfx(a[0].capacity)}"))
+                f"xD{a[4].shape[0]}{_sfx(a[0])}"))
         self.scatter_cands = insp.instrument(
             jax.jit(_ba.scatter_candidate_rows, donate_argnums=(0,)),
             "scatter_candidate_rows",
@@ -148,7 +161,7 @@ class SolverKit:
                                    f"xS{a[1].shape[0]}"))
         self._pass1_one = insp.instrument(
             jax.jit(_ba.assign_round_pass,
-                    static_argnames=("rounds",),
+                    static_argnames=("rounds", "with_grants"),
                     donate_argnums=(0,)),
             "assign_round_pass", shape_of=_pn)
         self._pass1_sh = sh and insp.instrument(
@@ -159,7 +172,7 @@ class SolverKit:
         self._pass2_one = insp.instrument(
             jax.jit(_ba.assign_followup_pass,
                     static_argnames=("k", "rounds", "spread_bits",
-                                     "method"),
+                                     "method", "with_grants"),
                     donate_argnums=(0, 1)),
             "assign_followup_pass",
             shape_of=lambda a, k: f"P{a[2].capacity}xN{a[0].capacity}")
@@ -171,7 +184,7 @@ class SolverKit:
             "assign_followup_pass",
             shape_of=lambda a, k: (
                 f"P{a[2].capacity}"
-                f"xN{a[0].capacity}{_sfx(a[0].capacity)}"))
+                f"xN{a[0].capacity}{_sfx(a[0])}"))
 
         # quality mode: the LP-relaxation packing solve, the second
         # solver backend.  Same donation contract as the greedy entries.
@@ -199,11 +212,11 @@ class SolverKit:
 
         def _fpn(args, kwargs):
             return (f"P{args[2].capacity}xN{args[0].capacity}"
-                    f"{_sfx(args[0].capacity)}")
+                    f"{_sfx(args[0])}")
 
         self._forecast_solve_one = insp.instrument(
             jax.jit(forecast_gang_assign,
-                    static_argnames=("passes", "solver"),
+                    static_argnames=("passes", "solver", "with_grants"),
                     donate_argnums=(0,)),
             "forecast_gang_assign", shape_of=_fpn)
         self._forecast_solve_sh = sh and insp.instrument(
@@ -252,11 +265,13 @@ class SolverKit:
                 and n_cap % self.shards == 0
                 and n_cap >= self.shard_min_nodes)
 
-    def _sharded(self, n_cap: int, batch=None, factored=False) -> bool:
+    def _sharded(self, n_cap: int, batch=None, factored=False,
+                 devices=False) -> bool:
         """The placement choice, made here and nowhere else: does a
         stage over (a state of ``n_cap`` rows, ``batch``) run its
-        ``shard_map`` program?  The stages differ in what the program
-        needs besides an active mesh:
+        ``shard_map`` program?  Never over a state with ``devices``:
+        the twins carry no device stage.  Else the stages differ in
+        what the program needs besides an active mesh:
 
         - the LP packing twin replicates pods: nothing (``batch`` None);
         - the incremental stages split pods over the pods axis: a batch
@@ -267,6 +282,7 @@ class SolverKit:
           2-D mesh.
         """
         return (self.sharding_active_for(n_cap)
+                and not devices
                 and (batch is None
                      or batch.capacity % self.pod_shards == 0)
                 and (not factored or batch.selector_mask is not None))
@@ -280,22 +296,25 @@ class SolverKit:
             return state
         return pmesh.shard_cluster_state(state, self.mesh)
 
-    def place_batch(self, batch, n_cap: int):
+    def place_batch(self, batch, n_cap: int, devices: bool = False):
         """Pin a batch that is reused across rounds under the 2-D
         mesh's pod-axis sharding, so the sharded entries consume it in
         place instead of resharding it per call.  Only where the
-        gang/greedy twin would take it: a single-device entry must not
-        receive a mesh-committed batch.  No entry donates the batch."""
-        if self.pod_shards > 1 and self._sharded(n_cap, batch, True):
+        gang/greedy twin would take it (``devices``: the state it meets
+        carries some): a single-device entry must not receive a mesh-
+        committed batch.  No entry donates the batch."""
+        if self.pod_shards > 1 and self._sharded(n_cap, batch, True,
+                                                 devices):
             return pmesh.shard_pod_batch(batch, self.mesh)
         return batch
 
-    def selection(self, n_cap: int, batch) -> str:
+    def selection(self, n_cap: int, batch, devices: bool = False) -> str:
         """Which candidate selection :meth:`select_scored` runs for
         these shapes: ``"sharded"`` or the single-device method's name.
         A candidate cache is valid only for the selection that built
         it."""
-        return "sharded" if self._sharded(n_cap, batch) else self.method
+        return ("sharded" if self._sharded(n_cap, batch, devices=devices)
+                else self.method)
 
     # -- one entry per stage --------------------------------------------------
     # Each hands its arguments to ONE of its two programs; ``state`` (and
@@ -306,38 +325,44 @@ class SolverKit:
 
     # koordlint: shape[state: NxR i32 nodes]
     def solve(self, state, batch, config, gangs, quota, *, passes, solver):
-        """``ops/gang.gang_assign``: (assignments, state, quota)."""
-        if self._sharded(state.capacity, batch, True):
-            return self._solve_sh(state, batch, config, gangs, quota,
-                                  passes=passes, solver=solver)
+        """``ops/gang.gang_assign``: (assignments, state, quota,
+        grants)."""
+        if self._sharded(state.capacity, batch, True,
+                         state.devices is not None):
+            return (*self._solve_sh(state, batch, config, gangs, quota,
+                                    passes=passes, solver=solver), None)
         return self._solve_one(state, batch, config, gangs, quota,
-                               passes=passes, solver=solver)
+                               passes=passes, solver=solver,
+                               with_grants=True)
 
     # koordlint: shape[state: NxR i32 nodes, reserve: NxR i32 nodes]
     def forecast_solve(self, state, reserve, batch, config, gangs, quota,
                        *, passes, solver):
         """``forecast/kernels.forecast_gang_assign``: :meth:`solve` with
         ``reserve`` charged for the duration of the solve."""
-        if self._sharded(state.capacity, batch, True):
-            return self._forecast_solve_sh(
+        if self._sharded(state.capacity, batch, True,
+                         state.devices is not None):
+            return (*self._forecast_solve_sh(
                 state, reserve, batch, config, gangs, quota,
-                passes=passes, solver=solver)
+                passes=passes, solver=solver), None)
         return self._forecast_solve_one(
             state, reserve, batch, config, gangs, quota,
-            passes=passes, solver=solver)
+            passes=passes, solver=solver, with_grants=True)
 
     # koordlint: shape[state: NxR i32 nodes]
     def quality_solve(self, state, batch, config, quota):
         """``quality/lp_pack.lp_pack_assign``: (assignments, state,
-        quota, iterations)."""
-        if self._sharded(state.capacity):
+        quota, iterations).  No device stage: see the module's note."""
+        if self._sharded(state.capacity,
+                         devices=state.devices is not None):
             return self._quality_solve_sh(state, batch, config, quota)
         return self._quality_solve_one(state, batch, config, quota)
 
     def select_scored(self, state, batch, config):
         """Full candidate selection: (cand_key, cand_node, cand_score)."""
         k = min(_ba.CAND_K, state.capacity)
-        if self._sharded(state.capacity, batch):
+        if self._sharded(state.capacity, batch,
+                         devices=state.devices is not None):
             return self._select_scored_sh(
                 state, batch, config, k=k,
                 spread_bits=_ba.CAND_SPREAD_BITS, with_scores=True)
@@ -350,7 +375,8 @@ class SolverKit:
         """Re-score an aligned candidate cache against the dirty node
         rows: (cand_key, cache)."""
         k = min(_ba.CAND_K, state.capacity)
-        if self._sharded(state.capacity, batch):
+        if self._sharded(state.capacity, batch,
+                         devices=state.devices is not None):
             return self._refresh_cands_sh(
                 state, batch, config, cache, dirty_rows, dirty_valid,
                 k=k, spread_bits=_ba.CAND_SPREAD_BITS)
@@ -361,24 +387,28 @@ class SolverKit:
     # koordlint: shape[state: NxR i32 nodes]
     def pass1(self, state, batch, quota, cand_key, cand_node, config):
         """First propose/accept pass over given candidates:
-        (assignments, state, quota, est_accum)."""
-        if self._sharded(state.capacity, batch):
-            return self._pass1_sh(state, batch, quota, cand_key,
-                                  cand_node, config, rounds=self.rounds)
+        (assignments, state, quota, est_accum, grants)."""
+        if self._sharded(state.capacity, batch,
+                         devices=state.devices is not None):
+            return (*self._pass1_sh(state, batch, quota, cand_key,
+                                    cand_node, config, rounds=self.rounds),
+                    None)
         return self._pass1_one(state, batch, quota, cand_key, cand_node,
-                               config, rounds=self.rounds)
+                               config, rounds=self.rounds, with_grants=True)
 
     # koordlint: shape[state: NxR i32 nodes, est_accum: NxR i32 nodes]
     def pass2(self, state, est_accum, batch, quota, config):
         """A later pass: full selection over a compacted leftover batch
         against the est-usage-augmented state, then accept:
-        (assignments, state, quota, est_accum)."""
+        (assignments, state, quota, est_accum, grants)."""
         k = min(_ba.CAND_K, state.capacity)
-        if self._sharded(state.capacity, batch):
-            return self._pass2_sh(
+        if self._sharded(state.capacity, batch,
+                         devices=state.devices is not None):
+            return (*self._pass2_sh(
                 state, est_accum, batch, quota, config, k=k,
-                rounds=self.rounds, spread_bits=_ba.CAND_SPREAD_BITS)
+                rounds=self.rounds, spread_bits=_ba.CAND_SPREAD_BITS),
+                None)
         return self._pass2_one(
             state, est_accum, batch, quota, config, k=k,
             rounds=self.rounds, spread_bits=_ba.CAND_SPREAD_BITS,
-            method=self.method)
+            method=self.method, with_grants=True)

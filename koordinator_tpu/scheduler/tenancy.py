@@ -529,7 +529,11 @@ class TenantScheduler:
                         and sched.forecast_plane is not None)
                     # the batched program is the single-device one
                     or self.kit.sharding_active_for(
-                        sched.snapshot.capacity)):
+                        sched.snapshot.capacity)
+                    # a tenant with a device plane keeps the per-tenant
+                    # dispatch: its solve carries the device stage, the
+                    # tenant-axis program stacks states without one
+                    or sched.snapshot.resident_state.devices is not None):
                 return False
             # the ONE batched program broadcasts tenant 0's config over
             # the tenant axis: every live tenant must share it (by

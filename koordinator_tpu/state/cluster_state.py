@@ -30,6 +30,7 @@ from flax import struct
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
 from koordinator_tpu.ops import introspection as insp
+from koordinator_tpu.ops.deviceshare import DeviceState
 
 
 #: Per-dimension quantity bound: integer score/percentage math multiplies by
@@ -90,6 +91,13 @@ class ClusterState:
     #: a dense (P, N) tensor (C ≪ N; the reference walks nodeSelector/taints
     #: per (pod, node) — the class map is the vectorized equivalent).
     node_class: jax.Array
+    #: the GPU device plane in the same node rows ((N, D, 2) free and
+    #: total, (N, D) valid / healthy / group), or None while no node has
+    #: reported a device inventory.  None is part of the pytree's
+    #: STRUCTURE: a cluster without devices traces the programs it
+    #: always traced, and one with devices gets DeviceShare's Filter and
+    #: Reserve inside the same solve (ops/deviceshare.py).
+    devices: DeviceState | None = None
 
     @property
     def capacity(self) -> int:
@@ -193,6 +201,8 @@ class ClusterState:
             node_prod_usage=self.node_prod_usage[rows],
             node_valid=valid,
             node_class=self.node_class[rows],
+            devices=(None if self.devices is None else
+                     jax.tree.map(lambda a: a[rows], self.devices)),
         )
 
     def fold_requested(self, delta: np.ndarray) -> "ClusterState":

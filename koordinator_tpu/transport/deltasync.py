@@ -1361,6 +1361,8 @@ class SchedulerBinding:
         manager = self.scheduler.device_manager
         if manager is None:
             return
+        if devices or manager.registered_types_for(name):
+            metrics.deviceshare_inventory_events.inc()
         for dev_type, inventory in (devices or {}).items():
             if isinstance(inventory, list):
                 manager.register_node_devices(dev_type, name, inventory)
