@@ -199,7 +199,6 @@ class ReconnectingSidecarClient:
         # is concurrency-safe (per-request waiter map), and holding the
         # lock across a call would serialize caller threads behind a
         # wedged sidecar for the full timeout each
-        from koordinator_tpu import metrics
         from koordinator_tpu.transport.channel import (
             RpcError,
             RpcRemoteError,
@@ -209,25 +208,35 @@ class ReconnectingSidecarClient:
         try:
             return client.call(*call_args, **call_kwargs)
         except RpcRemoteError as e:
-            if e.resync and self.on_connect is not None:
-                # server-directed resync: our watch view is stale (e.g.
-                # it restarted and lost the node this push named).
-                # Re-HELLO on the still-healthy connection; the failed
-                # call still surfaces (its state may be gone for real)
-                # and the caller's next tick runs against the new view.
-                self.resyncs += 1
-                metrics.sync_resyncs_total.inc()
-                try:
-                    if client.connected:
-                        self.on_connect(client)
-                except Exception:
-                    pass  # resync is best effort; reconnect path remains
+            if e.resync:
+                # the failed call still surfaces (its state may be gone
+                # for real) and the caller's next tick runs against the
+                # new view
+                self.resync()
             raise
         except (RpcError, OSError):
             with self._lock:
                 if self._client is client:
                     self._close_locked()
             raise
+
+    def resync(self) -> None:
+        """Server-directed resync: our watch view is stale (e.g. the
+        sidecar restarted and lost a node a push named; the ERROR, or a
+        run's reply, says ``resync``).  Re-HELLO on the still-healthy
+        connection."""
+        from koordinator_tpu import metrics
+
+        client = self._client
+        if self.on_connect is None or client is None:
+            return
+        self.resyncs += 1
+        metrics.sync_resyncs_total.inc()
+        try:
+            if client.connected:
+                self.on_connect(client)
+        except Exception:
+            pass  # resync is best effort; reconnect path remains
 
     # koordlint: guarded-by(self._lock)
     def _close_locked(self) -> None:
@@ -248,6 +257,12 @@ def build_koordlet_parser() -> argparse.ArgumentParser:
     parser.add_argument("--cgroup-root-dir", default="/sys/fs/cgroup")
     parser.add_argument("--proc-root-dir", default="/proc")
     parser.add_argument("--sys-root-dir", default="/sys")
+    parser.add_argument(
+        "--var-run-root-dir", default="/var/run/koordinator",
+        help="where the agent keeps what outlives it: the metric cache's "
+             "snapshot (restored at start, so two agents that share the "
+             "directory share their history) and the prediction "
+             "checkpoints")
     parser.add_argument("--cgroup-driver-systemd", action="store_true")
     parser.add_argument("--cgroup-v2", action="store_true")
     parser.add_argument("--audit-log-dir", default="")
@@ -316,6 +331,7 @@ def main_koordlet(argv: list[str], device_report_fn=None,
         cgroup_root=args.cgroup_root_dir,
         proc_root=args.proc_root_dir,
         sys_root=args.sys_root_dir,
+        var_run_root=args.var_run_root_dir,
         use_cgroup_v2=args.cgroup_v2,
         cgroup_driver_systemd=args.cgroup_driver_systemd,
     )
@@ -1109,14 +1125,12 @@ def main_koord_manager(argv: list[str], lease_store=None,
     component.update_sloconfig = update_sloconfig
 
     if args.scheduler_sidecar_addr:
-        import numpy as _np
-
         from koordinator_tpu.manager.colocation_loop import (
             ColocationLoop,
             ManagerSyncBinding,
+            sidecar_push,
         )
         from koordinator_tpu.transport import StateSyncClient
-        from koordinator_tpu.transport.wire import FrameType
 
         binding = ManagerSyncBinding(clock=clock)
         sync = StateSyncClient(binding)
@@ -1136,17 +1150,11 @@ def main_koord_manager(argv: list[str], lease_store=None,
             args.scheduler_sidecar_addr, on_push=sync.on_push,
             on_connect=bootstrap_watch)
 
-        def push_allocatable(name: str, allocatable) -> None:
-            sidecar.call(
-                FrameType.STATE_PUSH,
-                {"kind": "node_allocatable", "name": name},
-                {"allocatable": _np.asarray(allocatable, _np.int32)})
-
         component.sync_binding = binding
         component.sync = sync
         component.sync_client = sidecar
         component.colocation_loop = ColocationLoop(
-            component.noderesource, binding, push_allocatable,
+            component.noderesource, binding, sidecar_push(sidecar),
             ensure_fn=sidecar.ensure)
 
         def stop() -> None:

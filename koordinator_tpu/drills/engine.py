@@ -328,12 +328,12 @@ class DrillHarness:
         from koordinator_tpu.manager.colocation_loop import (
             ColocationLoop,
             ManagerSyncBinding,
+            sidecar_push,
         )
         from koordinator_tpu.manager.noderesource_controller import (
             NodeResourceController,
         )
         from koordinator_tpu.transport import StateSyncClient
-        from koordinator_tpu.transport.wire import FrameType
 
         binding = ManagerSyncBinding()
         sync = StateSyncClient(binding)
@@ -347,14 +347,8 @@ class DrillHarness:
             retry_policy=self.retry_policy, faults=self.injector,
             timeout=3.0, fault_domain="manager")
 
-        def push_allocatable(name, allocatable):
-            client.call(FrameType.STATE_PUSH,
-                        {"kind": "node_allocatable", "name": name},
-                        {"allocatable": np.asarray(allocatable,
-                                                   np.int32)})
-
         loop = ColocationLoop(NodeResourceController(), binding,
-                              push_allocatable, ensure_fn=client.ensure)
+                              sidecar_push(client), ensure_fn=client.ensure)
         return {"binding": binding, "sync": sync, "client": client,
                 "loop": loop}
 

@@ -21,9 +21,10 @@ closes it through the apiserver):
 binding that tracks every node's base capacity and the koordlet-reported
 usage vectors.  :class:`ColocationLoop` turns that view into
 :class:`NodeRecord` rows, runs the batched reconcile
-(manager/noderesource_controller.py), and pushes each patch back as a
-``node_allocatable`` event — the merge event that cannot clobber the
-koordlet's device inventory the way a full node_upsert would.
+(manager/noderesource_controller.py), and pushes a tick's patches back
+as runs of ``node_allocatable`` events — the merge event that cannot
+clobber the koordlet's device inventory the way a full node_upsert
+would.
 """
 
 from __future__ import annotations
@@ -31,19 +32,43 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable, Iterable, Optional
 
 import numpy as np
 
 from koordinator_tpu import metrics, timeline
 from koordinator_tpu.api import crds
-from koordinator_tpu.api.resources import ResourceDim
+from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
 from koordinator_tpu.manager.noderesource_controller import (
     NodeRecord,
     NodeResourceController,
 )
+from koordinator_tpu.transport.wire import STATE_PUSH_RUN_MAX, FrameType
 
 MIB = 1 << 20
+
+#: the columns a noderesource patch writes, in NodePatch's field order
+_PATCHED_DIMS = [int(ResourceDim.BATCH_CPU), int(ResourceDim.BATCH_MEMORY),
+                 int(ResourceDim.MID_CPU), int(ResourceDim.MID_MEMORY)]
+
+
+def sidecar_push(client) -> Callable[[list[str], np.ndarray], list]:
+    """The loop's ``push_fn`` over a ReconnectingSidecarClient: one
+    run-form ``node_allocatable`` STATE_PUSH a call, answered when every
+    event of the frame is committed and applied.  Returns the names the
+    sidecar rejected; where it says the watch view is stale
+    (``resync``), re-HELLOs first."""
+
+    def push(names, allocatable):
+        _, doc, _ = client.call(
+            FrameType.STATE_PUSH,
+            {"kind": "node_allocatable", "names": names},
+            {"allocatable": np.asarray(allocatable, np.int32)})
+        if doc.get("resync"):
+            client.resync()
+        return doc.get("rejected") or []
+
+    return push
 
 
 class _NodeView:
@@ -96,16 +121,16 @@ class ManagerSyncBinding:
             self.records.clear()
 
     @contextlib.contextmanager
-    def _watched(self):
-        """One node delta applied to the view: a ``colo.watch`` span and
-        a count, under the binding's lock."""
+    def _watched(self, n: int = 1):
+        """``n`` node deltas applied to the view: a ``colo.watch`` span
+        of that many members and a count, under the binding's lock."""
         t0 = timeline.RECORDER.open("colo.watch")
         try:
             with self.lock:
                 yield
         finally:
-            timeline.RECORDER.close(t0, "deltasync_apply")
-            metrics.colocation_watch_events_total.inc()
+            timeline.RECORDER.close(t0, "deltasync_apply", n=n)
+            metrics.colocation_watch_events_total.inc(float(n))
 
     def _merge_usage(self, view: _NodeView, entry: dict,
                      arrs: dict) -> None:
@@ -156,14 +181,20 @@ class ManagerSyncBinding:
             self._merge_usage(view, entry, arrs)
 
     def node_alloc(self, entry: dict, arrs: dict) -> None:
-        # our own patches echo back as deltas; base capacity dims
-        # (CPU/MEMORY) are untouched by the batch/mid patch, so applying
-        # the echo cannot feed back into the formula
-        with self._watched():
-            view = self.nodes.get(entry["name"])
-            if view is None:
-                return
-            view.allocatable = np.asarray(arrs["allocatable"], np.int32)
+        self.node_alloc_run([(entry, arrs)])
+
+    def node_alloc_run(self, items: list) -> None:
+        """Our own patches echo back as deltas, a pushed frame's as one
+        run: applied under one hold of the lock and one ``colo.watch``
+        span.  Base capacity dims (CPU/MEMORY) are untouched by the
+        batch/mid patch, so applying the echo cannot feed back into the
+        formula."""
+        with self._watched(len(items)):
+            for entry, arrs in items:
+                view = self.nodes.get(entry["name"])
+                if view is not None:
+                    view.allocatable = np.asarray(arrs["allocatable"],
+                                                  np.int32)
 
     def node_remove(self, name: str) -> None:
         with self._watched():
@@ -190,15 +221,20 @@ class ManagerSyncBinding:
 class ColocationLoop:
     """view -> NodeRecords -> batched reconcile -> node_allocatable push.
 
-    ``push_fn(name, allocatable)`` is the transport seam: the manager
-    binary wires it to a STATE_PUSH call on its sidecar client; tests
-    can call the service directly.  Tick-driven like the koordlet's
-    Daemon — the shell owns the cadence (``run`` is the convenience
-    loop for real deployments)."""
+    ``push_fn(names, allocatable)`` is the transport seam: a run of
+    patches in patch order, ``allocatable`` of shape ``(n,
+    NUM_RESOURCE_DIMS)`` with ``n <= STATE_PUSH_RUN_MAX``; it returns
+    when every patch it does not report is committed, and reports the
+    others as ``(name, reason)`` pairs (or nothing).  The manager binary
+    wires it to one run-form STATE_PUSH call on its sidecar client
+    (:func:`sidecar_push`); tests can call the service directly.
+    Tick-driven like the koordlet's Daemon — the shell owns the cadence
+    (``run`` is the convenience loop for real deployments)."""
 
     def __init__(self, controller: NodeResourceController,
                  binding: ManagerSyncBinding,
-                 push_fn: Callable[[str, np.ndarray], None],
+                 push_fn: Callable[[list[str], np.ndarray],
+                                   Optional[Iterable]],
                  ensure_fn: Optional[Callable[[], object]] = None,
                  forecast=None):
         self.controller = controller
@@ -288,10 +324,11 @@ class ColocationLoop:
         """One reconcile round; returns the number of patches pushed.
 
         Runs inside a ``manager.colocation_tick`` trace span; every
-        pushed patch gets a ``manager.colocation_push`` child whose
+        pushed frame gets a ``manager.colocation_push`` child whose
         context rides the STATE_PUSH frame to the sidecar (the RPC
-        client injects the active context), so a scheduler can see WHICH
-        manager tick changed a node's batch allocatable."""
+        client injects the active context) and is stamped on every
+        event of the frame, so a scheduler can see WHICH manager tick
+        changed a node's batch allocatable."""
         from koordinator_tpu import tracing
 
         self.ticks += 1
@@ -318,40 +355,72 @@ class ColocationLoop:
             return self._push(patches, tracing)
 
     def _push(self, patches, tracing) -> int:
+        """The tick's patches as rows in patch order, handed on in
+        frames of at most STATE_PUSH_RUN_MAX, each one synchronous
+        ``push_fn`` call.  One patch is a run of one."""
+        names, rows = self._patch_rows(patches)
         pushed = 0
-        for patch in patches:
-            with self.binding.lock:
+        for lo in range(0, len(names), STATE_PUSH_RUN_MAX):
+            hi = lo + STATE_PUSH_RUN_MAX
+            pushed += self._push_frame(names[lo:hi], rows[lo:hi], tracing)
+        return pushed
+
+    def _patch_rows(self, patches) -> tuple[list[str], np.ndarray]:
+        """(names, (n, R) allocatable): each patched node's row of the
+        view with the four batch / mid columns written over it; a node
+        the view no longer holds is left out."""
+        kept, base = [], []
+        with self.binding.lock:
+            for patch in patches:
                 view = self.binding.nodes.get(patch.name)
                 if view is None or view.allocatable is None:
                     continue
-                allocatable = view.allocatable.copy()
-            allocatable[ResourceDim.BATCH_CPU] = patch.batch_cpu_milli
-            allocatable[ResourceDim.BATCH_MEMORY] = patch.batch_mem_mib
-            allocatable[ResourceDim.MID_CPU] = patch.mid_cpu_milli
-            allocatable[ResourceDim.MID_MEMORY] = patch.mid_mem_mib
-            try:
-                with tracing.TRACER.span(
+                kept.append(patch)
+                base.append(view.allocatable)
+        if not kept:
+            return [], np.zeros((0, NUM_RESOURCE_DIMS), np.int32)
+        rows = np.array(base, np.int32)
+        # int32 at the conversion: a value the state tensors cannot hold
+        # raises here, it does not wrap
+        rows[:, _PATCHED_DIMS] = np.array(
+            [(p.batch_cpu_milli, p.batch_mem_mib, p.mid_cpu_milli,
+              p.mid_mem_mib) for p in kept], np.int32)
+        return [p.name for p in kept], rows
+
+    def _push_frame(self, names: list[str], rows: np.ndarray,
+                    tracing) -> int:
+        """One frame; returns how many of its names were committed."""
+        try:
+            with timeline.RECORDER.section("host_other", "colo.push.frame",
+                                           n=len(names)), \
+                    tracing.TRACER.span(
                         "manager.colocation_push", service="manager",
-                        attributes={"node": patch.name}):
-                    self.push_fn(patch.name, allocatable)
-                pushed += 1
-                metrics.colocation_patches_total.inc()
-            except Exception:  # noqa: BLE001 — a wedged sidecar costs
-                # this patch, not the loop; the diff state was already
-                # stamped, so force a re-sync next tick.  last_degraded
-                # must reset too: the degraded-suppression branch in
-                # reconcile() checks it INSTEAD of last_batch_cpu, so a
-                # dropped zeroing patch would otherwise never retry and
-                # the scheduler would keep advertising batch capacity on
-                # a node with expired metrics
-                self.push_failures += 1
-                metrics.colocation_push_failures_total.inc()
-                record = self.binding.records.get(patch.name)
-                if record is not None:
-                    record.last_batch_cpu = -1
-                    record.last_degraded = False
-                    record.last_device_resources = None
-        return pushed
+                        attributes={"tick": self.ticks, "first": names[0],
+                                    "last": names[-1], "n": len(names)}):
+                metrics.colocation_push_frames_total.inc()
+                rejected = self.push_fn(names, rows)
+            failed = [name for name, _reason in rejected or ()]
+        except Exception:  # noqa: BLE001 — a wedged sidecar costs
+            # this frame, not the loop; the diff state was already
+            # stamped, so force a re-sync next tick.  last_degraded
+            # must reset too: the degraded-suppression branch in
+            # reconcile() checks it INSTEAD of last_batch_cpu, so a
+            # dropped zeroing patch would otherwise never retry and
+            # the scheduler would keep advertising batch capacity on
+            # a node with expired metrics
+            failed = names
+        # a frame with no reply fails every name in it, a name the
+        # sidecar rejected fails alone
+        for name in failed:
+            record = self.binding.records.get(name)
+            if record is not None:
+                record.last_batch_cpu = -1
+                record.last_degraded = False
+                record.last_device_resources = None
+        self.push_failures += len(failed)
+        metrics.colocation_push_failures_total.inc(float(len(failed)))
+        metrics.colocation_patches_total.inc(float(len(names) - len(failed)))
+        return len(names) - len(failed)
 
     def run(self, interval_seconds: float = 60.0) -> None:  # pragma: no cover
         while not self._stop.is_set():

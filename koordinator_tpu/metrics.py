@@ -746,6 +746,10 @@ node_metric_expired = MANAGER.gauge(
 colocation_patches_total = MANAGER.counter(
     "colocation_patches_total",
     "node_allocatable patches pushed by the colocation loop")
+colocation_push_frames_total = MANAGER.counter(
+    "colocation_push_frames_total",
+    "run-form STATE_PUSH frames the colocation loop sent (patches / "
+    "frames is the run length; at most wire.STATE_PUSH_RUN_MAX)")
 colocation_push_failures_total = MANAGER.counter(
     "colocation_push_failures_total",
     "colocation-loop pushes lost to a wedged sidecar (retried next tick)")
@@ -794,7 +798,8 @@ sync_binding_backlog_peak = TRANSPORT.gauge(
     "sync_binding_backlog_peak",
     "High-water mark of the local-binding backlog since process start "
     "(the watermark the steady-state soak bounds and the trend engine "
-    "watches)")
+    "watches); a run-form STATE_PUSH frame commits up to 1,024 events "
+    "before its one drain, so a tick's frame length is its floor")
 sync_delta_frames_total = TRANSPORT.counter(
     "sync_delta_frames_total",
     "Committed deltasync EVENTS by whether anyone was there to be sent "

@@ -634,18 +634,66 @@ class StateSyncService:
         full node_upsert from the manager would clobber the koordlet's
         device inventory (upsert replaces the stored doc wholesale);
         this event merges.  Unknown node -> WireSchemaError, same rule
-        as node_usage."""
-        arrays = {"allocatable": np.asarray(allocatable, np.int32)}
+        as node_usage.  A run of one (update_node_allocatable_run)."""
+        rv, rejected = self.update_node_allocatable_run(
+            [name], np.asarray(allocatable, np.int32)[None])
+        if rejected:
+            raise UnknownNodeError(
+                f"node_allocatable for unknown node {name!r}")
+        return rv
 
-        def store():
-            entry = self.nodes.get(name)
-            if entry is None:
-                raise UnknownNodeError(
-                    f"node_allocatable for unknown node {name!r}")
-            entry["arrays"] = dict(entry["arrays"], **arrays)
-
-        return self._store_and_commit(
-            store, {"kind": NODE_ALLOC, "name": name}, arrays)
+    def update_node_allocatable_run(
+            self, names: list[str], allocatable: np.ndarray
+    ) -> tuple[int, list[tuple[str, str]]]:
+        """A run of node_allocatable patches, row i of ``allocatable``
+        for ``names[i]``, committed in that order under ONE hold of the
+        lock and applied to the local bindings as one run.  Each event
+        keeps its own rv, log entry and trace stamp, so a watcher, a
+        late HELLO and a snapshot see what ``len(names)`` single pushes
+        would have left.  A name this service does not hold is skipped
+        and reported, the others commit.  Returns (the last rv, the
+        skipped names each with its reason).  Malformed input (more
+        than wire.STATE_PUSH_RUN_MAX names or none, a name twice, a
+        matrix that is not one row a name) commits nothing."""
+        n = len(names)
+        if not 1 <= n <= wire.STATE_PUSH_RUN_MAX:
+            raise wire.WireSchemaError(
+                f"a node_allocatable run carries 1 to "
+                f"{wire.STATE_PUSH_RUN_MAX} events, got {n}")
+        if any(not isinstance(name, str) for name in names):
+            raise wire.WireSchemaError(
+                "node_allocatable run: every name must be a string")
+        if len(set(names)) != n:
+            raise wire.WireSchemaError(
+                "node_allocatable run: a node is named twice")
+        # a copy of the caller's matrix: the stored rows are views of it
+        block = np.array(allocatable, np.int32)
+        if block.ndim != 2 or block.shape[0] != n:
+            raise wire.WireSchemaError(
+                f"node_allocatable run: 'allocatable' must have one row "
+                f"for each of the {n} names, got shape {block.shape}")
+        rejected: list[tuple[str, str]] = []
+        # one sync.store span of as many members as commit (store, delta
+        # log, announce, local apply), the sync.<kind> apply nests under it
+        tl_t0 = timeline.RECORDER.open("sync.store")
+        try:
+            with self._lock:
+                rv = self.rv
+                for name, row in zip(names, block):
+                    entry = self.nodes.get(name)
+                    if entry is None:
+                        rejected.append((name, "unknown node"))
+                        continue
+                    arrays = {"allocatable": row}
+                    entry["arrays"] = dict(entry["arrays"], **arrays)
+                    rv = self._commit_locked(
+                        {"kind": NODE_ALLOC, "name": name}, arrays)
+            if self._local_bindings:
+                self._drain_bindings()
+        finally:
+            timeline.RECORDER.close(tl_t0, "deltasync_apply",
+                                    n=n - len(rejected))
+        return rv, rejected
 
     def update_node_devices(self, name: str,
                             devices: dict[str, list[dict]]) -> int:
@@ -764,23 +812,30 @@ class StateSyncService:
 
     def _handle_state_push_traced(self, doc: dict, arrays):
         kind = doc.get("kind")
-        name = doc["name"]
+        name = doc.get("name")
+        if name is None and kind != NODE_ALLOC:
+            raise wire.WireSchemaError(
+                f"{kind} push has no run form: it carries 'name', "
+                f"not 'names'")
 
-        def require_vector(key):
-            """Validate a pushed resource vector BEFORE it is committed:
-            a malformed array from a foreign client must fail ITS call,
-            not enter the replay log where it would poison every sync
-            client (including future bootstrappers) with a bad row."""
+        def require_vector(key, rows=None):
+            """Validate a pushed resource vector (``rows``: a matrix of
+            that many of them) BEFORE it is committed: a malformed array
+            from a foreign client must fail ITS call, not enter the
+            replay log where it would poison every sync client
+            (including future bootstrappers) with a bad row."""
             from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS
 
             if key not in arrays:
                 raise wire.WireSchemaError(
                     f"{kind} push requires a {key!r} array")
             arr = np.asarray(arrays[key])
-            if arr.ndim != 1 or arr.shape[0] != NUM_RESOURCE_DIMS:
+            shape = ((NUM_RESOURCE_DIMS,) if rows is None
+                     else (rows, NUM_RESOURCE_DIMS))
+            if arr.shape != shape:
                 raise wire.WireSchemaError(
                     f"{kind} push: {key!r} must have shape "
-                    f"({NUM_RESOURCE_DIMS},), got {arr.shape}")
+                    f"{shape}, got {arr.shape}")
             if arr.dtype.kind not in "iu":
                 raise wire.WireSchemaError(
                     f"{kind} push: {key!r} must be an integer vector, "
@@ -863,6 +918,19 @@ class StateSyncService:
                 hp_request=arrays.get("hp_request"),
                 hp_max_used_req=arrays.get("hp_max_used_req"),
                 report_time=doc.get("usage_time"))
+        elif kind == NODE_ALLOC and name is None:
+            # the run form: the whole matrix is checked (here and in the
+            # run itself) before its first event commits
+            names = doc["names"]
+            require_vector("allocatable", rows=len(names))
+            rv, rejected = self.update_node_allocatable_run(
+                names, arrays["allocatable"])
+            reply = {"rv": rv, "rejected": rejected}
+            if rejected:
+                # the pusher's watch view holds nodes this service does
+                # not: the single form's ERROR says resync, so does this
+                reply["resync"] = True
+            return reply, None
         elif kind == NODE_ALLOC:
             require_vector("allocatable")
             rv = self.update_node_allocatable(name, arrays["allocatable"])
@@ -1154,7 +1222,8 @@ class StateSyncClient:
 #: event kinds whose contiguous runs have a vectorized binding apply
 #: (value = the batched method name; a binding without it falls back to
 #: the per-event route)
-_RUN_METHODS = {NODE_USAGE: "node_usage_run", POD_ADD: "pod_add_run"}
+_RUN_METHODS = {NODE_USAGE: "node_usage_run", POD_ADD: "pod_add_run",
+                NODE_ALLOC: "node_alloc_run"}
 
 #: event kind -> its apply's timeline span name (made once: the name is
 #: taken on every event)
@@ -1167,41 +1236,64 @@ def _dispatch_events(binding, items: list[tuple[dict, dict]]) -> None:
     """Route an ORDERED event list, batching contiguous same-kind runs
     into one vectorized binding apply (ISSUE 19).
 
-    Only untraced events coalesce: a trace-stamped event keeps its
-    per-event ``sync.<kind>`` span (and its position relative to its
-    neighbors — runs never cross it, so apply order is exactly the
-    per-event order).  A run of K events costs one scheduler-lock
-    round-trip and one ``sync.<kind>`` timeline span of K members
-    instead of K of each; the batched appliers perform the same per-event mutation
-    in the same order, so the resulting state is bit-identical."""
+    Events coalesce when they carry the SAME trace stamp: none at all,
+    or equal contexts (the events of one run-form STATE_PUSH frame, all
+    committed under its dispatch span).  A differently stamped
+    neighbour, or a stamped event between unstamped ones, ends the run —
+    runs never cross it, so apply order is exactly the per-event order.
+    A run of K events costs one scheduler-lock round-trip, one
+    ``sync.<kind>`` timeline span of K members and, when stamped, one
+    ``sync.<kind>`` trace span joined to that context, instead of K of
+    each; the batched appliers perform the same per-event mutation in
+    the same order, so the resulting state is bit-identical."""
     i, n = 0, len(items)
     while i < n:
         entry, arrs = items[i]
-        method = _RUN_METHODS.get(entry.get("kind"))
+        kind = entry.get("kind")
+        method = _RUN_METHODS.get(kind)
         run_fn = getattr(binding, method, None) if method else None
-        if run_fn is None or entry.get(tracing.TRACE_DOC_KEY) is not None:
-            _dispatch_event(binding, entry, arrs)
-            i += 1
-            continue
         j = i + 1
-        while (j < n and items[j][0].get("kind") == entry["kind"]
-               and items[j][0].get(tracing.TRACE_DOC_KEY) is None):
-            j += 1
+        if run_fn is not None:
+            stamp = entry.get(tracing.TRACE_DOC_KEY)
+            while (j < n and items[j][0].get("kind") == kind
+                   and items[j][0].get(tracing.TRACE_DOC_KEY) == stamp):
+                j += 1
         if j - i == 1:
             _dispatch_event(binding, entry, arrs)
         else:
-            # one sync.<kind> span of j - i members
-            tl_t0 = timeline.RECORDER.open(_SPAN_NAMES[entry["kind"]])
-            try:
-                run_fn(items[i:j])
-            finally:
-                timeline.RECORDER.close(tl_t0, "deltasync_apply", n=j - i)
-            # staleness watchdog feed: one mark covers the run — the
-            # watchdog reads only the latest timestamp
-            mark = getattr(binding, "note_sync_event", None)
-            if mark is not None:
-                mark()
+            _dispatch_run(binding, kind, run_fn, items[i:j])
         i = j
+
+
+def _dispatch_run(binding, kind: str, run_fn,
+                  run: list[tuple[dict, dict]]) -> None:
+    """One ``sync.<kind>`` span of ``len(run)`` members around the
+    binding's run apply; under the run's trace context where its events
+    carry one (_dispatch_event's rule, once for the run)."""
+    tl_t0 = timeline.RECORDER.open(_SPAN_NAMES[kind])
+    try:
+        first, last = run[0][0], run[-1][0]
+        ctx = tracing.TraceContext.from_doc(
+            first.get(tracing.TRACE_DOC_KEY))
+        if ctx is None:
+            run_fn(run)
+        else:
+            with tracing.TRACER.span(
+                    f"sync.{kind}",
+                    service=getattr(binding, "service_name", None),
+                    parent=ctx,
+                    attributes={"n": len(run),
+                                "first": first.get("name"),
+                                "last": last.get("name"),
+                                "rv": last.get("rv")}):
+                run_fn(run)
+    finally:
+        timeline.RECORDER.close(tl_t0, "deltasync_apply", n=len(run))
+    # staleness watchdog feed: one mark covers the run — the
+    # watchdog reads only the latest timestamp
+    mark = getattr(binding, "note_sync_event", None)
+    if mark is not None:
+        mark()
 
 
 def _dispatch_event(binding, entry: dict,
@@ -1442,6 +1534,17 @@ class SchedulerBinding:
             self.scheduler.snapshot.upsert_node(_dc.replace(
                 spec, allocatable=np.asarray(arrs["allocatable"],
                                              np.int32)))
+
+    def node_alloc_run(self,
+                       items: list[tuple[dict, dict[str, np.ndarray]]]
+                       ) -> None:
+        """A NODE_ALLOC run (a frame of the manager's patches): ONE
+        scheduler-lock round-trip for K allocatable refreshes, each
+        through node_alloc, the one place a refresh is applied, in its
+        order (the lock is reentrant)."""
+        with self.scheduler.lock:
+            for entry, arrs in items:
+                self.node_alloc(entry, arrs)
 
     def node_remove(self, name: str) -> None:
         with self.scheduler.lock:

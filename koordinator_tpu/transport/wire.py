@@ -37,7 +37,14 @@ Two version numbers govern the wire:
   plugin feeds its informer view into the sidecar; v4 adds the columnar
   event codec for the hot frame types — deltasync DELTA/SNAPSHOT event
   lists ride as columnar numpy blocks instead of per-event JSON docs,
-  see docs/wire_protocol.md).
+  see docs/wire_protocol.md; within v4, additive: the RUN form of
+  ``STATE_PUSH`` for ``kind: node_allocatable`` — ``names: [str, ...]``
+  and an ``allocatable`` matrix of shape ``(n, R)`` in place of ``name``
+  and ``(R,)``, at most ``STATE_PUSH_RUN_MAX`` events a frame, committed
+  under one lock hold and answered ``{rv, rejected}``.  A server from
+  before it answers the frame with a schema error (``name`` missing);
+  the manager's colocation loop sends no other form and counts that as
+  a push failure).
 
 ``REQUEST_SCHEMAS`` types each schema'd frame's json document;
 ``validate_doc`` is enforced server-side on every request frame, so a
@@ -91,7 +98,8 @@ class FrameType(enum.IntEnum):
     PING = 10
     LEASE_GET = 11      # {name} -> lease record fields
     LEASE_UPDATE = 12   # CAS write: {name, expect_holder, <record>} -> {ok}
-    STATE_PUSH = 13     # client-originated state event -> {rv}; the
+    STATE_PUSH = 13     # client-originated state event -> {rv}, or a
+                        # run of them -> {rv, rejected}; the
                         # Go-plugin/informer -> sidecar feed direction
 
 
@@ -136,11 +144,32 @@ REQUEST_SCHEMAS: dict[FrameType, dict[str, tuple]] = {
     },
     FrameType.STATE_PUSH: {
         "kind": (str, True),
-        "name": (str, True),
+        # exactly one of the two (REQUEST_ONE_OF): ``name`` is one
+        # event, ``names`` a run of them (node_allocatable only)
+        "name": (str, False),
+        "names": (list, False),
         # event-kind-specific fields (labels, priority, quota, ...) ride
         # as extras; resource vectors ride the raw array section
     },
 }
+
+#: groups of fields of which a request carries EXACTLY one
+REQUEST_ONE_OF: dict[FrameType, tuple[tuple[str, ...], ...]] = {
+    FrameType.STATE_PUSH: (("name", "names"),),
+}
+
+#: most events one run-form STATE_PUSH frame may carry, checked by the
+#: sender and the server.  A run is committed under ONE hold of the sync
+#: service's lock, so a pusher that also watches (the manager) is as far
+#: behind as the frame is long when the hold ends; its sender takes the
+#: echo from the delta log before it sends the frame's reply, so the
+#: watch cursor is never more than one frame behind.  A frame longer
+#: than the log's retention (deltasync.DeltaLog, 4,096) would push that
+#: cursor out of the retained window: the watch is poisoned, the next
+#: tick is served a snapshot, every record is forgotten and the whole
+#: cluster is patched again, every tick.  A bring-up tick patches every
+#: node (10,240 in the colocation cell): ten frames, not one.
+STATE_PUSH_RUN_MAX = 1024
 
 
 #: every array key any STATE_PUSH kind accepts (deltasync
@@ -183,6 +212,11 @@ def validate_doc(ftype: FrameType, doc: dict) -> None:
             raise WireSchemaError(
                 f"{ftype.name}: field {field!r} has type "
                 f"{type(val).__name__}, expected {types}")
+    for group in REQUEST_ONE_OF.get(ftype, ()):
+        if sum(field in doc for field in group) != 1:
+            raise WireSchemaError(
+                f"{ftype.name}: exactly one of {group} is required "
+                f"(peer protocol skew? local proto={PROTOCOL_VERSION})")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -34,6 +34,7 @@ from koordinator_tpu.cmd.binaries import ReconnectingSidecarClient
 from koordinator_tpu.manager.colocation_loop import (
     ColocationLoop,
     ManagerSyncBinding,
+    sidecar_push,
 )
 from koordinator_tpu.manager.noderesource_controller import (
     NodeResourceController,
@@ -182,13 +183,9 @@ def test_chaos_soak(seed, tmp_path):
         sock, on_push=sync.on_push, on_connect=bootstrap_watch,
         retry_policy=FAST_RETRY, faults=inj, timeout=3.0)
 
-    def push_allocatable(name, allocatable):
-        mgr_client.call(FrameType.STATE_PUSH,
-                        {"kind": "node_allocatable", "name": name},
-                        {"allocatable": np.asarray(allocatable, np.int32)})
-
     loop = ColocationLoop(NodeResourceController(), binding,
-                          push_allocatable, ensure_fn=mgr_client.ensure)
+                          sidecar_push(mgr_client),
+                          ensure_fn=mgr_client.ensure)
 
     # -- solver driver: long transport timeout, per-call deadline_ms
     # bounds the steady-state waits (and lets the warmup ride out jit
@@ -296,6 +293,20 @@ def test_chaos_soak(seed, tmp_path):
         assert sync.rv == service.rv, "manager watch never caught up"
         assert not oracle.violations, oracle.violations[:3]
         assert oracle.accepted >= len(pods)
+        # the loop's patches left in run-form frames, lost ones were sent
+        # again: what the manager last stamped on a node is what the
+        # sidecar holds of it
+        from koordinator_tpu import metrics
+        from koordinator_tpu.api.resources import ResourceDim
+
+        assert 0 < metrics.colocation_push_frames_total.value() <= (
+            metrics.colocation_patches_total.value()
+            + metrics.colocation_push_failures_total.value())
+        assert len(binding.records) == NODES
+        for name, record in binding.records.items():
+            stored = service.nodes[name]["arrays"]["allocatable"]
+            assert int(stored[ResourceDim.BATCH_CPU]) == \
+                record.last_batch_cpu, name
 
         # ---- no thread/fd growth vs the warmed-up baseline
         def settled():

@@ -44,6 +44,36 @@ def test_koordlet_flags_and_gates(tmp_path):
         KOORDLET_GATES.set("AuditEvents", before_audit)
 
 
+def test_two_koordlets_with_their_own_var_run_share_no_metric_history(
+        tmp_path):
+    """The metric cache is restored at start from --var-run-root-dir: a
+    second agent on the default directory would take the first one's
+    samples for its own (what made test_colocation_loop_binary_to_binary
+    read another test's CPU usage); given its own, it starts empty."""
+    from koordinator_tpu.koordlet import metriccache as mc
+
+    def boot(var_run):
+        return main_koordlet([
+            "--cgroup-root-dir", str(tmp_path / "cg"),
+            "--proc-root-dir", str(tmp_path / "proc"),
+            "--var-run-root-dir", str(tmp_path / var_run)])
+
+    first = boot("a")
+    assert first.component.cfg.var_run_root == str(tmp_path / "a")
+    first.component.metric_cache.append(mc.NODE_CPU_USAGE, 25.0)
+    first.component.stop()       # snapshots under its own directory
+    assert (tmp_path / "a" / "metriccache.npz").exists()
+    heir, stranger = boot("a"), boot("b")
+    try:
+        assert heir.component.metric_cache.query(
+            mc.NODE_CPU_USAGE, None, 0.0, float("inf")).avg() == 25.0
+        assert stranger.component.metric_cache.query(
+            mc.NODE_CPU_USAGE, None, 0.0, float("inf")).count == 0
+    finally:
+        heir.component.stop()
+        stranger.component.stop()
+
+
 def test_koordlet_serves_runtime_hooks(tmp_path):
     from koordinator_tpu.api import extension as ext
     from koordinator_tpu.runtimeproxy import HookRequest, HookType

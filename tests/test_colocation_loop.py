@@ -9,6 +9,7 @@ capacity it wiped gets re-pushed.
 """
 
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import ResourceDim, resource_vector
 from koordinator_tpu.manager.colocation_loop import (
@@ -45,9 +46,12 @@ def _loop(service, clock):
     service.attach_binding(binding)
     pushes = []
 
-    def push(name, allocatable):
-        service.update_node_allocatable(name, allocatable)
-        pushes.append((name, np.asarray(allocatable).copy()))
+    def push(names, allocatable):
+        _rv, rejected = service.update_node_allocatable_run(
+            names, allocatable)
+        pushes.extend((name, row.copy())
+                      for name, row in zip(names, allocatable))
+        return rejected
 
     controller = NodeResourceController(clock=clock)
     return ColocationLoop(controller, binding, push), binding, pushes
@@ -307,7 +311,7 @@ def test_wire_fed_hp_request_aggregates_feed_calculate_policies():
         pushes = []
         loop = ColocationLoop(NodeResourceController(config, clock=clock),
                               binding,
-                              lambda name, alloc: pushes.append(alloc))
+                              lambda names, alloc: pushes.extend(alloc))
         # the node view needs allocatable: replay the upsert live too
         service.upsert_node("n0", resource_vector(cpu=16_000, memory=16_384))
         service.update_node_usage(
@@ -359,7 +363,7 @@ def test_bootstrap_replay_preserves_report_time_for_degrade():
 
     pushes = []
     loop = ColocationLoop(NodeResourceController(clock=clock), binding,
-                          lambda name, alloc: pushes.append(alloc))
+                          lambda names, alloc: pushes.extend(alloc))
     assert loop.tick() == 1, "stale node must push a zeroing patch"
     zeroed = pushes[-1]
     assert int(zeroed[ResourceDim.BATCH_CPU]) == 0
@@ -374,3 +378,197 @@ def test_bootstrap_replay_preserves_report_time_for_degrade():
         report_time=clock.t)
     assert loop.tick() == 1
     assert int(pushes[-1][ResourceDim.BATCH_CPU]) > 0
+
+
+# -- the run seam: push_fn(names, allocatable) --------------------------------
+
+def _cluster_loop(n, clock, push=None):
+    """A service of ``n`` reported nodes watched in process, and a loop
+    whose push commits to it (or is ``push``) and logs its calls."""
+    service = StateSyncService(retention=4 * n + 64)
+    binding = ManagerSyncBinding(clock=clock)
+    service.attach_binding(binding)
+    for i in range(n):
+        service.upsert_node(f"n{i}",
+                            resource_vector(cpu=16_000 + i, memory=16_384))
+        service.update_node_usage(
+            f"n{i}", resource_vector(cpu=2_000, memory=4_096),
+            sys_usage=resource_vector(cpu=500, memory=512),
+            hp_usage=resource_vector(cpu=3_000 + i % 7, memory=2_048))
+    calls = []
+
+    def commit(names, allocatable):
+        calls.append((list(names), np.array(allocatable)))
+        return service.update_node_allocatable_run(names, allocatable)[1]
+
+    def logged(names, allocatable):
+        calls.append((list(names), np.array(allocatable)))
+        return push(names, allocatable)
+
+    loop = ColocationLoop(NodeResourceController(clock=clock), binding,
+                          commit if push is None else logged)
+    return service, binding, loop, calls
+
+
+@pytest.mark.parametrize("n", [1, 1_024, 1_025, 2_500])
+def test_a_tick_of_n_patches_goes_out_in_frames_of_at_most_1024(n):
+    from koordinator_tpu import metrics, tracing
+    from koordinator_tpu.transport.wire import STATE_PUSH_RUN_MAX
+
+    clock = FakeClock()
+    service, binding, loop, calls = _cluster_loop(n, clock)
+    base = {name: view.allocatable.copy()
+            for name, view in binding.nodes.items()}
+    exporter = tracing.InMemoryExporter()
+    tracing.TRACER.add_exporter(exporter)
+    try:
+        assert loop.tick() == n
+    finally:
+        tracing.TRACER.remove_exporter(exporter)
+    frames = -(-n // STATE_PUSH_RUN_MAX)
+    assert [len(names) for names, _ in calls] == (
+        [STATE_PUSH_RUN_MAX] * (n // STATE_PUSH_RUN_MAX)
+        + [n % STATE_PUSH_RUN_MAX] * (n % STATE_PUSH_RUN_MAX > 0))
+    assert len(calls) == frames
+    # patch order is the view's order, across the frames
+    assert [name for names, _ in calls for name in names] == [
+        f"n{i}" for i in range(n)]
+    written = [int(d) for d in (
+        ResourceDim.BATCH_CPU, ResourceDim.BATCH_MEMORY,
+        ResourceDim.MID_CPU, ResourceDim.MID_MEMORY)]
+    kept = [d for d in range(len(base["n0"])) if d not in written]
+    for names, rows in calls:
+        assert rows.shape == (len(names), len(base["n0"]))
+        assert rows.dtype == np.int32
+        for name, row in zip(names, rows):
+            assert row[kept].tolist() == base[name][kept].tolist()
+            assert row[ResourceDim.BATCH_CPU] > 0
+            # what was sent is what the service holds
+            assert service.nodes[name]["arrays"][
+                "allocatable"].tolist() == row.tolist()
+    assert metrics.colocation_push_frames_total.value() == frames
+    assert metrics.colocation_patches_total.value() == n
+    assert metrics.colocation_push_failures_total.value() == 0
+    # one manager.colocation_push span a frame, naming the tick and the
+    # frame's first and last node
+    spans = exporter.find(name="manager.colocation_push")
+    assert [(s.attributes["tick"], s.attributes["first"],
+             s.attributes["last"], s.attributes["n"]) for s in spans] == [
+        (1, names[0], names[-1], len(names)) for names, _ in calls]
+    # steady state: nothing to say, no frame
+    assert loop.tick() == 0 and len(calls) == frames
+
+
+@pytest.mark.parametrize("case", ["fresh_patches", "zeroing_patches"])
+def test_a_frame_with_no_reply_fails_its_names_and_the_next_tick_resends(
+        case):
+    """Two frames, the second's call raises: exactly its names have their
+    diff state reset (last_degraded too: the zeroing-patch case) and are
+    sent again by the next tick; the first frame's are not."""
+    from koordinator_tpu import metrics
+    from koordinator_tpu.transport.wire import STATE_PUSH_RUN_MAX
+
+    n, lost = STATE_PUSH_RUN_MAX + 6, 6
+    clock = FakeClock()
+    down = [False]
+    service = [None]
+
+    def push(names, allocatable):
+        if down[0] and names[0] == f"n{STATE_PUSH_RUN_MAX}":
+            raise ConnectionError("sidecar wedged")
+        return service[0].update_node_allocatable_run(names, allocatable)[1]
+
+    service[0], binding, loop, calls = _cluster_loop(n, clock, push)
+    if case == "zeroing_patches":
+        assert loop.tick() == n          # capacity advertised
+        clock.t += 16 * 60               # every report goes stale
+        del calls[:]
+    down[0] = True
+    assert loop.tick() == n - lost
+    tail = [f"n{i}" for i in range(STATE_PUSH_RUN_MAX, n)]
+    assert [names for names, _ in calls][1] == tail
+    assert loop.push_failures == lost
+    assert metrics.colocation_push_failures_total.value() == lost
+    for name, record in binding.records.items():
+        failed = name in tail
+        assert (record.last_batch_cpu == -1) == failed, name
+        assert (record.last_device_resources is None) == failed
+        if case == "zeroing_patches":
+            assert record.last_degraded == (not failed), name
+            stored = service[0].nodes[name]["arrays"]["allocatable"]
+            assert (int(stored[ResourceDim.BATCH_CPU]) == 0) == (not failed)
+    down[0] = False
+    del calls[:]
+    assert loop.tick() == lost
+    assert [names for names, _ in calls] == [tail]
+    if case == "zeroing_patches":
+        assert not calls[0][1][:, int(ResourceDim.BATCH_CPU)].any()
+        assert all(int(service[0].nodes[name]["arrays"]["allocatable"][
+            ResourceDim.BATCH_CPU]) == 0 for name in tail)
+    assert loop.tick() == 0
+
+
+def test_a_rejected_name_fails_alone():
+    from koordinator_tpu import metrics
+
+    clock = FakeClock()
+    turn_away = [True]
+
+    def push(names, allocatable):
+        return [("n3", "unknown node")] if turn_away[0] else []
+
+    _service, binding, loop, calls = _cluster_loop(8, clock, push)
+    assert loop.tick() == 7
+    assert loop.push_failures == 1
+    assert metrics.colocation_patches_total.value() == 7
+    assert metrics.colocation_push_frames_total.value() == 1
+    assert [name for name, record in binding.records.items()
+            if record.last_batch_cpu == -1] == ["n3"]
+    turn_away[0] = False
+    del calls[:]
+    assert loop.tick() == 1
+    assert [names for names, _ in calls] == [["n3"]]
+
+
+def test_sidecar_push_sends_one_run_frame_and_reports_the_rejected(tmp_path):
+    """The manager binary's wiring: one run-form STATE_PUSH a call; a name
+    the sidecar does not hold comes back rejected and the reply's resync
+    re-HELLOs the watch."""
+    from koordinator_tpu.cmd.binaries import ReconnectingSidecarClient
+    from koordinator_tpu.manager.colocation_loop import sidecar_push
+    from koordinator_tpu.transport import RpcServer, StateSyncClient
+
+    server = RpcServer(str(tmp_path / "push.sock"))
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    for name in ("n0", "n1"):
+        service.upsert_node(name, resource_vector(cpu=16_000, memory=16_384))
+    binding = ManagerSyncBinding()
+    sync = StateSyncClient(binding)
+    hellos = []
+
+    def bootstrap(client):
+        hellos.append(1)
+        sync.bind_client(client)
+        sync.bootstrap(client)
+
+    sidecar = ReconnectingSidecarClient(
+        server.path, on_push=sync.on_push, on_connect=bootstrap)
+    try:
+        push = sidecar_push(sidecar)
+        rows = np.stack([resource_vector(cpu=16_000, memory=16_384,
+                                         batch_cpu=1_000 + i)
+                         for i in range(3)])
+        assert push(["n0", "n1"], rows[:2]) == []
+        assert sync.rv == service.rv == 4, "reply overtook the echo"
+        assert len(hellos) == 1
+        assert push(["n0", "ghost", "n1"], rows) == [
+            ["ghost", "unknown node"]]
+        assert len(hellos) == 2 and sidecar.resyncs == 1
+        assert service.rv == 6
+        for name, row in (("n0", rows[0]), ("n1", rows[2])):
+            assert binding.nodes[name].allocatable.tolist() == row.tolist()
+    finally:
+        sidecar.close()
+        server.stop()

@@ -47,6 +47,9 @@ def deployment(tmp_path):
             "--cgroup-root-dir", cfg.cgroup_root,
             "--proc-root-dir", cfg.proc_root,
             "--sys-root-dir", cfg.sys_root,
+            # its own var-run: the metric cache is restored from there at
+            # start, and the default is one directory for the whole host
+            "--var-run-root-dir", cfg.var_run_root,
             "--runtime-hook-server-addr", str(tmp_path / "hooks.sock"),
         ])
         assembled["proxy"] = MAINS["koord-runtime-proxy"]([
@@ -200,6 +203,9 @@ def test_nodemetric_loop_over_the_wire(tmp_path):
             "--cgroup-root-dir", cfg.cgroup_root,
             "--proc-root-dir", cfg.proc_root,
             "--sys-root-dir", cfg.sys_root,
+            # its own var-run: the metric cache is restored from there at
+            # start, and the default is one directory for the whole host
+            "--var-run-root-dir", cfg.var_run_root,
             "--scheduler-sidecar-addr", str(tmp_path / "sidecar.sock"),
             "--node-name", "n-metric",
             "--nodemetric-report-interval-seconds", "0",
@@ -305,6 +311,9 @@ def test_device_inventory_loop_over_the_wire(tmp_path):
             "--cgroup-root-dir", cfg.cgroup_root,
             "--proc-root-dir", cfg.proc_root,
             "--sys-root-dir", cfg.sys_root,
+            # its own var-run: the metric cache is restored from there at
+            # start, and the default is one directory for the whole host
+            "--var-run-root-dir", cfg.var_run_root,
             "--scheduler-sidecar-addr", str(tmp_path / "devloop.sock"),
             "--node-name", "n-dev",
             "--device-report-interval-seconds", "0",
@@ -435,6 +444,9 @@ def test_colocation_loop_binary_to_binary(tmp_path):
             "--cgroup-root-dir", cfg.cgroup_root,
             "--proc-root-dir", cfg.proc_root,
             "--sys-root-dir", cfg.sys_root,
+            # its own var-run: the metric cache is restored from there at
+            # start, and the default is one directory for the whole host
+            "--var-run-root-dir", cfg.var_run_root,
             "--scheduler-sidecar-addr", str(tmp_path / "colo.sock"),
             "--node-name", "n-colo",
             "--nodemetric-report-interval-seconds", "0",
@@ -483,6 +495,16 @@ def test_colocation_loop_binary_to_binary(tmp_path):
         assert batch_cpu >= 2_000, (
             f"batch capacity {batch_cpu} too small for the BE pod "
             f"(pushes={manager.colocation_loop.push_failures})")
+        # one node: every patch went out as a run-form frame of one, and
+        # the manager's watch took its echo before the push returned
+        from koordinator_tpu import metrics
+
+        frames = metrics.colocation_push_frames_total.value()
+        assert frames >= 1
+        assert frames == (metrics.colocation_patches_total.value()
+                          + manager.colocation_loop.push_failures)
+        assert manager.sync_binding.nodes["n-colo"].allocatable[
+            int(ResourceDim.BATCH_CPU)] == batch_cpu
 
         # and the BE pod now schedules — over the same solve socket
         result = solve_remote(solve_client)

@@ -523,7 +523,7 @@ class TestPredictiveColocation:
             pushes = []
             loop = ColocationLoop(
                 NodeResourceController(clock=clock), binding,
-                lambda name, alloc: pushes.append(np.asarray(alloc).copy()),
+                lambda names, alloc: pushes.extend(np.array(alloc)),
                 forecast=forecast)
             loop.tick()
             return pushes

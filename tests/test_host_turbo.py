@@ -582,6 +582,110 @@ class TestRunBatchedApply:
         _dispatch_events(binding, _usage_items(k=24))
         assert len(acquisitions) == 1
 
+    def test_node_alloc_run_identical_to_sequential(self):
+        """A frame of the manager's patches: the run apply leaves specs
+        and device rows bit-identical to the serial one, a later patch
+        of a node winning as it would serially, an unknown node
+        dropped."""
+        batched, serial = _scheduler(), _scheduler()
+        _feed_nodes(batched), _feed_nodes(serial)
+        rng = np.random.default_rng(11)
+        items = []
+        for i in range(24):
+            name = "ghost" if i == 5 else f"n{i % 8}"
+            items.append((
+                {"kind": deltasync.NODE_ALLOC, "name": name, "rv": i + 1},
+                {"allocatable": _r(
+                    cpu=20_000, memory=32_768,
+                    batch_cpu=int(rng.integers(0, 9_000)),
+                    batch_memory=int(rng.integers(0, 9_000)))}))
+        binding = SchedulerBinding(batched)
+        calls = []
+        orig = binding.node_alloc_run
+        binding.node_alloc_run = (
+            lambda run: (calls.append(len(run)), orig(run)))
+        _dispatch_events(binding, items)
+        assert calls == [24]
+        for entry, arrs in items:
+            _dispatch_event(SchedulerBinding(serial), entry, arrs)
+        assert list(batched.snapshot.node_specs) == list(
+            serial.snapshot.node_specs)
+        for name, spec in serial.snapshot.node_specs.items():
+            got = batched.snapshot.node_specs[name]
+            np.testing.assert_array_equal(got.allocatable, spec.allocatable)
+            np.testing.assert_array_equal(got.usage, spec.usage)
+        np.testing.assert_array_equal(
+            np.asarray(batched.snapshot.state.node_allocatable),
+            np.asarray(serial.snapshot.state.node_allocatable))
+        np.testing.assert_array_equal(
+            np.asarray(batched.snapshot.state.node_usage),
+            np.asarray(serial.snapshot.state.node_usage))
+
+    def test_equally_stamped_events_run_under_one_span(self):
+        """Events that carry the SAME trace context (the events of one
+        run-form frame) are one run under one sync.<kind> span joined to
+        that context; a differently stamped neighbour, or a stamped
+        event between unstamped ones, still ends a run, so the apply
+        order stays the per-event order."""
+        from koordinator_tpu import tracing
+        from koordinator_tpu.api.resources import ResourceDim
+
+        sched = _scheduler()
+        _feed_nodes(sched)
+        binding = SchedulerBinding(sched)
+        runs = []
+        orig = binding.node_alloc_run
+        binding.node_alloc_run = (
+            lambda run: (runs.append([e["name"] for e, _ in run]),
+                         runs.append(tracing.current_context()),
+                         orig(run)))
+        singles = []
+        orig_one = SchedulerBinding.node_alloc
+        binding.node_alloc = (
+            lambda entry, arrs: (singles.append(entry["name"]),
+                                 orig_one(binding, entry, arrs)))
+        ctx_a = tracing.TraceContext(trace_id="a" * 32, span_id="1" * 16)
+        ctx_b = tracing.TraceContext(trace_id="b" * 32, span_id="2" * 16)
+        stamps = ([ctx_a] * 3 + [ctx_b] + [None] * 2 + [ctx_a] + [None]
+                  + [ctx_b] * 2)
+        items = []
+        for i, ctx in enumerate(stamps):
+            entry = {"kind": deltasync.NODE_ALLOC, "name": f"n{i % 8}",
+                     "rv": i + 1}
+            if ctx is not None:
+                # each event its own dict, as _commit_locked stamps them
+                entry[tracing.TRACE_DOC_KEY] = ctx.to_doc()
+            items.append((entry, {"allocatable": _r(
+                cpu=20_000, memory=32_768, batch_cpu=100 + i)}))
+        exporter = tracing.InMemoryExporter()
+        tracing.TRACER.add_exporter(exporter)
+        try:
+            _dispatch_events(binding, items)
+        finally:
+            tracing.TRACER.remove_exporter(exporter)
+        # the three runs, each applied with its context active
+        assert runs[0::2] == [["n0", "n1", "n2"], ["n4", "n5"],
+                              ["n0", "n1"]]
+        assert [c and c.trace_id for c in runs[1::2]] == [
+            ctx_a.trace_id, None, ctx_b.trace_id]
+        # what stood alone went the per-event way, in its place (the
+        # runs' members pass through node_alloc too: the run applies
+        # each event as the single apply does)
+        assert singles == [f"n{i % 8}" for i in range(len(stamps))]
+        spans = exporter.find(name="sync.node_allocatable")
+        assert [(s.trace_id, s.parent_id, s.attributes.get("n"),
+                 s.attributes.get("first"), s.attributes.get("last"),
+                 s.attributes.get("name")) for s in spans] == [
+            (ctx_a.trace_id, ctx_a.span_id, 3, "n0", "n2", None),
+            (ctx_b.trace_id, ctx_b.span_id, None, None, None, "n3"),
+            (ctx_a.trace_id, ctx_a.span_id, None, None, None, "n6"),
+            (ctx_b.trace_id, ctx_b.span_id, 2, "n0", "n1", None)]
+        assert all(s.service == "scheduler" for s in spans)
+        # serial order: the last patch of every node is what it holds
+        for i in range(len(stamps) - 8, len(stamps)):
+            assert int(sched.snapshot.node_specs[f"n{i % 8}"].allocatable[
+                ResourceDim.BATCH_CPU]) == 100 + i
+
     def test_client_apply_routes_batched(self):
         """A DELTA batch arriving through StateSyncClient._apply (the
         replay/bootstrap path) hits the run-batched dispatch."""

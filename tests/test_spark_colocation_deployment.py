@@ -12,6 +12,7 @@ import copy
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -20,6 +21,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from benchmarks.context import Context  # noqa: E402
+from benchmarks.layers import colo_patches_per_frame  # noqa: E402
 from benchmarks.reference import colocation as reference  # noqa: E402
 from benchmarks.spans import Spans  # noqa: E402
 from koordinator_tpu import metrics, timeline  # noqa: E402
@@ -69,6 +72,7 @@ def run(tmp_path_factory):
                in metrics.colocation_sync_reason_total.items()}
         out["watched"] = metrics.colocation_watch_events_total.value()
         out["patches"] = metrics.colocation_patches_total.value()
+        out["frames"] = metrics.colocation_push_frames_total.value()
         out["frames_sent"] = metrics.sync_delta_frames_sent_total.value()
         out["events_sent"] = metrics.sync_delta_events_sent_total.value()
         return out
@@ -77,6 +81,7 @@ def run(tmp_path_factory):
     try:
         state = kind.setup(dep, params, spans)
         dep.books.window_open = True
+        t_open = time.perf_counter()
         cycles = [kind.cycle(dep, params, spans) for _ in range(CYCLES - 1)]
         # the last cycle's wave by itself: what the watch was sent for it
         dep.step_clock()
@@ -95,12 +100,15 @@ def run(tmp_path_factory):
         docs = timeline.RECORDER.cycles(64)
         # the suite zeroes every counter between tests: read them here
         counted = {k: v - before.get(k, 0) for k, v in counters().items()}
+        per_frame = colo_patches_per_frame.read(Context(
+            spans=spans, t_open=t_open, t_close=float("inf"),
+            timeline_docs=docs))
     finally:
         dep.close()
         os.chdir(cwd)
     return {"dep": dep, "state": state, "cycles": cycles,
             "compared": compared, "ticks": ticks, "held": held, "docs": docs,
-            "counted": counted, "wave": wave}
+            "counted": counted, "wave": wave, "per_frame": per_frame}
 
 
 def test_every_compared_number_reads_zero(run):
@@ -205,6 +213,16 @@ def test_spans_and_counters_carry_the_right_members(run):
     assert parents == {"colo.tick"}
     assert pushed == pytest.approx(
         sum(t["pushed"] for t in dep.tick_log[-int(round(ticks)):]))
+    # a tick's patches leave in frames: 128 nodes, so one frame a tick that
+    # has something to say, each one synchronous STATE_PUSH
+    recent = dep.tick_log[-int(round(ticks)):]
+    in_frames, parents = span_total(docs, "colo.push.frame")
+    assert in_frames == pytest.approx(pushed) and parents == {"colo.push"}
+    calls = [s for doc in docs for s in doc["segments"]
+             if s["name"] == "rpc.call.STATE_PUSH"
+             and s["parent"] == "colo.push.frame"]
+    assert sum(s["n"] for s in calls) == pytest.approx(
+        sum(t["pushed"] > 0 for t in recent))
     admitted, _ = span_total(docs, "colo.admit")
     assert admitted >= sum(c["arrived"] for c in run["cycles"])
     watched, parents = span_total(docs, "colo.watch")
@@ -215,6 +233,13 @@ def test_spans_and_counters_carry_the_right_members(run):
     counted = run["counted"]
     patches = sum(t["pushed"] for t in dep.tick_log)
     assert counted["patches"] == patches
+    assert counted["frames"] == sum(t["pushed"] > 0 for t in dep.tick_log)
+    # the benchmark's reader: patches / frames over the measured cycles
+    window = dep.tick_log[-CYCLES:]
+    assert run["per_frame"] == pytest.approx(
+        sum(t["pushed"] for t in window)
+        / sum(t["pushed"] > 0 for t in window))
+    assert 1 < run["per_frame"] <= nodes
     for reason in ("first", "time_gap", "diff"):
         assert counted[reason] == sum(tick["reasons"].count(reason)
                                       for tick in run["ticks"])

@@ -1789,3 +1789,319 @@ def test_backlog_peak_after_a_burst_from_two_threads(rpc, attached):
     assert metrics.sync_binding_backlog_peak.value() == 2.0
     assert metrics.sync_binding_backlog.value() == 0.0
     assert _frame_counts() == ({"no_recipient": 41} if attached else {})
+
+
+# -- the run form of STATE_PUSH (node_allocatable) ---------------------------
+
+def _alloc_rows(n: int, seed: int = 0) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((n, R), np.int32)
+    rows[:, 0] = 64_000
+    rows[:, 1] = 65_536
+    rows[:, 2:] = rng.integers(0, 50_000, (n, R - 2))
+    return rows
+
+
+def _run_service(server, n_nodes: int, **kw):
+    service = StateSyncService(**kw)
+    service.attach(server)
+    names = [f"n{i}" for i in range(n_nodes)]
+    for name in names:
+        service.upsert_node(name, resource_vector(cpu=64_000, memory=65_536))
+    return service, names
+
+
+def _push_run(client, names, rows, **extra):
+    return client.call(
+        FrameType.STATE_PUSH,
+        dict({"kind": "node_allocatable", "names": list(names)}, **extra),
+        {"allocatable": rows})
+
+
+def _held(service) -> dict:
+    """What a service holds: stored node state, the delta log, and the
+    snapshot a late HELLO would be served."""
+    doc, arrays = service._snapshot()
+
+    def strip(event):
+        return {k: v for k, v in event.items() if k != "trace"}
+
+    return {
+        "rv": service.rv,
+        "nodes": {name: (strip(entry["doc"]),
+                         {k: v.tolist() for k, v in entry["arrays"].items()})
+                  for name, entry in service.nodes.items()},
+        "log": [(rv, strip(event),
+                 {k: np.asarray(v).tolist() for k, v in arrs.items()})
+                for rv, event, arrs in service.log.since(0)],
+        "snapshot": ([strip(e) for e in doc["events"]],
+                     {k: v.tolist() for k, v in arrays.items()}),
+    }
+
+
+@pytest.mark.parametrize("n,over", [
+    (1, "wire"), (7, "wire"), (1_024, "wire"), (7, "in_process")])
+def test_a_run_leaves_what_n_single_pushes_leave(rpc, tmp_path, n, over):
+    """One run-form frame commits n events with consecutive rvs in name
+    order; stored state, delta log and a late HELLO's snapshot equal
+    those of n single pushes."""
+    server, clients = rpc
+    run_service, names = _run_service(server, n + 3)
+    server.start()
+    single_server = RpcServer(str(tmp_path / "single.sock"))
+    single_service, _ = _run_service(single_server, n + 3)
+    single_server.start()
+    try:
+        pushed, rows = names[1:n + 1][::-1], _alloc_rows(n)
+        if over == "wire":
+            _, doc, _ = _push_run(connect(server, clients), pushed, rows)
+            single = connect(single_server, clients)
+            for name, row in zip(pushed, rows):
+                single.call(FrameType.STATE_PUSH,
+                            {"kind": "node_allocatable", "name": name},
+                            {"allocatable": row})
+        else:
+            rv, rejected = run_service.update_node_allocatable_run(
+                pushed, rows)
+            doc = {"rv": rv, "rejected": rejected}
+            for name, row in zip(pushed, rows):
+                single_service.update_node_allocatable(name, row)
+        assert doc["rejected"] == [] and "resync" not in doc
+        assert doc["rv"] == run_service.rv == single_service.rv
+        tail = run_service.log.since(doc["rv"] - n)
+        assert [rv for rv, _, _ in tail] == list(
+            range(doc["rv"] - n + 1, doc["rv"] + 1))
+        assert [event["name"] for _, event, _ in tail] == pushed
+        assert _held(run_service) == _held(single_service)
+        for name, row in zip(pushed, rows):
+            assert run_service.nodes[name]["arrays"][
+                "allocatable"].tolist() == row.tolist()
+    finally:
+        single_server.stop()
+
+
+@pytest.mark.parametrize("case", [
+    "wrong_width", "one_row_short", "a_vector", "float_matrix",
+    "beyond_int32", "duplicate_names", "no_names", "too_many_names",
+    "a_name_that_is_no_string", "names_on_another_kind",
+    "name_with_names", "neither_name_nor_names", "no_matrix"])
+def test_a_malformed_run_fails_its_call_and_commits_nothing(rpc, case):
+    from koordinator_tpu.transport.wire import STATE_PUSH_RUN_MAX
+
+    server, clients = rpc
+    service, names = _run_service(server, 4)
+    server.start()
+    client = connect(server, clients)
+    doc = {"kind": "node_allocatable", "names": names[:3]}
+    arrays = {"allocatable": _alloc_rows(3)}
+    if case == "wrong_width":
+        arrays = {"allocatable": np.zeros((3, R + 1), np.int32)}
+    elif case == "one_row_short":
+        arrays = {"allocatable": _alloc_rows(2)}
+    elif case == "a_vector":
+        arrays = {"allocatable": _alloc_rows(3)[0]}
+    elif case == "float_matrix":
+        arrays = {"allocatable": _alloc_rows(3).astype(np.float32)}
+    elif case == "beyond_int32":
+        rows = _alloc_rows(3).astype(np.int64)
+        rows[2, 3] = 2**31          # the LAST row: the first two are sound
+        arrays = {"allocatable": rows}
+    elif case == "duplicate_names":
+        doc["names"] = [names[0], names[1], names[0]]
+    elif case == "no_names":
+        doc["names"], arrays = [], {"allocatable": _alloc_rows(0)}
+    elif case == "too_many_names":
+        many = STATE_PUSH_RUN_MAX + 1
+        doc["names"] = [f"n{i}" for i in range(many)]
+        arrays = {"allocatable": _alloc_rows(many)}
+    elif case == "a_name_that_is_no_string":
+        doc["names"] = [names[0], 7, names[1]]
+    elif case == "names_on_another_kind":
+        doc["kind"] = "node_usage"
+        arrays = {"usage": _alloc_rows(3)}
+    elif case == "name_with_names":
+        doc["name"] = names[0]
+    elif case == "neither_name_nor_names":
+        del doc["names"]
+    elif case == "no_matrix":
+        arrays = {}
+    before = _held(service)
+    with pytest.raises(RpcError):
+        client.call(FrameType.STATE_PUSH, doc, arrays)
+    assert _held(service) == before
+    # the connection outlives the refusal and a sound run still commits
+    _, reply, _ = _push_run(client, names[:3], _alloc_rows(3))
+    assert reply["rv"] == before["rv"] + 3 and reply["rejected"] == []
+
+
+def test_a_run_skips_and_reports_an_unknown_name_and_commits_the_rest(rpc):
+    server, clients = rpc
+    service, names = _run_service(server, 4)
+    server.start()
+    rows = _alloc_rows(4)
+    rv0 = service.rv
+    _, doc, _ = _push_run(connect(server, clients),
+                          [names[0], "ghost", names[2], "gone"], rows)
+    assert doc["rejected"] == [["ghost", "unknown node"],
+                               ["gone", "unknown node"]]
+    assert doc["resync"] is True       # the single form's ERROR says so too
+    assert doc["rv"] == service.rv == rv0 + 2
+    assert [e["name"] for _, e, _ in service.log.since(rv0)] == [
+        names[0], names[2]]
+    for name, row in ((names[0], rows[0]), (names[2], rows[2])):
+        assert service.nodes[name]["arrays"]["allocatable"].tolist() == \
+            row.tolist()
+    assert "ghost" not in service.nodes
+    # all of them unknown: nothing commits, the reply names them all
+    _, doc, _ = _push_run(connect(server, clients), ["a", "b"], rows[:2])
+    assert doc["rv"] == service.rv == rv0 + 2
+    assert [name for name, _ in doc["rejected"]] == ["a", "b"]
+    # the single form of one unknown node still fails its own call
+    with pytest.raises(RpcError, match="unknown node"):
+        connect(server, clients).call(
+            FrameType.STATE_PUSH,
+            {"kind": "node_allocatable", "name": "ghost"},
+            {"allocatable": rows[0]})
+
+
+class AllocMirror(MirrorBinding):
+    """MirrorBinding for a cluster whose allocatable moves."""
+
+    def reset(self):
+        super().reset()
+        self.alloc: dict[str, list[int]] = {}
+
+    def node_upsert(self, entry, arrs):
+        super().node_upsert(entry, arrs)
+        self.alloc[entry["name"]] = arrs["allocatable"].tolist()
+
+    def node_alloc(self, entry, arrs):
+        self.alloc[entry["name"]] = arrs["allocatable"].tolist()
+        self.applies[entry["name"]] = self.applies.get(entry["name"], 0) + 1
+
+
+def test_a_pusher_that_watches_pushes_a_whole_cluster_in_ten_frames(rpc):
+    """The bring-up tick: 10,240 patches, two and a half times the delta
+    log's retention, from a connection that is also a watcher.  In ten
+    frames of 1,024 the echo of each is taken by the connection's sender
+    before the frame's reply, so the watch's cursor is never more than a
+    frame behind: it is never poisoned, never resynced by snapshot, and
+    the echo of a frame is a few DELTA frames, not one per event."""
+    from koordinator_tpu.transport.wire import STATE_PUSH_RUN_MAX
+
+    server, clients = rpc
+    n = 10_240
+    service, names = _run_service(server, n)       # retention 4,096
+    assert service.log.retention == 4_096
+    server.start()
+    frames: list = []
+    sync = StateSyncClient(AllocMirror())
+    client = connect(server, clients, timeout=60.0, on_push=lambda f: (
+        frames.append(f), sync.on_push(f)))
+    sync.bind_client(client)
+    sync.bootstrap(client)
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    rows = _alloc_rows(n, seed=3)
+    rv0, per_frame = service.rv, []
+    for lo in range(0, n, STATE_PUSH_RUN_MAX):
+        seen = len(frames)
+        _, doc, _ = _push_run(client, names[lo:lo + STATE_PUSH_RUN_MAX],
+                              rows[lo:lo + STATE_PUSH_RUN_MAX])
+        assert doc["rejected"] == []
+        # the reply stood behind the echo in the connection's queue
+        assert sync.rv == doc["rv"] == service.rv
+        per_frame.append(len(frames) - seen)
+    assert service.rv == rv0 + n
+    assert client.connected and conn.alive and conn.dropped == 0
+    assert sync.binding.resets == 1, "resynced by snapshot"
+    assert sync.gaps == 0 and sync.skipped == 0 and not sync.needs_resync
+    assert set(sync.binding.applies.values()) == {1}
+    assert sync.binding.alloc == {
+        name: row.tolist() for name, row in zip(names, rows)}
+    # an idle connection gets the ready first event, then the run; the
+    # sender may take a ready frame mid-hold and be handed one more
+    assert per_frame[0] >= 2, per_frame
+    assert max(per_frame) <= STATE_PUSH_RUN_MAX // 16, per_frame
+    assert sum(per_frame) == len(frames) <= n // 64
+    assert _sent_counts() == (len(frames), n)
+
+
+def test_a_frame_longer_than_the_retention_would_poison_its_pusher(rpc):
+    """Why STATE_PUSH_RUN_MAX exists: a run committed in one lock hold
+    that outruns the retained log leaves the pusher's own cursor
+    outside it."""
+    server, clients = rpc
+    service, names = _run_service(server, 64, retention=32)
+    server.start()
+    sync, client = _watch(server, clients, service, binding=AllocMirror())
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    with service._lock:      # the sender cannot take a run meanwhile
+        service.update_node_allocatable_run(names, _alloc_rows(64))
+    wait_until(lambda: not client.connected)
+    assert not conn.alive and conn.dropped == 1
+
+
+def test_runs_from_three_pushers_beside_a_reporter_keep_every_watcher_whole(
+        rpc):
+    """Stress, time-bounded: three connections push runs over disjoint
+    nodes while an in-process reporter commits single events and two
+    watchers follow, with the interpreter switching threads 50 times as
+    often.  Every event has its own rv, no watcher sees a gap or an event
+    twice, and each ends holding what the service holds."""
+    import sys
+
+    server, clients = rpc
+    service, names = _run_service(server, 96, retention=1 << 16)
+    server.start()
+    watchers = [_watch(server, clients, service, binding=AllocMirror())[0]
+                for _ in range(2)]
+    rv0, rounds, width = service.rv, 12, 32
+    errors: list = []
+
+    def pusher(k: int) -> None:
+        try:
+            client = connect(server, clients, timeout=30.0)
+            mine = names[k * width:(k + 1) * width]
+            for r in range(rounds):
+                _, doc, _ = _push_run(client, mine,
+                                      _alloc_rows(width, seed=100 * k + r))
+                assert doc["rejected"] == []
+        except Exception as e:  # noqa: BLE001 — read on the main thread
+            errors.append(e)
+
+    def reporter() -> None:
+        try:
+            for i in range(rounds * width):
+                service.update_node_usage(
+                    names[i % 96], resource_vector(cpu=1 + i, memory=1 + i))
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    try:
+        threads = [threading.Thread(target=pusher, args=(k,), daemon=True)
+                   for k in range(3)]
+        threads.append(threading.Thread(target=reporter, daemon=True))
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors, errors
+    total = 4 * rounds * width
+    assert service.rv == rv0 + total
+    assert [rv for rv, _, _ in service.log.since(rv0)] == list(
+        range(rv0 + 1, rv0 + total + 1))
+    for sync in watchers:
+        wait_until(lambda: sync.rv == service.rv, timeout=30.0)
+        assert sync.gaps == 0 and sync.skipped == 0
+        assert sync.binding.resets == 1 and sync.binding.equals(service)
+        assert set(sync.binding.applies.values()) == {rounds}
+        assert sync.binding.alloc == {
+            name: entry["arrays"]["allocatable"].tolist()
+            for name, entry in service.nodes.items()}

@@ -262,6 +262,75 @@ class TestStatePushValidation:
             server.stop()
 
 
+class TestStatePushRunFormSchema:
+    """STATE_PUSH asks for exactly one of ``name`` / ``names``; ``names``
+    (the run form) is node_allocatable's alone, checked before anything
+    commits, on the framed path and on a handler reached directly."""
+
+    @pytest.mark.parametrize("doc,ok", [
+        ({"kind": "node_allocatable", "name": "n0"}, True),
+        ({"kind": "node_allocatable", "names": ["n0", "n1"]}, True),
+        ({"kind": "pod_remove", "name": "p0"}, True),
+        ({"kind": "node_allocatable"}, False),
+        ({"kind": "node_allocatable", "name": "n0", "names": ["n1"]},
+         False),
+        ({"kind": "node_allocatable", "names": "n0"}, False),
+        ({"kind": "node_allocatable", "name": ["n0"]}, False),
+        ({"names": ["n0"]}, False),
+    ])
+    def test_exactly_one_of_name_and_names(self, doc, ok):
+        if ok:
+            validate_doc(FrameType.STATE_PUSH, doc)
+        else:
+            with pytest.raises(WireSchemaError):
+                validate_doc(FrameType.STATE_PUSH, doc)
+
+    @pytest.mark.parametrize("kind", [
+        "node_upsert", "node_usage", "node_devices", "node_remove",
+        "pod_add", "pod_remove", "rsv_upsert", "rsv_remove", "mystery"])
+    def test_names_on_another_kind_is_refused_by_the_handler(self, kind):
+        import numpy as np
+
+        from koordinator_tpu.transport.deltasync import StateSyncService
+
+        service = StateSyncService()
+        service.upsert_node("n0", np.zeros(10, np.int32))
+        vector = np.zeros((1, 10), np.int32)
+        with pytest.raises(WireSchemaError, match="no run form"):
+            service._handle_state_push(
+                {"kind": kind, "names": ["n0"], "devices": {}},
+                {"allocatable": vector, "usage": vector,
+                 "requests": vector})
+        assert service.rv == 1 and list(service.nodes) == ["n0"]
+
+    def test_the_run_cap_is_checked_on_both_sides(self):
+        import numpy as np
+
+        from koordinator_tpu.manager.colocation_loop import (
+            STATE_PUSH_RUN_MAX as senders,
+        )
+        from koordinator_tpu.transport import wire
+        from koordinator_tpu.transport.deltasync import (
+            DeltaLog,
+            StateSyncService,
+        )
+
+        assert senders is wire.STATE_PUSH_RUN_MAX == 1_024
+        # a frame must stay well inside what the delta log retains
+        assert wire.STATE_PUSH_RUN_MAX * 4 <= DeltaLog().retention
+        service = StateSyncService()
+        names = [f"n{i}" for i in range(wire.STATE_PUSH_RUN_MAX + 1)]
+        for name in names:
+            service.upsert_node(name, np.zeros(10, np.int32))
+        rows = np.ones((len(names), 10), np.int32)
+        with pytest.raises(WireSchemaError, match="1 to 1024"):
+            service.update_node_allocatable_run(names, rows)
+        assert service.rv == len(names)
+        rv, rejected = service.update_node_allocatable_run(
+            names[:-1], rows[:-1])
+        assert rv == 2 * len(names) - 1 and rejected == []
+
+
 class TestStatePushNoPartialCommit:
     """Property: ANY state push either commits atomically (rv advances
     by one, the event replays to fresh clients) or raises WireSchemaError

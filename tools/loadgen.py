@@ -412,6 +412,7 @@ class SteadyStateHarness:
         from koordinator_tpu.manager.colocation_loop import (
             ColocationLoop,
             ManagerSyncBinding,
+            sidecar_push,
         )
         from koordinator_tpu.manager.noderesource_controller import (
             NodeResourceController,
@@ -425,7 +426,6 @@ class SteadyStateHarness:
         from koordinator_tpu.transport.retry import RetryPolicy
 
         cfg = self.cfg
-        FrameType = self._FrameType
         sock = f"{self.workdir}/loadgen-{tenant}.sock"
         server = RpcServer(sock, service="scheduler")
         sync = StateSyncService(retention=8192)
@@ -453,15 +453,8 @@ class SteadyStateHarness:
             retry_policy=retry, timeout=30.0)
         self._closers.append(mgr_client.close)
 
-        def push_allocatable(name, allocatable,
-                             _client=mgr_client):
-            _client.call(
-                FrameType.STATE_PUSH,
-                {"kind": "node_allocatable", "name": name},
-                {"allocatable": np.asarray(allocatable, np.int32)})
-
         self._colocations.append(ColocationLoop(
-            NodeResourceController(), binding, push_allocatable,
+            NodeResourceController(), binding, sidecar_push(mgr_client),
             ensure_fn=mgr_client.ensure))
 
         # register the fleet directly on the sync service (the
