@@ -26,6 +26,20 @@ Stages (per pod, seconds):
 * ``commit``     — commit bookkeeping -> bind ack
 * ``e2e``        — arrival (or enqueue when no arrival stamp) -> ack
 
+**Cut by time** (ISSUE 34).  The sketches above are cumulative since
+process start, so they cannot say what the last five minutes, or one
+measured window, were like.  Beside them the ledger keeps a bounded ring
+(:data:`JourneyLedger._SLICES_MAX` rounds) of per-round **slices**: the
+same bucket counts, per (tenant, qos, stage), of one round's binds,
+keyed by the round's commit stamp (``perf_counter``).
+``report(since_perf=, until_perf=)`` and ``snapshot_doc(...)`` merge the
+slices whose stamp lies in the range (bucket-wise add, as every merge
+here); with no bounds they read the cumulative sketches, as the gauges,
+the JSONL snapshot and ``tools/latency_report.py`` always do.
+``/debug/latency?last_s=N`` is the operator's form.  Slices are made
+where the sketches are, in the lazy digest: nothing is added to the
+enqueue or the bind path.
+
 Kill switch: ``KOORD_JOURNEY=0`` or ``--no-journey`` disables recording
 entirely.  The ledger never touches solve inputs, the pending-queue sort
 key, or quota charges — scheduling decisions are bit-identical either way
@@ -39,6 +53,7 @@ import math
 import os
 import threading
 import time
+from collections import deque
 from typing import Iterable
 
 import numpy as np
@@ -259,6 +274,9 @@ class JourneyLedger:
     #: staged rounds that force an inline digest (bounds memory when no
     #: reader ever samples the ledger)
     _STAGED_MAX = 512
+    #: rounds whose slices the ring keeps for the windowed reports (at a
+    #: round a second, a quarter of an hour)
+    _SLICES_MAX = 1024
 
     def __init__(self, enabled: bool = True) -> None:
         self._enabled = bool(enabled)
@@ -270,6 +288,11 @@ class JourneyLedger:
         # staged bind rounds awaiting digestion:
         # (tenant, qos_list, stamps, round_start_perf, solve_s, commit_s)
         self._staged: list[tuple] = []
+        # digested rounds, oldest first: (commit_perf, {(tenant, qos,
+        # stage): part}); a part is one series' share of the round as
+        # (bucket indices, counts, count, sum, min, max), _ZERO_IDX
+        # standing for the zero bucket
+        self._slices: deque = deque(maxlen=self._SLICES_MAX)
 
     # -- lifecycle ------------------------------------------------------
     @property
@@ -281,15 +304,17 @@ class JourneyLedger:
         with self._lock:
             self._enabled = bool(enabled)
             if not enabled:
-                self._sketches.clear()
-                self._pending.clear()
-                self._staged.clear()
+                self._clear_locked()
 
     def reset_for_tests(self) -> None:
         with self._lock:
-            self._sketches.clear()
-            self._pending.clear()
-            self._staged.clear()
+            self._clear_locked()
+
+    def _clear_locked(self) -> None:
+        self._sketches.clear()
+        self._pending.clear()
+        self._staged.clear()
+        self._slices.clear()
 
     # -- recording ------------------------------------------------------
     def note_enqueue(self, pod_name: str, arrival_ts: float = 0.0) -> None:
@@ -352,16 +377,20 @@ class JourneyLedger:
                 self._digest_locked()
 
     def _digest_locked(self) -> None:
-        """Fold every staged bind round into the sketches in one pass.
+        """Fold every staged bind round into the sketches, and into one
+        slice a round, in one pass.
 
         Per staged round only a handful of (P,)-shaped ops run to turn
         stamps into stage latencies; bucket counting and per-series
-        count/sum/min/max for ALL (tenant, qos, stage) series across
-        the whole drain then happen through one composite-key
+        count/sum/min/max for ALL (round, tenant, qos, stage) groups
+        across the whole drain then happen through one composite-key
         ``np.unique`` plus one sort — the numpy fixed cost is paid per
-        digest, not per round.  The per-round scalar stages (solve,
-        commit) never touch numpy: n identical samples are one O(1)
-        bucket add.  Caller holds ``self._lock``.
+        digest, not per round.  A group's buckets are the round's slice
+        of that series; the cumulative sketches take the groups' sum
+        (a second, small ``np.unique`` over the first one's result).
+        The per-round scalar stages (solve, commit) never touch numpy:
+        n identical samples are one O(1) bucket add.  Caller holds
+        ``self._lock``.
         """
         staged = self._staged
         if not staged:
@@ -369,19 +398,24 @@ class JourneyLedger:
         self._staged = []
         seg_groups: list[int] = []
         seg_vals: list[np.ndarray] = []
-        sketches: list[DDSketch] = []
-        gid: dict[tuple[str, int, str], int] = {}
+        # group id -> (slice position, series key)
+        groups_of: list[tuple[int, tuple[str, int, str]]] = []
+        # one slice a round: the staged tuples of one round share its
+        # start stamp; the slice is keyed by the last commit among them
+        slices: list[list] = []
+        slice_at: dict[float, int] = {}
 
-        def group(tenant: str, qos: int, stage: str) -> int:
-            key = (tenant, qos, stage)
-            g = gid.get(key)
-            if g is None:
-                g = gid[key] = len(sketches)
-                sketches.append(self._sketch(tenant, qos, stage))
-            return g
+        def group(at: int, tenant: str, qos: int, stage: str) -> int:
+            groups_of.append((at, (tenant, qos, stage)))
+            return len(groups_of) - 1
 
         for (tenant, qos_list, stamps, round_start_perf,
              solve_s, commit_s) in staged:
+            at = slice_at.get(round_start_perf)
+            if at is None:
+                at = slice_at[round_start_perf] = len(slices)
+                slices.append([0.0, {}])
+            slices[at][0] = max(slices[at][0], round_start_perf + solve_s)
             stamp_arr = np.asarray(stamps, np.float64)    # (P, 3)
             arrival = stamp_arr[:, 0]
             queue_s = np.maximum(round_start_perf - stamp_arr[:, 2], 0.0)
@@ -405,29 +439,28 @@ class JourneyLedger:
             distinct = sorted(set(qos_list))
             for q in distinct:
                 if len(distinct) == 1:
-                    sel = None                      # whole round
-                    n = len(qos_list)
+                    n = len(qos_list)                   # whole round
                     ing = (ingest_s[has_arrival]
                            if any_arrival else None)
-                    seg_vals.append(e2e_s)
-                    seg_groups.append(group(tenant, q, "e2e"))
-                    seg_vals.append(queue_s)
+                    e2e_q, queue_q = e2e_s, queue_s
                 else:
                     sel = np.asarray(qos_list) == q
                     n = int(sel.sum())
                     ing = (ingest_s[sel & has_arrival]
                            if any_arrival else None)
-                    seg_vals.append(e2e_s[sel])
-                    seg_groups.append(group(tenant, q, "e2e"))
-                    seg_vals.append(queue_s[sel])
-                seg_groups.append(group(tenant, q, "queue_wait"))
+                    e2e_q, queue_q = e2e_s[sel], queue_s[sel]
+                seg_vals.append(e2e_q)
+                seg_groups.append(group(at, tenant, q, "e2e"))
+                seg_vals.append(queue_q)
+                seg_groups.append(group(at, tenant, q, "queue_wait"))
                 if ing is not None and ing.size:
                     seg_vals.append(ing)
-                    seg_groups.append(group(tenant, q, "ingest"))
-                self._sketch(tenant, q, "solve").insert_repeated(
-                    solve_s, n)
-                self._sketch(tenant, q, "commit").insert_repeated(
-                    commit_s, n)
+                    seg_groups.append(group(at, tenant, q, "ingest"))
+                for stage, value in (("solve", solve_s),
+                                     ("commit", commit_s)):
+                    self._sketch(tenant, q, stage).insert_repeated(value, n)
+                    _add_part(slices[at][1], (tenant, q, stage),
+                              _scalar_part(value, n))
 
         flat = np.concatenate(seg_vals)
         lens = np.fromiter((v.size for v in seg_vals), np.int64,
@@ -441,6 +474,10 @@ class JourneyLedger:
         # representable latency fit comfortably in 32 bits
         composite = groups * (1 << 33) + (idx + (1 << 32))
         uniq, counts = np.unique(composite, return_counts=True)
+        u_group = uniq >> 33
+        u_bucket = (uniq & ((1 << 33) - 1)) - (1 << 32)
+        u_starts = np.concatenate(
+            ([0], np.flatnonzero(np.diff(u_group)) + 1, [uniq.size]))
         # per-group count/sum/min/max via one sort + reduceat
         order = np.argsort(groups, kind="stable")
         sv, sg = flat[order], groups[order]
@@ -450,23 +487,43 @@ class JourneyLedger:
         g_sums = np.add.reduceat(sv, starts).tolist()
         g_mins = np.minimum.reduceat(sv, starts).tolist()
         g_maxs = np.maximum.reduceat(sv, starts).tolist()
-        for g, cnt, gsum, gmin, gmax in zip(
-                g_ids, g_counts, g_sums, g_mins, g_maxs):
-            sk = sketches[g]
+        # every group holds a value, so uniq's groups are g_ids, in order
+        series_ids: dict[tuple[str, int, str], int] = {}
+        sketches: list[DDSketch] = []
+        u_series = np.empty(uniq.size, np.int64)
+        for pos, (g, cnt, gsum, gmin, gmax) in enumerate(zip(
+                g_ids, g_counts, g_sums, g_mins, g_maxs)):
+            at, key = groups_of[g]
+            lo, hi = u_starts[pos], u_starts[pos + 1]
+            _add_part(slices[at][1], key,
+                      (u_bucket[lo:hi].copy(), counts[lo:hi].copy(),
+                       cnt, gsum, gmin, gmax))
+            sid = series_ids.get(key)
+            if sid is None:
+                sid = series_ids[key] = len(sketches)
+                sketches.append(self._sketch(*key))
+            u_series[lo:hi] = sid
+            sk = sketches[sid]
             sk.count += cnt
             sk._sum += gsum
             if gmin < sk._min:
                 sk._min = gmin
             if gmax > sk._max:
                 sk._max = gmax
-        for comp, cnt in zip(uniq.tolist(), counts.tolist()):
-            g, b = divmod(comp, 1 << 33)
+        # the cumulative sketches: the groups' buckets summed per series
+        comp, inverse = np.unique(u_series * (1 << 33)
+                                  + (u_bucket + (1 << 32)),
+                                  return_inverse=True)
+        totals = np.bincount(inverse, weights=counts).astype(np.int64)
+        for c, cnt in zip(comp.tolist(), totals.tolist()):
+            sid, b = divmod(c, 1 << 33)
             b -= 1 << 32
-            sk = sketches[g]
+            sk = sketches[sid]
             if b == _ZERO_IDX:
                 sk.zero_count += cnt
             else:
                 sk.buckets[b] = sk.buckets.get(b, 0) + cnt
+        self._slices.extend(map(tuple, slices))
 
     def _sketch(self, tenant: str, qos: int, stage: str) -> DDSketch:
         key = (tenant, qos, stage)
@@ -485,39 +542,76 @@ class JourneyLedger:
         with self._lock:
             return len(self._pending)
 
-    def snapshot_doc(self, tenant: str | None = None) -> dict:
+    def _series_locked(self, tenant: str | None,
+                       since_perf: float | None,
+                       until_perf: float | None
+                       ) -> tuple[dict, int | None]:
+        """``({(tenant, qos, stage): DDSketch}, rounds)``: the cumulative
+        sketches (``rounds`` None) with no bounds, else the merge of the
+        ring's slices whose commit stamp lies in [since, until] and how
+        many rounds that was.  Caller holds ``self._lock``."""
+        self._digest_locked()
+        if since_perf is None and until_perf is None:
+            return {k: sk for k, sk in self._sketches.items()
+                    if tenant is None or k[0] == tenant}, None
+        lo = -math.inf if since_perf is None else since_perf
+        hi = math.inf if until_perf is None else until_perf
+        by_key: dict[tuple[str, int, str], list] = {}
+        rounds = 0
+        for commit_perf, parts in self._slices:
+            if not lo <= commit_perf <= hi:
+                continue
+            rounds += 1
+            for key, part in parts.items():
+                if tenant is None or key[0] == tenant:
+                    by_key.setdefault(key, []).append(part)
+        return {k: _merge_parts(v) for k, v in by_key.items()}, rounds
+
+    def snapshot_doc(self, tenant: str | None = None,
+                     since_perf: float | None = None,
+                     until_perf: float | None = None) -> dict:
         """Serializable snapshot: ``{"series": [{tenant,qos,stage,sketch}]}``.
 
         Deterministic ordering (sorted keys) so identical ledgers produce
-        byte-identical JSON.
+        byte-identical JSON.  With a bound (``perf_counter`` stamps) the
+        sketches are those of the rounds committed in the range, as far
+        back as the ring of slices reaches.
         """
         with self._lock:
-            self._digest_locked()
-            keys = sorted(k for k in self._sketches
-                          if tenant is None or k[0] == tenant)
+            found, rounds = self._series_locked(tenant, since_perf,
+                                                until_perf)
             series = [{"tenant": t, "qos": q, "stage": s,
-                       "sketch": self._sketches[(t, q, s)].to_doc()}
-                      for (t, q, s) in keys]
-        return {"alpha": RELATIVE_ACCURACY, "series": series}
+                       "sketch": found[(t, q, s)].to_doc()}
+                      for (t, q, s) in sorted(found)]
+        doc = {"alpha": RELATIVE_ACCURACY, "series": series}
+        if rounds is not None:
+            doc["rounds"] = rounds
+        return doc
 
     def report(self, tenant: str | None = None,
-               quantiles: tuple[float, ...] = (0.5, 0.9, 0.99)) -> dict:
-        """Human-facing journey table: per-series quantiles + counts."""
+               quantiles: tuple[float, ...] = (0.5, 0.9, 0.99),
+               since_perf: float | None = None,
+               until_perf: float | None = None) -> dict:
+        """Human-facing journey table: per-series quantiles + counts,
+        since process start or, with a bound, over the rounds committed
+        in [since_perf, until_perf] (``rounds`` says how many)."""
         with self._lock:
-            self._digest_locked()
-            keys = sorted(k for k in self._sketches
-                          if tenant is None or k[0] == tenant)
+            found, rounds = self._series_locked(tenant, since_perf,
+                                                until_perf)
             rows = []
-            for (t, q, s) in keys:
-                sk = self._sketches[(t, q, s)]
+            for (t, q, s) in sorted(found):
+                sk = found[(t, q, s)]
                 row = {"tenant": t, "qos": q, "stage": s,
                        "count": sk.count,
                        "mean_s": sk.mean(), "max_s": sk.max_value}
                 for quant in quantiles:
                     row[f"p{int(quant * 100)}_s"] = sk.quantile(quant)
                 rows.append(row)
-        return {"enabled": self._enabled, "alpha": RELATIVE_ACCURACY,
-                "series": rows}
+        doc = {"enabled": self._enabled, "alpha": RELATIVE_ACCURACY,
+               "series": rows}
+        if rounds is not None:
+            doc["rounds"] = rounds
+        return doc
 
     def write_jsonl(self, path: str) -> int:
         """Append one snapshot line per (tenant, qos, stage) series."""
@@ -549,6 +643,46 @@ class JourneyLedger:
                                        "stage": s, "q": tag})
         except Exception:
             pass
+
+
+def _scalar_part(value: float, n: int) -> tuple:
+    """The slice part of ``n`` samples of one value (what
+    ``DDSketch.insert_repeated`` adds)."""
+    v = max(float(value), 0.0)
+    idx = _ZERO_IDX if value <= _MIN_VALUE else DDSketch._index(value)
+    return (np.asarray([idx], np.int64), np.asarray([n], np.int64),
+            n, v * n, v, v)
+
+
+def _add_part(parts: dict, key: tuple[str, int, str], part: tuple) -> None:
+    """Put ``part`` under ``key``; a second part of one series in one
+    slice (a round committed pod by pod) joins the first."""
+    have = parts.get(key)
+    if have is not None:
+        part = (np.concatenate((have[0], part[0])),
+                np.concatenate((have[1], part[1])),
+                have[2] + part[2], have[3] + part[3],
+                min(have[4], part[4]), max(have[5], part[5]))
+    parts[key] = part
+
+
+def _merge_parts(parts: list[tuple]) -> DDSketch:
+    """One sketch from the slice parts of one series: bucket-wise add."""
+    out = DDSketch()
+    idx, inverse = np.unique(np.concatenate([p[0] for p in parts]),
+                             return_inverse=True)
+    counts = np.bincount(
+        inverse, weights=np.concatenate([p[1] for p in parts]))
+    for i, n in zip(idx.tolist(), counts.astype(np.int64).tolist()):
+        if i == _ZERO_IDX:
+            out.zero_count = n
+        else:
+            out.buckets[i] = n
+    out.count = sum(p[2] for p in parts)
+    out._sum = float(sum(p[3] for p in parts))
+    out._min = float(min(p[4] for p in parts))
+    out._max = float(max(p[5] for p in parts))
+    return out
 
 
 def merge_snapshot_rows(rows: Iterable[dict]) -> dict:

@@ -534,9 +534,10 @@ critical_path_seconds = SCHEDULER.gauge(
     "republished each cycle so cleared ones read 0")
 timeline_segments_dropped = SCHEDULER.counter(
     "timeline_segments_dropped_total",
-    "Timeline records the recorder's full ring pushed out before any "
-    "window read them; back-to-back spans of one name are one record, "
-    "so a steady scheduler sits at zero")
+    "Timeline records (spans, and wait observations: ISSUE 34) the "
+    "recorder's full rings pushed out before any window read them; "
+    "back-to-back spans of one name are one record, so a steady "
+    "scheduler sits at zero")
 explanation_queue_purged = SCHEDULER.counter(
     "explanation_queue_purged_total",
     "QUEUED ScheduleExplanation entries that ExplanationStore.delete / "
@@ -821,6 +822,17 @@ sync_delta_events_sent_total = TRANSPORT.counter(
     "Events carried by the frames of sync_delta_frames_sent_total, "
     "summed over connections; events / frames is the run length (1 "
     "where every watcher keeps up with every commit)")
+sync_watch_cursor_lag_events = TRANSPORT.gauge(
+    "sync_watch_cursor_lag_events",
+    "How far behind a watcher was, in events, when its connection's "
+    "sender thread last took a run from the delta log (label: "
+    "quantity=last|peak — last is the newest run's length over all "
+    "connections, peak the longest since process start).  Set once per "
+    "run, never per event.  The line to alert on is the log's retention "
+    "(4,096 events): a watcher whose cursor falls out of it is poisoned "
+    "and comes back by snapshot; a pusher waiting for its reply and a "
+    "watcher that keeps up are sent ready single-event frames and never "
+    "set it")
 sync_resyncs_total = TRANSPORT.counter(
     "sync_resyncs_total",
     "Server-requested resyncs honored by a reconnecting client (ERROR "

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from typing import Callable, Optional
 
 import numpy as np
@@ -149,7 +150,10 @@ def debug_timeline_body(scheduler, params: dict | None = None) -> dict:
     cycle gantts, newest first — typed segments, the wall-time
     attribution by cause (sums to 1.0 with an explicit unattributed
     residual), device-idle intervals derived from the dispatch/block
-    edges, and the cycle's critical-path chain + dominant cause.
+    edges, the cycle's critical-path chain + dominant cause, and
+    ``waits``: per name ``{n, wait_s, max_s}`` of the time work stood
+    in a queue (a frame in a connection's inbox or outbox) before the
+    thread that took it in this doc's wall did — never a segment.
 
     The recorder is process-wide (``timeline.RECORDER``): a
     multi-tenant front's cycles and an untenanted scheduler's
@@ -176,14 +180,19 @@ def debug_timeline_body(scheduler, params: dict | None = None) -> dict:
 
 
 def debug_latency_body(scheduler, params: dict | None = None) -> dict:
-    """The /debug/latency?tenant= payload (shared by DebugService and the
-    HTTP gateway): the pod-journey ledger's per-(tenant, qos, stage)
-    latency quantile table — TRUE per-pod arrival->bind e2e quantiles
-    plus the stage decomposition (ingest, queue_wait, solve, commit),
-    each from a mergeable log-bucketed sketch with <=1% relative error.
+    """The /debug/latency?tenant=&last_s= payload (shared by DebugService
+    and the HTTP gateway): the pod-journey ledger's per-(tenant, qos,
+    stage) latency quantile table — TRUE per-pod arrival->bind e2e
+    quantiles plus the stage decomposition (ingest, queue_wait, solve,
+    commit), each from a mergeable log-bucketed sketch with <=1%
+    relative error.  Since process start, or with ``last_s=N`` over the
+    rounds committed in the last N seconds (as far back as the ledger's
+    ring of per-round slices reaches; ``rounds`` says how many it
+    merged).
 
     501 when the ledger is off (``KOORD_JOURNEY=0`` / ``--no-journey``);
-    400 (typed) on a tenant filter that matches no recorded series."""
+    400 (typed) on a tenant filter that matches no recorded series or a
+    ``last_s`` that is not a positive number."""
     from koordinator_tpu import journey
 
     if not journey.LEDGER.enabled:
@@ -196,7 +205,19 @@ def debug_latency_body(scheduler, params: dict | None = None) -> dict:
             raise DebugApiError(
                 400, f"unknown tenant {tenant!r} "
                      f"(recorded: {', '.join(known) or 'none yet'})")
-    doc = journey.LEDGER.report(tenant=tenant)
+    last_s = (params or {}).get("last_s")
+    since = None
+    if last_s is not None:
+        try:
+            last_s = float(last_s)
+        except (TypeError, ValueError):
+            raise DebugApiError(400, "last_s must be a number") from None
+        if not last_s > 0:
+            raise DebugApiError(400, "last_s must be > 0")
+        since = time.perf_counter() - last_s
+    doc = journey.LEDGER.report(tenant=tenant, since_perf=since)
+    if since is not None:
+        doc["last_s"] = last_s
     doc["stages"] = list(journey.STAGES)
     doc["pending"] = journey.LEDGER.pending_count()
     return doc

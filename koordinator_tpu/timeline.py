@@ -19,7 +19,10 @@ instant the most specific active segment wins):
                       construction this bucket equals
                       ``pipeline_host_wait_fraction`` (same intervals the
                       ``_solve_device_s`` accumulator sums)
-``lock_wait``         waiting to acquire a scheduler round lock
+``lock_wait``         a tenant's round waiting for the front's round lock
+                      (``round_lock.acquire``, ``scheduler/tenancy.py``:
+                      the one lock wait that is recorded; ``Scheduler.lock``
+                      and the sync service's lock are not)
 ``json_codec``        wire payload encode/decode (``transport/wire.py``)
 ``deltasync_apply``   a sync event batch applying onto a binding
 ``dispatch``          host-side solve dispatch work (``_round_dispatch``)
@@ -64,6 +67,24 @@ invariant and ``tools/soak_report.py`` judge ``round``/``cycle`` docs
 only.  Every doc carries ``by_name``: ``{name: {n, busy_s, self_s,
 wait}}``, self time = busy minus what the span's children on the same
 thread cover; :data:`WAIT_NAMES` are waits, never summed as work.
+
+**Waits** (ISSUE 34).  A span says what a thread was doing; a **wait
+observation** says how long a piece of work stood in a queue before a
+thread took it: ``RECORDER.wait(name, t_queued, t_taken, n)``, made by
+the thread that takes the work off the queue, both stamps on
+``perf_counter``.  Observed today: ``rpc.inbox.<REQUEST TYPE>`` (a frame
+between the connection's reader and its dispatch worker),
+``rpc.outbox.<FRAME TYPE>`` (a reply, a ready DELTA or a push notice
+between ``_Conn.send`` and the connection's sender thread;
+``transport/channel.py``).  Every doc carries ``waits``: ``{name: {n,
+wait_s, max_s}}`` over the observations whose ``t_taken`` lies in the
+doc's wall.  A wait is NOT what the host was doing, so it never enters
+``segments``, ``by_name``, the sweep or the critical path (the
+benchmark names a device's idle gaps by the shortest segment over
+them).  The two waits that are spans, ``rpc.wait`` and
+``round_lock.acquire``, stay spans.  What the bounded ring drops before
+a window reads it is counted with the records in
+``timeline_segments_dropped_total``.
 
 **Attribution semantics.** ``host_wait_attribution{cause}`` decomposes
 the WHOLE cycle wall into fractions that sum to 1.0 (including
@@ -311,10 +332,14 @@ class TimelineRecorder:
     """
 
     def __init__(self, enabled: bool = True, max_segments: int = 16384,
-                 max_cycles: int = 64):
+                 max_cycles: int = 64, max_waits: int = 65536):
         self._enabled = enabled
         self._lock = threading.Lock()
         self._segments: deque = deque(maxlen=max_segments)
+        #: wait observations no window has read: (name, t_queued,
+        #: t_taken, n).  Appended and popped without the lock (both are
+        #: atomic on a deque); never iterated
+        self._waits: deque = deque(maxlen=max_waits)
         self._cycles: deque = deque(maxlen=max_cycles)
         self._tls = threading.local()
         #: bumped by every finish_cycle: a record of an older generation
@@ -341,6 +366,7 @@ class TimelineRecorder:
     def _forget(self) -> None:  # koordlint: guarded-by(self._lock)
         """Drop what no window has read; no run goes on across it."""
         self._segments.clear()
+        self._waits.clear()
         self._gen += 1
         self._last_end = None
 
@@ -436,6 +462,21 @@ class TimelineRecorder:
         if self._enabled and t1 > t0:
             self._record(st, node, t0, t1, cause, tenant, n)
 
+    def wait(self, name: str, t_queued: float, t_taken: float,
+             n: int = 1) -> None:
+        """Observe that ``n`` pieces of work stood queued from
+        ``t_queued`` until this thread took them at ``t_taken``
+        (perf_counter stamps; a ``t_queued`` of 0.0 is "no stamp was
+        taken": the recorder was off when the work was queued).  One
+        append, no lock; never a segment."""
+        if not self._enabled or not t_queued:
+            return
+        waits = self._waits
+        if len(waits) == waits.maxlen:
+            with self._lock:
+                self._count_drop()
+        waits.append((name, t_queued, t_taken, n))
+
     @contextlib.contextmanager
     def section(self, cause: str, name: str = "", tenant: str = "",
                 n: int = 1):
@@ -471,7 +512,47 @@ class TimelineRecorder:
                         "busy_s": r[_BUSY] * share})
         return out
 
-    def _doc(self, cycle: int, t0: float, t1: float, mode: str) -> dict:
+    def _take_waits(self, bounds: list[tuple[float, float]]
+                    ) -> list[dict]:
+        """One ``waits`` map per window of ``bounds`` (consecutive,
+        oldest first): every pending observation goes to the window
+        whose wall holds its ``t_taken``; one taken before the first
+        window is dropped (nobody will read it), one taken after the
+        last stays for the next call."""
+        out: list[dict] = [{} for _ in bounds]
+        first_lo, last_hi = bounds[0][0], bounds[-1][1]
+        ends = [(hi, doc) for (_, hi), doc in zip(bounds, out)]
+        pop, later = self._waits.popleft, []
+        try:
+            while True:
+                obs = pop()
+                name, t_queued, t_taken, n = obs
+                if t_taken > last_hi:
+                    later.append(obs)
+                    continue
+                if t_taken < first_lo:
+                    continue
+                for hi, doc in ends:
+                    if t_taken <= hi:
+                        break
+                waited = t_taken - t_queued
+                slot = doc.get(name)
+                if slot is None:
+                    doc[name] = [n, waited, waited]
+                else:
+                    slot[0] += n
+                    slot[1] += waited
+                    if waited > slot[2]:
+                        slot[2] = waited
+        except IndexError:
+            pass
+        self._waits.extend(later)
+        return [{name: {"n": n, "wait_s": wait_s, "max_s": max_s}
+                 for name, (n, wait_s, max_s) in doc.items()}
+                for doc in out]
+
+    def _doc(self, cycle: int, t0: float, t1: float, mode: str,
+             waits: dict) -> dict:
         wall = t1 - t0
         segments = self._window(t0, t1)
         totals, chain = sweep_attribution(segments, t0, t1)
@@ -490,6 +571,7 @@ class TimelineRecorder:
                 dict(s, start=s["start"] - t0, end=s["end"] - t0)
                 for s in sorted(segments, key=lambda s: s["start"])],
             "by_name": by_name(segments),
+            "waits": waits,
             "attribution": attribution,
             "attribution_s": totals,
             "unattributed_fraction": attribution[UNATTRIBUTED],
@@ -520,10 +602,13 @@ class TimelineRecorder:
             # seal every run: what a window was handed is not extended
             self._gen += 1
             last_end = self._last_end
-        docs = []
+        bounds = [(t0, t1)]
         if last_end is not None and last_end < t0:
-            docs.append(self._doc(cycle, last_end, t0, INGEST))
-        doc = self._doc(cycle, t0, t1, mode)
+            bounds.insert(0, (last_end, t0))
+        waits = self._take_waits(bounds)
+        docs = [self._doc(cycle, lo, hi, INGEST, w)
+                for (lo, hi), w in zip(bounds[:-1], waits)]
+        doc = self._doc(cycle, t0, t1, mode, waits[-1])
         docs.append(doc)
         with self._lock:
             self._cycles.extend(docs)

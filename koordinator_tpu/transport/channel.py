@@ -47,6 +47,16 @@ Handler = Callable[[dict, dict[str, np.ndarray]],
 #: client's runs encode -> send -> ``rpc.wait`` -> decode
 _SERVER_SPANS = {t: f"rpc.{t.name}" for t in FrameType}
 _CLIENT_SPANS = {t: f"rpc.call.{t.name}" for t in FrameType}
+#: timeline WAIT names (``timeline.RECORDER.wait``; never spans): a
+#: request between the connection's reader and its dispatch worker, and
+#: an outbound item between ``_Conn.send`` and the connection's sender
+#: thread.  A push notice is named for the frame it yields: the one
+#: push source is the sync service's live DELTA stream
+_INBOX_WAITS = {t: f"rpc.inbox.{t.name}" for t in FrameType}
+_OUTBOX_WAITS = {t: f"rpc.outbox.{t.name}" for t in FrameType}
+_NOTICE_WAIT = _OUTBOX_WAITS[FrameType.DELTA]
+
+_perf_counter = time.perf_counter
 
 #: the connection whose frame is currently being dispatched on THIS
 #: thread — handlers are (doc, arrays) -> (doc, arrays) with no
@@ -151,17 +161,23 @@ def _recv_exact(sock: socket.socket, faults=None):
 class _Conn:
     """One server-side connection: bounded outbound queue + sender thread.
 
-    A queue item is a ready :class:`Frame` (a reply), ``None`` (the
-    poison), or a push NOTICE: a callable the sender thread calls with
+    A queue item is ``None`` (the poison) or a pair ``(what, t_queued)``
+    where ``what`` is a ready :class:`Frame` (a reply, a single-event
+    DELTA) or a push NOTICE: a callable the sender thread calls with
     this connection when it reaches it, for the frame to send then
     (``None``: nothing to send).  A notice keeps its place in the FIFO,
-    so what it yields still leaves before every reply queued after it."""
+    so what it yields still leaves before every reply queued after it.
+    ``t_queued`` is the ``perf_counter`` at ``send`` (0.0 with the
+    timeline recorder off: no clock is read); the sender observes the
+    wait ``rpc.outbox.<FRAME TYPE>`` as it takes the pair off the queue.
+    The stamp lives in the pair, never on the frame: a ready DELTA frame
+    is shared between connections."""
 
     def __init__(self, sock: socket.socket, faults=None):
         self.sock = sock
         self.faults = faults
-        self.queue: "queue.Queue[Frame | Callable | None]" = queue.Queue(
-            SEND_QUEUE_DEPTH)
+        self.queue: "queue.Queue[tuple[Frame | Callable, float] | None]" \
+            = queue.Queue(SEND_QUEUE_DEPTH)
         self.alive = True
         self.dropped = 0
         #: negotiated message protocol for this peer (stamped by the
@@ -188,8 +204,9 @@ class _Conn:
         reconnect instead of silently missing one event."""
         if not self.alive:
             return
+        t_queued = _perf_counter() if timeline.RECORDER.enabled else 0.0
         try:
-            self.queue.put_nowait(item)
+            self.queue.put_nowait((item, t_queued))
         except queue.Full:
             self.dropped += 1
             self._sever()
@@ -232,8 +249,8 @@ class _Conn:
 
     def _drain(self) -> None:
         while True:
-            item = self.queue.get()
-            if item is None:
+            entry = self.queue.get()
+            if entry is None:
                 # poison AFTER the backlog: already-queued frames (e.g.
                 # a response to an in-flight call whose side effect
                 # already applied) still reach the peer, THEN the wire
@@ -249,7 +266,15 @@ class _Conn:
                 except OSError:
                     pass
                 return
-            if isinstance(item, Frame):
+            item, t_queued = entry
+            ready = isinstance(item, Frame)
+            if t_queued:
+                # a notice's wait ends here too, where the sender reaches
+                # it: building its frame is the source's busy time
+                timeline.RECORDER.wait(
+                    _OUTBOX_WAITS[item.type] if ready else _NOTICE_WAIT,
+                    t_queued, _perf_counter())
+            if ready:
                 frame = item
             else:
                 # a push notice: the frame is made now, from what the
@@ -318,8 +343,8 @@ class _ConnHandler(socketserver.BaseRequestHandler):
         recv = _recv_exact(self.request, faults=server.faults)
         conn = _Conn(self.request, faults=server.faults)
         server._on_connect(conn)
-        inbox: "queue.Queue[Optional[tuple[Frame, float]]]" = queue.Queue(
-            RECV_QUEUE_DEPTH)
+        inbox: "queue.Queue[Optional[tuple[Frame, float, float]]]" = \
+            queue.Queue(RECV_QUEUE_DEPTH)
         worker = threading.Thread(
             target=_dispatch_loop, args=(server, conn, inbox), daemon=True)
         worker.start()
@@ -335,7 +360,11 @@ class _ConnHandler(socketserver.BaseRequestHandler):
                     conn.send(Frame(FrameType.ACK, frame.request_id,
                                     encode_payload({})))
                     continue
-                inbox.put((frame, _time.monotonic()))
+                # the arrival stamp twice: monotonic for the deadline,
+                # perf_counter (the recorder's clock) for the inbox wait
+                inbox.put((frame, _time.monotonic(),
+                           _perf_counter() if timeline.RECORDER.enabled
+                           else 0.0))
         finally:
             # poison AFTER the backlog (blocking put: the worker is
             # draining); already-read frames still run their handlers —
@@ -355,12 +384,11 @@ def _dispatch_loop(server: "RpcServer", conn: _Conn, inbox) -> None:
         item = inbox.get()
         if item is None:
             return
-        frame, recv_time = item
-        _dispatch_one(server, conn, frame, recv_time)
+        _dispatch_one(server, conn, *item)
 
 
 def _dispatch_one(server: "RpcServer", conn: _Conn, frame: Frame,
-                  recv_time: float) -> None:
+                  recv_time: float, recv_perf: float = 0.0) -> None:
     import time as _time
 
     from koordinator_tpu import metrics
@@ -372,6 +400,9 @@ def _dispatch_one(server: "RpcServer", conn: _Conn, frame: Frame,
                             {"message": f"no handler for {frame.type}"})))
         return
     tl_t0 = timeline.RECORDER.open(_SERVER_SPANS[frame.type])
+    if recv_perf and tl_t0:
+        # the frame's wait in the inbox ends where its span opens
+        timeline.RECORDER.wait(_INBOX_WAITS[frame.type], recv_perf, tl_t0)
     try:
         doc, arrays = decode_payload(frame.payload)
         # typed request schemas: version/shape skew between

@@ -328,6 +328,8 @@ def _pack_for(events: list[tuple[int, dict, dict[str, np.ndarray]]],
 
 _FRAME_BUILT = {"outcome": "built"}
 _FRAME_NO_RECIPIENT = {"outcome": "no_recipient"}
+_LAG_LAST = {"quantity": "last"}
+_LAG_PEAK = {"quantity": "peak"}
 
 
 class StateSyncService:
@@ -369,6 +371,9 @@ class StateSyncService:
         #: high-water mark of the binding backlog (gauge shadow; only
         #: ever written under _lock alongside the append)
         self._backlog_peak = 0
+        #: longest run a watcher's sender has had to take from the log
+        #: (gauge shadow, written under _lock in _next_delta)
+        self._lag_peak = 0
 
     # -- mutations (informer event handlers) --------------------------------
 
@@ -509,7 +514,15 @@ class StateSyncService:
                 if not events:
                     return None
                 conn.cursor = events[-1][0]
-            n = len(events)
+                # how far behind this watcher was, in events: once per
+                # run, against the log's retention (the poison line)
+                n = len(events)
+                metrics.sync_watch_cursor_lag_events.set(
+                    float(n), labels=_LAG_LAST)
+                if n > self._lag_peak:
+                    self._lag_peak = n
+                    metrics.sync_watch_cursor_lag_events.set(
+                        float(n), labels=_LAG_PEAK)
             metrics.sync_delta_frames_sent_total.inc()
             metrics.sync_delta_events_sent_total.inc(float(n))
             return wire.Frame(FrameType.DELTA, 0, wire.encode_payload(

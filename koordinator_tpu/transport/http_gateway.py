@@ -55,15 +55,20 @@ Routes (all JSON bodies/responses unless noted):
                                           observatory's reconstructed
                                           cycle gantts: typed segments,
                                           host-wait attribution,
-                                          device-idle intervals, and
-                                          the critical-path chain +
-                                          dominant cause per cycle
-    GET  /debug/latency?tenant=        -> the pod-journey ledger's
+                                          device-idle intervals, the
+                                          critical-path chain +
+                                          dominant cause per cycle, and
+                                          ``waits`` (time work stood in
+                                          a queue) per doc
+    GET  /debug/latency?tenant=&last_s= -> the pod-journey ledger's
                                           per-(tenant, qos, stage)
                                           e2e latency quantile table
-                                          from mergeable sketches (501
-                                          when the ledger is off; typed
-                                          400 on an unknown tenant)
+                                          from mergeable sketches,
+                                          since start or over the last
+                                          N seconds (501 when the
+                                          ledger is off; typed 400 on
+                                          an unknown tenant or a bad
+                                          last_s)
     GET  /debug/profile?seconds=N      -> on-demand jax.profiler
                                           capture; 403 unless enabled
                                           at assembly (gated off by
@@ -436,8 +441,9 @@ class HttpGateway:
 
     def _debug_latency(self, req) -> None:
         """The pod-journey ledger's latency quantile table — same body
-        the DebugService serves (shared builder; ?tenant= filters, typed
-        400 on an unknown tenant, 501 while the ledger is off)."""
+        the DebugService serves (shared builder; ?tenant= filters,
+        ?last_s=N cuts to the last N seconds, typed 400 on an unknown
+        tenant or a bad last_s, 501 while the ledger is off)."""
         if self.scheduler is None:
             return req._reply(501, {"error": "no scheduler attached"})
         from urllib.parse import parse_qsl

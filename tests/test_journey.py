@@ -320,6 +320,193 @@ class TestWireThreading:
                 "commit"} <= stages
 
 
+class TestCutByTime:
+    """ISSUE 34: the same sketches, cut to a range of commit stamps by
+    merging the ring's per-round slices."""
+
+    def _rounds(self, led, n_rounds, seed=0, mixed_qos=False,
+                one_by_one=(), read_after=()):
+        """``n_rounds`` bind rounds a second apart on a made-up clock;
+        returns the per-series reference sketches, built sample by
+        sample, for every prefix: ``ref[k]`` covers rounds < k."""
+        rng = np.random.default_rng(seed)
+        refs = [{}]
+        base = 1_000.0
+        for r in range(n_rounds):
+            start = base + r
+            commit_at = start + (0.010 + 0.001 * r)
+            ack_at = commit_at + 0.002
+            # as the ledger takes them: differences of stamps
+            solve_s, commit_s = commit_at - start, ack_at - commit_at
+            pods = [PodSpec(name=f"r{r}p{i}",
+                            requests=np.zeros(4, np.int32),
+                            qos=(i % 2) * 3 if mixed_qos else 0)
+                    for i in range(int(rng.integers(3, 9)))]
+            waits = rng.uniform(0.0, 0.5, len(pods))
+            for pod, w in zip(pods, waits):
+                led._pending[pod.name] = (0.0, 0.0, start - float(w))
+            batches = ([[p] for p in pods] if r in one_by_one else [pods])
+            for batch in batches:
+                led.record_bind_batch(
+                    "a", batch, round_start_perf=start,
+                    commit_perf=commit_at, ack_perf=ack_at)
+            if r in read_after:
+                led.report()
+            ref = {k: sk.copy() for k, sk in refs[-1].items()}
+            for pod, w in zip(pods, waits):
+                for stage, v in (("queue_wait", start - (start - float(w))),
+                                 ("solve", solve_s), ("commit", commit_s)):
+                    ref.setdefault(("a", pod.qos, stage),
+                                   DDSketch()).insert(v)
+                ref.setdefault(("a", pod.qos, "e2e"), DDSketch()).insert(
+                    (start - (start - float(w))) + (solve_s + commit_s))
+            refs.append(ref)
+        return refs, base
+
+    @staticmethod
+    def _table(doc):
+        return {(r["tenant"], r["qos"], r["stage"]): canon(
+            DDSketch.from_doc(r["sketch"])) for r in doc["series"]}
+
+    @staticmethod
+    def _want(ref):
+        return {k: canon(sk) for k, sk in ref.items()}
+
+    @pytest.mark.parametrize("mixed_qos", [False, True])
+    def test_no_bounds_reads_what_it_read_before(self, mixed_qos):
+        led = JourneyLedger()
+        refs, _ = self._rounds(led, 7, seed=1, mixed_qos=mixed_qos,
+                               one_by_one=(2,))
+        doc = led.snapshot_doc()
+        assert "rounds" not in doc
+        assert self._table(doc) == self._want(refs[-1])
+        report = led.report()
+        assert "rounds" not in report
+        for row in report["series"]:
+            ref = refs[-1][(row["tenant"], row["qos"], row["stage"])]
+            assert row["count"] == ref.count
+            assert row["p50_s"] == ref.quantile(0.5)
+            assert row["p99_s"] == ref.quantile(0.99)
+            assert row["mean_s"] == pytest.approx(ref.mean())
+            assert row["max_s"] == pytest.approx(ref.max_value)
+
+    @pytest.mark.parametrize("mixed_qos", [False, True])
+    def test_bounds_over_every_round_equal_no_bounds(self, mixed_qos):
+        led = JourneyLedger()
+        self._rounds(led, 6, seed=2, mixed_qos=mixed_qos, one_by_one=(0, 4))
+        whole = led.snapshot_doc()
+        cut = led.snapshot_doc(since_perf=0.0, until_perf=1e12)
+        assert cut.pop("rounds") == 6
+        assert self._table(cut) == self._table(whole)
+        assert led.report(since_perf=0.0)["rounds"] == 6
+        for a, b in zip(led.report(since_perf=0.0)["series"],
+                        led.report()["series"]):
+            assert {k: a[k] for k in a if k != "mean_s"} == {
+                k: b[k] for k in b if k != "mean_s"}
+            assert a["mean_s"] == pytest.approx(b["mean_s"])
+
+    def test_a_range_holds_the_rounds_committed_in_it(self):
+        led = JourneyLedger()
+        refs, base = self._rounds(led, 8, seed=3)
+        # rounds 0..2 commit before base + 3
+        early = led.snapshot_doc(until_perf=base + 2.9)
+        assert early["rounds"] == 3
+        assert self._table(early) == self._want(refs[3])
+        report = led.report(since_perf=base + 2.9, until_perf=base + 5.9)
+        assert report["rounds"] == 3
+        counts = {r["stage"]: r["count"] for r in report["series"]}
+        want = refs[6][("a", 0, "e2e")].count - refs[3][("a", 0, "e2e")].count
+        assert counts == {s: want for s in
+                          ("e2e", "queue_wait", "solve", "commit")}
+        assert led.report(since_perf=base + 100.0) == {
+            "enabled": True, "alpha": RELATIVE_ACCURACY, "series": [],
+            "rounds": 0}
+
+    def test_two_disjoint_ranges_merge_to_the_whole(self):
+        led = JourneyLedger()
+        refs, base = self._rounds(led, 9, seed=4, mixed_qos=True)
+        first = led.snapshot_doc(until_perf=base + 3.9)
+        second = led.snapshot_doc(since_perf=base + 3.9)
+        assert first["rounds"] + second["rounds"] == 9
+        merged = merge_snapshot_rows(first["series"] + second["series"])
+        assert {k: canon(sk) for k, sk in merged.items()} == (
+            self._want(refs[-1]))
+
+    def test_a_read_between_rounds_changes_nothing(self):
+        """Digestion is lazy: a reader in the middle of the run folds
+        what is staged, the rest folds later, the sum is the same."""
+        read, unread = JourneyLedger(), JourneyLedger()
+        refs, _ = self._rounds(read, 5, seed=5, read_after=(0, 2))
+        self._rounds(unread, 5, seed=5)
+        assert len(read._staged) == 2 and len(unread._staged) == 5
+        for led in (read, unread):
+            cut = led.snapshot_doc(since_perf=0.0)
+            assert cut.pop("rounds") == 5
+            assert self._table(cut) == self._want(refs[-1])
+            assert self._table(led.snapshot_doc()) == self._want(refs[-1])
+
+    def test_the_ring_is_bounded(self, monkeypatch):
+        monkeypatch.setattr(JourneyLedger, "_SLICES_MAX", 5)
+        led = JourneyLedger()
+        refs, base = self._rounds(led, 12, seed=6)
+        cut = led.snapshot_doc(since_perf=0.0)
+        assert cut["rounds"] == 5 and len(led._slices) == 5
+        # the five newest rounds; the cumulative sketches still hold all
+        want = refs[12][("a", 0, "e2e")].count - refs[7][("a", 0, "e2e")].count
+        e2e = [r for r in cut["series"] if r["stage"] == "e2e"][0]
+        assert e2e["sketch"]["count"] == want
+        assert self._table(led.snapshot_doc()) == self._want(refs[-1])
+
+    def test_the_inline_digest_keeps_its_slices(self, monkeypatch):
+        """No reader ever comes: at _STAGED_MAX staged rounds the bind
+        path digests inline, and the slices are there afterwards."""
+        monkeypatch.setattr(JourneyLedger, "_STAGED_MAX", 4)
+        led = JourneyLedger()
+        self._rounds(led, 9, seed=7)
+        assert len(led._staged) == 1 and len(led._slices) == 8
+        assert led.report(since_perf=0.0)["rounds"] == 9
+
+    def test_disabling_clears_the_slices(self):
+        led = JourneyLedger()
+        self._rounds(led, 3, seed=8)
+        led.report()
+        assert len(led._slices) == 3
+        led.set_enabled(False)
+        assert len(led._slices) == 0
+
+    def test_debug_latency_last_s(self):
+        sched, svc = _assemble()
+        svc.add_pod("p1", np.asarray(
+            resource_vector(cpu=1_000, memory=1_024), np.int32))
+        sched.schedule_round()
+        body = debug_latency_body(sched, {"last_s": "60"})
+        assert body["last_s"] == 60.0 and body["rounds"] == 1
+        assert any(r["stage"] == "e2e" and r["count"] == 1
+                   for r in body["series"])
+        # a round older than the range is not in it
+        stale = debug_latency_body(sched, {"last_s": 1e-9})
+        assert stale["rounds"] == 0 and stale["series"] == []
+        assert "rounds" not in debug_latency_body(sched, {})
+        for bad in ("soon", "0", "-3"):
+            with pytest.raises(DebugApiError) as ei:
+                debug_latency_body(sched, {"last_s": bad})
+            assert ei.value.status == 400
+
+    def test_both_surfaces_serve_last_s(self):
+        from koordinator_tpu.scheduler.services import DebugService
+
+        sched, svc = _assemble()
+        svc.add_pod("p1", np.asarray(
+            resource_vector(cpu=1_000, memory=1_024), np.int32))
+        sched.schedule_round()
+        status, body = DebugService(sched).handle(
+            "/debug/latency", {"last_s": "30"})
+        assert status == 200 and body["rounds"] == 1
+        status, body = DebugService(sched).handle(
+            "/debug/latency", {"last_s": "never"})
+        assert status == 400 and "error" in body
+
+
 class TestBitIdentity:
     """THE acceptance criterion: KOORD_JOURNEY=0 must not change one
     scheduling decision or quota charge."""
@@ -365,6 +552,27 @@ class TestBitIdentity:
         assert on_assign == off_assign
         assert on_used == off_used
         assert on_assign, "round placed nothing — vacuous comparison"
+
+    def test_a_windowed_read_between_rounds_moves_no_decision(self):
+        """ISSUE 34: the slices are made in the lazy digest, off the
+        scheduling path; a reader that cuts the ledger by time between
+        the rounds changes no decision either."""
+        plain_assign, plain_used = self._run(True)
+        orig = Scheduler.schedule_round
+
+        def round_then_read(sched, *a, **kw):
+            out = orig(sched, *a, **kw)
+            journey.LEDGER.report(since_perf=0.0)
+            return out
+
+        Scheduler.schedule_round = round_then_read
+        try:
+            read_assign, read_used = self._run(True)
+        finally:
+            Scheduler.schedule_round = orig
+        off_assign, off_used = self._run(False)
+        assert read_assign == plain_assign == off_assign
+        assert read_used == plain_used == off_used
 
 
 class TestDebugSurface:

@@ -890,6 +890,179 @@ class TestSpansOfTheServedPath:
         assert all(v > 0 for v in loaded.values())
 
 
+class TestWaits:
+    """ISSUE 34: a wait observation is time a piece of work stood in a
+    queue; it lands in the ``waits`` map of the doc whose wall holds the
+    moment it was taken, and nowhere the host's own time is counted."""
+
+    def test_a_wait_lands_in_the_doc_that_holds_its_take(self):
+        rec = timeline.TimelineRecorder()
+        rec.add(10.0, 11.0, "host_other", "rpc.STATE_PUSH")
+        rec.finish_cycle(1, 10.0, 12.0, publish=False)
+        # queued inside the first round, taken between the rounds
+        rec.wait("rpc.inbox.STATE_PUSH", 11.5, 13.0)
+        # queued between the rounds, taken inside the second one
+        rec.wait("rpc.outbox.ACK", 13.5, 14.25)
+        rec.wait("rpc.outbox.ACK", 14.0, 14.5, n=3)
+        # taken after the second round: the next window's
+        rec.wait("rpc.outbox.DELTA", 14.5, 15.5)
+        rec.add(14.0, 14.5, "host_other", "rpc.SOLVE_REQUEST")
+        doc = rec.finish_cycle(2, 14.0, 15.0, publish=False)
+        ingest = rec.cycles(2)[1]
+        assert ingest["mode"] == timeline.INGEST
+        assert ingest["waits"] == {"rpc.inbox.STATE_PUSH": {
+            "n": 1, "wait_s": pytest.approx(1.5),
+            "max_s": pytest.approx(1.5)}}
+        assert doc["waits"] == {"rpc.outbox.ACK": {
+            "n": 4, "wait_s": pytest.approx(1.25),
+            "max_s": pytest.approx(0.75)}}
+        after = rec.finish_cycle(3, 16.0, 17.0, publish=False)
+        assert after["waits"] == {}
+        assert rec.cycles(2)[1]["waits"] == {"rpc.outbox.DELTA": {
+            "n": 1, "wait_s": pytest.approx(1.0),
+            "max_s": pytest.approx(1.0)}}
+
+    def test_a_wait_is_never_a_segment(self):
+        rec = timeline.TimelineRecorder()
+        rec.add(0.0, 1.0, "host_other", "rpc.STATE_PUSH")
+        rec.wait("rpc.inbox.STATE_PUSH", 0.25, 4.0)
+        doc = rec.finish_cycle(1, 0.0, 10.0, publish=False)
+        assert doc["waits"]["rpc.inbox.STATE_PUSH"]["n"] == 1
+        assert [s["name"] for s in doc["segments"]] == ["rpc.STATE_PUSH"]
+        assert set(doc["by_name"]) == {"rpc.STATE_PUSH"}
+        assert not any("inbox" in c["name"] for c in doc["critical_path"])
+        # 3.75 s of waiting moved no cause: the sweep never saw it
+        assert doc["attribution_s"]["host_other"] == pytest.approx(1.0)
+        assert doc["attribution_s"][timeline.UNATTRIBUTED] == (
+            pytest.approx(9.0))
+        assert sum(doc["attribution"].values()) == pytest.approx(1.0)
+
+    def test_nothing_is_stored_when_disabled(self):
+        rec = timeline.TimelineRecorder(enabled=False)
+        rec.wait("rpc.inbox.STATE_PUSH", 1.0, 2.0)
+        assert len(rec._waits) == 0
+        rec.set_enabled(True)
+        # "no stamp was taken" (0.0) is not an observation either
+        rec.wait("rpc.inbox.STATE_PUSH", 0.0, 2.0)
+        assert len(rec._waits) == 0
+        rec.wait("rpc.inbox.STATE_PUSH", 1.0, 2.0)
+        assert len(rec._waits) == 1
+        # the kill switch forgets what no window has read
+        rec.set_enabled(False)
+        rec.set_enabled(True)
+        assert rec.finish_cycle(1, 0.0, 3.0, publish=False)["waits"] == {}
+
+    def test_the_ring_is_bounded_and_counts_what_it_drops(self):
+        rec = timeline.TimelineRecorder(max_waits=4)
+        for i in range(6):
+            rec.wait("rpc.outbox.ACK", 1.0 + i, 1.5 + i)
+        assert len(rec._waits) == 4 and rec.dropped == 2
+        doc = rec.finish_cycle(1, 0.0, 10.0, publish=False)
+        assert doc["waits"]["rpc.outbox.ACK"]["n"] == 4
+
+    def test_a_wait_taken_before_the_first_window_is_dropped(self):
+        rec = timeline.TimelineRecorder()
+        rec.wait("rpc.outbox.ACK", 1.0, 2.0)
+        doc = rec.finish_cycle(1, 5.0, 6.0, publish=False)
+        assert doc["waits"] == {} and len(rec._waits) == 0
+
+    def test_concurrent_observers_lose_and_double_nothing(self):
+        """Sender and worker threads append while a round's thread takes
+        the windows: every observation is in exactly one doc."""
+        import threading
+
+        rec = timeline.TimelineRecorder(max_waits=1 << 20)
+        threads, per_thread = 16, 2_000
+        base = timeline._perf_counter()
+        rec.finish_cycle(0, base - 1.0, base, publish=False)
+        stop = threading.Event()
+        taken: list[int] = []
+
+        def observe():
+            for _ in range(per_thread):
+                now = timeline._perf_counter()
+                rec.wait("rpc.outbox.ACK", now - 1e-4, now)
+
+        def take():
+            cycle = 1
+            while not stop.is_set():
+                now = timeline._perf_counter()
+                doc = rec.finish_cycle(cycle, now - 1e-5, now,
+                                       publish=False)
+                cycle += 1
+                taken.append(sum(
+                    d["waits"].get("rpc.outbox.ACK", {}).get("n", 0)
+                    for d in rec.cycles(2) if d["cycle"] == doc["cycle"]))
+
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            workers = [threading.Thread(target=observe)
+                       for _ in range(threads)]
+            taker = threading.Thread(target=take)
+            taker.start()
+            for w in workers:
+                w.start()
+            for w in workers:
+                w.join(60)
+            stop.set()
+            taker.join(60)
+        finally:
+            sys.setswitchinterval(was)
+        assert not taker.is_alive()
+        assert not any(w.is_alive() for w in workers)
+        now = timeline._perf_counter()
+        last = rec.finish_cycle(10 ** 9, now - 1e-6, now, publish=False)
+        taken.append(sum(
+            d["waits"].get("rpc.outbox.ACK", {}).get("n", 0)
+            for d in rec.cycles(2) if d["cycle"] == last["cycle"]))
+        assert sum(taken) == threads * per_thread
+        assert len(rec._waits) == 0 and rec.dropped == 0
+
+    def test_the_served_path_observes_its_queues(self, kit_off, tmp_path):
+        """Over a real socket: every pushed frame waited in the inbox
+        and its reply in the outbox, the waits lie in ``waits`` only,
+        and the round keeps the 5 % invariant."""
+        timeline.RECORDER.reset_for_tests()
+        served = _Served(kit_off, str(tmp_path / "w.sock"), capacity=128)
+        try:
+            now = timeline._perf_counter()
+            timeline.RECORDER.finish_cycle(0, now - 1e-3, now,
+                                           publish=False)
+            _drive_served(served)
+            # the last reply's sender may still be on its way
+            deadline = timeline._perf_counter() + 5.0
+            while (not all(c.idle() for c in served.server.live_conns())
+                   and timeline._perf_counter() < deadline):
+                pass
+            now = timeline._perf_counter()
+            timeline.RECORDER.finish_cycle(99, now - 1e-6, now,
+                                           publish=False)
+        finally:
+            served.close()
+        docs = timeline.RECORDER.cycles(16)
+        waits: dict[str, dict] = {}
+        for doc in docs:
+            for name, row in doc["waits"].items():
+                slot = waits.setdefault(name, {"n": 0, "wait_s": 0.0})
+                slot["n"] += row["n"]
+                slot["wait_s"] += row["wait_s"]
+                assert 0.0 <= row["max_s"] <= row["wait_s"] + 1e-12
+            names = ({s["name"] for s in doc["segments"]}
+                     | set(doc["by_name"])
+                     | {c["name"] for c in doc["critical_path"]})
+            assert not [n for n in names
+                        if n.startswith(("rpc.inbox.", "rpc.outbox."))]
+        # five pushes and one solve went through both queues
+        assert waits["rpc.inbox.STATE_PUSH"]["n"] == 5
+        assert waits["rpc.inbox.SOLVE_REQUEST"]["n"] == 1
+        assert waits["rpc.outbox.ACK"]["n"] == 5
+        assert waits["rpc.outbox.SOLVE_RESPONSE"]["n"] == 1
+        assert all(row["wait_s"] > 0.0 for row in waits.values())
+        round_doc = next(d for d in docs if d["mode"] == "round")
+        assert round_doc["unattributed_fraction"] < 0.05
+
+
 class TestDeviceStageNames:
     """``jax.named_scope`` stage names (ISSUE 24 part 3) reach the
     lowered program: metadata a profiler trace shows, nothing else."""

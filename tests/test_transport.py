@@ -1109,14 +1109,14 @@ def test_conn_close_with_full_queue_does_not_leak_sender_thread():
     conn.queue.get = tracking_get
     frame = Frame(FrameType.DELTA, 0, encode_payload({"x": 1}))
     # sender holds one frame inside the blocked sendall...
-    conn.queue.put_nowait(frame)
+    conn.queue.put_nowait((frame, 0.0))
     sender = threading.Thread(target=conn._drain, daemon=True)
     conn._sender = sender
     sender.start()
     assert in_send.wait(5)
     # ...while the queue refills to capacity: close() sees Full
     for _ in range(4):
-        conn.queue.put_nowait(frame)
+        conn.queue.put_nowait((frame, 0.0))
 
     conn.close()          # Full -> shutdown (sender drains) -> poison retry
     sender.join(5)
@@ -1607,6 +1607,10 @@ def test_fault_on_a_many_event_frame_is_caught_and_repaired(tmp_path, action,
     frame, the watcher severs and its re-HELLO takes the snapshot; a
     doubled run is dropped event by event by the rv guard.  Either way
     the watcher ends with the service's state."""
+    _fault_on_a_many_event_frame(tmp_path, action, gaps)
+
+
+def _fault_on_a_many_event_frame(tmp_path, action, gaps):
     server = RpcServer(str(tmp_path / "f.sock"),
                        faults=ScriptedFaults([action]))
     clients: list = []
@@ -1645,6 +1649,193 @@ def test_fault_on_a_many_event_frame_is_caught_and_repaired(tmp_path, action,
         for c in clients:
             c.close()
         server.stop()
+
+
+# -- the time work waited in a connection's queues (ISSUE 34) ----------------
+
+
+def _waits_since(t0: float) -> dict:
+    """The recorder's ``waits`` map over [t0, now]."""
+    from koordinator_tpu import timeline
+
+    doc = timeline.RECORDER.finish_cycle(1, t0, time.perf_counter(),
+                                         publish=False)
+    return doc["waits"]
+
+
+def _held_echo(server):
+    """An echo handler that holds the dispatch worker for 50 ms where
+    the request says so; ``entered`` is set as it starts to."""
+    entered = threading.Event()
+
+    def echo(doc, arrays):
+        if doc.get("hold"):
+            entered.set()
+            time.sleep(0.05)
+        return {"ok": True}, None
+
+    server.register(FrameType.SOLVE_REQUEST, echo)
+    return entered
+
+
+def test_a_held_handler_makes_the_next_frame_wait_in_the_inbox(rpc):
+    """The reader stays eager behind a busy handler: the second frame is
+    read, stamped and queued while the first one's handler runs, and its
+    ``rpc.inbox.*`` wait is the time it stood there."""
+    server, clients = rpc
+    entered = _held_echo(server)
+    server.start()
+    client = connect(server, clients)
+    t0 = time.perf_counter()
+    first = threading.Thread(
+        target=client.call, args=(FrameType.SOLVE_REQUEST, {"hold": True}))
+    first.start()
+    assert entered.wait(5)
+    client.call(FrameType.SOLVE_REQUEST, {})
+    first.join(5)
+    waits = _waits_since(t0)
+    inbox = waits["rpc.inbox.SOLVE_REQUEST"]
+    assert inbox["n"] == 2
+    assert inbox["max_s"] >= 0.040
+    assert inbox["wait_s"] >= inbox["max_s"]
+    # the two replies went through the outbox unhindered
+    assert waits["rpc.outbox.SOLVE_RESPONSE"]["n"] == 2
+    assert waits["rpc.outbox.SOLVE_RESPONSE"]["max_s"] < 0.040
+
+
+def test_a_reply_behind_a_held_sender_waits_in_the_outbox(rpc):
+    """The sender thread is held on a push notice: the reply queued
+    after it keeps its place in the FIFO and its ``rpc.outbox.*`` wait
+    is the time the sender took to reach it."""
+    server, clients = rpc
+    _held_echo(server)
+    server.start()
+    client = connect(server, clients)
+    wait_until(lambda: len(server._conns) == 1)
+    conn = server._conns[0]
+    gate = threading.Event()
+    t0 = time.perf_counter()
+    conn.send(lambda c: gate.wait(5) and None)
+    threading.Timer(0.05, gate.set).start()
+    client.call(FrameType.SOLVE_REQUEST, {})
+    waits = _waits_since(t0)
+    assert waits["rpc.outbox.SOLVE_RESPONSE"]["n"] == 1
+    assert waits["rpc.outbox.SOLVE_RESPONSE"]["wait_s"] >= 0.040
+    # the notice itself waited for nobody, and yielded no frame
+    assert waits["rpc.outbox.DELTA"]["n"] == 1
+    assert waits["rpc.outbox.DELTA"]["max_s"] < 0.040
+    assert waits["rpc.inbox.SOLVE_REQUEST"]["max_s"] < 0.040
+
+
+def test_burst_behind_a_held_sender_shows_its_lag(rpc):
+    """A burst commits while the watcher's sender cannot read the log:
+    the notice's wait from the commit that queued it to the sender
+    reaching it is ``rpc.outbox.DELTA``, and the gauge says how long the
+    run was that the sender then took (last, and the peak so far)."""
+    from koordinator_tpu import metrics
+
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    service.upsert_node("n0", resource_vector(cpu=64_000, memory=65_536))
+    frames: list = []
+    sync = StateSyncClient(MirrorBinding())
+    client = connect(server, clients, on_push=lambda f: (
+        frames.append(f), sync.on_push(f)))
+    sync.bootstrap(client)
+    wait_until(lambda: len(server._conns) == 1)
+    gauge = metrics.sync_watch_cursor_lag_events
+    assert gauge.value({"quantity": "peak"}) == 0
+    t0, n = time.perf_counter(), 500
+    with service._lock:
+        for i in range(n):
+            service.update_node_usage(
+                "n0", resource_vector(cpu=10 + i, memory=i))
+    wait_until(lambda: sync.rv == service.rv)
+    wait_until(lambda: server._conns[0].idle())
+    runs = [len(_decoded([f.payload])) for f in frames]
+    assert sum(runs) == n and runs[0] == 1
+    assert gauge.value({"quantity": "last"}) == runs[-1]
+    assert gauge.value({"quantity": "peak"}) == max(runs) >= n // 10
+    delta = _waits_since(t0)["rpc.outbox.DELTA"]
+    # one observation per item the sender took: the ready first frame
+    # and a notice per run (a notice that found nothing new counts too)
+    # (the sender's wait for the service's lock, once it has the notice,
+    # is sync.frame's time, not the outbox's)
+    assert delta["n"] >= len(frames)
+    assert delta["wait_s"] >= delta["max_s"] > 0.0
+    # a later, shorter run moves last and leaves the peak
+    with service._lock:
+        for i in range(3):
+            service.update_node_usage(
+                "n0", resource_vector(cpu=900 + i, memory=i))
+    wait_until(lambda: sync.rv == service.rv)
+    assert gauge.value({"quantity": "last"}) <= 3
+    assert gauge.value({"quantity": "peak"}) == max(runs)
+
+
+@pytest.mark.parametrize("action, gaps", [
+    ("drop", 1), ("reorder", 1), ("duplicate", 0)])
+def test_a_faulted_frame_loses_nothing_to_the_stamp(tmp_path, action, gaps):
+    """The stamp rides the queue item, not the frame: under each fault
+    the scenario of (f) ends as it ends without the recorder looking
+    (the same frames, the same repair), and every DELTA item the senders
+    took was observed once, whatever then happened to its bytes."""
+    t0 = time.perf_counter()
+    _fault_on_a_many_event_frame(tmp_path, action, gaps)
+    waits = _waits_since(t0)
+    # the run's notice and the ready frame of the event after it
+    assert waits["rpc.outbox.DELTA"]["n"] == _sent_counts()[0] == 2
+    # every HELLO was answered through the outbox as well
+    hellos = waits["rpc.inbox.HELLO"]["n"]
+    assert hellos == 1 + gaps
+    assert sum(row["n"] for name, row in waits.items()
+               if name in ("rpc.outbox.SNAPSHOT", "rpc.outbox.DELTA",
+                           "rpc.outbox.ACK")) == 2 + hellos
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_no_stamp_is_taken_with_the_recorder_off(rpc, monkeypatch, enabled):
+    """``KOORD_TIMELINE=0``: the channel reads no clock for the waits
+    and stores nothing; on, a request costs three reads (arrival, reply
+    queued, reply taken) and a pushed event two."""
+    from koordinator_tpu import timeline
+    from koordinator_tpu.transport import channel
+
+    reads = []
+
+    def counted():
+        reads.append(1)
+        return time.perf_counter()
+
+    server, clients = rpc
+    service = StateSyncService()
+    service.attach(server)
+    server.start()
+    was = timeline.RECORDER.enabled
+    timeline.RECORDER.set_enabled(enabled)
+    try:
+        sync, client = _watch(server, clients, service)
+        wait_until(lambda: len(server._conns) == 1
+                   and server._conns[0].idle())
+        monkeypatch.setattr(channel, "_perf_counter", counted)
+        for i in range(5):
+            client.call(FrameType.STATE_PUSH,
+                        {"kind": "pod_add", "name": f"p{i}", "priority": 1},
+                        {"requests": resource_vector(cpu=100, memory=64)})
+        wait_until(lambda: sync.rv == service.rv)
+        wait_until(lambda: server._conns[0].idle())
+        stored = len(timeline.RECORDER._waits)
+    finally:
+        timeline.RECORDER.set_enabled(was)
+    if enabled:
+        # 5 requests x 3, and per request its event's echo: 1 or 2 items
+        assert 5 * 5 <= len(reads) <= 5 * 7
+        assert stored >= 5 * 3
+    else:
+        assert reads == [] and stored == 0
+    assert sync.binding.pods.keys() == {f"p{i}" for i in range(5)}
 
 
 class _CountingDeque(collections.deque):
