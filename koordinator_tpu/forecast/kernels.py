@@ -172,12 +172,13 @@ def forecast_gang_assign(state, reserve, pods, cfg, gangs, quota=None,
     from koordinator_tpu.ops.gang import gang_assign
 
     charged = state.replace(node_requested=state.node_requested + reserve)
-    a, new_state, new_quota, *grants = gang_assign(
+    # ``rest``: with_grants' device grants and scan stats, handed through
+    a, new_state, new_quota, *rest = gang_assign(
         charged, pods, cfg, gangs, quota, passes=passes, solver=solver,
         with_grants=with_grants)
     return (a, new_state.replace(
         node_requested=new_state.node_requested - reserve), new_quota,
-        *grants)
+        *rest)
 
 
 def reserve_fraction_sums(reserve: jax.Array, state) -> tuple[jax.Array,

@@ -439,6 +439,14 @@ solve_deadline_shed_total = SCHEDULER.counter(
     "solve_deadline_shed_total",
     "SOLVE_REQUESTs shed because their deadline expired before the solve "
     "could start (the caller already timed out; running it helps nobody)")
+greedy_scan_rows = SCHEDULER.counter(
+    "solver_greedy_scan_rows_total",
+    "Rows handed to the exact greedy scan (the rescue pass over the batch "
+    "engine's leftovers, the reservation pre-pass, a round below the batch "
+    "threshold), by outcome: stepped (live at the scan's entry: one loop "
+    "step each, per gang pass) or pruned (no node passed the entry filter, "
+    "so none could at its own step: never visited).  Padded rows are not "
+    "rows")
 round_flight_overwritten = SCHEDULER.counter(
     "round_flight_overwritten_total",
     "Flight records evicted by ring overwrite (dump reasons are "

@@ -7,11 +7,12 @@ Two entry points:
   kernel the Go/py scheduler shell calls for single-pod cycles (P=1..k) and the
   benchmark target (BASELINE.md: batched Score at 1k-10k nodes).
 
-- :func:`greedy_assign` — sequential greedy assignment with capacity feedback
-  via ``lax.scan`` in priority order: the tensor equivalent of running the
+- :func:`greedy_assign` — sequential greedy assignment with capacity feedback,
+  one loop step per pod in priority order: the tensor equivalent of running the
   reference's scheduleOne loop over a whole pending queue. Each step re-filters
   and re-scores against the updated free capacity, exactly as the reference's
-  snapshot would after each binding.
+  snapshot would after each binding. The loop steps only over the pods that
+  have a feasible node when it starts: the others could never be placed.
 
 The scoring pipeline composes the koordinator scheduler profile's score
 plugins with their weights (cmd/koord-scheduler/main.go:47-58 registry;
@@ -171,6 +172,107 @@ def score_pods(
     return scores, feasible
 
 
+@struct.dataclass
+class ScanStats:
+    """What the exact scan did, as device scalars that leave the solve
+    beside the assignments (summed over gang passes by ``gang_assign``)."""
+
+    steps: jax.Array  # () int32 — loop trips: the rows live at entry
+
+
+#: rows per block of the scan's entry filter: no (P, N) temporary wider
+#: than this many rows, whatever the batch
+_ENTRY_BLOCK = 1024
+
+
+def scan_filter(state, rows, rows_est, cfg, requested, est_added, qstate,
+                dev_free, via_rsv=None):
+    """(B, N) bool: the exact scan's Filter for a block of pod rows
+    (``rows``: a :class:`PodBatch` of B rows, ``rows_est`` their (B, R)
+    estimates) against a scan carry.  The scan's step (B = 1) and its
+    entry filter (:func:`scan_alive`) both call it, on the single device
+    and on a mesh shard, so the two cannot drift.  ``via_rsv``
+    (broadcastable to (B, N)) ORs into the plain fit: the nodes a row
+    reaches through a matched reservation."""
+    free = state.replace(node_requested=requested).free
+    fits = filtering.fit_mask(free, rows.requests)
+    if via_rsv is not None:
+        fits = fits | via_rsv
+    feasible = filtering.combine_masks(
+        fits,
+        # est_added accumulates in-flight pods' estimated usage (the
+        # reference's pod-assign cache) on top of whichever usage base the
+        # threshold policy selects.
+        _threshold_mask(
+            cfg,
+            state.node_usage + est_added,
+            state.node_agg_usage + est_added,
+            state.node_allocatable,
+            rows_est,
+        ),
+        rows.feasible_rows(state),
+        state.node_valid[None, :],
+        rows.valid[:, None],
+    )
+    if state.devices is not None:
+        feasible = feasible & deviceshare.device_fit_pods(
+            state.devices, rows.requests, free=dev_free)
+    if qstate is not None:
+        feasible = feasible & quota_admission_mask(
+            qstate, rows.requests, rows.quota_id, rows.non_preemptible,
+        )[:, None]
+    return feasible
+
+
+def scan_alive(state, pods, pod_est_all, cfg, quota, rsv=None, match=None):
+    """(P,) bool: the rows with at least one node that passes the scan's
+    own Filter against the state the scan ENTERS with.
+
+    Everything that Filter reads moves one way inside a scan (requested,
+    est_added and quota ``used`` only grow; ``dev_free`` and a
+    reservation's remainder only shrink), so a row that is dead here is
+    dead at its own step, and its step would leave the carry bit for bit
+    as it found it: skipping it is the same result.  A superset of step
+    feasibility, never an approximation of it: a row with a fitting
+    matched reservation counts as reaching EVERY node through it (which
+    node is not looked up: no (P, V) x (V, N) product), and every other
+    term is the step's.
+
+    Reduced in blocks of ``_ENTRY_BLOCK`` rows.  Over a node shard of a
+    mesh this is "some LOCAL node passes": the caller merges the shards."""
+    from koordinator_tpu.ops.reservation import reservation_fit
+
+    p = pods.capacity
+    block = min(p, _ENTRY_BLOCK)
+    n_blocks = -(-p // block)
+    # a ragged tail re-reads the last row; its copies are cut off below
+    row_ids = jnp.minimum(
+        jnp.arange(n_blocks * block).reshape(n_blocks, block), p - 1)
+    est0 = jnp.zeros_like(state.node_usage)
+    dev_free = None if state.devices is None else state.devices.free
+
+    def alive_block(ids):
+        rows = jax.tree.map(lambda a: a[ids], pods)
+        via_rsv = None
+        if rsv is not None:
+            via_rsv = jnp.any(
+                reservation_fit(rsv, state.free, rows.requests, match[ids]),
+                axis=-1)[:, None]
+        return jnp.any(
+            scan_filter(state, rows, pod_est_all[ids], cfg,
+                        state.node_requested, est0, quota, dev_free,
+                        via_rsv),
+            axis=-1)
+
+    return jax.lax.map(alive_block, row_ids).reshape(-1)[:p]
+
+
+def live_first(pods: PodBatch, alive: jnp.ndarray) -> jnp.ndarray:
+    """(P,) the scan's visiting order: priority order (index order among
+    equals) with the live rows first, their relative order untouched."""
+    return jnp.lexsort((jnp.arange(pods.capacity), -pods.priority, ~alive))
+
+
 def _greedy_scan(
     state: ClusterState,
     pods: PodBatch,
@@ -181,11 +283,13 @@ def _greedy_scan(
     rsv_boost: int = 10_000,
 ):
     """Shared sequential-assignment scan (the single source of truth for both
-    plain and reservation-aware greedy assignment).
+    plain and reservation-aware greedy assignment): one batched entry filter
+    (:func:`scan_alive`), then a loop over the live rows only, in priority
+    order.  A row that is never visited keeps the -1 its step would write.
 
     Returns (assignments, rsv_choice, new_state, new_rsv, new_quota,
-    grants); the reservation outputs are None when ``rsv`` is None, and
-    ``grants`` (a :class:`~koordinator_tpu.ops.deviceshare.DeviceGrants`)
+    grants, stats); the reservation outputs are None when ``rsv`` is None,
+    and ``grants`` (a :class:`~koordinator_tpu.ops.deviceshare.DeviceGrants`)
     is None when the state carries no devices.
     """
     from koordinator_tpu.ops.reservation import (
@@ -198,55 +302,34 @@ def _greedy_scan(
     if match is not None:
         match = jnp.asarray(match)  # host producers hand over np.ndarray
 
-    order = jnp.lexsort((jnp.arange(pods.capacity), -pods.priority))
-
     pod_est_all = scoring.estimate_pod_usage_by_band(
         pods.requests, cfg.estimator_factors, cfg.estimator_defaults
     )
+    alive = scan_alive(state, pods, pod_est_all, cfg, quota, rsv, match)
+    order = live_first(pods, alive)
+    n_live = jnp.sum(alive, dtype=jnp.int32)
 
     dev = state.devices
     dreq = (None if dev is None
             else deviceshare.pod_device_requests(pods.requests))
 
-    def step(carry, idx):
-        # est_added accumulates in-flight pods' estimated usage (the
-        # reference's pod-assign cache) on top of whichever usage base the
-        # threshold policy selects.
-        requested, est_added, cur_rsv, qstate, dev_free = carry
+    def step(i, carry):
+        (requested, est_added, cur_rsv, qstate, dev_free,
+         nodes, rsv_rows, sels) = carry
+        idx = order[i]
+        row = jax.tree.map(lambda a: a[idx][None], pods)
         req = pods.requests[idx]          # (R,)
         pod_est = pod_est_all[idx]        # (R,)
-        valid = pods.valid[idx]
 
-        free = jnp.where(
-            state.node_valid[:, None], state.node_allocatable - requested, 0
-        )
-        fits = jnp.all((req[None, :] <= free) | (req[None, :] == 0), axis=-1)
+        via_rsv = None
         if cur_rsv is not None:
-            fits_v = reservation_fit(cur_rsv, free, req[None, :], match[idx][None])[0]
-            via_rsv = reservation_node_mask(fits_v[None], cur_rsv, state.capacity)[0]
-            fits = fits | via_rsv
-        feasible = (
-            fits
-            & _threshold_mask(
-                cfg,
-                state.node_usage + est_added,
-                state.node_agg_usage + est_added,
-                state.node_allocatable,
-                pod_est[None, :],
-            )[0]
-            & pods.feasible_row(state, idx)
-            & state.node_valid
-            & valid
-        )
-        if dev is not None:
-            feasible = feasible & deviceshare.device_fit_pods(
-                dev, req[None, :], free=dev_free)[0]
-        if qstate is not None:
-            admitted = quota_admission_mask(
-                qstate, req[None, :], pods.quota_id[idx][None],
-                pods.non_preemptible[idx][None],
-            )[0]
-            feasible = feasible & admitted
+            fits_v = reservation_fit(
+                cur_rsv, state.replace(node_requested=requested).free,
+                req[None, :], match[idx][None])
+            via_rsv = reservation_node_mask(fits_v, cur_rsv, state.capacity)
+        feasible = scan_filter(
+            state, row, pod_est[None, :], cfg, requested, est_added, qstate,
+            dev_free, via_rsv)[0]
 
         scores = _composite_score(
             cfg, state.node_allocatable, requested,
@@ -254,19 +337,20 @@ def _greedy_scan(
             req[None, :], pod_est[None, :],
         )[0]
         if cur_rsv is not None:
-            scores = scores + jnp.where(via_rsv, rsv_boost, 0)
+            scores = scores + jnp.where(via_rsv[0], rsv_boost, 0)
         masked = jnp.where(feasible, scores, -1)
         best = jnp.argmax(masked)
         assigned = masked[best] >= 0
         node = jnp.where(assigned, best, -1)
+        nodes = nodes.at[idx].set(node)
 
         if cur_rsv is not None:
-            r_idx = nominate_reservation(fits_v[None], cur_rsv, node[None])[0]
+            r_idx = nominate_reservation(fits_v, cur_rsv, node[None])[0]
             r_idx = jnp.where(assigned, r_idx, -1)
             cur_rsv, spill = allocate_from_reservation(cur_rsv, r_idx, req)
             add = jnp.where(assigned, spill, 0)
+            rsv_rows = rsv_rows.at[idx].set(r_idx)
         else:
-            r_idx = jnp.int32(-1)
             add = jnp.where(assigned, req, 0)
         add_est = jnp.where(assigned, pod_est, 0)
         requested = requested.at[best].add(add)
@@ -277,7 +361,6 @@ def _greedy_scan(
                 jnp.where(assigned, pods.quota_id[idx], -1),
                 non_preemptible=pods.non_preemptible[idx],
             )
-        sel = None
         if dev is not None:
             # DeviceShare Reserve on the chosen node: feasibility above
             # was checked against this same free row, so a device pod
@@ -290,31 +373,30 @@ def _greedy_scan(
             sel = sel[0] & assigned
             dev_free = dev_free.at[best].add(
                 -(sel[:, None] * one.ask[0][None, :]))
-        return ((requested, est_added, cur_rsv, qstate, dev_free),
-                (node, r_idx, sel))
+            sels = sels.at[idx].set(sel)
+        return (requested, est_added, cur_rsv, qstate, dev_free,
+                nodes, rsv_rows, sels)
 
-    ((requested, _, new_rsv, new_quota, dev_free),
-     (nodes_in_order, rsv_in_order, sel_in_order)) = jax.lax.scan(
-        step,
+    unplaced = jnp.full(pods.capacity, -1, jnp.int32)
+    (requested, _, new_rsv, new_quota, dev_free,
+     assignments, rsv_choice, selection) = jax.lax.fori_loop(
+        0, n_live, step,
         (state.node_requested, jnp.zeros_like(state.node_usage), rsv, quota,
-         None if dev is None else dev.free),
-        order,
-    )
-    assignments = jnp.full(pods.capacity, -1, jnp.int32).at[order].set(nodes_in_order)
-    rsv_choice = (
-        jnp.full(pods.capacity, -1, jnp.int32).at[order].set(rsv_in_order)
-        if rsv is not None
-        else None
+         None if dev is None else dev.free,
+         unplaced,
+         None if rsv is None else unplaced,
+         None if dev is None
+         else jnp.zeros((pods.capacity, dev.shape[1]), bool)),
     )
     new_state = state.replace(node_requested=requested)
     grants = None
     if dev is not None:
         new_state = new_state.replace(devices=dev.replace(free=dev_free))
         grants = deviceshare.DeviceGrants(
-            selection=jnp.zeros((pods.capacity, dev.shape[1]), bool)
-            .at[order].set(sel_in_order),
+            selection=selection,
             lost_races=jnp.zeros(pods.capacity, jnp.int32))
-    return assignments, rsv_choice, new_state, new_rsv, new_quota, grants
+    return (assignments, rsv_choice, new_state, new_rsv, new_quota, grants,
+            ScanStats(steps=n_live))
 
 
 def keep_devices(new_state: ClusterState, state: ClusterState) -> ClusterState:
@@ -350,12 +432,13 @@ def greedy_assign(
     selectHost randomizes among maxima; we fix the choice for reproducibility).
 
     ``with_grants=True`` appends the device grants (None for a state
-    without devices) and leaves them taken off ``new_state.devices``;
-    without it the device plane is handed back untouched.
+    without devices), taken off ``new_state.devices``, and the scan's
+    :class:`ScanStats`; without it the device plane is handed back
+    untouched.
     """
-    assignments, _, new_state, _, new_quota, grants = _greedy_scan(
+    assignments, _, new_state, _, new_quota, grants, stats = _greedy_scan(
         state, pods, cfg, quota=quota
     )
     if with_grants:
-        return assignments, new_state, new_quota, grants
+        return assignments, new_state, new_quota, grants, stats
     return assignments, keep_devices(new_state, state), new_quota

@@ -1,7 +1,7 @@
 """Batch-parallel assignment: propose/accept rounds instead of an O(P) scan.
 
 ``greedy_assign`` (ops/assignment.py) is the exact sequential solver — one
-``lax.scan`` step per pod, 50k dependent steps at the north-star shape.  This
+dependent loop step per placeable pod, 50k at the north-star shape.  This
 module is the throughput path: the whole pending queue lands in a handful of
 data-parallel rounds.
 

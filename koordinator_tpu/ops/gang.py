@@ -146,8 +146,10 @@ def gang_assign(
     """Batch assignment with gang all-or-nothing semantics.
 
     Returns (assignments, state, quota) as :func:`greedy_assign` does (quota
-    is None when not given), and the device grants after them with
-    ``with_grants``. ``passes`` > 1 re-solves leftover pods after
+    is None when not given), and after them with ``with_grants`` the device
+    grants and the exact scans' :class:`~koordinator_tpu.ops.assignment.
+    ScanStats` summed over the passes (None from the batch engine, which
+    runs no scan). ``passes`` > 1 re-solves leftover pods after
     failed-gang rollback so freed capacity is reclaimed within the batch.
 
     ``solver`` picks the per-pass assignment engine: ``"greedy"`` is the
@@ -160,7 +162,11 @@ def gang_assign(
     solver's candidate selection (batch_assign.CANDIDATE_METHODS), so
     gang solves can force the chunked/approx paths too.
     """
-    from koordinator_tpu.ops.assignment import keep_devices, pod_estimates
+    from koordinator_tpu.ops.assignment import (
+        ScanStats,
+        keep_devices,
+        pod_estimates,
+    )
     from koordinator_tpu.ops.batch_assign import batch_assign
 
     if solver not in ("greedy", "batch"):
@@ -196,6 +202,7 @@ def gang_assign(
             selection=jnp.zeros((pods.capacity, state.devices.shape[1]),
                                 bool),
             lost_races=jnp.zeros(pods.capacity, jnp.int32))
+    stats = ScanStats(steps=jnp.int32(0)) if solver == "greedy" else None
 
     for _ in range(passes):
         with jax.named_scope("gang_pass"):
@@ -208,9 +215,10 @@ def gang_assign(
                     solve_state, active_pods, cfg, cur_quota,
                     method=method, with_grants=True)
             else:
-                a, _, _, g = greedy_assign(
+                a, _, _, g, scanned = greedy_assign(
                     solve_state, active_pods, cfg, cur_quota,
                     with_grants=True)
+                stats = jax.tree.map(jnp.add, stats, scanned)
 
             final, cur_state, keep, failed = rollback_failed_gangs(
                 a, cur_state, active_pods, gangs, prior_kept=kept_so_far
@@ -244,5 +252,5 @@ def gang_assign(
             )
 
     if with_grants:
-        return total, cur_state, cur_quota, grants
+        return total, cur_state, cur_quota, grants, stats
     return total, keep_devices(cur_state, state), cur_quota

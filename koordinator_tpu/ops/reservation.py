@@ -265,13 +265,15 @@ def reservation_greedy_assign(
     reservation's remainder first and only the spill to node_requested
     (Reserve semantics of plugin.go Reserve + nominator).
 
-    Returns (assignments, rsv_choice, new_state, new_rsv, new_quota).
+    Returns (assignments, rsv_choice, new_state, new_rsv, new_quota,
+    stats): the last is the scan's :class:`~koordinator_tpu.ops.assignment.
+    ScanStats`.
     """
     from koordinator_tpu.ops.assignment import _greedy_scan, keep_devices
 
     # device feasibility joins the scan; the grants are the commit's
     # (the pre-pass binds one pod at a time through ``_commit_bind``)
-    a, choice, new_state, new_rsv, new_quota, _ = _greedy_scan(
+    a, choice, new_state, new_rsv, new_quota, _, stats = _greedy_scan(
         state, pods, cfg, quota=quota, rsv=rsv, match=match, rsv_boost=boost
     )
-    return a, choice, keep_devices(new_state, state), new_rsv, new_quota
+    return a, choice, keep_devices(new_state, state), new_rsv, new_quota, stats

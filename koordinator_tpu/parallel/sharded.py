@@ -582,49 +582,42 @@ def sharded_refresh_candidates(mesh, state, pods, cfg, cache, dirty_rows,
 def _greedy_local(st_local, pods, cfg, quota):
     """Shard-local exact greedy scan over GATHERED (full-P) pods:
     mirrors ``ops/assignment._greedy_scan`` (no reservations) step for
-    step, with the per-step argmax merged over the nodes axis as
+    step — the same Filter (``scan_filter``), the same entry filter
+    (``scan_alive`` on the local node shard, merged with ONE ``pmax`` over
+    the nodes axis before the loop), the same visiting order and trip
+    count — with the per-step argmax merged over the nodes axis as
     (max score, then MIN global node id among the ties) — equal to the
     single-device ``jnp.argmax`` first-occurrence rule, because the
     local argmax already picks the lowest local index and global ids
-    order identically to local ones within a shard."""
+    order identically to local ones within a shard.
+
+    Returns (assignments, requested, new_quota, steps)."""
     from koordinator_tpu.ops.assignment import (
         _composite_score,
-        _threshold_mask,
+        live_first,
+        scan_alive,
+        scan_filter,
     )
 
     n_loc = st_local.capacity
     off = _shard_offset(n_loc)
     node_ids = off + jnp.arange(n_loc, dtype=jnp.int32)
-    order = jnp.lexsort((jnp.arange(pods.capacity), -pods.priority))
     pod_est_all = pod_estimates(pods, cfg)
+    alive = jax.lax.pmax(
+        scan_alive(st_local, pods, pod_est_all, cfg, quota).astype(jnp.int32),
+        NODES_AXIS) > 0
+    order = live_first(pods, alive)
+    n_live = jnp.sum(alive, dtype=jnp.int32)
 
-    def step(carry, idx):
-        requested, est_added, qstate = carry
+    def step(i, carry):
+        requested, est_added, qstate, nodes = carry
+        idx = order[i]
+        row = jax.tree.map(lambda a: a[idx][None], pods)
         req = pods.requests[idx]
         pod_est = pod_est_all[idx]
-        valid = pods.valid[idx]
-        free = jnp.where(
-            st_local.node_valid[:, None],
-            st_local.node_allocatable - requested, 0)
-        fits = jnp.all((req[None, :] <= free) | (req[None, :] == 0),
-                       axis=-1)
-        feasible = (
-            fits
-            & _threshold_mask(
-                cfg,
-                st_local.node_usage + est_added,
-                st_local.node_agg_usage + est_added,
-                st_local.node_allocatable,
-                pod_est[None, :],
-            )[0]
-            & pods.feasible_row(st_local, idx)
-            & st_local.node_valid
-            & valid)
-        if qstate is not None:
-            admitted = quota_admission_mask(
-                qstate, req[None, :], pods.quota_id[idx][None],
-                pods.non_preemptible[idx][None])[0]
-            feasible = feasible & admitted
+        feasible = scan_filter(
+            st_local, row, pod_est[None, :], cfg, requested, est_added,
+            qstate, None)[0]
         scores = _composite_score(
             cfg, st_local.node_allocatable, requested,
             st_local.node_usage + est_added,
@@ -648,15 +641,13 @@ def _greedy_local(st_local, pods, cfg, quota):
                 qstate, jnp.where(assigned, req, 0),
                 jnp.where(assigned, pods.quota_id[idx], -1),
                 non_preemptible=pods.non_preemptible[idx])
-        return (requested, est_added, qstate), node
+        return requested, est_added, qstate, nodes.at[idx].set(node)
 
-    carry0 = (st_local.node_requested,
-              jnp.zeros_like(st_local.node_usage), quota)
-    (requested, _, new_quota), nodes_in_order = jax.lax.scan(
-        step, carry0, order)
-    assignments = jnp.full(pods.capacity, -1, jnp.int32).at[order].set(
-        nodes_in_order)
-    return assignments, requested, new_quota
+    requested, _, new_quota, assignments = jax.lax.fori_loop(
+        0, n_live, step,
+        (st_local.node_requested, jnp.zeros_like(st_local.node_usage), quota,
+         jnp.full(pods.capacity, -1, jnp.int32)))
+    return assignments, requested, new_quota, n_live
 
 
 # koordlint: shape[st_local: NxR i32 nodes]
@@ -694,6 +685,7 @@ def _gang_body(st_local, pods, cfg, gangs, quota, *, passes, solver,
     cur_quota = quota
     pod_est_all = pod_estimates(pods_f, cfg)       # (P, R)
     est_local = jnp.zeros_like(st_local.node_usage)
+    steps = jnp.int32(0)                           # the exact scans' trips
 
     for _ in range(passes):
         with jax.named_scope("gang_pass"):
@@ -716,7 +708,9 @@ def _gang_body(st_local, pods, cfg, gangs, quota, *, passes, solver,
                     solve_st, act_pods, cur_quota, ck, cn,
                     rounds=rounds, n_total=n_total)
             else:
-                a, _, _ = _greedy_local(solve_st, act_pods, cfg, cur_quota)
+                a, _, _, scanned = _greedy_local(
+                    solve_st, act_pods, cfg, cur_quota)
+                steps = steps + scanned
 
             # rollback_failed_gangs, replicated flags + owner-local rebuild
             assigned = (a >= 0) & act_pods.valid
@@ -746,7 +740,8 @@ def _gang_body(st_local, pods, cfg, gangs, quota, *, passes, solver,
             # gangs back off for the rest of the batch
             active = active & ~keep & ~failed
 
-    return total, st_local.replace(node_requested=requested), cur_quota
+    return (total, st_local.replace(node_requested=requested), cur_quota,
+            steps)
 
 
 @lru_cache(maxsize=None)
@@ -758,7 +753,7 @@ def _gang_program(mesh, n_total, p_total, passes, solver, k, strata,
                 strata=strata, rounds=rounds, n_total=n_total,
                 p_total=p_total),
         mesh=mesh, in_specs=(_NODES, _PODS, _REP, _REP, _REP),
-        out_specs=(_REP, _NODES, _REP), check_vma=False))
+        out_specs=(_REP, _NODES, _REP, _REP), check_vma=False))
 
 
 def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
@@ -776,9 +771,12 @@ def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
     single-device ``gang_assign`` (selection is recall-exact here, like
     every sharded entry).
 
-    Returns (assignments, new_state, new_quota) with the state
-    node-sharded; requires the factored (selector-mask) feasibility
-    form — a dense (P, N) ``pods.feasible`` cannot tile."""
+    Returns (assignments, new_state, new_quota, stats) with the state
+    node-sharded and ``stats`` the exact scans' ``ScanStats`` (None for
+    ``solver="batch"``); requires the factored (selector-mask)
+    feasibility form — a dense (P, N) ``pods.feasible`` cannot tile."""
+    from koordinator_tpu.ops.assignment import ScanStats
+
     if solver not in ("greedy", "batch"):
         raise ValueError(f"unknown solver {solver!r}")
     if pods.feasible is not None:
@@ -794,7 +792,9 @@ def sharded_gang_assign(mesh, state, pods, cfg, gangs, quota=None,
     check_pod_shardable(pods.capacity, mesh)
     fn = _gang_program(mesh, n_total, pods.capacity, passes, solver,
                        min(k, n_total), strata, rounds)
-    return fn(state, pods, cfg, gangs, quota)
+    a, new_state, new_quota, steps = fn(state, pods, cfg, gangs, quota)
+    return (a, new_state, new_quota,
+            ScanStats(steps=steps) if solver == "greedy" else None)
 
 
 # koordlint: shape[state: NxR i32 nodes, reserve: NxR i32 nodes]
@@ -813,11 +813,11 @@ def sharded_forecast_gang_assign(mesh, state, reserve, pods, cfg, gangs,
     is the unchanged shard_map program, so acceptance decisions are
     bit-identical to the single-device forecast entry."""
     charged = state.replace(node_requested=state.node_requested + reserve)
-    a, new_state, new_quota = sharded_gang_assign(
+    a, new_state, new_quota, stats = sharded_gang_assign(
         mesh, charged, pods, cfg, gangs, quota, passes=passes,
         solver=solver, k=k, rounds=rounds, spread_bits=spread_bits)
     return a, new_state.replace(
-        node_requested=new_state.node_requested - reserve), new_quota
+        node_requested=new_state.node_requested - reserve), new_quota, stats
 
 
 def sharded_greedy_assign(mesh, state, pods, cfg, quota=None):
@@ -840,7 +840,7 @@ def sharded_greedy_assign(mesh, state, pods, cfg, quota=None):
 # koordlint: shape[st_local: NxR i32 nodes]
 def _greedy_body(st_local, pods, cfg, quota):
     pods_f = _gather_pods(pods)
-    a, requested, new_quota = _greedy_local(st_local, pods_f, cfg, quota)
+    a, requested, new_quota, _ = _greedy_local(st_local, pods_f, cfg, quota)
     return a, st_local.replace(node_requested=requested), new_quota
 
 

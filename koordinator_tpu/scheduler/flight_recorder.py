@@ -69,6 +69,14 @@ class RoundRecord:
     #: dump answers "how many LP phases did that cost" in place
     quality_mode: str = "off"
     quality_iterations: int = 0
+    #: the exact greedy scan (ops/assignment._greedy_scan): rows the
+    #: round's rescue pass handed to it and the loop steps it took (the
+    #: rows live at its entry; the others were pruned unvisited), and the
+    #: same pair for the reservation pre-pass; 0 / 0 without that pass
+    rescue_rows: int = 0
+    rescue_steps: int = 0
+    prepass_rows: int = 0
+    prepass_steps: int = 0
     #: critical-path join (ISSUE 18): the timeline observatory's verdict
     #: for the cycle this round ran in — which cause dominated the
     #: cycle's covering chain and for how long — annotated after the

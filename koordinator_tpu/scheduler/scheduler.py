@@ -207,6 +207,9 @@ class RoundHandle:
     #: the solve's device grants (``ops/deviceshare.DeviceGrants``, in
     #: flight), None when it carried no device stage
     grants: object = None
+    #: the solve's ``ops/assignment.ScanStats`` (in flight), None unless
+    #: the exact greedy scan WAS the round's solve
+    scan_stats: object = None
     #: incremental-path finish context (None = full/greedy path)
     inc: dict | None = None
     #: quality-path finish context (ISSUE 13): the LP solve's in-flight
@@ -902,10 +905,11 @@ class Scheduler:
         small, idx = batch.compact(matched)
         m_small = np.zeros((small.capacity, rsv_set.capacity), bool)
         m_small[: len(idx)] = match[idx]
-        a_r, rc, new_state, _, new_quota = self._rsv_solve(
+        a_r, rc, new_state, _, new_quota, scanned = self._rsv_solve(
             self.snapshot.state, small, self.config, rsv_set,
             jnp.asarray(m_small), quota)
         a_r, rc = np.asarray(a_r), np.asarray(rc)
+        self._prepass_scan = self._count_scan(len(idx), scanned)
         self.snapshot.adopt_state(new_state,
                                   changed_rows=np.unique(a_r[a_r >= 0]))
         sub_pods = [pods[i] for i in idx]
@@ -1430,6 +1434,9 @@ class Scheduler:
         self._last_dirty_node_frac = 0.0
         self._last_dirty_pod_frac = 0.0
         self._last_unschedulable_top = {}
+        #: (rows handed over, loop steps taken) of the round's exact
+        #: scans: the rescue pass, the reservation pre-pass
+        self._rescue_scan = self._prepass_scan = (0, 0)
         self._round_recordable = False
         #: solve-dispatch edge for the journey ledger's queue_wait/solve
         #: stage split — round-scoped: set here, read by the bind-commit
@@ -1472,6 +1479,10 @@ class Scheduler:
             half=half,
             quality_mode=self.quality_mode,
             quality_iterations=self._last_quality_iters,
+            rescue_rows=self._rescue_scan[0],
+            rescue_steps=self._rescue_scan[1],
+            prepass_rows=self._prepass_scan[0],
+            prepass_steps=self._prepass_scan[1],
         ))
 
     def schedule_round(self) -> SchedulingResult:
@@ -1906,13 +1917,13 @@ class Scheduler:
                     metrics.incremental_solve_total.inc(labels={
                         "path": self.last_solve_path})
                 if forecast_reserve is not None:
-                    assignments, new_state, new_quota, grants = (
+                    assignments, new_state, new_quota, grants, scanned = (
                         self.kit.forecast_solve(
                             self.snapshot.state, forecast_reserve, batch,
                             self.config, gangs, quota,
                             passes=self.gang_passes, solver=solver))
                 else:
-                    assignments, new_state, new_quota, grants = (
+                    assignments, new_state, new_quota, grants, scanned = (
                         self.kit.solve(
                             self.snapshot.state, batch, self.config, gangs,
                             quota, passes=self.gang_passes, solver=solver))
@@ -1923,6 +1934,7 @@ class Scheduler:
                 handle.assignments = assignments
                 handle.new_quota = new_quota
                 handle.grants = grants
+                handle.scan_stats = scanned
         except Exception:
             self._recover_solve_failure()
             raise
@@ -2035,7 +2047,11 @@ class Scheduler:
                     new_state = self.snapshot.state
                     grants = _RoundGrants.of(handle.grants)
                 a = np.asarray(self._block_timed(assignments))
-                leftover = np.asarray(batch.valid) & (a < 0)
+                valid = np.asarray(batch.valid)
+                if handle.scan_stats is not None:
+                    # the exact scan WAS the solve: counted, not a rescue
+                    self._count_scan(int(valid.sum()), handle.scan_stats)
+                leftover = valid & (a < 0)
                 if solver == "batch" and bool(leftover[: len(pods)].any()):
                     # exact rescue pass over the leftovers: the batch engine's
                     # top-k/round approximation may fail pods a greedy scan
@@ -2054,9 +2070,10 @@ class Scheduler:
                     rescue_gid = jnp.where(
                         (gid >= 0) & jnp.asarray(satisfied)[jnp.maximum(gid, 0)],
                         -1, gid)
-                    # compact the leftovers first: the exact greedy solve is a
-                    # sequential scan over the POD AXIS, so rescuing 50 pods
-                    # must cost a 64-row scan, not the full 50k-row batch.
+                    # compact the leftovers first: the exact greedy solve
+                    # filters every row it is handed once and then steps over
+                    # the live ones, so rescuing 50 pods must cost a 64-row
+                    # filter, not one over the full 50k-row batch.
                     # ``leftover`` is the single source of truth for which rows
                     # rescue (compact keeps exactly those and marks the rest of
                     # the padded capacity invalid).
@@ -2067,21 +2084,24 @@ class Scheduler:
                         # charged accounting as its main solve — an
                         # uncharged rescue would re-admit exactly the
                         # pods the reserve just filtered
-                        r_small, new_state, new_quota, g_small = (
+                        r_small, new_state, new_quota, g_small, scanned = (
                             self.kit.forecast_solve(
                                 new_state, handle.forecast_reserve, small,
                                 self.config, gangs, new_quota,
                                 passes=self.gang_passes, solver="greedy"))
                     else:
-                        r_small, new_state, new_quota, g_small = (
+                        r_small, new_state, new_quota, g_small, scanned = (
                             self.kit.solve(
                                 new_state, small, self.config, gangs,
                                 new_quota,
                                 passes=self.gang_passes, solver="greedy"))
                     self.snapshot.state = new_state
                     r_full = np.full(batch.capacity, -1, np.int32)
-                    r_full[idx] = np.asarray(
-                        self._block_timed(r_small))[: len(idx)]
+                    # the scan's step count rides the same program and
+                    # the same block as its assignments: no extra wait
+                    r_small, scanned = self._block_timed((r_small, scanned))
+                    r_full[idx] = np.asarray(r_small)[: len(idx)]
+                    self._rescue_scan = self._count_scan(len(idx), scanned)
                     grants = _RoundGrants.merged(
                         grants, g_small, idx, r_full[idx] >= 0,
                         batch.capacity)
@@ -2336,6 +2356,19 @@ class Scheduler:
             labels={"mode": self.quality_mode, "outcome": outcome})
 
     # -- incremental delta-driven solve -------------------------------------
+
+    def _count_scan(self, rows: int, stats) -> tuple[int, int]:
+        """(rows, steps) of one exact-scan solve whose results the caller
+        already holds: ``rows`` pods were handed to it, ``stats``
+        (``ops/assignment.ScanStats``) says how many loop steps it took
+        over them (summed over gang passes: a row live at the entry of two
+        passes counts two steps).  Adds both outcomes to
+        ``solver_greedy_scan_rows_total``."""
+        steps = int(np.asarray(stats.steps))
+        metrics.greedy_scan_rows.inc(steps, labels={"outcome": "stepped"})
+        metrics.greedy_scan_rows.inc(max(rows - steps, 0),
+                                     labels={"outcome": "pruned"})
+        return rows, steps
 
     def _block_timed(self, value):  # koordlint: guarded-by(self.lock)
         """Block on a jitted solve's result, accumulating the wait into

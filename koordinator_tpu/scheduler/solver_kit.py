@@ -51,6 +51,14 @@ from koordinator_tpu.ops import batch_assign as _ba
 from koordinator_tpu.parallel import mesh as pmesh
 
 
+def _no_grants(solved):
+    """A gang/greedy twin's (assignments, state, quota, stats) in the
+    single-device entry's form: the twins carry no device stage, so the
+    grants before the stats are None."""
+    assignments, state, quota, stats = solved
+    return assignments, state, quota, None, stats
+
+
 class SolverKit:
     """Construction is cheap (wrapping, not compiling); compilation
     happens per (entry, shape bucket) on first use and is shared by
@@ -326,11 +334,13 @@ class SolverKit:
     # koordlint: shape[state: NxR i32 nodes]
     def solve(self, state, batch, config, gangs, quota, *, passes, solver):
         """``ops/gang.gang_assign``: (assignments, state, quota,
-        grants)."""
+        grants, stats); ``stats`` is the exact scans' ``ScanStats``,
+        None from the batch engine."""
         if self._sharded(state.capacity, batch, True,
                          state.devices is not None):
-            return (*self._solve_sh(state, batch, config, gangs, quota,
-                                    passes=passes, solver=solver), None)
+            return _no_grants(self._solve_sh(
+                state, batch, config, gangs, quota,
+                passes=passes, solver=solver))
         return self._solve_one(state, batch, config, gangs, quota,
                                passes=passes, solver=solver,
                                with_grants=True)
@@ -342,9 +352,9 @@ class SolverKit:
         ``reserve`` charged for the duration of the solve."""
         if self._sharded(state.capacity, batch, True,
                          state.devices is not None):
-            return (*self._forecast_solve_sh(
+            return _no_grants(self._forecast_solve_sh(
                 state, reserve, batch, config, gangs, quota,
-                passes=passes, solver=solver), None)
+                passes=passes, solver=solver))
         return self._forecast_solve_one(
             state, reserve, batch, config, gangs, quota,
             passes=passes, solver=solver, with_grants=True)

@@ -1,10 +1,14 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
+from koordinator_tpu.ops import assignment, filtering
 from koordinator_tpu.ops.assignment import ScoringConfig, greedy_assign, score_pods
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
+from tests.problem_helpers import build_problem
+from tests.scan_reference import compare_with_reference
 
 R = NUM_RESOURCE_DIMS
 CPU, MEM = ResourceDim.CPU, ResourceDim.MEMORY
@@ -173,3 +177,111 @@ def test_greedy_assign_deterministic():
     a1, _, _ = greedy_assign(state, pods, cfg)
     a2, _, _ = greedy_assign(state, pods, cfg)
     assert np.array_equal(np.asarray(a1), np.asarray(a2))
+
+
+# -- the scan steps only over the rows that are live at its entry -----------
+# (tests/scan_reference.py: the one-step-per-padded-row scan it must equal)
+
+
+def contended(seed, n_nodes=12, n_pods=300, dead_share=0.0,
+              equal_priority=False, dense=False, capacity=None):
+    """A seeded problem in which capacity runs out mid-scan: few nodes, many
+    pods.  ``dead_share`` of the pods ask for more CPU than any node has."""
+    state, pods = build_problem(n_nodes=n_nodes, n_pods=n_pods, seed=seed,
+                                factored=not dense)
+    rng = np.random.default_rng(1_000 + seed)
+    requests = np.asarray(pods.requests).copy()
+    dead = np.zeros(pods.capacity, bool)
+    dead[:n_pods] = rng.random(n_pods) < dead_share
+    requests[dead, CPU] = 1_000_000
+    replaced = dict(requests=jnp.asarray(requests))
+    if equal_priority:
+        replaced["priority"] = jnp.full(pods.capacity, 5_000, jnp.int32)
+    if dense:
+        feasible = np.zeros((pods.capacity, n_nodes), bool)
+        feasible[:n_pods] = rng.random((n_pods, n_nodes)) < 0.6
+        replaced["feasible"] = jnp.asarray(feasible)
+    pods = pods.replace(**replaced)
+    if capacity is not None:        # an odd capacity: no power-of-two padding
+        pods = jax.tree.map(lambda a: a[:capacity], pods)
+    return state, pods, dead[:pods.capacity]
+
+
+SCAN_CASES = {
+    # every valid row has a node at entry; capacity still runs out mid-scan
+    "no_dead_rows": dict(n_nodes=48, n_pods=60),
+    "all_dead_rows": dict(dead_share=1.0),
+    "mixed_with_padded_rows": dict(dead_share=0.4),
+    "equal_priorities": dict(dead_share=0.3, equal_priority=True),
+    "dense_feasibility": dict(dead_share=0.3, dense=True),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_pruned_scan_equals_the_stepwise_scan(case, seed):
+    state, pods, dead = contended(seed, **SCAN_CASES[case])
+    a, steps, alive, step_feasible = compare_with_reference(
+        state, pods, ScoringConfig.default())
+    valid = np.asarray(pods.valid)
+    assert not alive[dead].any() and (a[dead] == -1).all()
+    if case == "all_dead_rows":
+        assert steps == 0 and (a == -1).all()
+    elif case == "no_dead_rows":
+        assert steps == int(valid.sum())
+    else:
+        # the scan is contended: rows live at entry lose their node mid-scan
+        assert 0 < int((a >= 0).sum()) < steps < int(valid.sum())
+        assert (alive & ~step_feasible).any()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_entry_filter_in_blocks_with_a_ragged_tail(seed, monkeypatch):
+    # 300 rows in blocks of 64: five blocks, the last re-reads row 299
+    monkeypatch.setattr(assignment, "_ENTRY_BLOCK", 64)
+    state, pods, dead = contended(seed, dead_share=0.4, capacity=300)
+    assert pods.capacity == 300
+    a, steps, alive, _ = compare_with_reference(
+        state, pods, ScoringConfig.default(),
+        scan=jax.jit(assignment._greedy_scan))
+    assert 0 < steps == int(alive.sum()) < 300 and not alive[dead].any()
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13])
+def test_pruned_scan_with_devices_equals_the_stepwise_scan(seed):
+    """``gpushare-1k``'s standing pods: the node's aggregate GPU rows hold
+    the ask, no single device does.  Only the device term of the entry
+    filter prunes them."""
+    from tests.test_gpushare_deployment import ASKS, fragmented
+
+    _, state = fragmented(seed)
+    rng = np.random.default_rng(seed)
+    names = list(ASKS)
+    picks = rng.integers(len(names), size=96)
+    request = np.zeros((96, R), np.int32)
+    request[:, CPU] = 1_000
+    for i, pick in enumerate(picks):
+        request[i, ResourceDim.GPU], request[i, ResourceDim.GPU_MEMORY] = (
+            ASKS[names[pick]])
+    pods = PodBatch.build(request, class_capacity=8,
+                          priority=rng.integers(9_000, 9_010, 96))
+    a, steps, alive, _ = compare_with_reference(
+        state, pods, ScoringConfig.default())
+    too_wide = np.zeros(pods.capacity, bool)
+    too_wide[:96] = picks == names.index("too_wide")
+    aggregate_fits = np.asarray(filtering.fit_mask(
+        state.free, pods.requests)).any(axis=1)
+    assert too_wide.any() and aggregate_fits[too_wide].all()
+    assert not alive[too_wide].any() and (a[too_wide] == -1).all()
+    assert 0 < int((a >= 0).sum()) <= steps
+
+
+def test_greedy_assign_reports_its_steps():
+    state, pods, dead = contended(0, dead_share=0.4)
+    solve = jax.jit(greedy_assign, static_argnames=("with_grants",))
+    a, _, _, grants, stats = solve(state, pods, ScoringConfig.default(),
+                                   with_grants=True)
+    assert grants is None
+    live = int(np.asarray(pods.valid).sum()) - int(dead.sum())
+    assert int((np.asarray(a) >= 0).sum()) <= int(stats.steps) <= live
+    assert len(solve(state, pods, ScoringConfig.default())) == 3

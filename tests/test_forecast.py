@@ -343,7 +343,7 @@ class TestForecastSolveEntries:
         a_ref, st_ref, _ = kernels.forecast_gang_assign(
             state, reserve, pods, cfg, gangs, None, solver="batch")
         mesh = pmesh.solver_mesh(jax.devices(), pods_axis=2)
-        a_sh, st_sh, _ = ps.sharded_forecast_gang_assign(
+        a_sh, st_sh, _, _ = ps.sharded_forecast_gang_assign(
             mesh, state, reserve, pods, cfg, gangs, None, solver="batch")
         np.testing.assert_array_equal(np.asarray(a_ref), np.asarray(a_sh))
         np.testing.assert_array_equal(np.asarray(st_ref.node_requested),

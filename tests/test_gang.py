@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
 from koordinator_tpu.ops.assignment import ScoringConfig
@@ -227,3 +228,67 @@ def test_gang_assign_rejects_unknown_solver():
     with pytest.raises(ValueError, match="solver"):
         gang_assign(state, pods, cfg(), GangInfo.build(np.array([1])),
                     solver="annealing")
+
+
+# -- the pruned scan under the gang pass loop (tests/scan_reference.py) -------
+
+
+def stepwise_greedy_assign(state, pods, cfg, quota=None, with_grants=False):
+    """``greedy_assign`` over the one-step-per-row reference scan."""
+    from koordinator_tpu.ops.assignment import ScanStats
+    from tests.scan_reference import scan_reference
+
+    a, _, new_state, _, new_quota, grants, _ = scan_reference(
+        state, pods, cfg, quota=quota)
+    assert with_grants
+    return a, new_state, new_quota, grants, ScanStats(steps=jnp.int32(0))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("passes", [1, 2])
+def test_gang_passes_over_the_pruned_scan_equal_the_stepwise_scan(
+        passes, seed, monkeypatch):
+    """Gangs that fit, a gang that is rolled back (its capacity goes to
+    later pods in pass 2), dead rows and padded rows: every output of the
+    pass loop equals the loop over the stepwise scan, and the step count
+    is the sum of the passes' live rows."""
+    from koordinator_tpu.ops import gang as gang_mod
+
+    rng = np.random.default_rng(seed)
+    state = mk_state(rng.integers(8_000, 16_000, 6))
+    n = 40
+    cpus = rng.integers(500, 3_000, n)
+    gang_id = np.full(n, -1, np.int32)
+    gang_id[:6], gang_id[6:14], gang_id[14:18] = 0, 1, 2
+    cpus[6:14] = 7_000                  # gang 1: eight of these never fit
+    dead = np.zeros(n, bool)
+    dead[20:] = rng.random(n - 20) < 0.4
+    cpus[dead] = 100_000
+    pods = mk_pods(cpus, gang_id, state,
+                   priority=rng.integers(5_000, 5_003, n))
+    gangs = GangInfo.build(np.array([6, 8, 4], np.int32))
+    solve = jax.jit(gang_assign, static_argnames=("passes", "with_grants"))
+    a, st, _, grants, stats = solve(state, pods, cfg(), gangs,
+                                    passes=passes, with_grants=True)
+    monkeypatch.setattr(gang_mod, "greedy_assign", stepwise_greedy_assign)
+    want_a, want_st, _, _, _ = jax.jit(
+        gang_assign, static_argnames=("passes", "with_grants"))(
+        state, pods, cfg(), gangs, passes=passes, with_grants=True)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(want_a))
+    np.testing.assert_array_equal(np.asarray(st.node_requested),
+                                  np.asarray(want_st.node_requested))
+    a = np.asarray(a)[:n]
+    assert grants is None
+    assert (a[6:14] == -1).all() and (a[dead] == -1).all()
+    live = n - int(dead.sum())
+    assert int((a >= 0).sum()) <= int(stats.steps) <= passes * live
+    assert int(stats.steps) >= live       # pass 1 steps every live row
+
+
+def test_batch_engine_reports_no_scan():
+    state = mk_state([10_000])
+    pods = mk_pods([3_000] * 4, [0] * 4, state)
+    gangs = GangInfo.build(np.array([4]))
+    out = gang_assign(state, pods, cfg(), gangs, solver="batch",
+                      with_grants=True)
+    assert len(out) == 5 and out[4] is None

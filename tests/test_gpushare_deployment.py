@@ -149,7 +149,7 @@ def test_one_pod_filter_and_grant_equal_the_reference(seed, ask):
         | (np.asarray(pods.requests[0])[None, :] == 0), axis=1)
     assert np.array_equal(np.asarray(feasible[0])[:NODES], want & aggregate)
     # Reserve: the minors, on the node the solve chose
-    a, new_state, _, grants = _greedy(state, pods, cfg, with_grants=True)
+    a, new_state, _, grants, _ = _greedy(state, pods, cfg, with_grants=True)
     node = int(a[0])
     if not (want & aggregate).any():
         assert node == -1 and not np.asarray(grants.selection[0]).any()
@@ -223,8 +223,9 @@ def test_a_batch_of_hundreds_grants_nothing_twice(seed, engine):
     pods = PodBatch.build(request, class_capacity=8,
                           priority=rng.integers(9_000, 10_000, p))
     solve = jitted()[0 if engine == "greedy" else 1]
-    a, new_state, _, grants = solve(state, pods, ScoringConfig.default(),
-                                    with_grants=True)
+    # the greedy engine appends its scan stats after the grants
+    a, new_state, _, grants, *_ = solve(state, pods, ScoringConfig.default(),
+                                        with_grants=True)
     a, sel = np.asarray(a)[:p], np.asarray(grants.selection)[:p]
     before = table.free.copy()
     bound = 0

@@ -2,6 +2,7 @@
 
 import jax
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
 from koordinator_tpu.ops.assignment import ScoringConfig, greedy_assign, score_pods
@@ -204,3 +205,61 @@ def test_sharded_gang_quota_assign_matches_unsharded():
     assert np.array_equal(
         np.asarray(q_ref.headroom), np.asarray(q_sh.headroom)
     )
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sharded_twin_with_dead_rows_equals_the_single_device_scan(seed):
+    """The shard_map twin of the exact scan (entry filter on each node
+    shard, one pmax, then the loop over the live rows) against the single-
+    device scan and the one-step-per-row reference: dead rows (too wide for
+    any node), rows whose only nodes sit on ONE shard, padded rows, and a
+    quota that runs out mid-scan."""
+    import jax.numpy as jnp
+
+    from koordinator_tpu.ops.gang import GangInfo
+    from koordinator_tpu.parallel import sharded as ps
+    from tests.problem_helpers import build_problem as factored_problem
+    from tests.scan_reference import compare_with_reference
+    from tests.test_quota import leaves_under_a_parent
+
+    n = 100
+    state, pods = factored_problem(n_nodes=64, n_pods=n, seed=seed)
+    rng = np.random.default_rng(seed)
+    requests = np.asarray(pods.requests).copy()
+    dead = np.zeros(pods.capacity, bool)
+    dead[:n] = rng.random(n) < 0.3
+    requests[dead, CPU] = 1_000_000
+    # pods 0-9 fit only nodes 56-63: alive on the last shard alone
+    selector = np.asarray(pods.selector_mask).copy()
+    node_class = np.asarray(state.node_class).copy()
+    node_class[56:] = 7
+    selector[:, 7] = False
+    selector[:10] = False
+    selector[:10, 7] = True
+    quota_id = np.full(pods.capacity, -1, np.int32)
+    quota_id[:n] = rng.integers(-1, 3, n)
+    pods = pods.replace(requests=jnp.asarray(requests),
+                        selector_mask=jnp.asarray(selector),
+                        quota_id=jnp.asarray(quota_id))
+    state = state.replace(node_class=jnp.asarray(node_class))
+    quota = leaves_under_a_parent([30_000, 900_000], parent_cpu=2_000_000)
+    cfg = ScoringConfig.default()
+
+    want_a, steps, alive, _ = compare_with_reference(state, pods, cfg,
+                                                     quota=quota)
+    assert 0 < steps < n and not alive[dead].any()
+    mesh = pmesh.solver_mesh()
+    a, st, q = ps.sharded_greedy_assign(mesh, state, pods, cfg, quota)
+    ref_a, ref_st, ref_q = jax.jit(greedy_assign)(state, pods, cfg, quota)
+    np.testing.assert_array_equal(np.asarray(a), want_a)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(ref_a))
+    np.testing.assert_array_equal(np.asarray(st.node_requested),
+                                  np.asarray(ref_st.node_requested))
+    np.testing.assert_array_equal(np.asarray(q.headroom),
+                                  np.asarray(ref_q.headroom))
+    # the gang twin hands the same step count out as the single device
+    gangs = GangInfo.build(np.zeros(0, np.int32))
+    ga, _, _, stats = ps.sharded_gang_assign(
+        mesh, state, pods, cfg, gangs, quota, passes=1, solver="greedy")
+    np.testing.assert_array_equal(np.asarray(ga), want_a)
+    assert int(stats.steps) == steps

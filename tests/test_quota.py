@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
 from koordinator_tpu.ops.assignment import ScoringConfig, greedy_assign
@@ -12,6 +13,7 @@ from koordinator_tpu.quota import (
 )
 from koordinator_tpu.quota.tree import UNBOUNDED, hamilton_deltas
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
+from tests.scan_reference import compare_with_reference
 
 R = NUM_RESOURCE_DIMS
 CPU, MEM = ResourceDim.CPU, ResourceDim.MEMORY
@@ -349,3 +351,81 @@ def test_greedy_assign_respects_quota():
     assert sorted(a.tolist())[0] == -1
     assert sorted(a.tolist())[1] >= 0
     assert int(qs2.headroom[idx["q"], CPU]) == 500
+
+
+# -- the pruned scan under quota (tests/scan_reference.py) -------------------
+
+
+def leaves_under_a_parent(leaf_cpu, parent_cpu, min_cpu=None):
+    """A flattened tree built by hand: row 0 the parent, rows 1.. its
+    leaves, CPU the one checked dim, headrooms as given."""
+    q, cap, depth = len(leaf_cpu) + 1, 8, 4
+    headroom = np.zeros((cap, R), np.int32)
+    headroom[0, CPU], headroom[1:q, CPU] = parent_cpu, leaf_cpu
+    min_headroom = headroom.copy()
+    if min_cpu is not None:
+        min_headroom[1:q, CPU] = min_cpu
+    checked = np.zeros((cap, R), bool)
+    checked[:q, CPU] = True
+    chain = np.full((cap, depth), -1, np.int32)
+    chain[0, 0] = 0
+    chain[1:q, 0], chain[1:q, 1] = np.arange(1, q), 0
+    valid = np.zeros(cap, bool)
+    valid[:q] = True
+    return QuotaDeviceState(
+        headroom=jnp.asarray(headroom), min_headroom=jnp.asarray(min_headroom),
+        checked=jnp.asarray(checked), chain=jnp.asarray(chain),
+        valid=jnp.asarray(valid))
+
+
+QUOTA_SCAN_CASES = {
+    # leaf 1 is at its max when the scan starts: its pods are dead though
+    # every node has room; the other leaves never bind
+    "leaf_at_its_max_at_entry": dict(leaf_cpu=[0, 900_000, 900_000],
+                                     parent_cpu=2_000_000),
+    # every pod is admitted at entry; leaf 1 runs out after a few
+    "leaf_exhausted_mid_scan": dict(leaf_cpu=[5_000, 900_000, 900_000],
+                                    parent_cpu=2_000_000),
+    # the leaves have room, their parent runs out mid-scan
+    "parent_exhausted_mid_scan": dict(leaf_cpu=[900_000] * 3,
+                                      parent_cpu=20_000),
+    # non-preemptible pods check min besides: leaf 1's min is spent at
+    # entry, leaf 2's mid-scan
+    "min_spent_for_non_preemptible": dict(leaf_cpu=[900_000] * 3,
+                                          parent_cpu=2_000_000,
+                                          min_cpu=[0, 6_000, 900_000]),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("case", list(QUOTA_SCAN_CASES))
+def test_pruned_scan_under_quota_equals_the_stepwise_scan(case, seed):
+    rng = np.random.default_rng(seed)
+    alloc = np.zeros((16, R), np.int32)
+    alloc[:, CPU], alloc[:, MEM] = 64_000, 262_144
+    state = ClusterState.from_arrays(alloc)
+    n = 40
+    req = np.zeros((n, R), np.int32)
+    req[:, CPU] = rng.integers(500, 2_500, n)
+    req[:, MEM] = 512
+    quota_id = rng.integers(1, 4, n).astype(np.int32)
+    quota_id[rng.random(n) < 0.1] = -1          # no quota: always admitted
+    kw = QUOTA_SCAN_CASES[case]
+    pods = PodBatch.build(
+        req, priority=rng.integers(5_000, 5_004, n).astype(np.int32),
+        quota_id=quota_id, node_capacity=state.capacity,
+        non_preemptible=(rng.random(n) < 0.5 if "min_cpu" in kw else None))
+    qs = leaves_under_a_parent(**kw)
+    a, steps, alive, step_feasible = compare_with_reference(
+        state, pods, ScoringConfig.default(), quota=qs)
+    a, dead = a[:n], quota_id == 1
+    if case == "leaf_at_its_max_at_entry":
+        assert steps == int((~dead).sum()) == int((a >= 0).sum())
+    else:
+        # some admitted at entry are turned away at their own step
+        dead &= (np.asarray(pods.non_preemptible)[:n] if "min_cpu" in kw
+                 else False)
+        assert steps == int((~dead).sum())
+        assert (alive & ~step_feasible).any()
+        assert 0 < int((a >= 0).sum()) < steps
+    assert not alive[:n][dead].any() and (a[dead] == -1).all()

@@ -8,6 +8,7 @@ accounting) and the Pending->Available->Expired phase machine.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
 from koordinator_tpu.ops.assignment import ScoringConfig
@@ -27,6 +28,7 @@ from koordinator_tpu.scheduler.reservations import (
 )
 from koordinator_tpu.scheduler.snapshot import ClusterSnapshot, NodeSpec, PodSpec
 from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
+from tests.scan_reference import compare_with_reference
 
 R = NUM_RESOURCE_DIMS
 CPU, MEM = ResourceDim.CPU, ResourceDim.MEMORY
@@ -147,7 +149,7 @@ def test_greedy_assign_charges_reservation_then_node():
     pods = mk_pods([8_000], state, mem=1_024)
     rsv = one_reservation(node=0, cpu=6_000, mem=2_048)
     match = jnp.ones((pods.capacity, rsv.capacity), bool)
-    a, rc, new_state, new_rsv, _ = jax.jit(reservation_greedy_assign)(
+    a, rc, new_state, new_rsv, _, _ = jax.jit(reservation_greedy_assign)(
         state, pods, quiet_cfg(), rsv, match
     )
     assert int(a[0]) == 0 and int(rc[0]) == 0
@@ -162,7 +164,7 @@ def test_greedy_assign_prefers_reserved_node():
     pods = mk_pods([2_000], state)
     rsv = one_reservation(node=1, cpu=4_000)
     match = jnp.ones((pods.capacity, rsv.capacity), bool)
-    a, rc, _, _, _ = jax.jit(reservation_greedy_assign)(
+    a, rc, _, _, _, _ = jax.jit(reservation_greedy_assign)(
         state, pods, quiet_cfg(), rsv, match
     )
     assert int(a[0]) == 1 and int(rc[0]) == 0
@@ -220,7 +222,7 @@ def test_exhausted_reservation_gets_no_boost():
     pods = mk_pods([2_000, 2_000], state)
     rsv = one_reservation(node=1, cpu=4_000, allocate_once=np.array([True]))
     match = jnp.ones((pods.capacity, rsv.capacity), bool)
-    a, rc, _, _, _ = jax.jit(reservation_greedy_assign)(
+    a, rc, _, _, _, _ = jax.jit(reservation_greedy_assign)(
         state, pods, quiet_cfg(), rsv, match
     )
     a, rc = np.asarray(a), np.asarray(rc)
@@ -233,7 +235,7 @@ def test_greedy_assign_accepts_numpy_match():
     pods = mk_pods([2_000], state)
     rsv = one_reservation(node=0, cpu=4_000)
     match = np.ones((pods.capacity, rsv.capacity), bool)  # numpy, not jnp
-    a, rc, _, _, _ = reservation_greedy_assign(state, pods, quiet_cfg(), rsv, match)
+    a, rc, _, _, _, _ = reservation_greedy_assign(state, pods, quiet_cfg(), rsv, match)
     assert int(a[0]) == 0
 
 
@@ -289,3 +291,75 @@ def test_allocate_once_commit_marks_succeeded():
     spec = cache.get("rsv-b")
     assert spec.phase is ReservationPhase.SUCCEEDED
     np.testing.assert_array_equal(spec.allocated, spec.requests)
+
+
+# -- the pruned scan with reservations (tests/scan_reference.py) -------------
+
+
+def test_owner_dead_by_capacity_lives_through_its_reservation():
+    """The OR-side of the entry filter: both nodes are full for the plain
+    fit, so every pod is dead by capacity; the owner alone reaches node 1
+    through its reservation's remainder and must be stepped and placed."""
+    state = mk_state([10_000, 10_000], requested_cpus=[10_000, 10_000])
+    pods = mk_pods([3_000, 3_000, 3_000], state)
+    rsv = one_reservation(node=1, cpu=4_000)
+    match = np.zeros((pods.capacity, rsv.capacity), bool)
+    match[1, 0] = True
+    a, steps, alive, _ = compare_with_reference(
+        state, pods, quiet_cfg(), rsv=rsv, match=match)
+    assert alive[:3].tolist() == [False, True, False] and steps == 1
+    assert a[:3].tolist() == [-1, 1, -1]
+
+
+def test_allocate_once_reservation_taken_mid_scan():
+    """Two owners of one allocate-once reservation on a full node: both
+    are live at entry, the first consumes it whole, the second is turned
+    away at its own step — stepped, not pruned."""
+    state = mk_state([10_000], requested_cpus=[10_000])
+    pods = mk_pods([1_000, 1_000, 1_000], state)
+    rsv = one_reservation(node=0, cpu=4_000,
+                          allocate_once=np.array([True]))
+    match = np.zeros((pods.capacity, rsv.capacity), bool)
+    match[:2, 0] = True
+    a, steps, alive, step_feasible = compare_with_reference(
+        state, pods, quiet_cfg(), rsv=rsv, match=match)
+    assert alive[:3].tolist() == [True, True, False] and steps == 2
+    assert step_feasible[:3].tolist() == [True, False, False]
+    assert a[:3].tolist() == [0, -1, -1]
+
+
+@pytest.mark.parametrize("policy", ["aligned", "restricted"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_pruned_scan_with_reservations_equals_the_stepwise_scan(seed, policy):
+    """Seeded: 10 nearly full nodes, 12 reservations (some allocate-once),
+    48 pods of which a third own some reservations and a quarter fit
+    nothing; remainders run out mid-scan."""
+    rng = np.random.default_rng(seed)
+    n_nodes, n_rsv, n = 10, 12, 48
+    node_cpu = np.full(n_nodes, 32_000)
+    rsv_node = rng.integers(0, n_nodes, n_rsv)
+    rsv_cpu = rng.integers(2_000, 6_000, n_rsv)
+    reserved = np.zeros(n_nodes, np.int64)
+    np.add.at(reserved, rsv_node, rsv_cpu)
+    # the reserve-pod trick: reserved capacity is already requested; little
+    # is free beside it
+    state = mk_state(node_cpu, requested_cpus=np.minimum(
+        node_cpu, reserved + rng.integers(18_000, 30_000, n_nodes)))
+    cpus = rng.integers(500, 5_000, n)
+    cpus[rng.random(n) < 0.25] = 40_000
+    req = np.zeros((n, R), np.int32)
+    req[:, CPU], req[:, MEM] = cpus, 256
+    pods = PodBatch.build(
+        req, priority=rng.integers(5_000, 5_003, n).astype(np.int32),
+        node_capacity=state.capacity)
+    rsv = ReservationSet.build(
+        np.stack([vec(c, 4_096) for c in rsv_cpu]), rsv_node,
+        allocate_once=rng.random(n_rsv) < 0.3,
+        restricted=np.full(n_rsv, policy == "restricted"))
+    match = np.zeros((pods.capacity, rsv.capacity), bool)
+    owners = rng.random(n) < 0.35
+    match[:n, :n_rsv] = owners[:, None] & (rng.random((n, n_rsv)) < 0.4)
+    a, steps, alive, step_feasible = compare_with_reference(
+        state, pods, quiet_cfg(), rsv=rsv, match=match)
+    assert not alive[:n][cpus == 40_000].any()
+    assert 0 < int((a >= 0).sum()) <= steps < n

@@ -425,6 +425,114 @@ def test_rescue_places_surplus_members_of_satisfied_gang():
     assert len(res.assignments) == 5 and not res.failures
 
 
+class TestExactScanStepCounter:
+    """The exact scan's trip count on the round's flight record and in
+    ``solver_greedy_scan_rows_total``: rows handed to it, the steps it
+    took, the rest pruned at its entry."""
+
+    @staticmethod
+    def counted(outcome):
+        from koordinator_tpu import metrics
+
+        return metrics.greedy_scan_rows.value({"outcome": outcome})
+
+    @staticmethod
+    def standing_round(sched, standing=39):
+        """One batch round that leaves ``standing`` pods no node holds, and
+        a gang of four of which three fit and one never can: the batch
+        engine rolls the gang back whole, so all four come back as
+        leftovers — three of them live when the rescue scan starts."""
+        for i in range(standing):
+            sched.enqueue(pod(f"standing-{i}", cpu=900_000))
+        sched.register_gang(GangRecord(name="g", min_member=4))
+        for i in range(3):
+            sched.enqueue(pod(f"g{i}", cpu=2_000, gang="g"))
+        sched.enqueue(pod("g-wide", cpu=900_000, gang="g"))
+        for i in range(5):
+            sched.enqueue(pod(f"plain-{i}", cpu=1_000))
+        return sched.schedule_round()
+
+    def test_rescue_rows_and_steps_on_the_record_and_the_counter(self):
+        sched, _ = mk_scheduler([node(f"n{i}") for i in range(4)],
+                                batch_solver_threshold=2)
+        res = self.standing_round(sched)
+        assert sched.last_solver == "batch"
+        assert set(res.assignments) == {f"plain-{i}" for i in range(5)}
+        assert len(res.failures) == 43
+        rec = sched.flight_recorder.last()
+        # 40 rows that fit no node, 3 that do: only those are stepped
+        assert (rec.rescue_rows, rec.rescue_steps) == (43, 3)
+        assert (rec.prepass_rows, rec.prepass_steps) == (0, 0)
+        assert self.counted("stepped") == 3
+        assert self.counted("pruned") == 40
+        # every standing pod is still diagnosed, with its real reason
+        assert res.failures["standing-0"].insufficient_resources == 4
+        assert rec.to_doc()["rescue_steps"] == 3
+
+    def test_round_without_a_rescue_pass_records_nothing(self):
+        sched, _ = mk_scheduler([node(f"n{i}") for i in range(4)],
+                                batch_solver_threshold=2)
+        for i in range(5):
+            sched.enqueue(pod(f"plain-{i}", cpu=1_000))
+        res = sched.schedule_round()
+        assert sched.last_solver == "batch" and len(res.assignments) == 5
+        rec = sched.flight_recorder.last()
+        assert (rec.rescue_rows, rec.rescue_steps) == (0, 0)
+        assert self.counted("stepped") == self.counted("pruned") == 0
+
+    def test_greedy_round_is_counted_and_is_no_rescue(self):
+        # below the threshold the exact scan IS the solve
+        sched, _ = mk_scheduler([node("n1", cpu=4_000)],
+                                batch_solver_threshold=64)
+        sched.enqueue(pod("fits", cpu=1_000))
+        sched.enqueue(pod("too-big", cpu=50_000))
+        res = sched.schedule_round()
+        assert sched.last_solver == "greedy"
+        assert res.assignments == {"fits": "n1"}
+        rec = sched.flight_recorder.last()
+        assert (rec.rescue_rows, rec.rescue_steps) == (0, 0)
+        assert self.counted("stepped") == 1 and self.counted("pruned") == 1
+
+    def test_reading_the_steps_adds_no_device_wait(self):
+        """The count rides the rescue solve's own program and the one
+        block on its assignments: a round with a rescue pass blocks twice
+        (main solve, rescue), as it did before there was a count."""
+        from koordinator_tpu import timeline
+
+        enabled = timeline.RECORDER.enabled
+        timeline.RECORDER.set_enabled(True)
+        try:
+            sched, _ = mk_scheduler([node(f"n{i}") for i in range(4)],
+                                    batch_solver_threshold=2)
+            self.standing_round(sched)
+            doc = next(d for d in timeline.RECORDER.cycles(4)
+                       if d["mode"] == "round")
+            assert doc["by_name"]["block_until_ready"]["n"] == 2
+            assert sched.flight_recorder.last().rescue_steps == 3
+        finally:
+            timeline.RECORDER.set_enabled(enabled)
+
+    def test_reservation_prepass_stamps_its_own_pair(self):
+        from koordinator_tpu.scheduler.reservations import (
+            OwnerMatcher,
+            ReservationSpec,
+        )
+
+        sched, _ = mk_scheduler([node("n1"), node("n2")])
+        sched.add_reservation(ReservationSpec(
+            name="r-fits", requests=resource_vector(cpu=2_000, memory=1_024),
+            owners=[OwnerMatcher(labels={"app": "a"})]))
+        sched.add_reservation(ReservationSpec(
+            name="r-wide", requests=resource_vector(cpu=900_000, memory=1_024),
+            owners=[OwnerMatcher(labels={"app": "b"})]))
+        sched.schedule_round()
+        rec = sched.flight_recorder.last()
+        # two reserve-pods through the exact pre-pass: one can be placed
+        assert (rec.prepass_rows, rec.prepass_steps) == (2, 1)
+        assert (rec.rescue_rows, rec.rescue_steps) == (0, 0)
+        assert self.counted("stepped") == 1 and self.counted("pruned") == 1
+
+
 class TestReservationRounds:
     """Reservation lifecycle through the round loop (plugins/reservation:
     reserve-pod placement, owner allocation, expiration)."""

@@ -414,7 +414,7 @@ def test_two_axis_gang_and_greedy_tier1():
     for solver in ("batch", "greedy"):
         a_ref, st_ref, _ = f(state, pods, cfg, gangs, None, passes=2,
                              solver=solver)
-        a, st, _ = ps.sharded_gang_assign(mesh, state, pods, cfg, gangs,
+        a, st, _, _ = ps.sharded_gang_assign(mesh, state, pods, cfg, gangs,
                                           None, passes=2, solver=solver)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(a_ref),
                                       err_msg=solver)
@@ -570,7 +570,7 @@ def test_full_2d_mesh_shape_sweep():
 
         for solver in ("batch", "greedy"):
             ga_ref, gst_ref, gq_ref = gang_refs[solver]
-            ga, gst, gq = ps.sharded_gang_assign(
+            ga, gst, gq, _ = ps.sharded_gang_assign(
                 mesh, state, gpods, cfg, gangs, quota, passes=2,
                 solver=solver)
             np.testing.assert_array_equal(
