@@ -185,6 +185,10 @@ class Grants:
 
     devices: deviceshare.DeviceGrants | None = None
     cpus: numa.CPUGrants | None = None
+    #: () int32 the rows the batch rounds' Reserve loops granted on
+    #: (trips x block, summed over the planes, rounds and gang passes);
+    #: the exact scan grants one pod a step and adds none
+    accept_rows: jax.Array | None = None
 
 
 def empty_grants(state: ClusterState, p: int) -> Grants | None:
@@ -198,7 +202,8 @@ def empty_grants(state: ClusterState, p: int) -> Grants | None:
         cpus=(None if state.cpus is None else numa.CPUGrants(
             selection=jnp.zeros((p, state.cpus.shape[1]), bool),
             lost_races=jnp.zeros(p, jnp.int32),
-            turn=jnp.zeros(p, jnp.int32))))
+            turn=jnp.zeros(p, jnp.int32))),
+        accept_rows=jnp.int32(0))
 
 
 def apply_grants(state: ClusterState, pods: PodBatch, node: jnp.ndarray,
@@ -219,9 +224,9 @@ def apply_grants(state: ClusterState, pods: PodBatch, node: jnp.ndarray,
 
 def merge_grants(kept: Grants | None, new: Grants | None,
                  keep: jnp.ndarray) -> Grants | None:
-    """The kept pods' rows of ``new`` over ``kept``, lost races summed;
-    ``new`` took its cores after everything ``kept`` holds, so its turns
-    follow theirs."""
+    """The kept pods' rows of ``new`` over ``kept``, lost races and
+    accept rows summed; ``new`` took its cores after everything ``kept``
+    holds, so its turns follow theirs."""
     if kept is None:
         return None
 
@@ -240,7 +245,8 @@ def merge_grants(kept: Grants | None, new: Grants | None,
     return Grants(
         devices=(None if kept.devices is None
                  else merged(kept.devices, new.devices)),
-        cpus=cpus)
+        cpus=cpus,
+        accept_rows=kept.accept_rows + new.accept_rows)
 
 
 @struct.dataclass

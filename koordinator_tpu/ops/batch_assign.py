@@ -621,72 +621,93 @@ def _choose_candidate(cand_key, cand_tb, fits):
         jnp.where(fits & (masked == best_key), cand_tb, -1), axis=1)
 
 
-def _device_accept(dev, dev_free, dreq, choice, accept, order_pos):
-    """DeviceShare Reserve for one round's accepted proposals: every node
-    serves the pods accepted onto it one at a time, in priority order,
-    each against the devices the ones before it left, so two pods of one
-    round never share a grant.  All nodes take their k-th pod in the
-    same step; the loop runs as many steps as the fullest node has
-    accepted device pods.  Returns (dev_free, (P, D) selection, (P,)
-    granted); a pod that is not granted lost its race."""
-    p, n = choice.shape[0], dev_free.shape[0]
+#: rows a trip of the plane stages' Reserve loop grants on, at most: the
+#: pods in turn beyond them wait one trip (:func:`_plane_accept`)
+ACCEPT_BLOCK = 256
+
+
+def _plane_accept(grant, apply, book, waiting, choice, order_pos, cols,
+                  width):
+    """A plane stage's Reserve for one round's accepted proposals: every
+    node serves the pods accepted onto it one at a time, in priority
+    order, each against what the ones before it left, so two pods of one
+    round never share a grant.  A trip's turn is each node's first
+    waiting pod (the smallest ``order_pos``); the first ``width`` pods in
+    turn, by index, are granted on their own node rows, and the others
+    keep waiting.  Those are on distinct nodes, each with every pod ahead
+    of it there served, so each is its node's turn again next trip and
+    the grants are the same as in one trip over all of them.
+
+    ``grant(book, pods, rows)`` is the plane's grant for the (W,) pods,
+    each on its (W,) node row of ``book``: ((W, cols) selection, (W,)
+    ok); ``apply(book, pods, rows, sel)`` takes the selections off the
+    book.  Returns (book, (P, cols) selection, (P,) granted, () rows
+    granted on: trips x ``width``); a waiting pod that is not granted
+    lost its race."""
+    p, n = choice.shape[0], book.shape[0]
+
+    def cond(c):
+        return jnp.any(c[0])
+
+    def body(c):
+        waiting, book, selection, granted, rows_done = c
+        seg = jnp.where(waiting, choice, n)
+        first = jax.ops.segment_min(
+            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
+        turn = waiting & (order_pos == first[seg])
+        # the block's pods, then P for each empty slot: its grant is
+        # masked off, and its writes by pod index are dropped
+        pods = jnp.nonzero(turn, size=width, fill_value=p)[0]
+        safe = jnp.minimum(pods, p - 1)
+        rows = choice[safe]
+        sel, ok = grant(book, safe, rows)
+        ok = ok & (pods < p)
+        sel = sel & ok[:, None]
+        return (waiting.at[pods].set(False, mode="drop"),
+                apply(book, safe, rows, sel),
+                selection.at[pods].set(sel, mode="drop"),
+                granted.at[pods].set(ok, mode="drop"),
+                rows_done + width)
+
+    _, book, selection, granted, rows_done = jax.lax.while_loop(
+        cond, body,
+        (waiting, book, jnp.zeros((p, cols), bool), jnp.zeros(p, bool),
+         jnp.int32(0)))
+    return book, selection, granted, rows_done
+
+
+def _device_accept(dev, dev_free, dreq, choice, accept, order_pos, width):
+    """DeviceShare Reserve (:func:`_plane_accept`) over the per-device
+    free tensor: (dev_free, (P, D) selection, (P,) granted, () rows)."""
     usable = dev.valid & dev.healthy
-    ask = dreq.ask
 
-    def cond(c):
-        return jnp.any(c[0])
+    def grant(free, pods, rows):
+        return deviceshare.grant_rows(
+            free[rows], dev.total[rows], usable[rows], dev.group[rows],
+            jax.tree.map(lambda a: a[pods], dreq))
 
-    def body(c):
-        waiting, free, selection, granted = c
-        seg = jnp.where(waiting, choice, n)
-        first = jax.ops.segment_min(
-            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
-        turn = waiting & (order_pos == first[seg])
-        row = jnp.where(turn, choice, 0)
-        sel, ok = deviceshare.grant_rows(
-            free[row], dev.total[row], usable[row], dev.group[row], dreq)
-        ok = ok & turn
-        sel = sel & ok[:, None]
-        free = free.at[row].add(-(sel[:, :, None] * ask[:, None, :]))
-        return waiting & ~turn, free, selection | sel, granted | ok
+    def apply(free, pods, rows, sel):
+        return free.at[rows].add(
+            -(sel[:, :, None] * dreq.ask[pods][:, None, :]))
 
-    _, dev_free, selection, granted = jax.lax.while_loop(
-        cond, body,
-        (accept & dreq.wants, dev_free,
-         jnp.zeros((p, dev.shape[1]), bool), jnp.zeros(p, bool)))
-    return dev_free, selection, granted
+    return _plane_accept(grant, apply, dev_free, accept & dreq.wants,
+                         choice, order_pos, dev.shape[1], width)
 
 
-def _cpuset_accept(cpus, held, creq, choice, accept, order_pos):
-    """NodeNUMAResource Reserve for one round's accepted proposals, as
-    :func:`_device_accept` does for devices: every node serves the pods
-    accepted onto it one at a time, in priority order, each against the
-    cores the ones before it left.  Returns (held, (P, C) selection,
-    (P,) granted); a pod that is not granted lost its race."""
-    p, n = choice.shape[0], held.shape[0]
+def _cpuset_accept(cpus, held, creq, choice, accept, order_pos, width):
+    """NodeNUMAResource Reserve (:func:`_plane_accept`) over the CPU
+    plane's ``held``: (held, (P, C) selection, (P,) granted, () rows)."""
 
-    def cond(c):
-        return jnp.any(c[0])
+    def grant(held, pods, rows):
+        on = jax.tree.map(lambda a: a[rows], cpus.replace(held=held))
+        return numa.grant_cpu_rows(
+            on, on.held, jax.tree.map(lambda a: a[pods], creq))
 
-    def body(c):
-        waiting, held, selection, granted = c
-        seg = jnp.where(waiting, choice, n)
-        first = jax.ops.segment_min(
-            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
-        turn = waiting & (order_pos == first[seg])
-        row = jnp.where(turn, choice, 0)
-        sel, ok = numa.grant_cpu_rows(
-            jax.tree.map(lambda a: a[row], cpus), held[row], creq)
-        ok = ok & turn
-        sel = sel & ok[:, None]
-        held = held.at[row].add(sel * creq.unit[:, None])
-        return waiting & ~turn, held, selection | sel, granted | ok
+    def apply(held, pods, rows, sel):
+        return held.at[rows].add(sel * creq.unit[pods][:, None])
 
-    _, held, selection, granted = jax.lax.while_loop(
-        cond, body,
-        (accept & creq.wants, held,
-         jnp.zeros((p, cpus.shape[1]), bool), jnp.zeros(p, bool)))
-    return held, selection, granted
+    return _plane_accept(grant, apply, held, accept & creq.wants, choice,
+                         order_pos, cpus.shape[1], width)
 
 
 @jax.named_scope("assign_rounds")
@@ -702,7 +723,8 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds,
     proposes again next round, as a pod that lost a capacity race does.
     Over a state with CPUs a pinned proposal needs a cpuset fit and a
     grant the same way (:func:`_cpuset_accept`, after the devices: a pod
-    that loses its cores gives its devices back).  ``with_grants``
+    that loses its cores gives its devices back).  Both grant in blocks
+    of at most ``ACCEPT_BLOCK`` pods a trip.  ``with_grants``
     appends the :class:`~koordinator_tpu.ops.assignment.Grants` to
     (assignments, state, quota)."""
     cand_valid = cand_key >= 0
@@ -727,6 +749,7 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds,
         # each pod's place in the priority order, for the plane stages
         order_pos = jnp.zeros(pods.capacity, jnp.int32).at[order].set(
             jnp.arange(pods.capacity, dtype=jnp.int32))
+        width = min(pods.capacity, ACCEPT_BLOCK)
         carry = carry.replace(
             dev_free=None if dev is None else dev.free,
             cpu_held=None if cpus is None else cpus.held,
@@ -770,16 +793,19 @@ def _assign_rounds(state, pods, quota, cand_key, cand_node, rounds,
                 )
         dev_free, cpu_held, grants = c.dev_free, c.cpu_held, c.grants
         if dev is not None:
-            dev_free, dev_sel, granted = _device_accept(
-                dev, dev_free, dreq, choice, accept, order_pos)
+            dev_free, dev_sel, granted, rows = _device_accept(
+                dev, dev_free, dreq, choice, accept, order_pos, width)
             lost = accept & dreq.wants & ~granted
             accept = accept & ~lost
-            grants = grants.replace(devices=deviceshare.DeviceGrants(
-                selection=grants.devices.selection | dev_sel,
-                lost_races=grants.devices.lost_races + lost))
+            grants = grants.replace(
+                devices=deviceshare.DeviceGrants(
+                    selection=grants.devices.selection | dev_sel,
+                    lost_races=grants.devices.lost_races + lost),
+                accept_rows=grants.accept_rows + rows)
         if cpus is not None:
-            cpu_held, cpu_sel, granted = _cpuset_accept(
-                cpus, cpu_held, creq, choice, accept, order_pos)
+            cpu_held, cpu_sel, granted, rows = _cpuset_accept(
+                cpus, cpu_held, creq, choice, accept, order_pos, width)
+            grants = grants.replace(accept_rows=grants.accept_rows + rows)
             lost = accept & creq.wants & ~granted
             accept = accept & ~lost
             if dev is not None:

@@ -77,6 +77,9 @@ class RoundRecord:
     rescue_steps: int = 0
     prepass_rows: int = 0
     prepass_steps: int = 0
+    #: rows the batch solves' plane stages granted on in their Reserve
+    #: loops (trips x block, over rounds and passes); 0 without a plane
+    accept_rows: int = 0
     #: critical-path join (ISSUE 18): the timeline observatory's verdict
     #: for the cycle this round ran in — which cause dominated the
     #: cycle's covering chain and for how long — annotated after the

@@ -159,12 +159,15 @@ class _RoundGrants:
     their compacted rows merged in.  ``planes`` maps a plane of
     ``ops/assignment.Grants`` to its ((P, D or C) bool selection, (P,)
     int32 lost races); ``cpu_turn`` is the (P,) order the cpuset grants
-    were taken in (``ops/numa.CPUGrants.turn``), None without them."""
+    were taken in (``ops/numa.CPUGrants.turn``), None without them;
+    ``accept_rows`` the rows the solves' Reserve loops granted on
+    (``Grants.accept_rows``), summed."""
 
     def __init__(self, planes: dict[str, tuple[np.ndarray, np.ndarray]],
-                 cpu_turn: np.ndarray | None = None):
+                 cpu_turn: np.ndarray | None = None, accept_rows: int = 0):
         self.planes = planes
         self.cpu_turn = cpu_turn
+        self.accept_rows = accept_rows
 
     @classmethod
     def of(cls, grants) -> "_RoundGrants | None":
@@ -174,7 +177,8 @@ class _RoundGrants:
         return cls({name: (np.array(g.selection), np.array(g.lost_races))
                     for name in _PLANES
                     if (g := getattr(grants, name)) is not None},
-                   None if grants.cpus is None else np.array(grants.cpus.turn))
+                   None if grants.cpus is None else np.array(grants.cpus.turn),
+                   int(grants.accept_rows))
 
     @staticmethod
     def merged(current: "_RoundGrants | None", grants, rows: np.ndarray,
@@ -188,6 +192,7 @@ class _RoundGrants:
             return current
         if current is None:
             current = _RoundGrants({})
+        current.accept_rows += int(grants.accept_rows)
         k = len(rows)
         for name in _PLANES:
             g = getattr(grants, name)
@@ -1504,6 +1509,9 @@ class Scheduler:
         #: (rows handed over, loop steps taken) of the round's exact
         #: scans: the rescue pass, the reservation pre-pass
         self._rescue_scan = self._prepass_scan = (0, 0)
+        #: rows the round's batch solves granted on in their plane
+        #: stages' Reserve loops (``ops/assignment.Grants.accept_rows``)
+        self._accept_rows = 0
         self._round_recordable = False
         #: solve-dispatch edge for the journey ledger's queue_wait/solve
         #: stage split — round-scoped: set here, read by the bind-commit
@@ -1550,6 +1558,7 @@ class Scheduler:
             rescue_steps=self._rescue_scan[1],
             prepass_rows=self._prepass_scan[0],
             prepass_steps=self._prepass_scan[1],
+            accept_rows=self._accept_rows,
         ))
 
     def schedule_round(self) -> SchedulingResult:
@@ -2182,6 +2191,8 @@ class Scheduler:
             self._recover_solve_failure()
             raise
         result.round_pods = len(pods)
+        if grants is not None:
+            self._accept_rows = grants.accept_rows
         # wall vs. device: the Solve phase's wall time is in the monitor;
         # this is the share spent blocked on jitted solve results
         metrics.solver_device_latency.observe(

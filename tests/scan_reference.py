@@ -163,7 +163,8 @@ def scan_reference(
         grants = Grants(devices=deviceshare.DeviceGrants(
             selection=jnp.zeros((pods.capacity, dev.shape[1]), bool)
             .at[order].set(sel_in_order),
-            lost_races=jnp.zeros(pods.capacity, jnp.int32)))
+            lost_races=jnp.zeros(pods.capacity, jnp.int32)),
+            accept_rows=jnp.int32(0))
     step_feasible = jnp.zeros(pods.capacity, bool).at[order].set(any_in_order)
     return (assignments, rsv_choice, new_state, new_rsv, new_quota, grants,
             step_feasible)

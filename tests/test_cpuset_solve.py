@@ -105,22 +105,23 @@ def cpu_plane(nodes: list[reference.Node], capacity: int):
         single_numa=jnp.asarray(single))
 
 
-def fragmented(seed: int, single: bool | None = None):
-    """A seeded cluster of 12 nodes of 16 or 32 CPUs (half SingleNUMANode
-    unless ``single`` says), fragmented by random prior grants: (the
-    reference's nodes, the program's state with the same CPU plane)."""
+def fragmented(seed: int, single: bool | None = None, count: int = NODES):
+    """A seeded cluster of ``count`` nodes of 16 or 32 CPUs (half
+    SingleNUMANode unless ``single`` says), fragmented by random prior
+    grants: (the reference's nodes, the program's state with the same CPU
+    plane)."""
     rng = np.random.default_rng(seed)
     nodes = [ref_node(int(rng.choice([16, 32])),
                       bool(i % 2) if single is None else single)
-             for i in range(NODES)]
-    for _ in range(int(rng.integers(10, 30))):
-        node = nodes[int(rng.integers(NODES))]
+             for i in range(count)]
+    for _ in range(int(rng.integers(10, 30)) * count // NODES):
+        node = nodes[int(rng.integers(count))]
         bind = int(rng.choice([FULL, SPREAD]))
         excl = bool(rng.random() < 0.5)
         cpus = reference.take(node, int(rng.choice([1, 2, 4])), bind, excl)
         if cpus is not None:
             reference.reserve(node, cpus, excl)
-    alloc = np.zeros((NODES, NUM_RESOURCE_DIMS), np.int32)
+    alloc = np.zeros((count, NUM_RESOURCE_DIMS), np.int32)
     alloc[:, ResourceDim.CPU] = BIG_CPU
     alloc[:, ResourceDim.MEMORY] = BIG_MEM
     state = ClusterState.from_arrays(alloc)
@@ -277,6 +278,143 @@ def test_the_scan_entry_filter_prunes_a_pod_no_numa_node_holds():
     assert granted(grants, 0) == []
 
 
+# -- the Reserve loop in blocks ----------------------------------------------
+
+
+def full_width_cpuset_accept(cpus, held, creq, choice, accept, order_pos):
+    """The oracle: the Reserve loop as it was before it granted in blocks.
+    Every trip grants on all P rows, each pod on its node's row whether
+    or not it is its turn, and keeps the rows whose turn it is.  Returns
+    what ``batch_assign._cpuset_accept`` does, the rows as trips x P."""
+    import jax
+    import jax.numpy as jnp
+
+    p, n = choice.shape[0], held.shape[0]
+
+    def body(c):
+        waiting, held, selection, granted, rows = c
+        seg = jnp.where(waiting, choice, n)
+        first = jax.ops.segment_min(
+            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
+        turn = waiting & (order_pos == first[seg])
+        row = jnp.where(turn, choice, 0)
+        sel, ok = numa.grant_cpu_rows(
+            jax.tree.map(lambda a: a[row], cpus), held[row], creq)
+        ok = ok & turn
+        sel = sel & ok[:, None]
+        held = held.at[row].add(sel * creq.unit[:, None])
+        return (waiting & ~turn, held, selection | sel, granted | ok,
+                rows + p)
+
+    _, held, selection, granted, rows = jax.lax.while_loop(
+        lambda c: jnp.any(c[0]), body,
+        (accept & creq.wants, held, jnp.zeros((p, cpus.shape[1]), bool),
+         jnp.zeros(p, bool), jnp.int32(0)))
+    return held, selection, granted, rows
+
+
+@functools.cache
+def accept_loops():
+    """(the blocked loop at width 8, the full-width oracle), jitted."""
+    import jax
+
+    from koordinator_tpu.ops import batch_assign as ba
+
+    return (jax.jit(functools.partial(ba._cpuset_accept, width=8)),
+            jax.jit(full_width_cpuset_accept))
+
+
+#: plane -> (SingleNUMANode nodes, bind policies, exclusive share)
+ACCEPT_PLANES = {
+    "single_numa": (True, (FULL, SPREAD), 0.5),
+    "spread": (False, (SPREAD,), 0.0),
+    "exclusive": (None, (FULL,), 1.0),
+}
+
+
+@pytest.mark.parametrize("plane", sorted(ACCEPT_PLANES))
+@pytest.mark.parametrize("seed", [21, 22])
+def test_the_blocked_reserve_loop_grants_what_the_full_width_one_did(
+        seed, plane, monkeypatch):
+    """64 accepted pinned proposals on 40 fragmented nodes, up to a dozen
+    on one node, granted 8 pods a trip, leave the plane, the selections
+    and the granted set the loop over all 64 rows a trip left; and a
+    whole solve in blocks of 8 gives that loop's solve's assignments,
+    grants, lost races and turns."""
+    import jax
+    import jax.numpy as jnp
+
+    from koordinator_tpu.ops import batch_assign as ba
+
+    single, binds, excl_share = ACCEPT_PLANES[plane]
+    nodes, state = fragmented(seed, single, count=40)
+    rng = np.random.default_rng(seed + 200)
+    asks = [(int(rng.choice([1, 2, 4, 6, 8])), int(rng.choice(binds)),
+             bool(rng.random() < excl_share)) for _ in range(64)]
+    pods = pinned_batch(asks, capacity=64,
+                        priority=rng.integers(0, 100, len(asks)))
+    creq = numa.pod_cpu_requests(pods)
+    # half the pods crowd onto 5 nodes, the rest spread over all 40: a
+    # first trip of more than 8 turns, then several trips on the crowd
+    choice = jnp.asarray(np.where(rng.random(64) < 0.5,
+                                  rng.integers(0, 5, 64),
+                                  rng.integers(0, 40, 64)), jnp.int32)
+    accept = jnp.asarray(rng.random(64) < 0.9)
+    order_pos = jnp.asarray(rng.permutation(64), jnp.int32)
+    blocked, oracle = accept_loops()
+    args = (state.cpus, state.cpus.held, creq, choice, accept, order_pos)
+    got, want = blocked(*args), oracle(*args)
+    for name, g, w in zip(("held", "selection", "granted"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    assert np.asarray(want[2]).any() and int(got[3]) % 8 == 0
+    assert int(got[3]) < int(want[3])
+
+    def solve():
+        # a fresh function each call: jit reuses a trace of the same one,
+        # and the patches below must be traced
+        return jax.jit(lambda *a: ba.batch_assign(*a, with_grants=True))(
+            state, pods, ScoringConfig.default())
+
+    monkeypatch.setattr(ba, "ACCEPT_BLOCK", 8)
+    a, new_state, _, grants = solve()
+    monkeypatch.setattr(
+        ba, "_cpuset_accept",
+        lambda *args: full_width_cpuset_accept(*args[:6]))
+    a0, state0, _, grants0 = solve()
+    assert int(grants.accept_rows) < int(grants0.accept_rows)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(a0))
+    assert (np.asarray(a) >= 0).sum() > 8
+    np.testing.assert_array_equal(np.asarray(new_state.cpus.held),
+                                  np.asarray(state0.cpus.held))
+    for name in ("selection", "lost_races", "turn"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(grants.cpus, name)),
+            np.asarray(getattr(grants0.cpus, name)), name)
+
+
+def test_the_loop_counts_the_rows_of_its_blocks():
+    """Node 0 takes five pinned pods, 30 other nodes one each, in blocks
+    of 8: node 0's pods lead the priority order at indices 0-4, so each
+    trip serves node 0's next pod and 7 others, five trips of 8 rows."""
+    import jax.numpy as jnp
+
+    _, state = fragmented(31, single=False, count=40)
+    state = state.replace(cpus=state.cpus.replace(
+        held=jnp.zeros_like(state.cpus.held)))
+    pods = pinned_batch([(2, FULL, False)] * 35, capacity=64)
+    creq = numa.pod_cpu_requests(pods)
+    choice = jnp.asarray([0] * 5 + list(range(1, 31)) + [0] * 29, jnp.int32)
+    accept = jnp.arange(64) < 35
+    blocked, oracle = accept_loops()
+    args = (state.cpus, state.cpus.held, creq, choice, accept,
+            jnp.arange(64, dtype=jnp.int32))
+    _, _, granted_, rows = blocked(*args)
+    assert np.asarray(granted_)[:35].all()
+    assert int(rows) == 40
+    # the full-width loop's five trips each granted on all 64 rows
+    assert int(oracle(*args)[3]) == 5 * 64
+
+
 # -- through the scheduler ----------------------------------------------------
 
 
@@ -296,7 +434,7 @@ def pinned(name: str, cpus: int, bind: int = FULL,
                    cpu_bind_policy=bind, priority=priority)
 
 
-def mk_scheduler(names: list[str], cpus: int = 16):
+def mk_scheduler(names: list[str], cpus: int = 16, **kwargs):
     cm = CPUManager()
     snap = ClusterSnapshot(capacity=16)
     for name in names:
@@ -309,7 +447,7 @@ def mk_scheduler(names: list[str], cpus: int = 16):
     cfg = ScoringConfig.default().replace(
         usage_thresholds=jnp.zeros(NUM_RESOURCE_DIMS, jnp.int32),
         estimator_defaults=jnp.zeros(NUM_RESOURCE_DIMS, jnp.int32))
-    return Scheduler(snap, config=cfg, cpu_manager=cm), cm
+    return Scheduler(snap, config=cfg, cpu_manager=cm, **kwargs), cm
 
 
 def cpuset_of(sched, pod: str) -> list[int]:
@@ -425,3 +563,16 @@ def test_span_and_counter_read_the_grants():
     moved = {k: v - before.get(k, 0) for k, v in after.items()}
     # a and b, then c once a has left; c waited a round first
     assert moved.get("granted") == 3 and moved.get("no_cpuset", 0) >= 1
+
+
+def test_a_batch_round_records_the_rows_its_reserve_loop_granted_on():
+    """The flight record's ``accept_rows``: a batch round over the CPU
+    plane reads its solves' trips x block (the batch of 8 rows is one
+    block, at least one trip); nothing else in the round adds to it."""
+    sched, _ = mk_scheduler(["n0", "n1"], batch_solver_threshold=2)
+    for i in range(4):
+        sched.enqueue(pinned(f"p{i}", 4, FULL if i % 2 else SPREAD))
+    res = sched.schedule_round()
+    assert sched.last_solver == "batch" and len(res.assignments) == 4
+    rows = sched.flight_recorder.last().accept_rows
+    assert rows > 0 and rows % 8 == 0

@@ -5,9 +5,12 @@ cases, device_allocator_test.go joint allocation, benchmark shape 1024 nodes
 x 8 GPUs from plugin_benchmark_test.go:143-145).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from koordinator_tpu.ops.deviceshare import (
     DEV_BINPACK,
@@ -250,3 +253,130 @@ def test_shared_alloc_prefers_topology_group():
     assert bool(ok)
     assert np.flatnonzero(np.asarray(nic_sel)).tolist() == [1]
     assert (np.flatnonzero(np.asarray(gpu_sel)) >= 4).all()
+
+
+# -- the batch rounds' Reserve loop in blocks ---------------------------------
+
+
+def full_width_device_accept(dev, dev_free, dreq, choice, accept, order_pos):
+    """The oracle: the device Reserve loop as it was before it granted in
+    blocks.  Every trip grants on all P rows, each pod on its node's row
+    whether or not it is its turn, and keeps the rows whose turn it is.
+    Returns what ``batch_assign._device_accept`` does, the rows as trips x
+    P."""
+    from koordinator_tpu.ops.deviceshare import grant_rows
+
+    p, n = choice.shape[0], dev_free.shape[0]
+    usable = dev.valid & dev.healthy
+    ask = dreq.ask
+
+    def body(c):
+        waiting, free, selection, granted, rows = c
+        seg = jnp.where(waiting, choice, n)
+        first = jax.ops.segment_min(
+            jnp.where(waiting, order_pos, p), seg, num_segments=n + 1)
+        turn = waiting & (order_pos == first[seg])
+        row = jnp.where(turn, choice, 0)
+        sel, ok = grant_rows(
+            free[row], dev.total[row], usable[row], dev.group[row], dreq)
+        ok = ok & turn
+        sel = sel & ok[:, None]
+        free = free.at[row].add(-(sel[:, :, None] * ask[:, None, :]))
+        return (waiting & ~turn, free, selection | sel, granted | ok,
+                rows + p)
+
+    _, free, selection, granted, rows = jax.lax.while_loop(
+        lambda c: jnp.any(c[0]), body,
+        (accept & dreq.wants, dev_free, jnp.zeros((p, dev.shape[1]), bool),
+         jnp.zeros(p, bool), jnp.int32(0)))
+    return free, selection, granted, rows
+
+
+def random_gpu_cluster(seed: int, nodes: int = 40, pods: int = 64):
+    """A seeded cluster of 8-GPU nodes (some with 4), a third of the
+    devices partly taken, a few unhealthy, and a batch of shared and
+    whole-GPU pods: (state with the device plane, its pod batch)."""
+    from koordinator_tpu.api.resources import NUM_RESOURCE_DIMS, ResourceDim
+    from koordinator_tpu.state.cluster_state import ClusterState, PodBatch
+
+    rng = np.random.default_rng(seed)
+    alloc = np.zeros((nodes, NUM_RESOURCE_DIMS), np.int32)
+    alloc[:, ResourceDim.CPU] = 10_000_000
+    alloc[:, ResourceDim.MEMORY] = 10_000_000
+    alloc[:, ResourceDim.GPU] = 800
+    alloc[:, ResourceDim.GPU_MEMORY] = 8 * 81_920
+    state = ClusterState.from_arrays(alloc)
+    per_node = [gpu_node(int(rng.choice([4, 8]))) for _ in range(nodes)]
+    dev = DeviceState.build(per_node, node_capacity=state.capacity,
+                            device_capacity=8)
+    taken = rng.choice([0, 0, 10, 25, 50, 100], size=dev.valid.shape)
+    used = np.stack([taken, taken * 819], axis=-1).astype(np.int32)
+    free = np.asarray(dev.free) - np.where(
+        np.asarray(dev.valid)[..., None], used, 0)
+    healthy = np.asarray(dev.healthy) & (rng.random(dev.valid.shape) < 0.95)
+    dev = dev.replace(free=jnp.asarray(free), healthy=jnp.asarray(healthy))
+    request = np.zeros((pods, NUM_RESOURCE_DIMS), np.int32)
+    request[:, ResourceDim.CPU] = 1_000
+    request[:, ResourceDim.MEMORY] = 1_024
+    share = rng.choice([10, 20, 25, 50], pods)
+    whole = rng.choice([1, 2, 4], pods)
+    is_share = rng.random(pods) < 0.5
+    request[:, ResourceDim.GPU] = np.where(is_share, share, 100 * whole)
+    request[:, ResourceDim.GPU_MEMORY] = np.where(
+        is_share, share * 819, 81_920 * whole)
+    batch = PodBatch.build(request, capacity=pods,
+                           priority=rng.integers(0, 100, pods))
+    return state.replace(devices=dev), batch
+
+
+@pytest.mark.parametrize("seed", [21, 22, 23])
+def test_the_blocked_device_reserve_loop_grants_what_the_full_width_one_did(
+        seed, monkeypatch):
+    """64 accepted device proposals on 40 nodes, up to a dozen on one
+    node, granted 8 pods a trip, leave the free tensor, the selections
+    and the granted set the loop over all 64 rows a trip left; and a
+    whole solve in blocks of 8 gives that loop's solve's assignments,
+    grants and lost races."""
+    from koordinator_tpu.ops import batch_assign as ba
+    from koordinator_tpu.ops.assignment import ScoringConfig
+    from koordinator_tpu.ops.deviceshare import pod_device_requests
+
+    state, pods = random_gpu_cluster(seed)
+    rng = np.random.default_rng(seed + 200)
+    dreq = pod_device_requests(pods.requests)
+    # half the pods crowd onto 5 nodes, the rest spread over all 40
+    choice = jnp.asarray(np.where(rng.random(64) < 0.5,
+                                  rng.integers(0, 5, 64),
+                                  rng.integers(0, 40, 64)), jnp.int32)
+    accept = jnp.asarray(rng.random(64) < 0.9)
+    order_pos = jnp.asarray(rng.permutation(64), jnp.int32)
+    dev = state.devices
+    args = (dev, dev.free, dreq, choice, accept, order_pos)
+    got = jax.jit(functools.partial(ba._device_accept, width=8))(*args)
+    want = jax.jit(full_width_device_accept)(*args)
+    for name, g, w in zip(("free", "selection", "granted"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    assert np.asarray(want[2]).any() and not np.asarray(want[2]).all()
+    assert int(got[3]) % 8 == 0 and int(got[3]) < int(want[3])
+
+    def solve():
+        # a fresh function each call: jit reuses a trace of the same one,
+        # and the patches below must be traced
+        return jax.jit(lambda *a: ba.batch_assign(*a, with_grants=True))(
+            state, pods, ScoringConfig.default())
+
+    monkeypatch.setattr(ba, "ACCEPT_BLOCK", 8)
+    a, new_state, _, grants = solve()
+    monkeypatch.setattr(
+        ba, "_device_accept",
+        lambda *args: full_width_device_accept(*args[:6]))
+    a0, state0, _, grants0 = solve()
+    assert int(grants.accept_rows) < int(grants0.accept_rows)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(a0))
+    assert (np.asarray(a) >= 0).sum() > 8
+    np.testing.assert_array_equal(np.asarray(new_state.devices.free),
+                                  np.asarray(state0.devices.free))
+    for name in ("selection", "lost_races"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(grants.devices, name)),
+            np.asarray(getattr(grants0.devices, name)), name)

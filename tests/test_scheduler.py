@@ -478,6 +478,8 @@ class TestExactScanStepCounter:
         assert sched.last_solver == "batch" and len(res.assignments) == 5
         rec = sched.flight_recorder.last()
         assert (rec.rescue_rows, rec.rescue_steps) == (0, 0)
+        # no plane, so no Reserve loop in the batch solve
+        assert rec.accept_rows == 0
         assert self.counted("stepped") == self.counted("pruned") == 0
 
     def test_greedy_round_is_counted_and_is_no_rescue(self):
