@@ -571,6 +571,13 @@ def build_scheduler_parser() -> argparse.ArgumentParser:
     parser.add_argument("--node-capacity", type=int, default=1024,
                         help="initial padded node-state capacity")
     parser.add_argument("--gang-passes", type=int, default=2)
+    parser.add_argument(
+        "--topology-layer-labels", default="",
+        help="label keys of the network-topology layers, top-down and "
+             "comma-separated, between the cluster and the node (e.g. "
+             "spine,block): the tree gangs with a topology requirement are "
+             "gathered on is built from the nodes' labels under these keys; "
+             "empty = no tree")
     parser.add_argument("--batch-solver-threshold", type=int, default=1024,
                         help="queue size at which rounds switch from the "
                              "exact greedy scan to the data-parallel "
@@ -800,6 +807,8 @@ def main_koord_scheduler(argv: list[str],
         quality_mode=args.quality_mode,
         quality_slack_threshold=args.quality_slack_threshold,
         forecast_mode=args.forecast_mode,
+        topology_layers=[k.strip() for k in
+                         args.topology_layer_labels.split(",") if k.strip()],
     )
     tenant_front = None
     if args.tenants > 1:

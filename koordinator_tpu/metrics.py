@@ -596,6 +596,17 @@ cpuset_grants = SCHEDULER.counter(
     "node's aggregate rows in a round in which a pod ahead of it took the "
     "cores; it proposed again), no_cpuset (left the round unbound, or "
     "refused at the commit)")
+# -- network-topology gang plans (scheduler._apply_topology_plans) --
+topology_plans = SCHEDULER.counter(
+    "topology_plans_total",
+    "Gangs with a network-topology requirement planned in a round, by "
+    "outcome: planned (a gather plan pinned its members), no_fit (no "
+    "domain held it against what the round's earlier plans left; the gang "
+    "backed off whole)")
+topology_tree_nodes = SCHEDULER.gauge(
+    "topology_tree_nodes",
+    "Topology nodes (cluster root, every labelled domain, every node) of "
+    "the tree built from node labels, at its last build")
 deviceshare_inventory_events = SCHEDULER.counter(
     "deviceshare_inventory_events_total",
     "Device inventories applied (node_upsert with devices, node_devices): "

@@ -64,55 +64,89 @@ class TopologyRequirements:
     layer_multiples: tuple = ()       # (L,) pod-count multiple per layer (1 = none)
 
 
+#: ``node_path`` entry of a layer a node carries no label for: past every
+#: topology id, so the segment sums up the tree drop it
+NO_DOMAIN = 2**30
+
+
 class TopologyTree:
     """Host-side tree builder: nodes join by their label path (parent->child
     layer names), as tree.AddNode derives TreeNodeMeta from node labels
-    (``networktopology/tree.go:108,141``)."""
+    (``networktopology/tree.go:108,141``).
+
+    A domain is keyed by its whole path from the root, so two racks of one
+    name under different pods are two racks.  Topology ids are handed out in
+    order of first appearance and never reused: a tree only grows.  A node
+    without a label at some layer hangs from the deepest layer it has one
+    for; a node with none joins at the root."""
 
     def __init__(self, layer_names: list[str]):
         # layer_names: top-down, excluding the implicit cluster root and
         # including the node layer last, e.g. ["spine", "block", "node"].
         self.layer_names = ["cluster", *layer_names]
         self.num_layers = len(self.layer_names)
-        self._index: dict[tuple[int, str], int] = {(0, ""): 0}
+        self._index: dict[tuple, int] = {(): 0}
         self._parent = [0]
         self._layer = [0]
-        self._paths: list[np.ndarray] = []
-        self._leaf_topo: list[int] = []
+        #: physical node row -> its (L,) ancestor ids
+        self._paths: dict[int, np.ndarray] = {}
+
+    @property
+    def num_topo(self) -> int:
+        return len(self._parent)
 
     def add_node(self, path: list[str]) -> int:
         """Register a physical node by its label path (one name per non-root
-        layer; the last entry is the node's own name). Returns node index."""
+        layer; the last entry is the node's own name) at the next row.
+        Returns node index."""
+        row = max(self._paths, default=-1) + 1
+        self.set_node(row, path)
+        return row
+
+    def set_node(self, row: int, path: list) -> None:
+        """(Re)place physical node ``row`` by its label path; ``None`` where
+        the node has no label for that layer (never for the node itself)."""
         if len(path) != self.num_layers - 1:
             raise ValueError(f"path needs {self.num_layers - 1} entries, got {len(path)}")
-        parent = 0
-        ids = [0]
+        if path[-1] is None:
+            raise ValueError("a node's own name cannot be missing")
+        ids = np.full(self.num_layers, NO_DOMAIN, np.int32)
+        ids[0] = 0
+        parent, prefix = 0, ()
         for depth, name in enumerate(path, start=1):
-            key = (depth, name)
-            tid = self._index.get(key)
+            if name is None:
+                continue
+            prefix = (*prefix, (depth, name))
+            tid = self._index.get(prefix)
             if tid is None:
                 tid = len(self._parent)
-                self._index[key] = tid
+                self._index[prefix] = tid
                 self._parent.append(parent)
                 self._layer.append(depth)
-            ids.append(tid)
+            ids[depth] = tid
             parent = tid
-        self._paths.append(np.array(ids, np.int32))
-        self._leaf_topo.append(parent)
-        return len(self._paths) - 1
+        self._paths[row] = ids
+
+    def drop_node(self, row: int) -> None:
+        """Row ``row`` holds no node any more; its leaf stays, empty."""
+        self._paths.pop(row, None)
+
+    def rows(self) -> dict[int, np.ndarray]:
+        return self._paths
 
     def build(self, capacity: int | None = None) -> TopologyArrays:
-        n = len(self._paths)
+        n = max(self._paths, default=-1) + 1
         cap = capacity if capacity is not None else n
+        if cap < n:
+            raise ValueError(f"capacity {cap} under the tree's {n} rows")
         t = len(self._parent)
         node_path = np.zeros((cap, self.num_layers), np.int32)
-        if n:
-            node_path[:n] = np.stack(self._paths)
         node_topo = np.zeros(cap, np.int32)
-        node_topo[:n] = self._leaf_topo
         topo_to_node = np.full(t, -1, np.int32)
-        for i, tid in enumerate(self._leaf_topo):
-            topo_to_node[tid] = i
+        for row, ids in self._paths.items():
+            node_path[row] = ids
+            node_topo[row] = ids[-1]
+            topo_to_node[ids[-1]] = row
         return TopologyArrays(
             topo_layer=jnp.asarray(self._layer, jnp.int32),
             topo_parent=jnp.asarray(self._parent, jnp.int32),
@@ -135,15 +169,27 @@ def gang_offer_slots(
     ``gang_requests`` is the (P, R) request matrix of the gang's pods (invalid
     rows zero). Slots on node n = the longest prefix of the pod list whose
     cumulative request fits the node's free capacity. When ``cfg`` (a
-    ScoringConfig) is given, the k-th slot must also pass the load-aware usage
-    thresholds with k pods' estimated usage added — the reference computes
-    slots by running the FULL filter chain per added pod, so a plan never
-    pins a pod onto a node the solver would then reject.
+    ScoringConfig) is given, the k-th slot must also pass the load-aware
+    usage thresholds with k pods' estimated usage added — the reference
+    computes slots by running the FULL filter chain per added pod, so a plan
+    never pins a pod onto a node the solver would then reject.
     """
-    free = state.node_allocatable - state.node_requested  # (N, R)
+    return _offer_slots(state.node_allocatable, state.node_requested,
+                        state.node_usage, state.node_agg_usage,
+                        gang_requests, node_valid, cfg)
+
+
+def _offer_slots(alloc, requested, usage, agg_usage, gang_requests,
+                 node_valid, cfg, n_members=None):
+    """:func:`gang_offer_slots` on the accounting's rows; at most
+    ``n_members`` slots a node (the reference's loop runs over the gang's
+    pods only)."""
+    free = alloc - requested  # (N, R)
     cum = jnp.cumsum(gang_requests, axis=0)  # (P, R)
     # fits[n, p] = pods[0..p] all fit on node n simultaneously
     fits = jnp.all(cum[None, :, :] <= free[:, None, :], axis=-1)
+    if n_members is not None:
+        fits = fits & (jnp.arange(gang_requests.shape[0]) < n_members)[None, :]
     if cfg is not None:
         from koordinator_tpu.ops import scoring
         from koordinator_tpu.ops.assignment import _threshold_mask
@@ -152,8 +198,7 @@ def gang_offer_slots(
             gang_requests, cfg.estimator_factors, cfg.estimator_defaults
         )
         thr = _threshold_mask(
-            cfg, state.node_usage, state.node_agg_usage,
-            state.node_allocatable, jnp.cumsum(est, axis=0),
+            cfg, usage, agg_usage, alloc, jnp.cumsum(est, axis=0),
         )  # (P, N)
         fits = fits & thr.T
     prefix = jnp.cumprod(fits.astype(jnp.int32), axis=1)
@@ -168,7 +213,8 @@ def aggregate_tree(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Sum per-physical-node values into every ancestor topology node in one
     segment-sum over the tiled (N*L,) ancestor paths
-    (``network_topology_solver.go:212 evaluateTopologyNode``)."""
+    (``network_topology_solver.go:212 evaluateTopologyNode``).  A layer a
+    node has no label for (``NO_DOMAIN``) is dropped by the sum."""
     t = topo.num_topo
     n = topo.node_path.shape[0]  # tree node capacity; state may be padded larger
     seg = topo.node_path.reshape(-1)  # (N*L,)
@@ -186,10 +232,14 @@ def constrain_multiples(
     """Bottom-up rounding of each topology node's slots to its layer's
     pod-count multiple (``network_topology_solver.go:249
     doConstrainOfferSlot``): a node's slots become the sum of its children's
-    constrained slots, rounded down to the layer multiple."""
+    constrained slots, rounded down to the layer multiple.  A child may sit
+    more than one layer down (a node with no label for the layers between);
+    it is final by the time its parent's layer is rebuilt."""
     t = topo.num_topo
     num_layers = layer_multiples.shape[0]
     slots = topo_slots
+    parent_layer = topo.topo_layer[topo.topo_parent]
+    is_child = topo.topo_parent != jnp.arange(t)
 
     def round_layer(s, layer):
         m = jnp.maximum(layer_multiples[layer], 1)
@@ -199,7 +249,7 @@ def constrain_multiples(
     # Leaf layer rounds in place; each upper layer is rebuilt from children.
     slots = round_layer(slots, num_layers - 1)
     for layer in range(num_layers - 2, -1, -1):
-        child = topo.topo_layer == layer + 1
+        child = is_child & (parent_layer == layer)
         summed = jax.ops.segment_sum(
             jnp.where(child, slots, 0), topo.topo_parent, num_segments=t
         )
@@ -236,7 +286,7 @@ def _ancestor_chain_keys(topo: TopologyArrays, values: jnp.ndarray) -> jnp.ndarr
     (``network_topology_solver.go:334``)."""
     cols = []
     cur = jnp.arange(topo.num_topo)
-    for _ in range(topo.num_layers):
+    for _ in range(int(topo.node_path.shape[1])):
         cols.append(values[cur])
         cur = topo.topo_parent[cur]
     return jnp.stack(cols, axis=1)
@@ -260,12 +310,100 @@ def rank_candidates(
     sign = 1 if prefer_lower_slots else -1
     # lexsort: last key is the primary.
     keys = [jnp.arange(topo.num_topo), -sc]
-    for layer in range(topo.num_layers - 1, -1, -1):
+    num_layers = int(topo.node_path.shape[1])
+    for layer in range(num_layers - 1, -1, -1):
         keys.append(sign * sl[:, layer])
-    for layer in range(topo.num_layers - 1, -1, -1):
+    for layer in range(num_layers - 1, -1, -1):
         keys.append(-ex[:, layer])
     keys.append(~candidates)  # candidates first
     return jnp.lexsort(keys)
+
+
+#: rows of :func:`plan_prep`'s (5, T) output
+PREP_SLOTS, PREP_SCORES, PREP_EXISTING, PREP_CAND, PREP_RANKED = range(5)
+
+
+def plan_prep(
+    alloc: jnp.ndarray,
+    work: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+    node_valid: jnp.ndarray,
+    requests: jnp.ndarray,
+    feasible: jnp.ndarray,
+    member: jnp.ndarray,
+    topo: TopologyArrays,
+    desired: jnp.ndarray,
+    must_gather_layer: jnp.ndarray,
+    layer_multiples: jnp.ndarray,
+    node_scores: jnp.ndarray,
+    node_existing: jnp.ndarray,
+    cfg=None,
+) -> jnp.ndarray:
+    """The device half of one gang's plan, in one program: the members'
+    requests packed to the front, the nodes every member may use, offer
+    slots against the round's working accounting ``work`` = (requested,
+    usage, agg_usage), tree aggregation, layer multiples, eligibility and
+    ranking.  Returns (5, T) int32: rows ``PREP_*`` (the candidate mask as
+    0/1), one transfer for the host walk."""
+    p = requests.shape[0]
+    order = jnp.argsort(~member, stable=True)
+    n_members = member.sum()
+    gang_requests = jnp.where((jnp.arange(p) < n_members)[:, None],
+                              requests[order], 0)
+    # per-pod feasibility (affinity etc.) applies to the whole gang: a node
+    # any member cannot use offers no slots to the gather plan
+    node_ok = node_valid & ~jnp.any(member[:, None] & ~feasible, axis=0)
+    requested, usage, agg_usage = work
+    slots = _offer_slots(alloc, requested, usage, agg_usage, gang_requests,
+                         node_ok, cfg, n_members)
+    t_slots, t_scores, t_existing = aggregate_tree(
+        topo, slots, node_scores, node_existing)
+    t_slots = constrain_multiples(topo, t_slots, layer_multiples)
+    cand, _ = eligible_candidates(topo, t_slots, desired, must_gather_layer)
+    ranked = rank_candidates(topo, cand, t_slots, t_scores, t_existing)
+    return jnp.stack([t_slots, t_scores, t_existing,
+                      cand.astype(jnp.int32), ranked.astype(jnp.int32)])
+
+
+def charge_plan(
+    work: tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray],
+    requests: jnp.ndarray,
+    plan: jnp.ndarray,
+    cfg,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``work`` with one gang's plan taken: each planned pod's request on
+    its node's requested row and its estimated usage (``cfg``'s LoadAware
+    estimator) on the node's usage rows, as the solve will charge them, so
+    the next gang of the round is planned against what this one leaves."""
+    from koordinator_tpu.ops import scoring
+
+    requested, usage, agg_usage = work
+    on = plan >= 0
+    rows = jnp.where(on, plan, 0)
+    requested = requested.at[rows].add(jnp.where(on[:, None], requests, 0))
+    est = jnp.where(on[:, None], scoring.estimate_pod_usage_by_band(
+        requests, cfg.estimator_factors, cfg.estimator_defaults), 0)
+    return requested, usage.at[rows].add(est), agg_usage.at[rows].add(est)
+
+
+@dataclasses.dataclass(frozen=True)
+class HostTree:
+    """The tree's arrays on the host, with each topology node's children:
+    what the distribute walk reads, made once per tree build."""
+
+    parent: np.ndarray
+    layer: np.ndarray
+    topo_to_node: np.ndarray
+    children: dict
+
+    @classmethod
+    def of(cls, topo: TopologyArrays) -> "HostTree":
+        parent = np.asarray(topo.topo_parent)
+        children: dict[int, list[int]] = {}
+        for tid, p in enumerate(parent.tolist()):
+            if p != tid:
+                children.setdefault(p, []).append(tid)
+        return cls(parent, np.asarray(topo.topo_layer),
+                   np.asarray(topo.topo_to_node), children)
 
 
 def _distribute_host(
@@ -278,16 +416,12 @@ def _distribute_host(
     root: int,
     desired: int,
     layer_multiples: np.ndarray,
+    children: dict,
 ) -> tuple[list[int], list[int]]:
     """Recursive slot distribution over the chosen subtree
     (``network_topology_solver.go:353 distributeOfferSlot``). Host-side: only
-    touches the T-sized tree. Returns (ordered physical node ids, counts)."""
-    t = len(topo_parent)
-    children: dict[int, list[int]] = {}
-    for tid in range(t):
-        p = int(topo_parent[tid])
-        if p != tid:
-            children.setdefault(p, []).append(tid)
+    touches the T-sized tree (``children``: :attr:`HostTree.children`).
+    Returns (ordered physical node ids, counts)."""
 
     def chain(tid):
         out = [tid]
@@ -330,6 +464,59 @@ def _distribute_host(
     return (nodes, counts) if got >= desired else ([], [])
 
 
+def layer_multiples_of(req: TopologyRequirements, num_layers: int) -> np.ndarray:
+    """(L,) int32 pod-count multiple per layer, 1 where the gang sets none."""
+    mults = np.asarray(req.layer_multiples or (), np.int32)
+    return np.pad(mults, (0, num_layers - len(mults)), constant_values=1)
+
+
+def walk_ranked(prep: np.ndarray, host: HostTree, member_idx: np.ndarray,
+                desired: int, mults: np.ndarray, capacity: int) -> np.ndarray:
+    """The host half of one gang's plan: walk the ranked candidates of
+    :func:`plan_prep`'s output until one distributes fully.  Returns the
+    (``capacity``,) int32 planned node of each member, all -1 when no
+    candidate holds the gang."""
+    plan = np.full(capacity, -1, np.int32)
+    cand = prep[PREP_CAND].astype(bool)
+    if not cand.any():
+        return plan
+    for tid in prep[PREP_RANKED]:
+        if not cand[tid]:
+            break
+        nodes, counts = _distribute_host(
+            host.parent, host.layer, host.topo_to_node, prep[PREP_SLOTS],
+            prep[PREP_SCORES], prep[PREP_EXISTING], int(tid), desired, mults,
+            host.children)
+        if nodes:
+            flat = np.repeat(nodes, counts)[: len(member_idx)]
+            plan[member_idx[: len(flat)]] = flat
+            return plan
+    return plan
+
+
+def _prep_one(state, pods, gang_mask, topo, req, node_scores, node_existing,
+              cfg):
+    """(member_idx, desired, mults, :func:`plan_prep`'s output) of one gang
+    against ``state``."""
+    n = state.capacity
+    if node_scores is None:
+        node_scores = jnp.zeros(n, jnp.int32)
+    if node_existing is None:
+        node_existing = jnp.zeros(n, jnp.int32)
+    gang_mask = np.asarray(gang_mask)
+    member_idx = np.flatnonzero(gang_mask)
+    desired = req.desired_slots if req.desired_slots > 0 else len(member_idx)
+    mults = layer_multiples_of(req, topo.num_layers)
+    out = plan_prep(
+        state.node_allocatable,
+        (state.node_requested, state.node_usage, state.node_agg_usage),
+        state.node_valid, pods.requests, pods.feasible_rows(state),
+        jnp.asarray(gang_mask), topo, jnp.int32(desired),
+        jnp.int32(req.must_gather_layer), jnp.asarray(mults), node_scores,
+        node_existing, cfg)
+    return member_idx, desired, mults, out
+
+
 def gang_candidate_prep(
     state: ClusterState,
     pods: PodBatch,
@@ -342,56 +529,20 @@ def gang_candidate_prep(
 ):
     """Candidate-prep pipeline shared by BOTH gang planners (the
     baseline :func:`plan_gang_placement` and
-    quality/topo_gang.plan_gang_placement_quality): whole-gang node
-    feasibility intersection, desired-slots default, member-request
-    front-packing, layer-multiple padding, then the offer-slots ->
-    tree-aggregation -> multiples -> eligibility kernel chain.  One
-    implementation, so a feasibility or multiples fix can never land
-    in one planner and silently diverge the other.
+    quality/topo_gang.plan_gang_placement_quality): :func:`plan_prep`,
+    the whole-gang node feasibility intersection, member-request
+    front-packing, offer slots, tree aggregation, multiples and
+    eligibility.  One implementation, so a feasibility or multiples fix
+    can never land in one planner and silently diverge the other.
 
     Returns ``(member_idx, desired, mults, t_slots, t_scores,
     t_existing, cand)``; only candidate ORDER and the commit rule
     differ between planners downstream.
     """
-    n = state.capacity
-    node_valid = state.node_valid
-    if node_scores is None:
-        node_scores = jnp.zeros(n, jnp.int32)
-    if node_existing is None:
-        node_existing = jnp.zeros(n, jnp.int32)
-
-    gang_mask = np.asarray(gang_mask)
-    member_idx = np.flatnonzero(gang_mask)
-    # Per-pod feasibility (affinity etc.) applies to the whole gang: a node
-    # any member cannot use offers no slots to the gather plan.
-    if member_idx.size:
-        node_valid = node_valid & jnp.all(
-            pods.feasible_rows(state)[jnp.asarray(member_idx)], axis=0
-        )
-    desired = req.desired_slots if req.desired_slots > 0 else len(member_idx)
-    gang_requests = jnp.where(
-        jnp.asarray(gang_mask)[:, None], pods.requests, 0
-    )
-    # Pack member requests to the front so the prefix test sees them contiguously.
-    order = np.argsort(~gang_mask, kind="stable")
-    gang_requests = gang_requests[jnp.asarray(order)]
-
-    mults = jnp.asarray(
-        np.pad(
-            np.asarray(req.layer_multiples or (), np.int32),
-            (0, topo.num_layers - len(req.layer_multiples or ())),
-            constant_values=1,
-        )
-    )
-
-    slots = gang_offer_slots(state, gang_requests, node_valid, cfg)
-    t_slots, t_scores, t_existing = aggregate_tree(
-        topo, slots, node_scores, node_existing)
-    t_slots = constrain_multiples(topo, t_slots, mults)
-    cand, _ = eligible_candidates(
-        topo, t_slots, jnp.int32(desired), jnp.int32(req.must_gather_layer)
-    )
-    return member_idx, desired, mults, t_slots, t_scores, t_existing, cand
+    member_idx, desired, mults, out = _prep_one(
+        state, pods, gang_mask, topo, req, node_scores, node_existing, cfg)
+    return (member_idx, desired, jnp.asarray(mults), out[PREP_SLOTS],
+            out[PREP_SCORES], out[PREP_EXISTING], out[PREP_CAND] > 0)
 
 
 def plan_gang_placement(
@@ -409,32 +560,7 @@ def plan_gang_placement(
     (``network_topology_solver.go:53``): the plan is then fed to the solver
     one node at a time (the reference's FindOneNode path).
     """
-    member_idx, desired, mults, t_slots, t_scores, t_existing, cand = (
-        gang_candidate_prep(state, pods, gang_mask, topo, req,
-                            node_scores, node_existing, cfg))
-    ranked = rank_candidates(topo, cand, t_slots, t_scores, t_existing)
-
-    # Host-side: walk ranked candidates until one distributes fully.
-    cand_np = np.asarray(cand)
-    plan = np.full(pods.capacity, -1, np.int32)
-    if not cand_np.any():
-        return plan
-    parent_np = np.asarray(topo.topo_parent)
-    layer_np = np.asarray(topo.topo_layer)
-    t2n = np.asarray(topo.topo_to_node)
-    slots_np = np.asarray(t_slots)
-    scores_np = np.asarray(t_scores)
-    exist_np = np.asarray(t_existing)
-    mults_np = np.asarray(mults)
-    for tid in np.asarray(ranked):
-        if not cand_np[tid]:
-            break
-        nodes, counts = _distribute_host(
-            parent_np, layer_np, t2n, slots_np, scores_np, exist_np,
-            int(tid), desired, mults_np,
-        )
-        if nodes:
-            flat = np.repeat(nodes, counts)[: len(member_idx)]
-            plan[member_idx[: len(flat)]] = flat
-            return plan
-    return plan
+    member_idx, desired, mults, out = _prep_one(
+        state, pods, gang_mask, topo, req, node_scores, node_existing, cfg)
+    return walk_ranked(np.asarray(out), HostTree.of(topo), member_idx,
+                       desired, mults, pods.capacity)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from koordinator_tpu.ops.network_topology import (
+    HostTree,
     TopologyArrays,
     TopologyRequirements,
     _ancestor_chain_keys,
@@ -149,9 +150,7 @@ def plan_gang_placement_quality(
     cand_np = np.asarray(cand)
     if not cand_np.any():
         return plan
-    parent_np = np.asarray(topo.topo_parent)
-    layer_np = np.asarray(topo.topo_layer)
-    t2n = np.asarray(topo.topo_to_node)
+    host = HostTree.of(topo)
     slots_np = np.asarray(t_slots)
     scores_np = np.asarray(t_scores)
     exist_np = np.asarray(t_existing)
@@ -165,8 +164,8 @@ def plan_gang_placement_quality(
         if not cand_np[tid] or realized >= beam:
             break
         nodes, counts = _distribute_host(
-            parent_np, layer_np, t2n, slots_np, scores_np, exist_np,
-            int(tid), desired, mults_np,
+            host.parent, host.layer, host.topo_to_node, slots_np, scores_np,
+            exist_np, int(tid), desired, mults_np, host.children,
         )
         if not nodes:
             continue

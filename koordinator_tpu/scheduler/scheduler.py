@@ -32,9 +32,12 @@ from koordinator_tpu import journey, metrics, timeline, tracing
 from koordinator_tpu.ops.assignment import ScoringConfig
 from koordinator_tpu.ops.gang import GangInfo
 from koordinator_tpu.ops.network_topology import (
+    HostTree,
     TopologyArrays,
     TopologyRequirements,
-    plan_gang_placement,
+    TopologyTree,
+    layer_multiples_of,
+    walk_ranked,
 )
 from koordinator_tpu.quota.admission import QuotaDeviceState
 from koordinator_tpu.ops.deviceshare import split_request
@@ -129,6 +132,19 @@ class SchedulingResult:
     nominations: dict[str, tuple[str, list[str]]] = dataclasses.field(
         default_factory=dict
     )
+
+
+def _committed(tree, device):
+    """``tree``'s leaves as device arrays, each committed to ``device``;
+    where ``device`` is None (a state spread over a mesh) left for the
+    program to place."""
+    def commit(x):
+        if device is None:
+            return jnp.asarray(x)
+        if isinstance(x, jax.Array) and x.committed:
+            return x
+        return jax.device_put(x, device)
+    return jax.tree.map(commit, tree)
 
 
 def _wants_device(pod) -> bool:
@@ -291,6 +307,7 @@ class Scheduler:
         batch_solver_threshold: int = 1024,
         clock=time.monotonic,
         topology_tree: TopologyArrays | None = None,
+        topology_layers: list[str] | None = None,
         barrier=None,
         debug_service=None,
         hints=None,
@@ -346,6 +363,17 @@ class Scheduler:
         self.batch_solver_threshold = batch_solver_threshold
         self.clock = clock
         self.topology_tree = topology_tree
+        #: label keys of the network-topology layers, top-down, between
+        #: the cluster root and the node (ClusterNetworkTopology's layers):
+        #: when given, ``topology_tree`` is built from the nodes' labels
+        #: and grown as nodes join (``_current_topology``)
+        self.topology_layers = list(topology_layers or ()) or None
+        self._topo_builder = (TopologyTree([*self.topology_layers, "node"])
+                              if self.topology_layers else None)
+        #: the snapshot's labels_rev the tree was last built at
+        self._topo_rev = -1
+        #: (tree, its HostTree) of the last plan round
+        self._topo_host: tuple[TopologyArrays, HostTree] | None = None
 
         #: startup sync barrier (barrier.SyncBarrier) — rounds no-op until
         #: the informer replays past it
@@ -1425,6 +1453,38 @@ class Scheduler:
         return QuotaDeviceState.from_tree(self.quota_tree)
 
     # koordlint: guarded-by(self.lock)
+    def _current_topology(self) -> TopologyArrays | None:
+        """The network-topology tree the round plans on: the one given at
+        construction, or the one built from the nodes' labels under
+        ``topology_layers`` (``networktopology/tree.go``), rebuilt only
+        when a node joined or left or its labels changed, and sized to
+        the node capacity.  A node without a label at a layer hangs from
+        the deepest layer it has one for."""
+        if self._topo_builder is None:
+            return self.topology_tree
+        snap = self.snapshot
+        tree = self.topology_tree
+        if (self._topo_rev == snap.labels_rev and tree is not None
+                and tree.node_path.shape[0] == snap.capacity):
+            return tree
+        builder, keys = self._topo_builder, self.topology_layers
+        with timeline.RECORDER.section("host_other", "topo.tree",
+                                       self.tenant, n=len(snap.node_index)):
+            live = {}
+            for name, row in snap.node_index.items():
+                labels = snap.node_specs[name].labels
+                live[row] = [labels.get(k) or None for k in keys] + [name]
+            for row in [r for r in builder.rows() if r not in live]:
+                builder.drop_node(row)
+            for row, path in live.items():
+                builder.set_node(row, path)
+            self.topology_tree = builder.build(capacity=snap.capacity)
+        self._topo_rev = snap.labels_rev
+        metrics.topology_tree_nodes.set(float(builder.num_topo),
+                                        labels=self._tl())
+        return self.topology_tree
+
+    # koordlint: guarded-by(self.lock)
     def _apply_topology_plans(
         self, batch: PodBatch, gang_index: dict[str, int]
     ) -> PodBatch:
@@ -1432,56 +1492,102 @@ class Scheduler:
         ``coscheduling.go:137-144``): a gang with network-topology
         requirements gets a placement plan up front; each member's feasible
         set is pinned to its planned node. A gang whose plan fails is masked
-        out of the round entirely (all-or-nothing at plan level)."""
-        if self.topology_tree is None:
+        out of the round entirely (all-or-nothing at plan level).
+
+        The round's gangs are planned one after another in the scheduler's
+        gang order (``gang_index``, the GangInfo rows: by name), each against
+        what the plans before it leave: a plan is charged into a working
+        copy of the round's accounting on the device before the next gang
+        is planned, so two gangs never get the same free capacity."""
+        topo = self._current_topology()
+        if topo is None:
             return batch
         # densifying the factored mask is O(P·N): skip it entirely unless
         # some gang in this round actually carries topology requirements
-        if not any(
-            self.gangs.get(name) is not None
-            and self.gangs[name].topology is not None
-            for name in gang_index
-        ):
+        wanted = [(gi, self.gangs[name].topology)
+                  for name, gi in gang_index.items()
+                  if self.gangs.get(name) is not None
+                  and self.gangs[name].topology is not None]
+        if not wanted:
             return batch
         gang_ids = np.asarray(batch.gang_id)
-        feasible = np.array(batch.feasible_rows(self.snapshot.state))
         valid = np.array(batch.valid)
-        changed = False
-        for name, gi in gang_index.items():
-            gang = self.gangs.get(name)
-            if gang is None or gang.topology is None:
-                continue
-            mask = (gang_ids == gi) & valid
-            if not mask.any():
-                continue
-            # quality mode swaps in the rank-aware, topology-distance
-            # planner (quality/topo_gang): same feasibility kernels,
-            # minimal-diameter commit rule
-            if self.quality_mode != "off":
-                from koordinator_tpu.quality.topo_gang import (
-                    plan_gang_placement_quality,
-                )
-
-                plan_fn = plan_gang_placement_quality
-            else:
-                plan_fn = plan_gang_placement
-            plan = plan_fn(
-                self.snapshot.state, batch, mask, self.topology_tree,
-                gang.topology, cfg=self.config,
-            )
-            changed = True
-            desired = gang.topology.desired_slots or int(mask.sum())
-            planned = np.flatnonzero(mask & (plan >= 0))
-            if len(planned) < min(desired, int(mask.sum())):
-                # no gather plan at all -> the whole gang backs off
-                valid[mask] = False
-                continue
-            # pin planned members; surplus members (pending > desired_slots)
-            # stay unpinned and schedule freely once the gang is permitted
-            feasible[planned] = False
-            feasible[planned, plan[planned]] = True
-        if not changed:
+        todo = [(req, mask) for gi, req in wanted
+                if (mask := (gang_ids == gi) & valid).any()]
+        if not todo:
             return batch
+        state = self.snapshot.state
+        feasible_dev = batch.feasible_rows(state)
+        feasible = np.array(feasible_dev)
+        if self._topo_host is None or self._topo_host[0] is not topo:
+            self._topo_host = (topo, HostTree.of(topo))
+        host = self._topo_host[1]
+        # a jitted program's cache is keyed on which arguments are committed
+        # to the device, and a round's state is committed or not by how it
+        # was last written (a host flush, a fold, a solve): committing every
+        # argument keeps the two plan programs at one entry each
+        on = state.node_allocatable.devices()
+        device = next(iter(on)) if len(on) == 1 else None
+        requests, feasible_dev, alloc, valid_dev, cfg, topo_dev, no_score = (
+            _committed((batch.requests, feasible_dev, state.node_allocatable,
+                        state.node_valid, self.config, topo,
+                        np.zeros(state.capacity, np.int32)), device))
+        work = _committed((state.node_requested, state.node_usage,
+                           state.node_agg_usage), device)
+        tl, tenant = timeline.RECORDER, self.tenant
+        t_plan = tl.open("topo.plan")
+        try:
+            for req, mask in todo:
+                member_idx = np.flatnonzero(mask)
+                desired = req.desired_slots or len(member_idx)
+                if self.quality_mode != "off":
+                    # quality mode swaps in the rank-aware, topology-
+                    # distance planner (quality/topo_gang): same
+                    # feasibility kernels, minimal-diameter commit rule
+                    from koordinator_tpu.quality.topo_gang import (
+                        plan_gang_placement_quality,
+                    )
+
+                    with tl.section("build_batch", "topo.walk", tenant):
+                        plan = plan_gang_placement_quality(
+                            state.replace(node_requested=work[0],
+                                          node_usage=work[1],
+                                          node_agg_usage=work[2]),
+                            batch, mask, topo, req, cfg=self.config)
+                else:
+                    mults = layer_multiples_of(req, topo.num_layers)
+                    with tl.section("build_batch", "topo.prep", tenant):
+                        member, want, gather, mult = _committed((
+                            mask, np.int32(desired),
+                            np.int32(req.must_gather_layer), mults), device)
+                        prep = np.asarray(self.kit.topo_prep(
+                            alloc, work, valid_dev, requests, feasible_dev,
+                            member, topo_dev, want, gather, mult, no_score,
+                            no_score, cfg))
+                    with tl.section("build_batch", "topo.walk", tenant):
+                        plan = walk_ranked(prep, host, member_idx, desired,
+                                           mults, batch.capacity)
+                planned = np.flatnonzero(mask & (plan >= 0))
+                if len(planned) < min(desired, len(member_idx)):
+                    # no gather plan at all -> the whole gang backs off
+                    valid[mask] = False
+                    metrics.topology_plans.inc(labels=dict(
+                        self._tl() or {}, outcome="no_fit"))
+                    continue
+                metrics.topology_plans.inc(labels=dict(
+                    self._tl() or {}, outcome="planned"))
+                with tl.section("build_batch", "topo.charge", tenant):
+                    work = self.kit.topo_charge(
+                        work, requests, _committed(plan, device), cfg)
+                # pin planned members; surplus members (pending >
+                # desired_slots) stay unpinned and schedule freely once
+                # the gang is permitted
+                feasible[planned] = False
+                feasible[planned, plan[planned]] = True
+        finally:
+            # a record of its own per round: its extent is what a reader
+            # lays over the device's operations
+            tl.close(t_plan, "build_batch", tenant, n=len(todo), merge=False)
         # topology pinning needs per-(pod, node) edits: densify the mask
         return batch.replace(
             feasible=jnp.asarray(feasible), valid=jnp.asarray(valid),

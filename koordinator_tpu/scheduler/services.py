@@ -12,6 +12,7 @@ and the runtime-togglable top-N score dump.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import threading
 import time
@@ -427,7 +428,9 @@ class DebugService:
         return [
             {"name": g.name, "min_member": g.min_member,
              "rejected": g.rejected,
-             "first_failure": g.first_failure}
+             "first_failure": g.first_failure,
+             "topology": (dataclasses.asdict(g.topology)
+                          if g.topology is not None else None)}
             for g in self.scheduler.gangs.values()
         ]
 

@@ -127,6 +127,9 @@ class ClusterSnapshot:
         self.node_index: dict[str, int] = {}
         self._row_to_name: dict[int, str] = {}
         self.node_specs: dict[str, NodeSpec] = {}
+        #: bumped whenever a row gets a node or loses it, or a node's
+        #: labels change: what a tree built from labels keys its rebuild on
+        self.labels_rev = 0
         self._free_rows: list[int] = list(range(capacity - 1, -1, -1))
         self._dirty: set[int] = set()
         #: rows whose solver-visible state changed since the incremental
@@ -353,6 +356,9 @@ class ClusterSnapshot:
                 self.device_manager.node_row_added(spec.name)
             if self.cpu_manager is not None:
                 self.cpu_manager.node_row_added(spec.name)
+        old = self.node_specs.get(spec.name)
+        if old is None or old.labels != spec.labels:
+            self.labels_rev += 1
         self.node_specs[spec.name] = spec
         self._class_of(spec)  # register the equivalence class up front
         self._dirty.add(row)
@@ -365,6 +371,7 @@ class ClusterSnapshot:
             return
         del self.node_specs[name]
         del self._row_to_name[row]
+        self.labels_rev += 1
         self._free_rows.append(row)
         self._dirty.add(row)
         self._cand_dirty.add(row)

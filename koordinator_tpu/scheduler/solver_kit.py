@@ -134,6 +134,19 @@ class SolverKit:
                     donate_argnums=(0,)),
             "gang_assign", shape_of=_pn)
 
+        # a round's network-topology gang plans: the device half of one
+        # gang's plan, and the charge of a plan into the round's working
+        # accounting before the next gang is planned
+        from koordinator_tpu.ops import network_topology as _nt
+
+        self.topo_prep = insp.instrument(
+            jax.jit(_nt.plan_prep), "topo_plan_prep",
+            shape_of=lambda a, k: (f"P{a[3].shape[0]}xN{a[0].shape[0]}"
+                                   f"xT{a[6].num_topo}"))
+        self.topo_charge = insp.instrument(
+            jax.jit(_nt.charge_plan), "topo_charge_plan",
+            shape_of=lambda a, k: f"P{a[1].shape[0]}xN{a[0][0].shape[0]}")
+
         # the incremental stages: selection recall-exact on the mesh,
         # acceptance bit-identical (parallel/sharded.py)
         self._select_scored_one = insp.instrument(

@@ -452,15 +452,16 @@ class TimelineRecorder:
         return now
 
     def close(self, t0: float, cause: str, tenant: str = "",
-              n: int = 1) -> None:
-        """Pop the span :meth:`open` pushed and record it."""
+              n: int = 1, merge: bool = True) -> None:
+        """Pop the span :meth:`open` pushed and record it; ``merge=False``
+        as in :meth:`add`."""
         if not t0:
             return
         t1 = _perf_counter()
         st = self._tls.state
         node = st.stack.pop()
         if self._enabled and t1 > t0:
-            self._record(st, node, t0, t1, cause, tenant, n)
+            self._record(st, node, t0, t1, cause, tenant, n, merge)
 
     def wait(self, name: str, t_queued: float, t_taken: float,
              n: int = 1) -> None:

@@ -42,6 +42,9 @@ POD_ADD = "pod_add"
 POD_REMOVE = "pod_remove"
 RSV_UPSERT = "rsv_upsert"
 RSV_REMOVE = "rsv_remove"
+#: a gang's record (PodGroup: min-member, network-topology requirement),
+#: registered before its members arrive
+GANG = "gang"
 
 
 class ResyncRequired(Exception):
@@ -127,7 +130,7 @@ def _pack_events(
 
 _KIND_CODES = {NODE_UPSERT: 0, NODE_USAGE: 1, NODE_ALLOC: 2,
                NODE_DEVICES: 3, NODE_REMOVE: 4, POD_ADD: 5,
-               POD_REMOVE: 6, RSV_UPSERT: 7, RSV_REMOVE: 8}
+               POD_REMOVE: 6, RSV_UPSERT: 7, RSV_REMOVE: 8, GANG: 9}
 _CODE_KINDS = {v: k for k, v in _KIND_CODES.items()}
 
 #: per-kind default fields elided from the wire and reconstructed at
@@ -142,6 +145,7 @@ _V2_DEFAULTS: dict[str, dict] = {
     RSV_UPSERT: {"owners": [], "allocate_once": False, "ttl_sec": None,
                  "node": None, "node_selector": {}, "tolerations": {},
                  "restricted": False},
+    GANG: {"topology": None},
 }
 
 
@@ -317,6 +321,35 @@ def _validate_devices(devices: dict | None, context: str) -> None:
                         f"field {field!r} must be an integer")
 
 
+def _validate_gang(min_member, topology, context: str) -> None:
+    """A gang record's fields, checked before it commits (a malformed one
+    would crash every sync client's binding on replay)."""
+    if not wire.check_field_type(min_member, int) or min_member < 1:
+        raise wire.WireSchemaError(
+            f"{context}: 'min_member' must be a positive integer, "
+            f"got {min_member!r}")
+    if topology is None:
+        return
+    if not wire.check_field_type(topology, dict):
+        raise wire.WireSchemaError(
+            f"{context}: 'topology' must be an object or absent")
+    unknown = set(topology) - {"desired_slots", "must_gather_layer",
+                               "layer_multiples"}
+    if unknown:
+        raise wire.WireSchemaError(
+            f"{context}: 'topology' has unknown fields {sorted(unknown)}")
+    for field in ("desired_slots", "must_gather_layer"):
+        if not wire.check_field_type(topology.get(field, 0), int):
+            raise wire.WireSchemaError(
+                f"{context}: topology {field!r} must be an integer")
+    mults = topology.get("layer_multiples", [])
+    if not isinstance(mults, list) or any(
+            not wire.check_field_type(m, int) or m < 1 for m in mults):
+        raise wire.WireSchemaError(
+            f"{context}: topology 'layer_multiples' must be a list of "
+            f"positive integers")
+
+
 def _pack_for(events: list[tuple[int, dict, dict[str, np.ndarray]]],
               v4: bool) -> tuple[dict, dict[str, np.ndarray]]:
     """``events`` packed in a peer's negotiated form: columnar at proto
@@ -360,6 +393,7 @@ class StateSyncService:
         self.nodes: dict[str, dict] = {}      # name -> {doc, arrays}
         self.pods: dict[str, dict] = {}       # name -> {doc, arrays}
         self.reservations: dict[str, dict] = {}
+        self.gangs: dict[str, dict] = {}
         self._server = None
         self._local_bindings: list = []
         #: committed events awaiting local-binding apply; populated under
@@ -802,6 +836,23 @@ class StateSyncService:
             lambda: self.reservations.pop(name, None),
             {"kind": RSV_REMOVE, "name": name}, {})
 
+    def upsert_gang(self, name: str, min_member: int,
+                    topology: dict | None = None) -> int:
+        """A gang's record (the PodGroup's minMember and the job's
+        network-topology requirement: ``desired_slots``,
+        ``must_gather_layer``, ``layer_multiples``, as
+        ``ops/network_topology.TopologyRequirements``).  Pushed before
+        its members' ``pod_add`` events, so the scheduler holds it when
+        they arrive; a snapshot replays it before every pod."""
+        _validate_gang(min_member, topology, "upsert_gang")
+        doc = {"kind": GANG, "name": name, "min_member": int(min_member),
+               "topology": dict(topology) if topology else None}
+
+        def store():
+            self.gangs[name] = {"doc": doc, "arrays": {}}
+
+        return self._store_and_commit(store, doc, {})
+
     # -- wire handlers -------------------------------------------------------
 
     def attach(self, server) -> None:
@@ -985,6 +1036,9 @@ class StateSyncService:
                 restricted=bool(doc.get("restricted", False)))
         elif kind == RSV_REMOVE:
             rv = self.remove_reservation(name)
+        elif kind == GANG:
+            rv = self.upsert_gang(name, doc.get("min_member"),
+                                  doc.get("topology"))
         else:
             raise wire.WireSchemaError(f"unknown state-push kind {kind!r}")
         return {"rv": rv}, None
@@ -993,9 +1047,11 @@ class StateSyncService:
                   ) -> tuple[dict, dict[str, np.ndarray]]:
         events = []
         # replay order matters: nodes before reservations (placement needs
-        # rows) before pods (owners need Available reservations)
+        # rows) before pods (owners need Available reservations); a gang's
+        # record before its members
         for entry in (list(self.nodes.values())
                       + list(self.reservations.values())
+                      + list(self.gangs.values())
                       + list(self.pods.values())):
             events.append((self.rv, entry["doc"], entry["arrays"]))
         doc, arrays = pack(events)
@@ -1250,7 +1306,7 @@ _RUN_METHODS = {NODE_USAGE: "node_usage_run", POD_ADD: "pod_add_run",
 #: taken on every event)
 _SPAN_NAMES = {kind: f"sync.{kind}" for kind in (
     NODE_UPSERT, NODE_USAGE, NODE_ALLOC, NODE_DEVICES, NODE_REMOVE,
-    POD_ADD, POD_REMOVE, RSV_UPSERT, RSV_REMOVE)}
+    POD_ADD, POD_REMOVE, RSV_UPSERT, RSV_REMOVE, GANG)}
 
 
 def _dispatch_events(binding, items: list[tuple[dict, dict]]) -> None:
@@ -1371,6 +1427,8 @@ def _route_event(binding, entry: dict,
         binding.reservation_upsert(entry, arrs)
     elif kind == RSV_REMOVE:
         binding.reservation_remove(entry["name"])
+    elif kind == GANG:
+        binding.gang_upsert(entry)
     # staleness watchdog feed: every applied event — remote watch OR
     # in-process drain — is evidence the state feed is alive
     mark = getattr(binding, "note_sync_event", None)
@@ -1644,3 +1702,18 @@ class SchedulerBinding:
 
     def reservation_remove(self, name: str) -> None:
         self.scheduler.remove_reservation(name)
+
+    def gang_upsert(self, entry: dict) -> None:
+        from koordinator_tpu.ops.network_topology import (
+            TopologyRequirements,
+        )
+        from koordinator_tpu.scheduler.scheduler import GangRecord
+
+        topology = entry.get("topology")
+        self.scheduler.register_gang(GangRecord(
+            name=entry["name"], min_member=int(entry["min_member"]),
+            topology=None if topology is None else TopologyRequirements(
+                desired_slots=int(topology.get("desired_slots", 0)),
+                must_gather_layer=int(topology.get("must_gather_layer", -1)),
+                layer_multiples=tuple(
+                    int(m) for m in topology.get("layer_multiples", ())))))
