@@ -220,7 +220,11 @@ def numa_score(
 # SingleNUMANode takes only inside one NUMA node; every other policy is
 # taken as none.  Exclusivity: a CPU on a core an exclusive (PCPU-level)
 # pod holds is nobody's, and an exclusive pod takes only CPUs of cores
-# nobody holds; a NUMA-level pod is held to PCPU level here.
+# nobody holds; a NUMA-level pod is held to PCPU level here.  Every
+# lookup along a node's CPU axis (a core's siblings, a CPU's NUMA pool,
+# the n-th key) is a compare against the ids and a reduce, never a
+# gather: a TPU gathers along the minor axis one element at a time, and
+# C <= MAX_CPUS_PER_NODE caps the (X, C, C) compare.
 
 #: a CPU's exclusive holders count in ``CPUState.held`` in units of this
 EXCL_UNIT = 1 << 8
@@ -311,23 +315,28 @@ def pod_cpu_requests(pods) -> PodCPURequests:
                & ((qos == int(QoSClass.LSR)) | (qos == int(QoSClass.LSE)))))
 
 
-def _core_sum(x, sib):
-    """(X, C): ``x`` summed over each CPU's core, by the sibling table."""
-    x_, c, t = sib.shape
-    got = jnp.take_along_axis(
-        x, jnp.maximum(sib, 0).reshape(x_, c * t), axis=1).reshape(x_, c, t)
-    return jnp.sum(jnp.where(sib >= 0, got, 0), axis=-1)
+def _on_core(sib):
+    """(X, C, C) bool: ``on[x, d, c]``, CPU d is one of CPU c's siblings
+    (the sibling table compared against the CPU ids)."""
+    cpu = jnp.arange(sib.shape[1], dtype=sib.dtype)
+    return jnp.any(sib[:, None, :, :] == cpu[None, :, None, None], axis=-1)
+
+
+def _core_sum(x, on):
+    """(X, C): ``x`` summed over each CPU's core (``on``: :func:`_on_core`)."""
+    return jnp.sum(jnp.where(on, x[:, :, None], 0), axis=1)
 
 
 def _eligible(cpus: CPUState, held):
     """The three eligibility masks of :attr:`PodCPURequests.kind` over
     rows of the plane ((X, C) each) against ``held``."""
+    on = _on_core(cpus.sib)
     ref = held % EXCL_UNIT
-    core_ref = _core_sum(ref, cpus.sib)
-    core_excl = _core_sum(held // EXCL_UNIT, cpus.sib)
+    core_ref = _core_sum(ref, on)
+    core_excl = _core_sum(held // EXCL_UNIT, on)
     base = cpus.valid & (ref < cpus.max_ref[:, None]) & (core_excl == 0)
     core_size = jnp.sum(cpus.sib >= 0, axis=-1)
-    whole = base & (_core_sum(base.astype(jnp.int32), cpus.sib) == core_size)
+    whole = base & (_core_sum(base.astype(jnp.int32), on) == core_size)
     return base, whole, base & (core_ref == 0)
 
 
@@ -409,18 +418,18 @@ def grant_cpu_rows(rows: CPUState, held, req: PodCPURequests):
     kind = req.kind[:, None]
     eligible = jnp.where(kind == 2, free_x, jnp.where(kind == 1, whole, base))
     n = _n_eff(req.n, req.full, rows.tpc)                      # (P,)
-    pool = jnp.take_along_axis(_numa_counts(eligible, rows.numa),
-                               rows.numa, axis=1)              # (P, C)
+    # the eligible CPUs of each CPU's NUMA node, selected by its id
+    at_numa = rows.numa[:, None, :] == jnp.arange(
+        MAX_NUMA, dtype=rows.numa.dtype)[None, :, None]         # (P, M, C)
+    pool = jnp.sum(jnp.where(
+        at_numa, _numa_counts(eligible, rows.numa)[:, :, None], 0),
+        axis=1)                                                 # (P, C)
     sat = (pool >= n[:, None]) & eligible
     eligible = jnp.where(rows.single_numa[:, None], sat, eligible)
-    cpu = jnp.arange(c, dtype=jnp.int32)[None, :]
+    cpu = jnp.arange(c, dtype=jnp.int32)
     # a CPU's rank among the eligible CPUs of its core below it
-    x_, _, t = rows.sib.shape
-    sib = rows.sib
-    sib_ok = jnp.take_along_axis(
-        eligible, jnp.maximum(sib, 0).reshape(x_, c * t),
-        axis=1).reshape(x_, c, t)
-    rank = jnp.sum((sib >= 0) & (sib < cpu[:, :, None]) & sib_ok, axis=-1)
+    below = _on_core(rows.sib) & (cpu[:, None] < cpu)         # (P, C, C)
+    rank = jnp.sum(below & eligible[:, :, None], axis=1, dtype=jnp.int32)
     intra = jnp.where(req.spread[:, None],
                       (rank * c + rows.core) * c + cpu,
                       rows.core * c + cpu)
@@ -430,8 +439,8 @@ def grant_cpu_rows(rows: CPUState, held, req: PodCPURequests):
            | rows.numa << 16
            | intra)
     ranked = jnp.sort(key, axis=-1)
-    nth = jnp.take_along_axis(ranked, jnp.clip(n - 1, 0, c - 1)[:, None],
-                              axis=1)
+    nth = jnp.sum(jnp.where(cpu == jnp.clip(n - 1, 0, c - 1)[:, None],
+                            ranked, 0), axis=-1, keepdims=True)
     ok = req.wants & (n >= 1) & (jnp.sum(eligible, axis=-1) >= n)
     return (key <= nth) & eligible & ok[:, None], ok
 

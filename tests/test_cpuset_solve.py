@@ -415,6 +415,58 @@ def test_the_loop_counts_the_rows_of_its_blocks():
     assert int(oracle(*args)[3]) == 5 * 64
 
 
+# -- the kernels against their gather forms ----------------------------------
+
+
+@pytest.mark.parametrize("solver", ["batch", "greedy"])
+@pytest.mark.parametrize("seed", [41, 42])
+def test_a_solve_grants_what_the_gather_forms_granted(seed, solver,
+                                                      monkeypatch):
+    """48 pinned pods on 12 fragmented nodes: the propose/accept rounds
+    (their Reserve loop in blocks of 8) or the exact scan (its per-step
+    take and one-column pools) give the same assignments, selections,
+    lost races, turns, rows granted on, scan steps and plane with the
+    compare-and-reduce kernels as with their gather forms
+    (``tests/numa_reference.py``) in their place."""
+    import jax
+
+    from koordinator_tpu.ops import batch_assign as ba
+    from tests import numa_reference
+
+    _, state = fragmented(seed)
+    rng = np.random.default_rng(seed + 300)
+    asks = [(int(rng.choice([1, 2, 4, 6, 8])), int(rng.choice([FULL, SPREAD])),
+             bool(rng.random() < 0.5)) for _ in range(48)]
+    pods = pinned_batch(asks, capacity=64,
+                        priority=rng.integers(0, 100, len(asks)))
+    fn = ba.batch_assign if solver == "batch" else greedy_assign
+    monkeypatch.setattr(ba, "ACCEPT_BLOCK", 8)
+
+    def solve():
+        # a fresh function each call: the patch below must be traced
+        a, new_state, _, grants, *stats = jax.jit(
+            lambda *args: fn(*args, with_grants=True))(
+                state, pods, ScoringConfig.default())
+        return {"assignments": a, "held": new_state.cpus.held,
+                "selection": grants.cpus.selection,
+                "lost_races": grants.cpus.lost_races,
+                "turn": grants.cpus.turn, "accept_rows": grants.accept_rows,
+                "steps": stats[0].steps if stats else 0}
+
+    got = solve()
+    traced: list[str] = []
+    numa_reference.patch(monkeypatch, traced)
+    want = solve()
+    assert {"_eligible", "grant_cpu_rows"} <= set(traced)
+    for name in got:
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(want[name]), name)
+    placed = int((np.asarray(got["assignments"])[:48] >= 0).sum())
+    assert 8 < placed < 48, placed
+    assert np.asarray(got["selection"]).any()
+    assert solver == "greedy" or np.asarray(got["lost_races"]).any()
+
+
 # -- through the scheduler ----------------------------------------------------
 
 
